@@ -1,13 +1,11 @@
 #!/usr/bin/env bash
-# Accelerated end-to-end golden run (analog of ci/gpu/cuda_test.sh:29-42):
-# polish lambda-phage through the device aligner + device consensus and
-# byte-diff the FASTA against the recorded device golden. Bit-identical
-# on the CPU mesh (XLA kernels) and on real TPU (Pallas kernels).
+# Accelerated end-to-end run (analog of ci/gpu/cuda_test.sh:29-42) on a
+# machine with a TPU: chip_smoke.py polishes a simulated 2 Mbp assembly
+# through the device aligner + device consensus and fails unless the run
+# really happened on the chip (platform, probes, Mosaic dispatch counts,
+# host rejects, quality vs truth, second-run byte identity). Inputs come
+# from the seeded simulator; nothing is read from outside the checkout.
+# One process per chip: run nothing else that needs the device beside it.
 set -e
 cd "$(dirname "$0")/../.."
-DATA=/root/reference/test/data
-python -m racon_tpu -t 8 -c 1 --tpualigner-batches 1 \
-  "$DATA/sample_reads.fastq.gz" "$DATA/sample_overlaps.paf.gz" \
-  "$DATA/sample_layout.fasta.gz" > /tmp/ci_tpu_out.fasta
-cmp /tmp/ci_tpu_out.fasta tests/data/golden_lambda_fastq_paf_device.fasta
-echo "device golden: OK"
+python chip_smoke.py

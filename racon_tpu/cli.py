@@ -82,16 +82,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "manifest through the lease protocol (implies "
                         "the shard runner; default: every local device "
                         "when a device backend is in use on multi-chip "
-                        "hardware, 1 otherwise; RACON_TPU_CHIPS is the "
+                        "hardware, 1 otherwise; more chips than are "
+                        "present is an error; RACON_TPU_CHIPS is the "
                         "env equivalent)")
     p.add_argument("--compile-cache", metavar="DIR", default=None,
                    help="persistent XLA compilation cache directory: "
                         "kernels compiled once are reloaded by every "
                         "later run/process, so warm starts skip the "
-                        "tens-of-seconds cold compile "
-                        "(RACON_TPU_COMPILE_CACHE is the env "
-                        "equivalent; default ~/.cache/racon_tpu_xla, "
-                        "RACON_TPU_NO_COMPILE_CACHE=1 disables)")
+                        "tens-of-seconds cold compile (default "
+                        "<checkout>/.xla_cache; JAX's own "
+                        "JAX_COMPILATION_CACHE_DIR, when set, places "
+                        "the cache instead and this option yields to "
+                        "it; RACON_TPU_NO_COMPILE_CACHE=1 disables)")
     p.add_argument("--profile", metavar="DIR", default=None,
                    help="write a jax.profiler trace of the polishing run "
                         "to DIR (view with TensorBoard / xprof; the TPU "
@@ -290,6 +292,23 @@ def _secondary_argv(argv, n: int):
     return [child] * n
 
 
+def _announce_device(args) -> None:
+    """One stderr line when a device backend is selected: where the
+    kernels will really run (platform, device kind, device count) and
+    which kernel family (Pallas on = Mosaic kernels, off = their XLA
+    twins). ``pallas_ok()`` runs the TPU's bit-exactness probe here, up
+    front, so a broken kernel fails the run before any work starts."""
+    if args.tpualigner_batches <= 0 and args.tpupoa_batches <= 0:
+        return
+    import jax
+
+    from .ops.pallas_nw import pallas_ok
+    devs = jax.devices()
+    print(f"[racon_tpu] device backend: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind!r} devices={len(devs)} "
+          f"pallas={'on' if pallas_ok() else 'off'}", file=sys.stderr)
+
+
 def _run_sharded(args, argv, trace_path, report_path, t_start, t0) -> int:
     """Route through the streaming shard runner (racon_tpu.exec)."""
     import subprocess
@@ -378,8 +397,8 @@ def main(argv=None) -> int:
     if args.compile_cache:
         # re-point the persistent XLA cache before anything compiles
         # (the import-time default already armed it; an explicit DIR
-        # wins — the daemon-mode prerequisite for compile-free warm
-        # starts)
+        # wins over the in-checkout default, and yields to
+        # JAX_COMPILATION_CACHE_DIR)
         from . import ops
         ops.configure_compile_cache(args.compile_cache)
 
@@ -420,6 +439,7 @@ def main(argv=None) -> int:
             parser.error("--serve and --submit are mutually exclusive")
         from .exec import parse_ram
         from .serve.service import PolishServer
+        _announce_device(args)
         server = PolishServer(
             args.serve,
             match=args.match, mismatch=args.mismatch, gap=args.gap,
@@ -464,6 +484,8 @@ def main(argv=None) -> int:
         except (ValueError, RuntimeError, OSError) as e:
             print(f"[racon_tpu::serve] error: {e}", file=sys.stderr)
             return 1
+
+    _announce_device(args)
 
     # RACON_TPU_CHIPS is documented as the --chips env equivalent, so
     # it must also route the run into the shard runner (where the chip

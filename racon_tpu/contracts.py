@@ -64,7 +64,8 @@ METRICS: FrozenSet[str] = frozenset((
     "align.chunks", "align.lanes_occupied", "align.lanes_total",
     "align.steps_wasted", "align.wavefront_work",
     "aligner.band_escalated", "aligner.capacity_scale",
-    "aligner.fallback_band", "aligner.ladder_narrow",
+    "aligner.fallback_band", "aligner.fallback_length",
+    "aligner.ladder_narrow", "aligner.pallas_chunks",
     "aligner.swar_chunks", "aligner.swar_guard_int32",
     # XLA compile attribution
     "compile.backend_total", "compile.jax_s",
@@ -73,7 +74,8 @@ METRICS: FrozenSet[str] = frozenset((
     "consensus.fallback_windows", "consensus.group_windows",
     "consensus.groups", "consensus.ins_overflow",
     "consensus.ins_overflow_windows", "consensus.lanes_occupied",
-    "consensus.lanes_total", "consensus.swar_guard_int32",
+    "consensus.lanes_total", "consensus.pallas_groups",
+    "consensus.swar_guard_int32",
     "consensus.sweep_truncated", "consensus.wavefront_steps",
     # device-resident align->consensus dataflow
     "dataflow.bytes_avoided", "dataflow.bytes_fetched",

@@ -140,6 +140,21 @@ def _auto_mesh(mesh):
     return None
 
 
+def _require_native(what: str) -> None:
+    """The device engines hand their rejects (band escapes, over-long
+    pairs, overflowed windows) to the native host engines — racon's
+    accelerator->CPU contract. Without the native core those would be
+    the pure-Python engines (seconds per overlap), a silent
+    orders-of-magnitude downgrade, so ``backend="tpu"`` requires it."""
+    if not native.available():
+        raise ValueError(
+            f"TPU {what} backend needs the native host core for its "
+            f"reject path, and it is unavailable (g++ missing or the "
+            f"build failed — see the 'native:' warning above; "
+            f"`python -c 'from racon_tpu import native; "
+            f"native.build(force=True)'` shows the compiler output)")
+
+
 def make_aligner(backend: str, num_threads: int, num_batches: int = 1,
                  mesh=None, device=None):
     if backend == "python":
@@ -151,11 +166,11 @@ def make_aligner(backend: str, num_threads: int, num_batches: int = 1,
             from ..ops.nw import TpuAligner
         except ImportError as e:
             raise ValueError(f"TPU aligner backend unavailable: {e}")
+        _require_native("aligner")
         # an explicit chip pin is single-device by definition: the chip
         # scheduler builds one engine per local device, so the
         # every-visible-device auto-mesh must NOT engage under it
-        return TpuAligner(fallback=NativeAligner(num_threads)
-                          if native.available() else PythonAligner(),
+        return TpuAligner(fallback=NativeAligner(num_threads),
                           num_batches=num_batches,
                           mesh=None if device is not None
                           else _auto_mesh(mesh),
@@ -184,9 +199,10 @@ def make_consensus(backend: str, match: int, mismatch: int, gap: int,
         # -b halves the alignment band (the reference's banded-cudapoa
         # speed/accuracy trade, src/main.cpp:124-126); a chip pin
         # (device) suppresses the auto-mesh — see make_aligner
+        _require_native("consensus")
         return TpuPoaConsensus(match, mismatch, gap,
-                               fallback=CpuPoaConsensus(match, mismatch, gap,
-                                                        num_threads),
+                               fallback=NativePoaConsensus(
+                                   match, mismatch, gap, num_threads),
                                band=BAND // 2 if banded else BAND,
                                num_batches=num_batches,
                                mesh=None if device is not None

@@ -103,10 +103,9 @@ REGISTRY: Dict[str, Flag] = _declare([
          "loop during Polisher.initialize(); set 0 to disable."),
     # ------------------------------------------------------- compile cache
     Flag("RACON_TPU_NO_COMPILE_CACHE", "0", "bool",
-         "Set to disable the persistent XLA compilation cache."),
-    Flag("RACON_TPU_COMPILE_CACHE", "", "path",
-         "Persistent XLA compilation cache directory (default "
-         "~/.cache/racon_tpu_xla)."),
+         "Set to disable the persistent XLA compilation cache (its "
+         "directory is JAX's own JAX_COMPILATION_CACHE_DIR, else "
+         "--compile-cache, else <checkout>/.xla_cache)."),
     # ------------------------------------------------------- observability
     Flag("RACON_TPU_TRACE", "", "path",
          "Write a Chrome trace-event JSON of the run's pipeline spans "

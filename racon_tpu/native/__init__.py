@@ -14,6 +14,7 @@ libasan.so)``), so the variant is chosen per process at first load.
 from __future__ import annotations
 
 import ctypes
+import os
 import pathlib
 import subprocess
 import threading
@@ -56,11 +57,29 @@ def _needs_build() -> bool:
     return any(src.stat().st_mtime > lib_mtime for src in _SOURCES)
 
 
+def _compile(cmd, out: pathlib.Path) -> subprocess.CompletedProcess:
+    """Run the g++ command ``cmd`` onto ``out`` atomically: compile to a
+    per-process temporary name, then rename — a concurrent process
+    never loads a half-written object, and a forced rebuild under a
+    running loader is safe."""
+    tmp = out.with_name(f".{out.name}.{os.getpid()}.tmp")
+    proc = subprocess.run([*cmd, "-o", str(tmp)], capture_output=True,
+                          text=True)
+    if proc.returncode == 0:
+        os.replace(tmp, out)
+    else:
+        tmp.unlink(missing_ok=True)
+    return proc
+
+
 def build(force: bool = False) -> pathlib.Path:
-    """Compile the native library if needed. Returns its path. The
-    sanitized variant keeps frame pointers and -O1 so ASan/UBSan reports
-    carry usable stacks; it caches to its own .so, so the fast build is
-    never evicted by a sanitizer run."""
+    """Compile the native library if needed (``force``: always, and the
+    optional parser extension with it — a .so copied in from another
+    machine was built ``-march=native`` for that machine's CPU, and its
+    mtime says nothing). Returns the library's path. The sanitized
+    variant keeps frame pointers and -O1 so ASan/UBSan reports carry
+    usable stacks; it caches to its own .so, so the fast build is never
+    evicted by a sanitizer run."""
     path = _lib_path()
     with _lock:
         if force or _needs_build():
@@ -73,20 +92,21 @@ def build(force: bool = False) -> pathlib.Path:
             cmd = [
                 "g++", *opt, "-std=c++17", "-shared", "-fPIC",
                 "-pthread",
-                *[str(s) for s in _SOURCES],
-                "-o", str(path), "-lz",
+                *[str(s) for s in _SOURCES], "-lz",
             ]
             # serializing the compile IS this lock's purpose: two
             # threads racing g++ onto one .so would tear the artifact
             # graftlint: disable=blocking-under-lock (the lock exists to serialize the one-time compile onto one .so)
-            proc = subprocess.run(cmd, capture_output=True, text=True)
+            proc = _compile(cmd, path)
             if proc.returncode != 0:
                 raise NativeBuildError(
                     f"native build failed:\n{proc.stderr[-4000:]}")
+        if force:
+            _EXT_PATH.unlink(missing_ok=True)  # load_ext() rebuilds it
     return path
 
 
-def _load_ext():
+def load_ext():
     """Build/load the optional CPython extension (fast overlap-record
     materialization); returns the module or None. Never raises — the
     ctypes path is the functional fallback."""
@@ -107,11 +127,10 @@ def _load_ext():
                     "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
                     "-march=native",
                     f"-I{sysconfig.get_paths()['include']}",
-                    *[str(s) for s in _EXT_SOURCES],
-                    "-o", str(_EXT_PATH), "-lz",
+                    *[str(s) for s in _EXT_SOURCES], "-lz",
                 ]
                 # graftlint: disable=blocking-under-lock (the lock exists to serialize the one-time compile onto one .so)
-                proc = subprocess.run(cmd, capture_output=True, text=True)
+                proc = _compile(cmd, _EXT_PATH)
                 if proc.returncode != 0:
                     return None
             import importlib.machinery
@@ -404,7 +423,7 @@ def parse_ovlfile(path: str, fmt: int):
     ``OverlapRecord.fields`` (io/parsers.py). Prefers the CPython
     extension (record materialization in C, >100 MB/s); the ctypes
     route below is the fallback."""
-    ext = _load_ext()
+    ext = load_ext()
     if ext is not None:
         return ext.parse_ovlfile(path, fmt)
     lib = load()

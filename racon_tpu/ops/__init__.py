@@ -4,7 +4,7 @@
   NW forward with VMEM-resident wavefronts + DMA-streamed direction rows,
   the wavefront-synchronized walk, and the fused walk+vote emitter.
 - ``racon_tpu.ops.nw``  — batched banded NW + on-device traceback with
-  bucketing/escalation and the XLA fallback kernels (role of the
+  bucketing/escalation and the XLA twin kernels (role of the
   reference's cudaaligner batches, ``src/cuda/cudaaligner.cpp``).
 - ``racon_tpu.ops.poa`` — device-resident batched POA consensus refinement
   (role of cudapoa, ``src/cuda/cudabatch.cpp``).
@@ -27,36 +27,51 @@ from .. import flags as _flags
 from ..utils.logger import log_swallowed as _log_swallowed
 
 
+# the fixed default cache path: <checkout>/.xla_cache, derived from the
+# package's own location (the path is part of the cache key, so it must
+# not move between runs — never $HOME, a temporary name, a pid or a time)
+DEFAULT_COMPILE_CACHE = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.dirname(
+        _os.path.abspath(__file__)))), ".xla_cache")
+
+
 def configure_compile_cache(cache_dir: _Optional[str] = None,
                             min_compile_time_s: float = 0.5
                             ) -> _Optional[str]:
-    """Point XLA's persistent compilation cache at ``cache_dir``.
+    """Place XLA's persistent compilation cache; returns the directory
+    in effect.
 
     The kernels are recompiled per (bucket shape x batch size) and a
-    cold CLI/test run pays tens of seconds of compile time otherwise —
-    for the resident-daemon direction (ROADMAP item 3) the cache IS the
-    difference between compile-dominated and compute-dominated jobs.
-    Resolution order: explicit argument (the CLI ``--compile-cache``),
-    ``RACON_TPU_COMPILE_CACHE``, ``~/.cache/racon_tpu_xla``.  Called
-    once at import with the flag defaults; calling again (any time
-    before the compiles it should capture) re-points the cache.
-    Returns the directory in effect, or None when setup failed — the
-    cache is an optimization, never fatal."""
-    cache_dir = (cache_dir
-                 or _flags.get_str("RACON_TPU_COMPILE_CACHE")
-                 or _os.path.join(_os.path.expanduser("~"), ".cache",
-                                  "racon_tpu_xla"))
-    try:
-        import jax as _jax
+    cold CLI/test run pays tens of seconds of compile time otherwise.
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: when it
+    is set JAX reads it itself, this function sets no directory in code
+    and an explicit ``cache_dir`` (the CLI ``--compile-cache``) yields
+    to it with a stderr note. Unset: ``cache_dir``, else
+    :data:`DEFAULT_COMPILE_CACHE`. Called once at import with the
+    default; calling again (any time before the compiles it should
+    capture) re-points the cache. An uncreatable directory is logged
+    and leaves the cache off (None) — the cache is an optimization."""
+    import jax as _jax
 
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                       min_compile_time_s)
+    env_dir = _os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        if cache_dir and _os.path.abspath(cache_dir) != \
+                _os.path.abspath(env_dir):
+            import sys as _sys
+            print(f"[racon_tpu] note: JAX_COMPILATION_CACHE_DIR="
+                  f"{env_dir} places the compile cache; ignoring "
+                  f"--compile-cache {cache_dir}", file=_sys.stderr)
+        return env_dir
+    cache_dir = cache_dir or DEFAULT_COMPILE_CACHE
+    try:
         _os.makedirs(cache_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                           min_compile_time_s)
-        return cache_dir
-    except Exception as _e:  # cache is an optimization, never fatal
+    except OSError as _e:  # cache is an optimization, never fatal
         _log_swallowed("ops: persistent XLA compile cache setup", _e)
         return None
+    _jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 # Persist XLA compilations across processes by default. Opt out with

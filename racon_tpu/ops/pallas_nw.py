@@ -38,10 +38,10 @@ The walk streams direction rows through a double-buffered VMEM window in
 *descending-a* chunks (the only order the walk needs), so the matrix never
 materializes in VMEM and arbitrarily long buckets fit.
 
-Availability is probed once (``pallas_ok()``) by running both kernels on
-a random small batch and comparing bit-for-bit against the XLA reference
-kernels; on hosts whose backend cannot lower Mosaic (the CPU test mesh)
-or where the comparison fails, callers fall back to the XLA kernels.
+Selection is by platform (``pallas_ok()``): off the TPU the XLA kernels
+are the path and no Mosaic call is attempted; on the TPU both kernels are
+probed once on a random small batch, bit-for-bit against the XLA reference
+kernels, and a probe that raises or mismatches fails the run.
 """
 
 from __future__ import annotations
@@ -769,13 +769,20 @@ def pallas_walk_ops(dirs, n, m, *, band: int):
 
 
 class PallasDispatchMixin:
-    """Shared try-Pallas-then-XLA dispatch with a per-shape disable memo:
-    one exotic-shape Mosaic failure must not downgrade the whole run to
-    the XLA kernels (the big well-tested shapes dominate wall-clock).
+    """Kernel-family choice shared by both device engines, plus the
+    per-engine device pin.
 
-    Also hosts the per-engine device pin (``device`` ctor kwarg of both
-    engines): the in-process chip scheduler gives every local chip its
-    own engine pair, and :meth:`_pinned` is the thread-local
+    :meth:`_use_pallas` is the one place an engine asks whether a shape
+    runs on the Mosaic kernels: the platform decides (``pallas_ok()``),
+    minus shapes a *static* rule excludes. There is no runtime
+    downgrade — on the chip a Mosaic kernel that fails to compile or
+    run for a shape fails the run with the shape in the message
+    (``tests/test_chip_compile.py`` guards the main-path shapes without
+    a chip).
+
+    The device pin (``device`` ctor kwarg of both engines): the
+    in-process chip scheduler gives every local chip its own engine
+    pair, and :meth:`_pinned` is the thread-local
     ``jax.default_device`` context the engines wrap their launch/fetch
     halves in so host->device puts (and the computations that follow
     them) land on that chip."""
@@ -789,184 +796,121 @@ class PallasDispatchMixin:
         import jax
         return jax.default_device(self.device)
 
-    _pallas_failed_shapes = None
-    # after this many distinct shape failures the breakage is systemic
-    # (e.g. a libtpu upgrade): disable globally instead of paying one
-    # failed Mosaic compile + warning per remaining shape
-    _PALLAS_MAX_SHAPE_FAILURES = 3
-
     def _use_pallas(self, shape_key) -> bool:
-        failed = self._pallas_failed_shapes
-        if failed and (shape_key in failed
-                       or len(failed) >= self._PALLAS_MAX_SHAPE_FAILURES):
-            return False
         return pallas_ok()
-
-    def _note_pallas_failure(self, shape_key, exc) -> None:
-        import warnings
-        warnings.warn(
-            f"Pallas kernels failed at shape {shape_key}; using the XLA "
-            f"kernels for this shape: {exc!r}", RuntimeWarning)
-        if self._pallas_failed_shapes is None:
-            self._pallas_failed_shapes = set()
-        self._pallas_failed_shapes.add(shape_key)
-        self.stats["pallas_fallback"] = \
-            self.stats.get("pallas_fallback", 0) + 1
 
 
 _PALLAS_OK = None
 
 
 def pallas_ok() -> bool:
-    """Probe once whether Mosaic kernels compile+run on this backend AND
-    reproduce the XLA reference kernels bit-for-bit on a random small
-    batch (True on real TPU; False on the CPU test mesh, which then uses
-    the XLA kernels). The value-level comparison matters: a Mosaic
-    regression that only corrupts values would otherwise ship silently —
-    tests pin JAX to CPU and never execute this path."""
+    """Whether this process runs the Mosaic kernels, decided from the
+    platform: ``False`` off the TPU (the XLA kernels *are* the CPU
+    path). On a TPU the kernels are probed once — compile, run, and
+    compare bit-for-bit against the XLA reference kernels on a random
+    small batch — and a probe that raises or mismatches is a hard error
+    (:class:`~racon_tpu.ops.swar.KernelProbeError` naming the kernel and
+    the first differing output), never a logged downgrade: the XLA
+    kernels would give the right bytes and hide a broken chip path. The
+    value-level comparison matters because tests pin JAX to the CPU and
+    never execute a Mosaic kernel."""
     global _PALLAS_OK
     if _PALLAS_OK is None:
-        try:
-            import numpy as np
-            from .nw import _nw_wavefront_kernel, _walk_ops_kernel
-
-            max_len, band = 256, 128
-            B, c = 8, band // 2
-            width = c + max_len + band
-            rng = np.random.default_rng(7)
-            bases = np.frombuffer(b"ACGT", np.uint8)
-            qrp = np.full((B, width), 6, np.uint8)
-            tp = np.full((B, width), 7, np.uint8)
-            n = np.zeros(B, np.int32)
-            m = np.zeros(B, np.int32)
-            for k in range(B):
-                ln = int(rng.integers(60, 200))
-                t = bases[rng.integers(0, 4, ln)]
-                q = np.delete(t.copy(), rng.integers(0, ln, 4))
-                flips = rng.random(len(q)) < 0.2
-                q[flips] = bases[rng.integers(0, 4, int(flips.sum()))]
-                qrp[k, c + max_len - len(q): c + max_len] = q[::-1]
-                tp[k, c: c + ln] = t
-                n[k], m[k] = len(q), ln
-            args = (jnp.asarray(qrp), jnp.asarray(tp),
-                    jnp.asarray(n), jnp.asarray(m))
-            # out_quant=512: this matrix feeds the packed aligner walk
-            dp, sp = pallas_nw_fwd(*args, max_len=max_len, band=band,
-                                   out_quant=512)
-            dx, sx = _nw_wavefront_kernel(*args, max_len=max_len, band=band)
-            opk, fip, fjp = pallas_walk_ops(dp, args[2], args[3],
-                                            band=band)
-            ox, fix, fjx = _walk_ops_kernel(dx, args[2], args[3],
-                                            band=band)
-            dp, sp, dx, sx, opk, fip, fjp, ox, fix, fjx = map(
-                np.asarray, (dp, sp, dx, sx, opk, fip, fjp, ox, fix, fjx))
-            # the Pallas walk's output is 2-bit packed — unpack to compare
-            shifts4 = np.arange(4, dtype=np.uint8) * 2
-            op_ = ((opk[:, :, None] >> shifts4) & 3).reshape(opk.shape[0],
-                                                             -1)
-            # rows past the block's dynamic sweep bound are never written
-            # by the Pallas kernel (and never read by any consumer) —
-            # compare only the guaranteed-computed rows
-            mx = int((n + m).max())
-            ok = (
-                np.array_equal(dp[:, :mx], dx[:, :mx])
-                and np.array_equal(sp, sx)
-                and np.array_equal(fip, fix) and np.array_equal(fjp, fjx)
-                and all(np.array_equal(op_[k][op_[k] < 3], ox[k][ox[k] < 3])
-                        for k in range(B)))
-
-            # fused walk+vote path must land on identical vote matrices
-            if ok:
-                from .poa import (CH, DEL, _accumulate_votes,
-                                  _vote_from_ops)
-                L, K, nW = max_len, 4, 4
-                qcodes = rng.integers(0, 5, (B, max_len)).astype(np.uint8)
-                qweights = rng.integers(0, 60,
-                                        (B, max_len)).astype(np.uint8)
-                qpw = jnp.asarray(
-                    (qweights.astype(np.uint16) << 3) | qcodes)
-                bg = jnp.asarray(rng.integers(0, 8, B).astype(np.int32))
-                win_of = jnp.asarray(
-                    (np.arange(B) % (nW - 1)).astype(np.int32))
-                idxx, wx8, okx = _vote_from_ops(
-                    jnp.asarray(ox), jnp.asarray(fix), jnp.asarray(fjx),
-                    jnp.asarray(sx), args[2], args[3], qpw,
-                    bg, max_len=max_len, band=band, L=L, K=K)
-                wx, ux, _ovx, _owx = _accumulate_votes(
-                    idxx, wx8, okx, win_of, args[3], bg, args[2],
-                    jnp.asarray(sx), n_windows=nW, L=L, K=K, band=band)
-                idx, w8, fiv, fjv = pallas_walk_vote(
-                    jnp.asarray(dp), args[2], args[3], bg, qpw,
-                    band=band, L=L, K=K, CH=CH, DEL=DEL)
-                okv = ((fiv == 0) & (fjv == 0)
-                       & (jnp.asarray(sp) < (band // 2)))
-                wp, up, _ovp, _owp = _accumulate_votes(
-                    idx, w8.astype(jnp.int32), okv, win_of, args[3], bg,
-                    args[2], jnp.asarray(sp), n_windows=nW, L=L, K=K,
-                    band=band)
-                ok = (np.array_equal(np.asarray(wx), np.asarray(wp))
-                      and np.array_equal(np.asarray(ux), np.asarray(up)))
-            _PALLAS_OK = ok
-        except Exception as e:
-            from ..utils.logger import log_swallowed
-            log_swallowed("pallas: availability probe failed; Mosaic "
-                          "kernels disabled for this process", e)
-            _PALLAS_OK = False
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu:
+            _probe_pallas()
+        _PALLAS_OK = on_tpu
     return _PALLAS_OK
+
+
+def _probe_pallas() -> None:
+    import numpy as np
+    from .nw import _nw_wavefront_kernel, _walk_ops_kernel
+    from .swar import (PROBE_BAND, PROBE_MAX_LEN, probe_batch,
+                       probe_equal)
+
+    max_len, band = PROBE_MAX_LEN, PROBE_BAND
+    args, n, m, rng = probe_batch(7, 60, 200, 4, pad_q=6, pad_t=7)
+    B = len(n)
+    # out_quant=512: this matrix feeds the packed aligner walk
+    dp, sp = pallas_nw_fwd(*args, max_len=max_len, band=band,
+                           out_quant=512)
+    dx, sx = _nw_wavefront_kernel(*args, max_len=max_len, band=band)
+    opk, fip, fjp = pallas_walk_ops(dp, args[2], args[3], band=band)
+    ox, fix, fjx = _walk_ops_kernel(dx, args[2], args[3], band=band)
+    # rows past the block's dynamic sweep bound are never written
+    # by the Pallas kernel (and never read by any consumer) —
+    # compare only the guaranteed-computed rows
+    mx = int((n + m).max())
+    probe_equal("pallas_nw_fwd", "dirs", np.asarray(dp)[:, :mx],
+                np.asarray(dx)[:, :mx])
+    probe_equal("pallas_nw_fwd", "score", sp, sx)
+    probe_equal("pallas_walk_ops", "fi", fip, fix)
+    probe_equal("pallas_walk_ops", "fj", fjp, fjx)
+    # the Pallas walk's output is 2-bit packed — unpack to compare;
+    # inactive-gap codes (>= 3) interleave there and only trail on the
+    # XLA walk, so compare the real path codes
+    opk, ox = np.asarray(opk), np.asarray(ox)
+    shifts4 = np.arange(4, dtype=np.uint8) * 2
+    op_ = ((opk[:, :, None] >> shifts4) & 3).reshape(opk.shape[0], -1)
+    for k in range(B):
+        probe_equal("pallas_walk_ops", f"ops[pair {k}]",
+                    op_[k][op_[k] < 3], ox[k][ox[k] < 3])
+
+    # fused walk+vote path must land on identical vote matrices
+    from .poa import CH, DEL, _accumulate_votes, _vote_from_ops
+    L, K, nW = max_len, 4, 4
+    qcodes = rng.integers(0, 5, (B, max_len)).astype(np.uint8)
+    qweights = rng.integers(0, 60, (B, max_len)).astype(np.uint8)
+    qpw = jnp.asarray((qweights.astype(np.uint16) << 3) | qcodes)
+    bg = jnp.asarray(rng.integers(0, 8, B).astype(np.int32))
+    win_of = jnp.asarray((np.arange(B) % (nW - 1)).astype(np.int32))
+    idxx, wx8, okx = _vote_from_ops(
+        jnp.asarray(ox), fix, fjx, sx, args[2], args[3], qpw, bg,
+        max_len=max_len, band=band, L=L, K=K)
+    wx, ux, _ovx, _owx = _accumulate_votes(
+        idxx, wx8, okx, win_of, args[3], bg, args[2], sx,
+        n_windows=nW, L=L, K=K, band=band)
+    idx, w8, fiv, fjv = pallas_walk_vote(
+        dp, args[2], args[3], bg, qpw, band=band, L=L, K=K, CH=CH,
+        DEL=DEL)
+    okv = (fiv == 0) & (fjv == 0) & (sp < (band // 2))
+    wp, up, _ovp, _owp = _accumulate_votes(
+        idx, w8.astype(jnp.int32), okv, win_of, args[3], bg, args[2],
+        sp, n_windows=nW, L=L, K=K, band=band)
+    probe_equal("pallas_walk_vote", "vote weights", wp, wx)
+    probe_equal("pallas_walk_vote", "vote counts", up, ux)
 
 
 _PALLAS_SWAR_OK = None
 
 
 def pallas_swar_ok() -> bool:
-    """Probe once whether the SWAR-packed Mosaic forward kernel
-    (``_fwd_kernel_swar``) reproduces the XLA reference bit-for-bit on a
-    random small batch. Separate memo from ``pallas_ok()`` so a packed-
-    kernel regression downgrades only the packed path — the int32 Pallas
-    kernels keep running."""
+    """Whether the SWAR-packed Mosaic forward kernel
+    (``_fwd_kernel_swar``) runs: ``False`` off the TPU like
+    :func:`pallas_ok`; on a TPU it is probed once bit-for-bit against
+    the XLA reference, and a failure is a hard error."""
     global _PALLAS_SWAR_OK
     if _PALLAS_SWAR_OK is None:
-        if not pallas_ok():
-            _PALLAS_SWAR_OK = False
-            return False
-        try:
+        ok = pallas_ok()
+        if ok:
             import numpy as np
             from .nw import _nw_wavefront_kernel
+            from .swar import (PROBE_BAND, PROBE_MAX_LEN, probe_batch,
+                               probe_equal)
 
-            max_len, band = 256, 128
-            B, c = 8, band // 2
-            width = c + max_len + band
-            rng = np.random.default_rng(17)
-            bases = np.frombuffer(b"ACGT", np.uint8)
-            qrp = np.zeros((B, width), np.uint8)
-            tp = np.zeros((B, width), np.uint8)
-            n = np.zeros(B, np.int32)
-            m = np.zeros(B, np.int32)
-            for k in range(B):
-                ln = int(rng.integers(60, 200))
-                t = bases[rng.integers(0, 4, ln)]
-                q = np.delete(t.copy(), rng.integers(0, ln, 4))
-                flips = rng.random(len(q)) < 0.2
-                q[flips] = bases[rng.integers(0, 4, int(flips.sum()))]
-                qrp[k, c + max_len - len(q): c + max_len] = q[::-1]
-                tp[k, c: c + ln] = t
-                n[k], m[k] = len(q), ln
-            args = (jnp.asarray(qrp), jnp.asarray(tp),
-                    jnp.asarray(n), jnp.asarray(m))
+            max_len, band = PROBE_MAX_LEN, PROBE_BAND
+            args, n, m, _rng = probe_batch(17, 60, 200, 4)
             # graftlint: disable=swar-guard (probe bucket: 256 + 2 < BIG16 by construction)
             dp, sp = pallas_nw_fwd(*args, max_len=max_len, band=band,
                                    out_quant=512, use_swar=True)
             dx, sx = _nw_wavefront_kernel(*args, max_len=max_len,
                                           band=band)
-            dp, sp, dx, sx = map(np.asarray, (dp, sp, dx, sx))
             mx = int((n + m).max())
-            _PALLAS_SWAR_OK = (np.array_equal(dp[:, :mx], dx[:, :mx])
-                               and np.array_equal(sp, sx))
-        except Exception as e:
-            from ..utils.logger import log_swallowed
-            log_swallowed("pallas: SWAR probe failed; packed Mosaic "
-                          "kernel disabled for this process", e)
-            _PALLAS_SWAR_OK = False
+            probe_equal("pallas_nw_fwd(use_swar=True)", "dirs",
+                        np.asarray(dp)[:, :mx], np.asarray(dx)[:, :mx])
+            probe_equal("pallas_nw_fwd(use_swar=True)", "score", sp, sx)
+        _PALLAS_SWAR_OK = ok
     return _PALLAS_SWAR_OK
 
 

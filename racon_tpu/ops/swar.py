@@ -28,8 +28,8 @@ scores** — real scores are < ``BIG16`` in both paths, the saturated
 cells form the same {BIG, BIG+1} classes, and every comparison the
 direction code depends on sees the same ordering. :func:`swar_ok` probes
 this once per process on a random batch (the same philosophy as
-``pallas_nw.pallas_ok``) and the dispatch layers fall back to int32 when
-it fails.
+``pallas_nw.pallas_ok``); a probe that fails is a hard error, never a
+quiet downgrade to int32.
 """
 
 from __future__ import annotations
@@ -113,69 +113,104 @@ def swar_fits(max_len: int) -> bool:
     return max_len + 2 < BIG16
 
 
+# geometry of the availability probes' one small bucket
+PROBE_MAX_LEN, PROBE_BAND = 256, 128
+
+
+class KernelProbeError(RuntimeError):
+    """A kernel family failed its bit-exactness probe on this backend."""
+
+
+def probe_batch(seed: int, lo: int, hi: int, n_del: int,
+                pad_q: int = 0, pad_t: int = 0):
+    """Random small batch (8 related pairs, ``max_len`` 256, ``band``
+    128) laid out as the wavefront kernels' padded rows — the shared
+    input of the three availability probes. Returns ``(args, n, m,
+    rng)``: the device arrays ``(qrp, tp, n, m)``, the host lengths and
+    the generator (for probes that draw more data)."""
+    max_len, band = PROBE_MAX_LEN, PROBE_BAND
+    B, c = 8, band // 2
+    width = c + max_len + band
+    rng = np.random.default_rng(seed)
+    bases = np.frombuffer(b"ACGT", np.uint8)
+    qrp = np.full((B, width), pad_q, np.uint8)
+    tp = np.full((B, width), pad_t, np.uint8)
+    n = np.zeros(B, np.int32)
+    m = np.zeros(B, np.int32)
+    for k in range(B):
+        ln = int(rng.integers(lo, hi))
+        t = bases[rng.integers(0, 4, ln)]
+        q = np.delete(t.copy(), rng.integers(0, ln, n_del))
+        flips = rng.random(len(q)) < 0.2
+        q[flips] = bases[rng.integers(0, 4, int(flips.sum()))]
+        qrp[k, c + max_len - len(q): c + max_len] = q[::-1]
+        tp[k, c: c + ln] = t
+        n[k], m[k] = len(q), ln
+    args = (jnp.asarray(qrp), jnp.asarray(tp),
+            jnp.asarray(n), jnp.asarray(m))
+    return args, n, m, rng
+
+
+def probe_equal(kernel: str, name: str, got, want) -> None:
+    """Raise :class:`KernelProbeError` naming ``kernel`` and the first
+    differing element of output ``name`` unless ``got == want``."""
+    got, want = np.asarray(got), np.asarray(want)
+    if got.shape != want.shape:
+        raise KernelProbeError(
+            f"{kernel}: output {name!r} has shape {got.shape}, the "
+            f"reference kernel gives {want.shape}")
+    diff = np.argwhere(got != want)
+    if len(diff):
+        at = tuple(int(i) for i in diff[0])
+        raise KernelProbeError(
+            f"{kernel}: output {name!r} differs from the reference "
+            f"kernel in {len(diff)} of {got.size} elements, first at "
+            f"{at}: got {got[at].item()!r}, reference "
+            f"{want[at].item()!r}")
+
+
 _SWAR_OK = None
 
 
 def swar_ok() -> bool:
     """Probe once whether the packed (int16-lane) XLA wavefront kernel
     reproduces the int32 kernel bit-for-bit on a random small batch —
-    dirs, scores, and walked tracebacks. Mirrors ``pallas_ok()``: a
-    backend whose 16-bit lowering misbehaves downgrades to the int32
-    kernels instead of shipping corrupt alignments."""
+    dirs, scores, and walked tracebacks. A backend whose 16-bit lowering
+    misbehaves fails the run (:class:`KernelProbeError` naming the
+    first differing output): quietly selecting int32 would hide a
+    broken kernel family behind right bytes. ``RACON_TPU_SWAR=0`` is
+    the explicit way to run without the packed kernels."""
     global _SWAR_OK
     from .. import flags
     if not flags.get_bool("RACON_TPU_SWAR"):
         return False  # global escape hatch / A-B switch, like DYNBOUND
     if _SWAR_OK is None:
-        try:
-            from .nw import _nw_wavefront_kernel, _walk_ops_kernel
+        from .nw import _nw_wavefront_kernel, _walk_ops_kernel
 
-            max_len, band = 256, 128
-            B, c = 8, band // 2
-            width = c + max_len + band
-            rng = np.random.default_rng(13)
-            bases = np.frombuffer(b"ACGT", np.uint8)
-            qrp = np.zeros((B, width), np.uint8)
-            tp = np.zeros((B, width), np.uint8)
-            n = np.zeros(B, np.int32)
-            m = np.zeros(B, np.int32)
-            for k in range(B):
-                ln = int(rng.integers(50, 220))
-                t = bases[rng.integers(0, 4, ln)]
-                q = np.delete(t.copy(), rng.integers(0, ln, 3))
-                flips = rng.random(len(q)) < 0.2
-                q[flips] = bases[rng.integers(0, 4, int(flips.sum()))]
-                qrp[k, c + max_len - len(q): c + max_len] = q[::-1]
-                tp[k, c: c + ln] = t
-                n[k], m[k] = len(q), ln
-            args = (jnp.asarray(qrp), jnp.asarray(tp),
-                    jnp.asarray(n), jnp.asarray(m))
-            # graftlint: disable=swar-guard (probe bucket: 256 + 2 < BIG16 by construction)
-            dp, sp = _nw_wavefront_kernel(*args, max_len=max_len,
-                                          band=band, swar=True)
-            dx, sx = _nw_wavefront_kernel(*args, max_len=max_len,
-                                          band=band)
-            # packed walk (round 17): the SWAR path's traceback carries
-            # (i, j) as one halfword pair — probe it against the
-            # unpacked walk on the same matrices, so a backend whose
-            # shift/mask lowering misbehaves downgrades the whole
-            # packed path (fwd + walk) together
-            # graftlint: disable=swar-guard (probe bucket: 256 + 2 < BIG16 by construction)
-            op_, fip, fjp = _walk_ops_kernel(dp, args[2], args[3],
-                                             band=band, swar=True)
-            ox, fix, fjx = _walk_ops_kernel(dx, args[2], args[3],
-                                            band=band)
-            _SWAR_OK = (
-                np.array_equal(np.asarray(dp), np.asarray(dx))
-                and np.array_equal(np.asarray(sp), np.asarray(sx))
-                and np.array_equal(np.asarray(op_), np.asarray(ox))
-                and np.array_equal(np.asarray(fip), np.asarray(fix))
-                and np.array_equal(np.asarray(fjp), np.asarray(fjx)))
-        except Exception as e:
-            from ..utils.logger import log_swallowed
-            log_swallowed("swar: availability probe failed; packed "
-                          "int16 kernels disabled for this process", e)
-            _SWAR_OK = False
+        max_len, band = PROBE_MAX_LEN, PROBE_BAND
+        args, _n, _m, _rng = probe_batch(13, 50, 220, 3)
+        # graftlint: disable=swar-guard (probe bucket: 256 + 2 < BIG16 by construction)
+        dp, sp = _nw_wavefront_kernel(*args, max_len=max_len,
+                                      band=band, swar=True)
+        dx, sx = _nw_wavefront_kernel(*args, max_len=max_len,
+                                      band=band)
+        # packed walk (round 17): the SWAR path's traceback carries
+        # (i, j) as one halfword pair — probed against the unpacked
+        # walk on the same matrices, so the packed path (fwd + walk)
+        # stands or falls together
+        # graftlint: disable=swar-guard (probe bucket: 256 + 2 < BIG16 by construction)
+        op_, fip, fjp = _walk_ops_kernel(dp, args[2], args[3],
+                                         band=band, swar=True)
+        ox, fix, fjx = _walk_ops_kernel(dx, args[2], args[3],
+                                        band=band)
+        kernel = "XLA SWAR wavefront (_nw_wavefront_kernel swar=True)"
+        probe_equal(kernel, "dirs", dp, dx)
+        probe_equal(kernel, "score", sp, sx)
+        kernel = "XLA SWAR walk (_walk_ops_kernel swar=True)"
+        probe_equal(kernel, "ops", op_, ox)
+        probe_equal(kernel, "fi", fip, fix)
+        probe_equal(kernel, "fj", fjp, fjx)
+        _SWAR_OK = True
     return _SWAR_OK
 
 

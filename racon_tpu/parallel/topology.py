@@ -46,14 +46,20 @@ def n_local_chips() -> int:
 def resolve_chips(requested: int = 0) -> int:
     """Number of in-process chip workers a run should spawn: an explicit
     request (CLI ``--chips``) wins, then ``RACON_TPU_CHIPS``, then every
-    local device.  Always clamped to the local device count and floored
-    at 1."""
+    local device (floored at 1).  An explicit request for more chips
+    than this process can address is an error, never a clamp — a
+    ``--chips 4`` run on one device would be a 1-chip run that says
+    nothing."""
     if requested <= 0:
         requested = flags.get_int("RACON_TPU_CHIPS")
     n = n_local_chips()
     if requested <= 0:
         return max(1, n)
-    return max(1, min(requested, n))
+    if requested > n:
+        raise ValueError(
+            f"--chips/RACON_TPU_CHIPS asks for {requested} chips but "
+            f"only {n} local device(s) are visible")
+    return requested
 
 
 @dataclass

@@ -1,0 +1,225 @@
+"""Ask the chip's compiler, without the chip (round 22).
+
+The tests pin JAX to the CPU, where no Mosaic kernel ever runs — the
+XLA twins are that platform's path. This file compiles the main path's
+Mosaic kernels, and the jitted programs the engines really dispatch
+with the Pallas branch forced, for a *described* ``v5e:2x2`` topology
+(one device, shapes only): what the TPU compiler refuses here — a
+misaligned slice, too much VMEM, a program over the chip's 16 GB — it
+would refuse on the chip, where since round 22 a refused kernel fails
+the run instead of quietly falling back to XLA. Nothing executes, so
+this says nothing about values or times; ``chip_smoke.py`` does that
+on the chip.
+
+Everything chip-shaped lives in fixtures of THIS file: only one
+process may load the TPU library, pytest-xdist gives a file to one
+worker, and describing the topology at import would break every other
+worker's collection. The persistent compile cache is off around the
+compiles (a described-chip entry cannot be read back without a chip).
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from racon_tpu.ops import nw, pallas_nw, poa, swar
+
+GIB = 1 << 30
+# what the v5e compiler reports as usable of the chip's 16 GB
+HBM_BYTES = int(15.75 * GIB)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> a ShapeDtypeStruct placed on one
+    described chip, with the persistent compile cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(lowered):
+    """Compile for the described chip; returns (compiled, total bytes
+    the program needs on the device)."""
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    return compiled, ma, total
+
+
+def _rows(chip, B, max_len, band):
+    width = band // 2 + max_len + band
+    return chip((B, width), jnp.uint8), chip((B,), jnp.int32)
+
+
+# ------------------------------------------------------ aligner kernels
+
+# every bucket at its own band, plus ladder rungs under the long
+# buckets (the 16384 x 8192 escape bucket and the 4096-band rung of
+# 768-lane consensus take 10-25 s each and are left to the chip)
+KERNEL_GEOMETRIES = [(256, 128), (1024, 384), (4096, 1024), (8192, 2048),
+                     (16384, 4096), (4096, 256), (8192, 768),
+                     (16384, 1536)]
+
+
+def test_kernel_geometries_are_the_engines():
+    """The list above tracks the engine's tables: buckets verbatim,
+    rungs from ``BAND_RUNGS`` and under their bucket's band."""
+    bands = dict((m, b) for m, b in nw.BUCKETS[:5])
+    for max_len, band in KERNEL_GEOMETRIES:
+        assert (max_len, band) in nw.BUCKETS or (
+            band in nw.BAND_RUNGS and band < bands[max_len])
+
+
+@pytest.mark.parametrize("max_len,band", KERNEL_GEOMETRIES)
+@pytest.mark.parametrize("use_swar", [False, True])
+def test_aligner_kernels_compile(chip, max_len, band, use_swar):
+    """``pallas_nw_fwd`` (int32 and SWAR) and ``pallas_walk_ops`` at
+    one 64-pair block of each geometry."""
+    B = 64
+    steps = nw._sweep_bound(2 * max_len, max_len)
+    rows, lens = _rows(chip, B, max_len, band)
+    assert not use_swar or swar.swar_fits(max_len)
+    compiled, _, _ = _compile(pallas_nw.pallas_nw_fwd.lower(
+        rows, rows, lens, lens, max_len=max_len, band=band, steps=steps,
+        out_quant=512, use_swar=use_swar))
+    assert "tpu_custom_call" in compiled.as_text()
+    if not use_swar:  # the walk is shared by both forward variants
+        dirs = chip((B, steps, band // 8), jnp.uint8)
+        compiled, _, _ = _compile(pallas_nw.pallas_walk_ops.lower(
+            dirs, lens, lens, band=band))
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_probe_shapes_compile(chip):
+    """The B=8 programs ``pallas_ok()`` / ``pallas_swar_ok()`` run
+    first thing on the chip (a refusal there stops every run)."""
+    max_len, band, B = swar.PROBE_MAX_LEN, swar.PROBE_BAND, 8
+    rows, lens = _rows(chip, B, max_len, band)
+    for use_swar in (False, True):
+        _compile(pallas_nw.pallas_nw_fwd.lower(
+            rows, rows, lens, lens, max_len=max_len, band=band,
+            out_quant=512, use_swar=use_swar))
+    dirs = chip((B, 2 * max_len, band // 8), jnp.uint8)
+    _compile(pallas_nw.pallas_walk_ops.lower(dirs, lens, lens, band=band))
+    _compile(pallas_nw.pallas_walk_vote.lower(
+        dirs, lens, lens, lens, chip((B, max_len), jnp.uint16),
+        band=band, L=max_len, K=4, CH=poa.CH, DEL=poa.DEL))
+
+
+# ------------------------------------- the programs the engines dispatch
+
+# (max_len, band, steps) of the 2 Mbp smoke workload's big align chunks
+# (30x reads of 2-8 kb against a ~10% draft: the stream's chunk plan,
+# from the lengths in its PAF); each at the engine's OWN pair cap for
+# that geometry, i.e. the largest chunk it would ever dispatch
+ALIGN_CHUNKS = [(16384, 4096, 16384), (16384, 3072, 14336),
+                (8192, 2048, 8192)]
+
+
+@pytest.mark.parametrize("max_len,band,steps", ALIGN_CHUNKS)
+def test_aligner_chunk_program_fits_the_chip(chip, max_len, band, steps):
+    """The fused Mosaic sweep+walk program at a budget-sized chunk:
+    compiles, fits the chip, and holds the direction matrix ONCE — as
+    two programs each side kept a relayout copy of it, which the
+    compiler refuses for an 8 GiB chunk."""
+    eng = nw.TpuAligner(fallback=None, num_batches=1)
+    B = min(eng._chunk_cap(steps, band), 1024)
+    dirs_bytes = B * steps * (band // 8)
+    assert dirs_bytes <= eng.chunk_dirs_budget()
+    rows, lens = _rows(chip, B, max_len, band)
+    compiled, ma, total = _compile(nw._pallas_align_chain.lower(
+        rows, rows, lens, lens, max_len=max_len, band=band, steps=steps,
+        use_swar=True))
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert total < HBM_BYTES, f"{total / GIB:.2f} GiB"
+    assert ma.temp_size_in_bytes < 1.1 * dirs_bytes, (
+        f"temp {ma.temp_size_in_bytes / GIB:.2f} GiB for a "
+        f"{dirs_bytes / GIB:.2f} GiB direction matrix: a relayout copy "
+        f"is back")
+    # the chunk's neighbours on the device: the row builder before it
+    # and the breaking-point reduction after it
+    blk = chip((B * max_len // 4,), jnp.uint8)
+    _compile(nw._build_rows_packed2.lower(blk, blk, lens, lens,
+                                          max_len=max_len, band=band))
+    if max_len == 8192:  # one is enough: plain XLA, ~7 s at B=1024
+        _compile(nw._breaking_points_kernel.lower(
+            chip((B, steps // 4), jnp.uint8), lens, lens, lens, lens,
+            w=500, NW=max_len // 500 + 2))
+
+
+def _consensus_engine(band=poa.BAND):
+    return poa.TpuPoaConsensus(3, -5, -4, fallback=None, band=band)
+
+
+def _refine_args(chip, Lq, Lb, B, nWp):
+    i32, u8, f32 = jnp.int32, jnp.uint8, jnp.float32
+    static = (chip((B,), i32), chip((B, Lq), jnp.uint16),
+              chip((B,), i32), chip((B,), bool))
+    state = (chip((B,), i32), chip((B,), i32), chip((nWp, Lb), u8),
+             chip((nWp, Lb), f32), chip((nWp,), i32),
+             chip((nWp, Lb), i32), chip((nWp,), bool),
+             chip((nWp,), bool), chip((nWp,), bool),
+             chip((1, 4 + nWp), i32))
+    return static + state + (chip((), f32), chip((), f32))
+
+
+def test_consensus_group_program_fits_the_chip(chip):
+    """``_refine_loop_packed`` — the consensus engine's one program per
+    group — with ``use_pallas=True`` at the full-arena group a 2 Mbp,
+    30x run dispatches (the engine's own warm-up shape rule)."""
+    eng = _consensus_engine()
+    Lq, Lb, band, steps, Lq2, B, nWp, rounds = eng._warmup_shapes(
+        500, 120_000, 4_000, 564, 1)[0]
+    assert (Lq, Lb, band, B) == (1024, 768, 512, poa.MAX_GROUP_PAIRS)
+    compiled, _, total = _compile(poa._refine_loop_packed.lower(
+        *_refine_args(chip, Lq, Lb, B, nWp), rounds=rounds,
+        n_windows=nWp, max_len=Lq, band=band, Lb=Lb, K=poa.K_INS,
+        steps=steps, use_pallas=True, use_swar=True, Lq2=Lq2,
+        scores=eng.scores, matmul_votes=eng.use_matmul_votes))
+    assert "tpu_custom_call" in compiled.as_text()
+    # the group's packed inputs wait in flight beside the running
+    # program (MAX_INFLIGHT_BYTES of them at most)
+    assert total + poa.MAX_INFLIGHT_BYTES < HBM_BYTES, \
+        f"{total / GIB:.2f} GiB"
+
+
+@pytest.mark.parametrize("band", [poa.BAND, poa.BAND // 2])
+def test_consensus_vote_kernel_compiles(chip, band):
+    """The fused walk+vote kernel at the default band and ``-b``'s."""
+    eng = _consensus_engine(band)
+    band_, L, Lq, Lb = eng._bucket_geometry(500)
+    steps, Lq2 = eng._sweep_geometry(Lq, 564 + 628, 564)
+    B = 256
+    lens = chip((B,), jnp.int32)
+    compiled, _, _ = _compile(pallas_nw.pallas_walk_vote.lower(
+        chip((B, steps, band_ // 8), jnp.uint8), lens, lens, lens,
+        chip((B, Lq2), jnp.uint16), band=band_, L=Lb, K=poa.K_INS,
+        CH=poa.CH, DEL=poa.DEL))
+    assert "tpu_custom_call" in compiled.as_text()
+    rows, _ = _rows(chip, B, Lq, band_)
+    _compile(pallas_nw.pallas_nw_fwd.lower(
+        rows, rows, lens, lens, max_len=Lq, band=band_, steps=steps,
+        use_swar=True))

@@ -37,7 +37,7 @@ def _fake_compile(max_len, band, duration=0.5):
 # ------------------------------------------------------------ attribution
 
 def test_attribution_names_function_and_shape_on_forced_retrace(
-        tmp_path):
+        tmp_path, monkeypatch):
     """A real forced retrace through a repo driver: the attributed
     event names the driving function and its dispatch geometry."""
     jax = pytest.importorskip("jax")
@@ -50,6 +50,7 @@ def test_attribution_names_function_and_shape_on_forced_retrace(
     # — re-pointed BACK afterwards: the cache dir is process-wide, and
     # leaving it on a tmp_path would make every later test in the
     # session compile cold
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     ops.configure_compile_cache(str(tmp_path / "xla_cache"))
     try:
         assert compilewatch.arm()

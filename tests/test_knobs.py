@@ -129,3 +129,19 @@ def test_device_alpha_identity_at_defaults():
     # counts are alpha-independent; weights shift under the e2e scores
     assert np.array_equal(np.asarray(u_def), np.asarray(u_e2e))
     assert not np.array_equal(np.asarray(w_def), np.asarray(w_e2e))
+
+
+def test_tpu_backend_requires_native_core(monkeypatch):
+    """``backend="tpu"`` hands its rejects to the native host engines;
+    without the native core it raises instead of quietly taking the
+    pure-Python engines (seconds per overlap) as the reject path."""
+    import pytest
+
+    from racon_tpu import native
+    monkeypatch.setattr(native, "available", lambda: False)
+    with pytest.raises(ValueError, match="native host core"):
+        make_aligner("tpu", 1)
+    with pytest.raises(ValueError, match="native host core"):
+        make_consensus("tpu", 3, -5, -4)
+    # the host backends keep their Python fallback
+    assert type(make_aligner("auto", 1)).__name__ == "PythonAligner"

@@ -117,3 +117,64 @@ def test_breaking_points_match_cigar_walker():
             cigars[k], q_off, t_begin, t_begin + len(t), w)
         assert bps[k].dtype == np.int32 and bps[k].shape[1] == 4
         assert bp_array_to_pairs(bps[k]) == oracle, f"pair {k}"
+
+
+# ------------------------------------------- kernel family by platform
+
+def test_pallas_ok_is_false_off_the_tpu_without_a_mosaic_attempt(
+        monkeypatch, capsys):
+    """On the CPU platform the XLA kernels ARE the path: ``pallas_ok()``
+    answers False from the platform alone — no Mosaic call is tried, so
+    nothing is swallowed and no warning is printed."""
+    from racon_tpu.obs import metrics
+    from racon_tpu.ops import pallas_nw
+
+    def no_mosaic(*a, **kw):
+        raise AssertionError("a Mosaic kernel was attempted off the TPU")
+
+    monkeypatch.setattr(pallas_nw, "_PALLAS_OK", None)
+    monkeypatch.setattr(pallas_nw, "_PALLAS_SWAR_OK", None)
+    monkeypatch.setattr(pallas_nw, "pallas_nw_fwd", no_mosaic)
+    monkeypatch.setattr(pallas_nw, "pallas_walk_ops", no_mosaic)
+    monkeypatch.setattr(pallas_nw, "pallas_walk_vote", no_mosaic)
+    before = dict(metrics.group("swallowed."))
+    assert pallas_nw.pallas_ok() is False
+    assert pallas_nw.pallas_swar_ok() is False
+    assert dict(metrics.group("swallowed.")) == before
+    assert "swallowed" not in capsys.readouterr().err
+
+
+def test_pallas_probe_failure_is_a_hard_error_on_the_tpu(monkeypatch):
+    """On the TPU a probe that raises or mismatches fails the run — it
+    is never a logged downgrade to the XLA kernels, and the memo stays
+    unset so nothing later reads a quiet False."""
+    import jax
+    from racon_tpu.ops import pallas_nw, swar
+
+    monkeypatch.setattr(pallas_nw, "_PALLAS_OK", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the CPU backend refuses the Mosaic call itself: that must surface
+    with pytest.raises(Exception, match="[Ii]nterpret mode|Mosaic|TPU"):
+        pallas_nw.pallas_ok()
+    assert pallas_nw._PALLAS_OK is None
+
+    def mismatch():
+        swar.probe_equal("pallas_nw_fwd", "dirs", [[0, 1], [2, 3]],
+                         [[0, 1], [2, 7]])
+
+    monkeypatch.setattr(pallas_nw, "_probe_pallas", mismatch)
+    with pytest.raises(swar.KernelProbeError,
+                       match=r"pallas_nw_fwd.*'dirs'.*\(1, 1\).*3.*7"):
+        pallas_nw.pallas_ok()
+    assert pallas_nw._PALLAS_OK is None
+
+
+def test_engine_takes_the_platforms_kernels_without_a_downgrade_path():
+    """The per-shape Pallas->XLA downgrade bookkeeping is gone: an engine
+    asks ``_use_pallas`` (the platform's answer) and keeps no failed-
+    shape memo or fallback stat."""
+    a = TpuAligner(fallback=NativeAligner(1))
+    assert a._use_pallas((256, 128, 512, 8)) is False
+    assert not any("pallas" in k for k in a.stats)
+    assert not [n for n in dir(a) if "pallas_fail" in n
+                or "note_pallas" in n]

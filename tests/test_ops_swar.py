@@ -469,3 +469,39 @@ def test_native_parser_long_single_line_fasta(tmp_path):
     assert len(recs) == 1
     assert recs[0].name == b"contig_long"
     assert recs[0].data == seq
+
+
+def test_probe_equal_names_kernel_and_first_difference():
+    """The probes' comparison raises with the kernel, the output and the
+    first differing element (what a chip run prints when a kernel family
+    miscomputes) and passes silently on equality."""
+    a = np.arange(12, dtype=np.int32).reshape(3, 4)
+    swar.probe_equal("k", "out", a, a.copy())
+    b = a.copy()
+    b[1, 2] = 99
+    b[2, 0] = 98
+    with pytest.raises(swar.KernelProbeError,
+                       match=r"k: output 'out' differs .* 2 of 12 "
+                             r"elements, first at \(1, 2\): got 6, "
+                             r"reference 99"):
+        swar.probe_equal("k", "out", a, b)
+    with pytest.raises(swar.KernelProbeError, match="shape"):
+        swar.probe_equal("k", "out", a, a[:2])
+
+
+def test_swar_probe_mismatch_raises(monkeypatch):
+    """``swar_ok()`` no longer selects int32 quietly: a packed kernel
+    that disagrees with the int32 kernel fails the run."""
+    from racon_tpu.ops import nw
+
+    real = nw._nw_wavefront_kernel
+
+    def skewed(*a, swar=False, **kw):
+        dirs, score = real(*a, swar=swar, **kw)
+        return dirs, (score + 1 if swar else score)
+
+    monkeypatch.setattr(swar, "_SWAR_OK", None)
+    monkeypatch.setattr(nw, "_nw_wavefront_kernel", skewed)
+    with pytest.raises(swar.KernelProbeError, match="SWAR wavefront.*score"):
+        swar.swar_ok()
+    assert swar._SWAR_OK is None

@@ -167,7 +167,7 @@ def test_ctypes_ovl_fallback_matches_oracle(data_dir):
         import pytest
         pytest.skip("native library unavailable")
 
-    with mock.patch.object(native, "_load_ext", lambda: None):
+    with mock.patch.object(native, "load_ext", lambda: None):
         for fname, fmt, parser in (
                 ("sample_overlaps.paf.gz", 0, P.parse_paf),
                 ("sample_ava_overlaps.mhap.gz", 1, P.parse_mhap),

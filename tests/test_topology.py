@@ -57,10 +57,27 @@ def test_chip_slot_pin_places_arrays():
 def test_resolve_chips_flag(monkeypatch):
     assert topology.resolve_chips(0) == 8       # auto: every device
     assert topology.resolve_chips(3) == 3       # explicit wins
-    assert topology.resolve_chips(64) == 8      # clamped to topology
+    with pytest.raises(ValueError, match="64 chips.*8 local"):
+        topology.resolve_chips(64)              # an error, never a clamp
     monkeypatch.setenv("RACON_TPU_CHIPS", "5")
     assert topology.resolve_chips(0) == 5       # env flag
     assert topology.resolve_chips(2) == 2       # explicit beats flag
+    monkeypatch.setenv("RACON_TPU_CHIPS", "9")
+    with pytest.raises(ValueError, match="9 chips"):
+        topology.resolve_chips(0)               # the env request too
+
+
+def test_resolve_chips_more_than_devices_raises(monkeypatch):
+    """``--chips 4`` on a two-device host is an error: clamping would
+    make it a 2-chip run that says nothing."""
+    monkeypatch.setattr(topology, "local_devices",
+                        lambda: jax.devices()[:2])
+    assert topology.resolve_chips(0) == 2
+    assert topology.resolve_chips(2) == 2
+    with pytest.raises(ValueError, match="4 chips.*2 local"):
+        topology.resolve_chips(4)
+    with pytest.raises(ValueError, match="4 chips"):
+        topology.Topology(4)
 
 
 def test_get_mesh_device_prefix():
@@ -310,8 +327,44 @@ print("COMPILE_S=%.4f" % (time.perf_counter() - t0))
 """
 
 
+def test_compile_cache_env_var_places_it(monkeypatch, tmp_path, capsys):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no cache
+    directory in code (JAX reads the variable itself) and an explicit
+    --compile-cache yields to it with a stderr note."""
+    from racon_tpu import ops
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updates.append(name), real_update(name, val)))
+    assert ops.configure_compile_cache() == str(tmp_path / "env")
+    assert ops.configure_compile_cache(str(tmp_path / "cli")) == \
+        str(tmp_path / "env")
+    assert "jax_compilation_cache_dir" not in updates
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "cli").exists()
+    err = capsys.readouterr().err
+    assert "JAX_COMPILATION_CACHE_DIR" in err and "ignoring" in err
+
+
+def test_compile_cache_default_is_in_checkout(monkeypatch):
+    """Unset, the cache resolves to the fixed <checkout>/.xla_cache —
+    never $HOME, a temporary name, a pid or a time (the path is part of
+    the cache key)."""
+    from racon_tpu import ops
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO_ROOT, ".xla_cache")
+    assert ops.DEFAULT_COMPILE_CACHE == want
+    assert ops.configure_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
 def test_compile_cache_second_run_near_zero(tmp_path):
-    """RACON_TPU_COMPILE_CACHE wiring: a second process compiling the
+    """JAX_COMPILATION_CACHE_DIR wiring: a second process compiling the
     same kernel shape loads it from the persistent cache instead of
     recompiling — proven by the cache gaining ZERO new entries on the
     second run (with min_compile_time 0 every fresh compile would
@@ -322,7 +375,7 @@ def test_compile_cache_second_run_near_zero(tmp_path):
 
     def run_once():
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   RACON_TPU_COMPILE_CACHE=str(cache))
+                   JAX_COMPILATION_CACHE_DIR=str(cache))
         out = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
                              capture_output=True, text=True, env=env,
                              cwd=REPO_ROOT, check=True)

@@ -75,6 +75,10 @@ def fragment_kc(tag):
 
 
 def main():
+    if not os.path.isdir(DATA):
+        sys.exit(f"record_goldens: the reference's λ-phage test data is "
+                 f"not at {DATA}; this tool works only where that "
+                 f"directory exists (the goldens are recorded against it)")
     import jax
     print(f"devices: {jax.devices()}", flush=True)
     consensus("sample_reads.fastq.gz", "sample_overlaps.paf.gz",

@@ -119,7 +119,7 @@ def simulate(mbp: float, seed: int = 23, coverage: int = 30,
 
 
 def write_inputs(mbp: float, out_dir: str, seed: int = 23,
-                 coverage: int = 30) -> dict:
+                 coverage: int = 30, n_contigs: int = 0) -> dict:
     """Generate and write the input triple (+ truth contigs) to
     ``out_dir``. Exists as a CLI so benches can generate big workloads in
     a THROWAWAY subprocess: a 100 Mbp set materializes several GB of read
@@ -128,7 +128,8 @@ def write_inputs(mbp: float, out_dir: str, seed: int = 23,
     import os
 
     reads, paf, contigs, truths = simulate(mbp, seed=seed,
-                                           coverage=coverage)
+                                           coverage=coverage,
+                                           n_contigs=n_contigs)
     os.makedirs(out_dir, exist_ok=True)
     paths = {"reads": os.path.join(out_dir, "reads.fastq"),
              "overlaps": os.path.join(out_dir, "ovl.paf"),
@@ -153,5 +154,9 @@ if __name__ == "__main__":
     ap.add_argument("out_dir")
     ap.add_argument("--seed", type=int, default=23)
     ap.add_argument("--coverage", type=int, default=30)
+    ap.add_argument("--contigs", type=int, default=0,
+                    help="split the genome into this many equal contigs "
+                         "(default: one per 2 Mbp)")
     a = ap.parse_args()
-    write_inputs(a.mbp, a.out_dir, seed=a.seed, coverage=a.coverage)
+    write_inputs(a.mbp, a.out_dir, seed=a.seed, coverage=a.coverage,
+                 n_contigs=a.contigs)
