@@ -523,16 +523,6 @@ def main(argv=None) -> int:
         if args.profile:
             import jax
             trace = jax.profiler.trace(args.profile)
-            # --profile wraps the WHOLE run in jax.profiler.trace; a
-            # concurrent RACON_TPU_JAX_PROFILE bracket around the polish
-            # phase would try to start a second trace inside it, which
-            # the jax profiler rejects mid-run — the wider --profile
-            # wins and the env hook is disarmed with a note
-            if flags.get_str("RACON_TPU_JAX_PROFILE"):
-                print("[racon::] note: --profile supersedes "
-                      "RACON_TPU_JAX_PROFILE (nested jax profiler "
-                      "sessions are not supported)", file=sys.stderr)
-                os.environ["RACON_TPU_JAX_PROFILE"] = ""
         else:
             trace = contextlib.nullcontext()
         with trace:

@@ -67,8 +67,9 @@ METRICS: FrozenSet[str] = frozenset((
     "aligner.fallback_band", "aligner.fallback_length",
     "aligner.ladder_narrow", "aligner.pallas_chunks",
     "aligner.swar_chunks", "aligner.swar_guard_int32",
-    # XLA compile attribution
-    "compile.backend_total", "compile.jax_s",
+    # XLA compile attribution + JAX's persistent-cache lookups
+    "compile.backend_total", "compile.cache_hits",
+    "compile.cache_requests", "compile.jax_s",
     # consensus pair arenas
     "consensus.capacity_scale", "consensus.dropped_layers",
     "consensus.fallback_windows", "consensus.group_windows",
@@ -128,6 +129,7 @@ DYNAMIC_METRIC_PREFIXES: Tuple[str, ...] = (
     "faults.",           # faults.<class> taxonomy counts
     "faults.injected.",  # faults.injected.<site>
     "fleet.tenant.",     # fleet.tenant.<name>.placed/.queued/...
+    "idle.",             # idle.<span>: device-idle seconds by host span
     "retrace.",          # retrace.<phase> per-phase deltas
     "retrace_total.",    # retrace_total.<phase> run accumulators
     "swallowed.",        # swallowed.<context>|<exc-type>
@@ -145,12 +147,15 @@ JOB_SCOPE_ROOT = "job."
 # process-lifetime facts that must survive run boundaries.  "aligner."
 # was the round-22 drift find: the family
 # existed since round 17 but never matched "align." (no dot), so its
-# counters leaked across back-to-back runs in one process.
+# counters leaked across back-to-back runs in one process.  A family
+# prefix also clears its bare name (metrics.clear_run): the aggregate
+# "align" / "consensus" span timers match no dotted prefix.  "idle." is
+# the occupancy ledger's derived family (obs/device_time.py).
 RUN_PREFIXES: Tuple[str, ...] = (
     "align.", "aligner.", "poa.", "consensus.", "queue.", "retrace.",
     "retrace_total.", "swallowed.", "trace.", "parse.", "overlap.",
     "transmute", "bp.", "build.", "stitch", "exec.", "faults.",
-    "lease.", "device.", "compile.", "dataflow.",
+    "lease.", "device.", "compile.", "dataflow.", "idle.",
 )
 
 # ------------------------------------------------------------- span names
@@ -160,8 +165,13 @@ RUN_PREFIXES: Tuple[str, ...] = (
 # a renamed span silently zeroes a report column)
 SPANS: FrozenSet[str] = frozenset((
     "align", "align.dispatch", "align.fetch",
+    # leaves of align.dispatch / align.fetch (the parents stay whole)
+    "align.pack", "align.put", "align.launch",
+    "align.wait", "align.get", "align.decode",
     "bp.decode",
     "build.backbone", "build.store", "build.windows",
+    # the compile listener's back-dated stages (obs/compilewatch.py)
+    "compile.backend", "compile.lower", "compile.trace",
     "consensus", "consensus.feed", "consensus.finish", "consensus.run",
     "exec.extract", "exec.index", "exec.merge", "exec.plan",
     "exec.shard",
@@ -172,6 +182,8 @@ SPANS: FrozenSet[str] = frozenset((
     "overlap.seed.fetch",
     "parse.overlaps", "parse.reads", "parse.targets",
     "poa.dispatch", "poa.fetch", "poa.pack", "poa.stage_b",
+    # leaves of poa.pack / poa.fetch
+    "poa.put", "poa.wait", "poa.get", "poa.decode",
     "queue.get", "queue.put",
     "stitch", "transmute",
 ))
@@ -196,7 +208,11 @@ FAULT_CLASSES: Tuple[str, ...] = ("transient-io", "device-oom", "stall",
 
 # -------------------------------------------------------- report schema
 
-SCHEMA_VERSION = 11
+SCHEMA_VERSION = 12
+
+# the oldest version validate_report still accepts, as itself: a stored
+# v11 report is held to the v11 key sets
+MIN_SCHEMA_VERSION = 11
 
 REPORT_KINDS: Tuple[str, ...] = ("cli", "exec", "job")
 
@@ -219,6 +235,7 @@ TOP_KEYS: Dict[str, int] = {
     "dataflow": 8,
     "overlap": 9,
     "fleet": 11,
+    "device_time": 12,
 }
 
 SECTION_KEYS: Dict[str, Dict[str, int]] = {
@@ -264,6 +281,12 @@ SECTION_KEYS: Dict[str, Dict[str, int]] = {
         "hosts_alive": 11, "hosts_dead": 11,
         "cost_cache_hits": 11, "cost_cache_misses": 11,
     },
+    "device_time": {
+        "window_s": 12, "busy_s": 12, "idle_s": 12, "head_idle_s": 12,
+        "tail_idle_s": 12, "programs": 12, "by_program": 12,
+        "idle_by": 12, "timeline": 12, "dropped": 12, "gaps": 12,
+        "clock": 12, "devices": 12,
+    },
 }
 
 # schema keys REMOVED at a version (key -> (section, removed_in));
@@ -300,6 +323,7 @@ SECTION_EMITTERS: Dict[str, Tuple[str, str]] = {
     "dataflow": ("racon_tpu/obs/metrics.py", "dataflow_summary"),
     "overlap": ("racon_tpu/obs/metrics.py", "overlap_summary"),
     "fleet": ("racon_tpu/obs/metrics.py", "fleet_summary"),
+    "device_time": ("racon_tpu/obs/device_time.py", "account"),
 }
 
 # report key -> the metric whose emission backs it ("section.key" ->

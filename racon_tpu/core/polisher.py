@@ -1219,11 +1219,7 @@ class Polisher:
         log.log()
 
         msg = "[racon_tpu::Polisher::polish] generating consensus"
-        # RACON_TPU_JAX_PROFILE brackets exactly the polish phase in
-        # jax.profiler.trace so XLA device activity lines up with the
-        # host spans (nullcontext when unset)
         with obs.span("consensus", windows=len(self.windows)), \
-                obs.jax_profile(), \
                 sanitize.PhaseRetraceBudget(
                     "consensus", prefixes=("racon_tpu.ops.poa",
                                            "racon_tpu.ops.pallas_nw",
@@ -1355,7 +1351,6 @@ class Polisher:
         fed_ranges: List = []
         try:
             with obs.span("consensus", windows=n_win), \
-                    obs.jax_profile(), \
                     sanitize.PhaseRetraceBudget(
                         "consensus", prefixes=("racon_tpu.ops.poa",
                                                "racon_tpu.ops.pallas_nw",

@@ -112,14 +112,11 @@ REGISTRY: Dict[str, Flag] = _declare([
          "(parse/align/decode/build/consensus/stitch, queue waits, "
          "per-shard tracks) to this file — load it in Perfetto or "
          "chrome://tracing; equivalent to the CLI --trace flag."),
-    Flag("RACON_TPU_JAX_PROFILE", "", "path",
-         "Bracket the polish phase in jax.profiler.trace writing to "
-         "this directory, so XLA device activity lines up with the "
-         "host spans (view with TensorBoard / xprof)."),
     Flag("RACON_TPU_RUN_REPORT", "", "path",
          "Write the schema-versioned machine-readable run_report.json "
          "(per-phase wall clock, dispatch-vs-fetch split, pack "
-         "occupancy, retrace and queue-stall metrics, per-shard rows) "
+         "occupancy, retrace and queue-stall metrics, the device_time "
+         "occupancy ledger, per-shard rows) "
          "to this file; equivalent to the CLI --run-report flag."),
     # ----------------------------------------------------------- sanitizer
     Flag("RACON_TPU_SANITIZE", "0", "bool",

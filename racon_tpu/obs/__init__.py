@@ -1,6 +1,6 @@
 """racon_tpu.obs — the unified observability subsystem.
 
-Three layers over one registry:
+Four layers over one registry:
 
 - **spans** (:mod:`.trace`) — ``with obs.span("align.dispatch"): ...``
   context-manager tracing threaded through the whole pipeline, exported
@@ -14,25 +14,30 @@ Three layers over one registry:
 - **run reports** (:mod:`.report`) — schema-versioned
   ``run_report.json`` per CLI/exec run (``--run-report FILE`` /
   ``RACON_TPU_RUN_REPORT``), validated first-party.
+- **device time** (:mod:`.device_time`) — the occupancy ledger: what
+  the program submitted to each device and when the device was done,
+  every idle second charged to the host span of the feeding thread (the
+  report's ``device_time`` section, the ``idle.<span>`` timers);
+  ``python -m racon_tpu.obs gaps`` lays it on a device trace.
 
-``RACON_TPU_JAX_PROFILE=DIR`` additionally brackets the polish phase in
-``jax.profiler.trace`` so XLA device activity lines up with the host
-spans (:func:`jax_profile`).
+One clock: spans, submissions and completions are stamped with
+``time.perf_counter_ns()``; :func:`begin` records the pair
+(``perf_counter_ns``, ``time.time_ns``) that the Chrome trace's metadata
+and the run report carry.
 """
 
 from __future__ import annotations
 
-import threading
-from contextlib import contextmanager, nullcontext
-
-from . import compilewatch, metrics, report, trace
+from . import compilewatch, device_time, metrics, report, trace
 from .trace import span, track  # noqa: F401  (the public span surface)
 
 
 def begin(trace_path=None, report_path=None) -> None:
-    """Mark a run boundary (per-run metrics reset) and arm span
-    recording: timers whenever either output was requested, ring
-    buffers only when a trace file was."""
+    """Mark a run boundary (per-run metrics, compile attribution and
+    the occupancy ledger reset) and set span recording — timers and
+    rings — to what this run asked for: on when either output was
+    requested, off otherwise (a job without ``--run-report`` that
+    follows one with it in the same process pays for nothing)."""
     metrics.clear_run()
     # compile attribution resets with the run metrics it rides next to
     # (clear_run drops the compile.* timers/counters) — a second run in
@@ -40,38 +45,9 @@ def begin(trace_path=None, report_path=None) -> None:
     # once per CLI/exec run; the resident server jobs never pass
     # through here, so the serve warm-path seal is untouched.
     compilewatch.reset()
+    device_time.reset()
     if trace_path or report_path:
+        trace.new_run()
         trace.activate(tracing=bool(trace_path))
-
-
-# one jax.profiler session per process: concurrent chip workers each
-# bracket their consensus phase in jax_profile(), and a second
-# profiler.trace start raises mid-polish — the loser would fault its
-# shard down the degradation ladder over telemetry
-_profile_lock = threading.Lock()
-
-
-def jax_profile():
-    """A context manager bracketing the enclosed phase in
-    ``jax.profiler.trace(RACON_TPU_JAX_PROFILE)`` — a no-op nullcontext
-    when the flag is unset (jax is not even imported then).  JAX allows
-    ONE profiler session per process, so when another thread (a
-    concurrent chip worker) already holds it, the phase runs
-    unprofiled instead of aborting the shard."""
-    from .. import flags
-    profile_dir = flags.get_str("RACON_TPU_JAX_PROFILE")
-    if not profile_dir:
-        return nullcontext()
-    if not _profile_lock.acquire(blocking=False):
-        return nullcontext()
-
-    @contextmanager
-    def _held():
-        try:
-            import jax
-            with jax.profiler.trace(profile_dir):
-                yield
-        finally:
-            _profile_lock.release()
-
-    return _held()
+    else:
+        trace.deactivate()

@@ -1,5 +1,7 @@
 """``python -m racon_tpu.obs --check FILE`` — run-report validation
-(the CI e2e check drives this)."""
+(the CI e2e check drives this); ``python -m racon_tpu.obs gaps
+RUN_REPORT DEVICE_TRACE`` — the report's occupancy ledger laid on a
+device trace (:mod:`racon_tpu.obs.gaps`)."""
 
 import sys
 

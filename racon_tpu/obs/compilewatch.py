@@ -9,6 +9,17 @@ round 14) observes every ``/jax/core/compile/*`` event and:
   fired on the compiling thread, so a service job's worker thread
   lands the time in THAT job's metric scope (the measured numerator of
   ``service_compile_fraction``, exactly as before);
+- back-dates one span event per stage (``compile.trace``,
+  ``compile.lower``, ``compile.backend``, from the event's own
+  duration) onto the compiling thread's ring and into the timers of
+  those names, so what the persistent cache saves (backend) reads apart
+  from what it does not (trace + lowering). A stage nested in another
+  (a jit traced inside a trace) gives its parent's timer only the
+  parent's self time: the three timers sum to thread time in the
+  compile pipeline, where ``compile.jax_s`` sums every event whole;
+- counts the persistent cache's lookups and hits
+  (``compile.cache_requests`` / ``compile.cache_hits``) from JAX's own
+  ``/jax/compilation_cache/*`` events;
 - **attributes** every backend compile to ``(function, shape
   signature, phase, scope)``: the nearest ``racon_tpu`` frame on the
   compiling thread's stack names the driving function, its integer
@@ -36,6 +47,7 @@ from __future__ import annotations
 import math
 import sys
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 from . import metrics, trace
@@ -49,6 +61,22 @@ GEOM_LOCALS = ("max_len", "band", "steps", "B", "nWp", "Lq", "Lb",
 
 MAX_EVENTS = 256        # bounded event ring (newest kept)
 MAX_VIOLATIONS = 64
+
+# JAX's compile-pipeline stages -> the span each is back-dated as
+STAGE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+# JAX's persistent-cache events -> the counter each feeds
+CACHE_COUNTERS = {
+    "/jax/compilation_cache/compile_requests_use_cache":
+        "compile.cache_requests",
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+}
+MAX_UNCLAIMED = 256     # per thread: stage events no parent has claimed
+
+_tls = threading.local()
 
 _lock = threading.Lock()
 _armed = False
@@ -98,14 +126,44 @@ def _attribute() -> Tuple[str, str]:
     return fn, ",".join(parts)
 
 
+def _record_stage(name: str, duration: float) -> None:
+    """Back-date one finished stage onto this thread's ring; its timer
+    gets the stage's self time (a stage that ended inside this one was
+    recorded before it: children fire first)."""
+    t1 = time.perf_counter_ns()
+    t0 = t1 - int(duration * 1e9)
+    unclaimed = getattr(_tls, "unclaimed", None)
+    if unclaimed is None:
+        unclaimed = _tls.unclaimed = []
+    inside = 0
+    while unclaimed and unclaimed[-1][0] >= t0:
+        c0, c1 = unclaimed.pop()
+        inside += c1 - c0
+    unclaimed.append((t0, t1))
+    del unclaimed[:-MAX_UNCLAIMED]
+    trace.record(name, t0, t1, seconds=max(0, t1 - t0 - inside) * 1e-9)
+
+
+def _on_event(event, **kwargs) -> None:
+    """The registered plain-event listener: the persistent cache's
+    lookups and hits."""
+    name = CACHE_COUNTERS.get(str(event))
+    if name is not None:
+        metrics.inc(name)
+
+
 def _on_duration(event, duration, **kwargs) -> None:
     """The registered listener: every compile-pipeline stage feeds the
-    ``compile.jax_s`` timer (the round-14 serve semantics, verbatim);
-    backend compiles additionally produce one attributed record."""
+    ``compile.jax_s`` timer (the round-14 serve semantics, verbatim)
+    and its own back-dated span; backend compiles additionally produce
+    one attributed record."""
     global _total_count
     if not str(event).startswith("/jax/core/compile/"):
         return
     metrics.add_time("compile.jax_s", duration)
+    stage = STAGE_SPANS.get(str(event))
+    if stage is not None:
+        _record_stage(stage, duration)
     if "backend_compile" not in str(event):
         return
     fn, signature = _attribute()
@@ -212,6 +270,7 @@ def arm() -> bool:
     with _lock:
         if not _armed:
             jmon.register_event_duration_secs_listener(_on_duration)
+            jmon.register_event_listener(_on_event)
             _armed = True
     return True
 

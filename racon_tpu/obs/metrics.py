@@ -115,6 +115,23 @@ def add_time(name: str, seconds: float) -> None:
         _timers[scoped] = _timers.get(scoped, 0.0) + seconds
 
 
+def replace_timers(prefix: str, values: Dict[str, float],
+                   scope: str = "") -> None:
+    """Set the whole timer family ``prefix`` of ``scope`` to ``values``
+    (``{suffix: seconds}``), dropping what it held — for a family that
+    is *derived* at report time (the occupancy ledger's ``idle.<span>``
+    seconds), so a report built twice reads the same numbers. The
+    scope is explicit, like a reader's: the thread that builds a job's
+    report is not the job's."""
+    scoped = scope + prefix
+    with _lock:
+        for k in [k for k in _timers if k.startswith(scoped)]:
+            del _timers[k]
+        for suffix, seconds in values.items():
+            _seen.add(prefix + suffix)
+            _timers[scoped + suffix] = seconds
+
+
 def seen_names() -> Set[str]:
     """Every metric name written this process (scope-stripped,
     cumulative across :func:`clear_run`/:func:`clear_job`) — the exit
@@ -180,7 +197,17 @@ def clear_run() -> None:
     (``job.<id>.*``) are deliberately NOT touched: a run boundary in
     one thread (a service job starting, a bench leg) must never wipe a
     concurrent job's in-flight gauges — that is :func:`clear_job`'s
-    call, made by the job's own lifecycle."""
+    call, made by the job's own lifecycle.
+
+    A family prefix (``"align."``) also drops the bare name
+    (``"align"``): the aggregate ``align`` / ``consensus`` span timers
+    match no dotted prefix and used to leak across the runs of one
+    process."""
+    bare = [p[:-1] for p in _RUN_PREFIXES if p.endswith(".")]
+    with _lock:
+        for store in (_counters, _gauges, _timers):
+            for name in bare:
+                store.pop(name, None)
     for prefix in _RUN_PREFIXES:
         clear(prefix)
 

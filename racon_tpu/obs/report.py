@@ -23,7 +23,7 @@ import os
 import sys
 from typing import Dict, List, Optional
 
-from . import compilewatch, metrics
+from . import compilewatch, device_time, metrics
 from .. import contracts
 
 # v2 (round 12): the "faults" section (fault-class / injected-site /
@@ -91,6 +91,16 @@ from .. import contracts
 # preemptions, the host-registry liveness gauges and the admission
 # cost-estimate cache accounting.  Gateway-level, unscoped; all zeros
 # for plain CLI/exec/serve runs.
+# v12 (PR 25): the "device_time" section became required — the
+# device-occupancy ledger (racon_tpu.obs.device_time): the window's
+# busy and idle seconds as the program itself saw them (what it
+# submitted, when the device was done), idle split into head / gaps /
+# tail and charged to the innermost host span of the feeding thread
+# ("idle_by", mirrored as the ``idle.<span>`` timers), per-program
+# occupied seconds, the first 256 ledger rows ("timeline"), the 32
+# longest idle intervals ("gaps") and the run's clock pair ("clock":
+# the same instant as perf_counter_ns and time_ns).  All zeros when
+# nothing was recorded.  A stored v11 report still validates as v11.
 # the schema's key sets (per section, per version) live in
 # racon_tpu/contracts.py — ONE registry shared with the schema-coherence
 # lint rule, so a schema bump is a contracts.py edit the gate enforces
@@ -101,6 +111,8 @@ SCHEMA_VERSION = contracts.SCHEMA_VERSION
 KINDS = contracts.REPORT_KINDS
 
 _NUM = (int, float)
+
+MIN_SCHEMA_VERSION = contracts.MIN_SCHEMA_VERSION
 
 _SCHEMA_KEYS = contracts.schema_keys()
 
@@ -123,6 +135,7 @@ _TOP = {
     "dataflow": (dict, True),           # resident-dataflow bytes (v8)
     "overlap": (dict, True),            # first-party overlapper (v9/v10)
     "fleet": (dict, True),              # fleet gateway counters (v11)
+    "device_time": (dict, True),        # device-occupancy ledger (v12)
     "devices": (dict, True),            # per-chip rows ({} single-chip)
     "peak_rss_bytes": (int, True),
     "metrics": (dict, True),            # full registry snapshot
@@ -143,6 +156,8 @@ _COMPILES_NUM_KEYS = tuple(sorted(
     _SCHEMA_KEYS["compiles"] - {"by_function", "events"}))
 _DATAFLOW_KEYS = tuple(sorted(_SCHEMA_KEYS["dataflow"]))
 _FLEET_KEYS = tuple(sorted(_SCHEMA_KEYS["fleet"]))
+_DEVICE_TIME_NUM_KEYS = ("window_s", "busy_s", "idle_s", "head_idle_s",
+                         "tail_idle_s", "programs", "dropped")
 # "mode" is the one string key of the overlap section
 _OVERLAP_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["overlap"] - {"mode"}))
 _OVERLAP_MODES = contracts.OVERLAP_MODES
@@ -259,6 +274,11 @@ def build_report(kind: str, *, argv: Optional[list] = None,
         # the span-timer mirrors (dispatch/fetch per chip). {} on
         # single-chip runs.
         "devices": metrics.device_summary(scope),
+        # the occupancy ledger (schema v12): busy/idle seconds of the
+        # window that ends now, idle charged to host spans.  Built
+        # BEFORE the metrics snapshot below: it writes the idle.<span>
+        # timers the snapshot carries
+        "device_time": device_time.summary(scope, float(wall_s)),
         "peak_rss_bytes": metrics.peak_rss_bytes(),
         "metrics": metrics.snapshot(scope or None),
     }
@@ -288,22 +308,64 @@ def _check_numeric_dict(errors: List[str], d: dict, where: str) -> None:
             errors.append(f"{where}[{k!r}] is not a numeric value: {v!r}")
 
 
+def _check_device_time(errors: List[str], dt: dict) -> None:
+    where = "device_time"
+    for key in sorted(_SCHEMA_KEYS[where] - set(dt)):
+        errors.append(f"{where}[{key!r}] missing")
+    for key in sorted(set(dt) - _SCHEMA_KEYS[where]):
+        errors.append(f"{where} unknown key {key!r}")
+    if errors:
+        return
+    for key in _DEVICE_TIME_NUM_KEYS:
+        if not isinstance(dt[key], _NUM) or isinstance(dt[key], bool):
+            errors.append(f"{where}[{key!r}] non-numeric")
+    for key in ("idle_by", "clock"):
+        if not isinstance(dt[key], dict):
+            errors.append(f"{where}[{key!r}] is not an object")
+        else:
+            _check_numeric_dict(errors, dt[key], f"{where}.{key}")
+    for key in ("by_program", "devices"):
+        if not isinstance(dt[key], dict) or not all(
+                isinstance(row, dict) for row in dt[key].values()):
+            errors.append(f"{where}[{key!r}] is not an object of rows")
+    if isinstance(dt["by_program"], dict):
+        for name, row in dt["by_program"].items():
+            if isinstance(row, dict):
+                _check_numeric_dict(errors, row,
+                                    f"{where}.by_program[{name!r}]")
+    if not isinstance(dt["timeline"], list) or not all(
+            isinstance(r, list) and len(r) == 6 for r in dt["timeline"]):
+        errors.append(f"{where}['timeline'] is not a list of [device, "
+                      f"kind, name, thread, submit_ns, complete_ns] rows")
+    if not isinstance(dt["gaps"], list) or not all(
+            isinstance(g, list) and len(g) == 3 and isinstance(g[2], dict)
+            for g in dt["gaps"]):
+        errors.append(f"{where}['gaps'] is not a list of [start_ns, "
+                      f"end_ns, {{span: seconds}}] rows")
+
+
 def validate_report(rep) -> List[str]:
     """Schema-check a (parsed) report; returns violations, [] = valid."""
     errors: List[str] = []
     if not isinstance(rep, dict):
         return [f"report is not an object: {type(rep).__name__}"]
-    if rep.get("schema_version") != SCHEMA_VERSION:
-        errors.append(f"schema_version {rep.get('schema_version')!r} "
-                      f"!= {SCHEMA_VERSION}")
-    for key, (types, required) in _TOP.items():
+    version = rep.get("schema_version")
+    if isinstance(version, bool) or not isinstance(version, int) \
+            or not MIN_SCHEMA_VERSION <= version <= SCHEMA_VERSION:
+        errors.append(f"schema_version {version!r} not in "
+                      f"{MIN_SCHEMA_VERSION}..{SCHEMA_VERSION}")
+        version = SCHEMA_VERSION
+    # a stored report of an older version is held to ITS key sets
+    top = {k: v for k, v in _TOP.items()
+           if contracts.TOP_KEYS[k] <= version}
+    for key, (types, required) in top.items():
         if key not in rep:
             if required:
                 errors.append(f"missing required key {key!r}")
             continue
         if not isinstance(rep[key], types) or isinstance(rep[key], bool):
             errors.append(f"{key!r} has type {type(rep[key]).__name__}")
-    for key in set(rep) - set(_TOP):
+    for key in set(rep) - set(top):
         errors.append(f"unknown key {key!r}")
     if errors:
         return errors
@@ -368,6 +430,8 @@ def validate_report(rep) -> List[str]:
                 errors.append(f"compiles.events[{i}] is not an "
                               f"attributed record (fn/signature/phase/"
                               f"duration_s)")
+    if "device_time" in top:
+        _check_device_time(errors, rep["device_time"])
     for kind in ("counters", "gauges", "timers"):
         store = rep["metrics"].get(kind)
         if not isinstance(store, dict):
@@ -432,10 +496,14 @@ def _main(argv) -> int:
             print(f"run report {argv[1]}: {err}", file=sys.stderr)
         if not errors:
             print(f"run report {argv[1]}: valid "
-                  f"(schema v{SCHEMA_VERSION}, kind={rep['kind']}, "
+                  f"(schema v{rep['schema_version']}, kind={rep['kind']}, "
                   f"{len(rep.get('shards', []))} shard rows)")
         return 1 if errors else 0
-    print("usage: python -m racon_tpu.obs --check FILE",
+    if argv and argv[0] == "gaps":
+        from . import gaps
+        return gaps.main(argv[1:])
+    print("usage: python -m racon_tpu.obs --check FILE\n"
+          "       python -m racon_tpu.obs gaps RUN_REPORT DEVICE_TRACE",
           file=sys.stderr)
     return 2
 

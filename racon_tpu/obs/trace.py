@@ -9,21 +9,27 @@ The one public span surface is the context manager::
 (the graftlint rule ``span-discipline`` enforces the ``with`` form —
 manual begin/end pairs leak open spans when an exception unwinds).
 
-Two independent switches:
+One switch, **active** (:func:`activate`; a run report *or* a trace was
+asked for): span exits accumulate their duration into the metrics
+registry's timers keyed by the span name (the run report's
+dispatch-vs-fetch split reads them) and land in per-thread ring buffers
+(bounded: the oldest events of a thread drop first, counted in
+``trace.dropped_events``) — :func:`export` writes them under ``--trace``
+and the device-occupancy ledger (:mod:`.device_time`) reads them to name
+what the feeding thread did while the device had nothing.
+(``activate``'s ``tracing`` argument is what callers pass when an export
+was asked for; recording no longer depends on it.)
 
-- **active** (:func:`activate`) — span exits accumulate their duration
-  into the metrics registry's timers keyed by the span name (the run
-  report's dispatch-vs-fetch split reads them).  On by itself when only
-  a run report was requested.
-- **tracing** (``activate(tracing=True)``) — span events additionally
-  land in per-thread ring buffers (bounded: the oldest events of a
-  thread drop first, counted in ``trace.dropped_events``) for
-  :func:`export`.
+**One clock**: every stamp is ``time.perf_counter_ns()``.
+:func:`new_run` (``obs.begin``) and the first :func:`activate` record
+the pair (``perf_counter_ns``, ``time.time_ns``) once — :func:`clock` —
+and the Chrome trace's metadata and the run report carry it, so any span
+can be placed on wall time and any two files of one run on each other.
 
-When neither is on — the default — ``span()`` returns one shared no-op
-singleton: the cost is a module-global load, a branch and a constant
-return, which is what keeps always-compiled-in spans out of the hot
-loops' profile (guarded by ``tests/test_obs.py``).  Output bytes are
+When recording is off — the default — ``span()`` returns one shared
+no-op singleton: the cost is a module-global load, a branch and a
+constant return, which is what keeps always-compiled-in spans out of the
+hot loops' profile (guarded by ``tests/test_obs.py``).  Output bytes are
 identical either way: spans observe, they never steer.
 
 Threads get their own buffer (and their own Perfetto track) the first
@@ -47,8 +53,7 @@ RING_CAP = 1 << 18
 
 _lock = threading.Lock()
 _active = False
-_tracing = False
-_origin = 0.0          # perf_counter at tracing start (trace time zero)
+_clock = None          # (perf_counter_ns, time_ns) of the run's start
 _threads: List["_ThreadBuf"] = []
 _epoch = 0             # bumped by deactivate(): stale thread-local
                        # buffers re-register instead of recording into
@@ -60,15 +65,17 @@ class _ThreadBuf:
     """Per-thread ring buffer of finished span events plus the thread's
     current :func:`track` stack."""
 
-    __slots__ = ("name", "events", "pos", "dropped", "tracks", "epoch")
+    __slots__ = ("name", "events", "pos", "dropped", "tracks", "epoch",
+                 "open")
 
     def __init__(self, name: str, epoch: int):
         self.name = name
-        self.events: list = []     # (track, name, t0, t1, args)
+        self.events: list = []     # (track, name, t0_ns, t1_ns, args)
         self.pos = 0
         self.dropped = 0
         self.tracks: List[str] = []
         self.epoch = epoch
+        self.open: list = []       # the thread's open spans, outermost first
 
     def append(self, ev) -> None:
         # a _ThreadBuf is single-writer by construction: _buf() hands
@@ -109,40 +116,53 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "_t0")
+    __slots__ = ("name", "args", "_t0", "_b")
 
     def __init__(self, name: str, args: dict):
         self.name = name
         self.args = args
 
     def __enter__(self):
-        # the open-span stack feeds phase attribution (the compile
-        # watch reads the innermost open span when XLA compiles on
-        # this thread) — a TLS list append, active-mode only
-        st = getattr(_tls, "span_stack", None)
-        if st is None:
-            st = _tls.span_stack = []
-        st.append(self.name)
-        self._t0 = time.perf_counter()
+        # the thread's open-span list feeds phase attribution (the
+        # compile watch reads the innermost open span when XLA compiles
+        # on this thread) and the occupancy ledger (a span still open
+        # at report time is cut like a finished one)
+        self._b = b = _buf()
+        b.open.append(self)
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        t1 = time.perf_counter()
-        st = getattr(_tls, "span_stack", None)
-        if st:
-            st.pop()
-        metrics.add_time(self.name, t1 - self._t0)
+        t1 = time.perf_counter_ns()
+        b = self._b
+        if b.open and b.open[-1] is self:
+            b.open.pop()
+        seconds = (t1 - self._t0) * 1e-9
+        metrics.add_time(self.name, seconds)
         # per-thread timer prefix (set_timer_prefix): the chip-worker
         # threads mirror their spans under device.<ordinal>.* so the
         # run report can attribute dispatch/fetch seconds per chip
         pfx = getattr(_tls, "timer_prefix", None)
         if pfx:
-            metrics.add_time(pfx + self.name, t1 - self._t0)
-        if _tracing:
-            b = _buf()
-            b.append((b.tracks[-1] if b.tracks else None,
-                      self.name, self._t0, t1, self.args or None))
+            metrics.add_time(pfx + self.name, seconds)
+        b.append((b.tracks[-1] if b.tracks else None,
+                  self.name, self._t0, t1, self.args or None))
         return False
+
+
+def record(name: str, t0_ns: int, t1_ns: int, seconds=None) -> None:
+    """Back-date one finished event onto the CURRENT THREAD's ring and
+    its duration into the timer ``name`` — for work whose length is
+    only known once it is over (the compile listener's stages).
+    ``seconds`` overrides what the timer gets (an event's self time,
+    where its children were recorded before it)."""
+    if not _active:
+        return
+    metrics.add_time(name, (t1_ns - t0_ns) * 1e-9 if seconds is None
+                     else seconds)
+    b = _buf()
+    b.append((b.tracks[-1] if b.tracks else None, name, t0_ns, t1_ns,
+              None))
 
 
 def span(name: str, **args):
@@ -179,7 +199,7 @@ class _Track:
 def track(name: str):
     """Route the current thread's spans onto a named sub-track until
     exit (e.g. one track per shard in the trace viewer)."""
-    if not _tracing:
+    if not _active:
         return NULL_SPAN
     return _Track(name)
 
@@ -188,8 +208,24 @@ def current_span():
     """The CURRENT THREAD's innermost open span name (None when no
     span is open or recording is off) — the compile watch stamps it as
     the phase of every XLA compile attributed to this thread."""
-    st = getattr(_tls, "span_stack", None)
-    return st[-1] if st else None
+    b = getattr(_tls, "buf", None)
+    return b.open[-1].name if b is not None and b.open else None
+
+
+def current_buf():
+    """The CURRENT THREAD's ring (registered on first use) — the
+    occupancy ledger keeps it with every submission, so an idle
+    interval can be cut by the spans of the thread that ended it."""
+    return _buf()
+
+
+def snapshot_events(b) -> list:
+    """``(name, t0_ns, t1_ns)`` of ring ``b``'s finished spans plus its
+    still-open ones (``t1_ns`` None). Another thread's ring is read
+    racing at worst into one stale event, like :func:`export`."""
+    out = [(name, t0, t1) for _, name, t0, t1, _ in list(b.events)]
+    out += [(sp.name, sp._t0, None) for sp in list(b.open)]
+    return out
 
 
 def get_timer_prefix():
@@ -210,15 +246,39 @@ def set_timer_prefix(prefix) -> None:
 
 # ------------------------------------------------------------- lifecycle
 
+def _stamp_clock() -> None:
+    global _clock
+    _clock = (time.perf_counter_ns(), time.time_ns())
+
+
 def activate(tracing: bool = False) -> None:
-    """Turn span recording on: timers always, ring buffers when
-    ``tracing``. Idempotent; tracing time zero is set at the first
-    tracing activation."""
-    global _active, _tracing, _origin
+    """Turn span recording on (timers and rings). Idempotent; the clock
+    pair is stamped at the first activation unless :func:`new_run`
+    already did. ``tracing`` (an export was asked for) changes nothing
+    any more: the rings fill either way."""
+    global _active
+    if _clock is None:
+        _stamp_clock()
     _active = True
-    if tracing and not _tracing:
-        _origin = time.perf_counter()
-        _tracing = True
+
+
+def new_run() -> None:
+    """A run boundary (``obs.begin``): stamp the run's clock pair and
+    start its rings empty, so a second job in one process neither
+    exports nor is charged the first job's spans. Live threads'
+    buffers re-register on their next span (the epoch bump)."""
+    global _threads, _epoch
+    with _lock:
+        _threads = []
+        _epoch += 1
+    _stamp_clock()
+
+
+def clock() -> dict:
+    """``{"perf_ns", "unix_ns"}``: the same instant on the span clock
+    and on wall time (zeros before any activation)."""
+    perf_ns, unix_ns = _clock or (0, 0)
+    return {"perf_ns": perf_ns, "unix_ns": unix_ns}
 
 
 def deactivate() -> None:
@@ -226,20 +286,16 @@ def deactivate() -> None:
     Live threads' stale thread-local buffers re-register on their next
     span (the epoch bump makes ``_buf`` replace them), so no thread
     keeps recording into an orphaned, never-exported ring."""
-    global _active, _tracing, _threads, _epoch
+    global _active, _threads, _epoch, _clock
     with _lock:
         _active = False
-        _tracing = False
         _threads = []
         _epoch += 1
+        _clock = None
 
 
 def is_active() -> bool:
     return _active
-
-
-def is_tracing() -> bool:
-    return _tracing
 
 
 # ---------------------------------------------------------------- export
@@ -249,10 +305,13 @@ def export(path: str) -> dict:
     and return ``{"events": n, "dropped": n}``.
 
     Format: ``{"traceEvents": [...]}`` with complete ("X") events in
-    microseconds relative to tracing start, one tid per (thread, track)
-    pair, and ``thread_name`` metadata rows — exactly what Perfetto and
-    chrome://tracing load directly."""
+    microseconds relative to the run's clock pair (``metadata.clock``:
+    ``ts`` 0 is ``perf_ns`` on the span clock and ``unix_ns`` on wall
+    time), one tid per (thread, track) pair, and ``thread_name``
+    metadata rows — exactly what Perfetto and chrome://tracing load
+    directly."""
     pid = os.getpid()
+    origin = clock()["perf_ns"]
     with _lock:
         bufs = list(_threads)
     events: list = []
@@ -274,8 +333,8 @@ def export(path: str) -> dict:
                                "args": {"name": label}})
             ev = {"name": name, "cat": name.split(".", 1)[0], "ph": "X",
                   "pid": pid, "tid": tid,
-                  "ts": round((t0 - _origin) * 1e6, 3),
-                  "dur": round((t1 - t0) * 1e6, 3)}
+                  "ts": (t0 - origin) / 1e3,
+                  "dur": (t1 - t0) / 1e3}
             if args:
                 ev["args"] = args
             events.append(ev)
@@ -285,5 +344,6 @@ def export(path: str) -> dict:
                       "args": {"name": "racon_tpu"}})
     from .report import atomic_write_bytes
     atomic_write_bytes(path, json.dumps(
-        {"traceEvents": events, "displayTimeUnit": "ms"}).encode())
+        {"traceEvents": events, "displayTimeUnit": "ms",
+         "metadata": {"clock": clock()}}).encode())
     return {"events": len(events), "dropped": dropped}

@@ -737,7 +737,7 @@ def _ops_to_cigar(path: np.ndarray) -> str:
 
 from .pallas_nw import PallasDispatchMixin
 from .. import faults, obs
-from ..obs import metrics
+from ..obs import device_time, metrics
 
 
 class TpuAligner(PallasDispatchMixin):
@@ -1241,6 +1241,72 @@ class TpuAligner(PallasDispatchMixin):
         blocks; the banded row layout (reversal, band offsets, padding) is
         built on device (:func:`_build_rows`) — the padded row arrays are
         ~3x the raw bases, so building them there cuts the bytes sent."""
+        # the three leaves of align.dispatch: host packing, the
+        # host->device puts, the jit calls until they return
+        leaf = dict(pairs=len(chunk), max_len=max_len, band=band)
+        with obs.span("align.pack", **leaf):
+            sw = self._swar_choice(max_len)
+            n, m, seqs, kind, bp_host = self._pack_chunk(
+                pairs, chunk, max_len, bp_meta, sw)
+            steps = _sweep_bound(int((n + m).max()), max_len)
+            self._count_arena(n, m, len(chunk), steps, band)
+        # multi-host: every process packs the (deterministic) chunk and
+        # materializes only its addressable shards of the global arrays
+        # (the flat char blocks shard evenly too: B is a mesh multiple,
+        # so [B * max_len] splits on row boundaries — max_len is a
+        # multiple of 4, so the 2-bit blocks split evenly as well)
+        from ..parallel import to_global
+        put = ((lambda a: to_global(self.mesh, a)) if self.mesh is not None
+               else jnp.asarray)
+        with obs.span("align.put", **leaf):
+            nd, md = put(n), put(m)
+            bp_dev = None if bp_host is None else [put(a) for a in bp_host]
+            q_d, t_d = put(seqs[0]), put(seqs[1])
+        device_time.submit("h2d", "align.put", t_d)
+        with obs.span("align.launch", **leaf):
+            build = {"2bit": _build_rows_packed2, "nibble": _build_rows_packed,
+                     "raw": _build_rows}[kind]
+            qrp, tp = build(q_d, t_d, nd, md, max_len=max_len, band=band)
+            device_time.submit("exec", build.__name__, tp)
+            args = (qrp, tp, nd, md)
+            B = n.shape[0]
+            use_pallas = self._use_pallas((max_len, band, steps, B))
+            if use_pallas:
+                from .pallas_nw import pallas_swar_ok
+                # the packed Mosaic kernel's XOR+mask equality reads
+                # 4-bit codes, so raw-byte chunks (alphabet > 15, rows
+                # not remapped) must never take it — bytes differing
+                # only in bits 4-7 would compare equal there
+                sw = sw and kind != "raw" and pallas_swar_ok()
+            # no try/except around the dispatch: a Mosaic kernel that
+            # does not compile or run for this shape fails the run (the
+            # jit error names the function and shapes)
+            out = self._dispatch(args, max_len, band, steps, use_pallas,
+                                 sw)
+            # the score vector, never the tables: the watcher must hold
+            # nothing the direction-matrix budget counts
+            device_time.submit(
+                "exec", "sharded_align" if self.mesh is not None
+                else "_pallas_align_chain" if use_pallas
+                else "align_chain", out[1])
+            if bp_dev is not None:
+                out = self._attach_bp(out, nd, md, bp_dev, bp_meta,
+                                      max_len)
+                device_time.submit("exec", "_breaking_points_kernel",
+                                   out[1])
+        # counted on the path actually taken: the Pallas-level
+        # decision can differ from the XLA-level one
+        self.stats["swar_chunks"] += int(sw)
+        metrics.inc("aligner.swar_chunks", int(sw))
+        # which kernel family ran: align.chunks counts every dispatch,
+        # this the Mosaic ones (all of them on the chip, none off it)
+        metrics.inc("aligner.pallas_chunks", int(use_pallas))
+        return chunk, pairs, n, m, out, max_len
+
+    def _pack_chunk(self, pairs, chunk, max_len, bp_meta, sw: bool):
+        """The host half of a launch: ``(n, m, (q, t) packed sequence
+        blocks, their kind, the bp kernel's host inputs | None)``;
+        ``sw``: the bucket may run packed lanes (2-bit blocks then)."""
         # Pad the batch to a power of two: B is part of the compiled shape,
         # so arbitrary batch sizes would recompile the kernels every call.
         B = self._pad_batch(len(chunk))
@@ -1256,14 +1322,55 @@ class TpuAligner(PallasDispatchMixin):
                 np.frombuffer(tb, dtype=np.uint8)
             n[k], m[k] = len(qb), len(tb)
 
-        steps = _sweep_bound(int((n + m).max()), max_len)
+        # host->device bytes are the bottleneck on thin links: when the
+        # chunk's alphabet fits 4 symbols (ACGT does) and the SWAR path
+        # is live, remap to 2-bit codes packed 16 per int32 word (4x
+        # fewer bytes than raw); up to 15 symbols (ACGTN does) remap to
+        # nibble codes (2x). Equality-preserving bijections either way —
+        # the kernels only ever compare characters for equality.
+        hist = np.bincount(qcat, minlength=256)
+        hist += np.bincount(tcat, minlength=256)
+        alphabet = np.flatnonzero(hist[1:]) + 1  # O(N), no sort; 0 is pad
+        if sw and len(alphabet) <= 4:
+            from .swar import pack_bases_2bit
+            lut = np.zeros(256, np.uint8)
+            lut[alphabet] = np.arange(len(alphabet), dtype=np.uint8)
+            kind = "2bit"
+            seqs = (pack_bases_2bit(lut[qcat]), pack_bases_2bit(lut[tcat]))
+        elif len(alphabet) <= 15:
+            lut = np.zeros(256, np.uint8)
+            lut[alphabet] = np.arange(1, len(alphabet) + 1, dtype=np.uint8)
+            q4 = lut[qcat]
+            t4 = lut[tcat]
+            kind = "nibble"
+            seqs = (q4[0::2] | (q4[1::2] << 4), t4[0::2] | (t4[1::2] << 4))
+        else:
+            kind = "raw"
+            seqs = (qcat, tcat)
+        bp_host = None
+        if bp_meta is not None:
+            # the breaking-points kernel's per-pair window geometry
+            w, metas = bp_meta
+            first_rel = np.zeros(B, np.int32)
+            nb = np.ones(B, np.int32)
+            for k, idx in enumerate(chunk):
+                t_begin, _ = metas[idx]
+                t_end = t_begin + len(pairs[idx][1])
+                n_reg = (t_end - 1) // w - t_begin // w
+                nb[k] = n_reg + 1
+                first_rel[k] = ((t_begin // w + 1) * w - 1 - t_begin
+                                if n_reg else m[k] - 1)
+            bp_host = (first_rel, nb)
+        return n, m, seqs, kind, bp_host
 
+    def _count_arena(self, n, m, pairs: int, steps: int, band: int) -> None:
+        B = n.shape[0]
         # occupancy telemetry (round 17): the launch's wavefront arena
         # is B x steps band-wide DP rows; each real pair only produces
         # work on its own n+m anti-diagonals — the rest (batch pow2
         # padding + dead wavefronts past each pair's finish) is the
         # waste the ragged packer and band ladder exist to cut
-        occ = int(n[:len(chunk)].sum()) + int(m[:len(chunk)].sum())
+        occ = int(n[:pairs].sum()) + int(m[:pairs].sum())
         total = B * steps
         self.stats["chunks"] += 1
         self.stats["lanes_occupied"] += occ
@@ -1276,92 +1383,15 @@ class TpuAligner(PallasDispatchMixin):
         metrics.inc("align.steps_wasted", total - occ)
         metrics.inc("align.wavefront_work", total * band)
 
-        # host->device bytes are the bottleneck on thin links: when the
-        # chunk's alphabet fits 4 symbols (ACGT does) and the SWAR path
-        # is live, remap to 2-bit codes packed 16 per int32 word (4x
-        # fewer bytes than raw); up to 15 symbols (ACGTN does) remap to
-        # nibble codes (2x). Equality-preserving bijections either way —
-        # the kernels only ever compare characters for equality.
-        hist = np.bincount(qcat, minlength=256)
-        hist += np.bincount(tcat, minlength=256)
-        alphabet = np.flatnonzero(hist[1:]) + 1  # O(N), no sort; 0 is pad
-        sw = self._swar_choice(max_len)
-        # multi-host: every process packs the (deterministic) chunk and
-        # materializes only its addressable shards of the global arrays
-        # (the flat char blocks shard evenly too: B is a mesh multiple,
-        # so [B * max_len] splits on row boundaries — max_len is a
-        # multiple of 4, so the 2-bit blocks split evenly as well)
-        from ..parallel import to_global
-        put = ((lambda a: to_global(self.mesh, a)) if self.mesh is not None
-               else jnp.asarray)
-        nd, md = put(n), put(m)
-        if sw and len(alphabet) <= 4:
-            from .swar import pack_bases_2bit
-            lut = np.zeros(256, np.uint8)
-            lut[alphabet] = np.arange(len(alphabet), dtype=np.uint8)
-            qrp, tp = _build_rows_packed2(
-                put(pack_bases_2bit(lut[qcat])),
-                put(pack_bases_2bit(lut[tcat])),
-                nd, md, max_len=max_len, band=band)
-        elif len(alphabet) <= 15:
-            lut = np.zeros(256, np.uint8)
-            lut[alphabet] = np.arange(1, len(alphabet) + 1, dtype=np.uint8)
-            q4 = lut[qcat]
-            t4 = lut[tcat]
-            q4 = q4[0::2] | (q4[1::2] << 4)
-            t4 = t4[0::2] | (t4[1::2] << 4)
-            qrp, tp = _build_rows_packed(put(q4), put(t4),
-                                         nd, md, max_len=max_len,
-                                         band=band)
-        else:
-            qrp, tp = _build_rows(put(qcat), put(tcat),
-                                  nd, md, max_len=max_len, band=band)
-        args = (qrp, tp, nd, md)
-        use_pallas = self._use_pallas((max_len, band, steps, B))
-        if use_pallas:
-            from .pallas_nw import pallas_swar_ok
-            # the packed Mosaic kernel's XOR+mask equality reads 4-bit
-            # codes, so raw-byte chunks (alphabet > 15, rows not
-            # remapped) must never take it — bytes differing only in
-            # bits 4-7 would compare equal there
-            sw = sw and len(alphabet) <= 15 and pallas_swar_ok()
-        # no try/except around the dispatch: a Mosaic kernel that does
-        # not compile or run for this shape fails the run (the jit
-        # error names the function and shapes)
-        out = self._dispatch(args, max_len, band, steps, use_pallas, sw)
-        out = self._attach_bp(out, chunk, pairs, n, m, max_len, bp_meta,
-                              put)
-        # counted on the path actually taken: the Pallas-level
-        # decision can differ from the XLA-level one
-        self.stats["swar_chunks"] += int(sw)
-        metrics.inc("aligner.swar_chunks", int(sw))
-        # which kernel family ran: align.chunks counts every dispatch,
-        # this the Mosaic ones (all of them on the chip, none off it)
-        metrics.inc("aligner.pallas_chunks", int(use_pallas))
-        return chunk, pairs, n, m, out, max_len
-
-    def _attach_bp(self, out, chunk, pairs, n, m, max_len, bp_meta, put):
+    def _attach_bp(self, out, nd, md, bp_dev, bp_meta, max_len: int):
         """In breaking-points mode, derive the per-boundary tables on
         device from the (device-resident) packed op stream; the stream
         itself is never fetched."""
-        if bp_meta is None:
-            return out
-        w, metas = bp_meta
+        w, _ = bp_meta
         ops_packed, score, fi, fj = out
-        B = ops_packed.shape[0]
         NW = max_len // max(w, 1) + 2
-        first_rel = np.zeros(B, np.int32)
-        nb = np.ones(B, np.int32)
-        for k, idx in enumerate(chunk):
-            t_begin, _ = metas[idx]
-            t_end = t_begin + len(pairs[idx][1])
-            n_reg = (t_end - 1) // w - t_begin // w
-            nb[k] = n_reg + 1
-            first_rel[k] = ((t_begin // w + 1) * w - 1 - t_begin
-                            if n_reg else m[k] - 1)
         bp_first, bp_last = _breaking_points_kernel(
-            ops_packed, put(n), put(m), put(first_rel), put(nb),
-            w=w, NW=NW)
+            ops_packed, nd, md, *bp_dev, w=w, NW=NW)
         return bp_first, bp_last, score, fi, fj
 
     def _dispatch(self, args, max_len, band, steps, use_pallas,
@@ -1408,11 +1438,27 @@ class TpuAligner(PallasDispatchMixin):
                            bp_meta=None, resident=False):
         chunk, pairs, n, m, out, _max_len = launched
         from ..parallel import fetch_global
-        if bp_meta is not None:
-            self._finish_chunk_bp(launched, band, cigars, reject,
-                                  bp_meta, resident)
-            return
-        ops_packed, score, fi, fj = fetch_global(list(out))
+        # resident bp chunks fetch only the accept gate's scalars
+        wanted = list(out[2:] if bp_meta is not None and resident else out)
+        # the three leaves of align.fetch: waiting for the device and
+        # nothing else, the device->host copy, the host decode
+        leaf = dict(pairs=len(chunk), band=band)
+        with obs.span("align.wait", **leaf):
+            jax.block_until_ready(wanted)
+        with obs.span("align.get", **leaf):
+            fetched = fetch_global(wanted)
+        with obs.span("align.decode", **leaf):
+            if bp_meta is not None:
+                self._finish_chunk_bp(launched, fetched, band, cigars,
+                                      reject, bp_meta, resident)
+            else:
+                self._finish_chunk_cigar(launched, fetched, band, cigars,
+                                         reject)
+
+    def _finish_chunk_cigar(self, launched, fetched, band, cigars,
+                            reject) -> None:
+        chunk, pairs, n, m, out, _max_len = launched
+        ops_packed, score, fi, fj = fetched
         from .. import sanitize
         if sanitize.enabled():
             sanitize.check_aligner_canaries(
@@ -1451,8 +1497,8 @@ class TpuAligner(PallasDispatchMixin):
         if obs_scores:
             self._observe_divergence(obs_scores, obs_maxlens)
 
-    def _finish_chunk_bp(self, launched, band, results, reject, bp_meta,
-                         resident=False):
+    def _finish_chunk_bp(self, launched, fetched, band, results, reject,
+                         bp_meta, resident=False):
         """Breaking-points decode: convert the fetched per-boundary tables
         to columnar (k, 4) int32 row arrays for the WHOLE chunk in one
         vectorized pass (same accept/reject gate as the CIGAR path — the
@@ -1465,13 +1511,12 @@ class TpuAligner(PallasDispatchMixin):
         into one shared :class:`_DevChunkBp` — the polisher's resident
         assemble derives layer rows from them without a host decode."""
         chunk, pairs, n, m, out, max_len = launched
-        from ..parallel import fetch_global
         w, metas = bp_meta
         if resident:
-            score, fi, fj = fetch_global(list(out[2:]))
+            score, fi, fj = fetched
             bp_first = bp_last = None
         else:
-            bp_first, bp_last, score, fi, fj = fetch_global(list(out))
+            bp_first, bp_last, score, fi, fj = fetched
         from .. import sanitize
         if sanitize.enabled():
             sanitize.check_aligner_canaries(
@@ -1488,10 +1533,10 @@ class TpuAligner(PallasDispatchMixin):
         # adaptive-ladder signal: every clean walk's finite score (see
         # the CIGAR path) — gate-failed ones are banded upper bounds,
         # so the estimate errs wide, never low
-        obs = clean & (score_h < (1 << 28))
-        if obs.any():
-            self._observe_divergence(score_h[obs],
-                                     np.maximum(n_h, m_h)[obs])
+        seen = clean & (score_h < (1 << 28))
+        if seen.any():
+            self._observe_divergence(score_h[seen],
+                                     np.maximum(n_h, m_h)[seen])
         tb = np.fromiter((metas[idx][0] for idx in chunk), np.int64, C)
         qo = np.fromiter((metas[idx][1] for idx in chunk), np.int64, C)
         te = tb + np.fromiter((len(pairs[idx][1]) for idx in chunk),
@@ -1598,12 +1643,14 @@ class TpuAligner(PallasDispatchMixin):
                 from .swar import pack_bases_2bit
                 blk = jnp.asarray(pack_bases_2bit(
                     np.zeros(B * max_len, np.uint8)))
-                qrp, tp = _build_rows_packed2(blk, blk, n, m,
-                                              max_len=max_len, band=band)
+                build, blocks = _build_rows_packed2, (blk, blk)
             else:
                 z = jnp.zeros((B * max_len,), jnp.uint8)
-                qrp, tp = _build_rows(z, z, n, m, max_len=max_len,
-                                      band=band)
+                build, blocks = _build_rows, (z, z)
+            qrp, tp = build(*blocks, n, m, max_len=max_len, band=band)
+            # the warm-up's dummy programs occupy the device like any
+            # other: the occupancy ledger counts them, as kind "warm"
+            device_time.submit("warm", build.__name__, tp)
             use_pallas = self._use_pallas((max_len, band, steps, B))
             if use_pallas and sw:
                 from .pallas_nw import pallas_swar_ok
@@ -1611,11 +1658,16 @@ class TpuAligner(PallasDispatchMixin):
             out = align_chain(qrp, tp, n, m, max_len=max_len, band=band,
                               steps=steps, use_pallas=use_pallas,
                               use_swar=sw)
+            device_time.submit(
+                "warm", "_pallas_align_chain" if use_pallas
+                else "align_chain", out[1])
             if w:
                 NW = max_len // max(w, 1) + 2
                 bp = _breaking_points_kernel(
                     out[0], n, m, jnp.zeros((B,), jnp.int32),
                     jnp.ones((B,), jnp.int32), w=w, NW=NW)
+                device_time.submit("warm", "_breaking_points_kernel",
+                                   bp[1])
                 # resident derive root (round 19): warmed with the SAME
                 # chunk geometry and the shared pow2 pool rule, so a
                 # resident run's per-chunk layer-row derivation

@@ -85,7 +85,7 @@ from .nw import _nw_wavefront_kernel, _walk_ops_kernel
 from .pallas_nw import PallasDispatchMixin
 from .. import faults, flags, obs, sanitize
 from ..core.window import WindowType
-from ..obs import metrics
+from ..obs import device_time, metrics
 
 # Alignment band for layer-vs-backbone-span alignment (layers are ~window
 # sized; c=256 covers ~50% divergence at 500 bp).
@@ -1771,6 +1771,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 jnp.zeros((_pow2_pool(Lq * B),), jnp.uint16),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 Lq=Lq)
+            device_time.submit("warm", "_refine_loop_packed", out[9])
+            device_time.submit("warm", "_gather_qpw_rows", gat)
             jax.block_until_ready(out[10])
             jax.block_until_ready(gat)
 
@@ -2026,6 +2028,17 @@ class TpuPoaConsensus(PallasDispatchMixin):
         put = ((lambda a: to_global(self.mesh, a)) if self.mesh is not None
                else jnp.asarray)
         dev_spec = packs[0][2] if allow_dev else None
+        # the one leaf of poa.pack that is separable: the host->device
+        # puts (and, on the resident path, the lane gather's launch)
+        with obs.span("poa.put", windows=len(live)):
+            state, static = self._put_group(put, pair_np, win_np, dev_spec,
+                                            nd, nWp, B, Lq)
+        device_time.submit("h2d", "poa.put", state[-1])
+        return {"shards": shards, "static": static, "state": state,
+                "nWp": nWp, "nd": nd, "B": B}
+
+    def _put_group(self, put, pair_np, win_np, dev_spec, nd, nWp, B, Lq):
+        """The packed group's arrays on the device: ``(state, static)``."""
         if dev_spec is not None:
             # resident lane ingest: the pool is already on device, so the
             # group's [B, Lq] uint16 lane block never crosses the link —
@@ -2033,6 +2046,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
             pool_d, src0_full, lens_full = dev_spec
             qpw_dev = _gather_qpw_rows(pool_d, jnp.asarray(src0_full),
                                        jnp.asarray(lens_full), Lq=Lq)
+            device_time.submit("exec", "_gather_qpw_rows", qpw_dev)
             saved = 2 * B * Lq
             self.stats["lane_upload_saved_bytes"] += saved
             metrics.inc("dataflow.bytes_avoided", saved)
@@ -2051,8 +2065,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
         dropped = zput(np.zeros((nd, 4 + nWp), np.int32))
         state = [bg, ed, bcodes, bweights, blen, covs, ever, frozen, conv,
                  dropped]
-        return {"shards": shards, "static": static, "state": state,
-                "nWp": nWp, "nd": nd, "B": B}
+        return state, static
 
     def _rounds_impl(self, launch, Lq, Lb, steps, Lq2=0) -> None:
         """Dispatch a group's full refinement loop (no host sync).
@@ -2086,6 +2099,10 @@ class TpuPoaConsensus(PallasDispatchMixin):
         pre_state = launch["state"]
         out = self._dispatch_loop(launch, pre_state, Lq, Lb, steps, Lq2,
                                   use_pallas, use_swar)
+        # the small telemetry rows, never the lane blocks
+        device_time.submit(
+            "exec", "_refine_loop_packed" if launch["nd"] == 1
+            else "sharded_refine_loop", out[9])
         launch["state"] = list(out[:10])
         if launch["nd"] == 1:
             launch["fetch2"] = out[10:12]
@@ -2186,52 +2203,92 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # per array (bweights always stays on device)
         from ..parallel import fetch_global
         if "fetch2" in launch:
-            mat, meta = fetch_global(list(launch["fetch2"]))
+            wanted = list(launch["fetch2"])
         else:
             (bg_d, ed_d, bcodes, _, blen, covs, ever, frozen, conv,
              dropped) = launch["state"]
-            fetch = [bcodes, blen, covs, ever, dropped]
+            wanted = [bcodes, blen, covs, ever, dropped]
             if collect is not None:  # straggler-resume state
-                fetch += [frozen, conv, bg_d, ed_d]
-            fetched = fetch_global(fetch)
+                wanted += [frozen, conv, bg_d, ed_d]
+        # the three leaves of poa.fetch: waiting for the device and
+        # nothing else, the device->host copy, the host decode
+        leaf = dict(windows=nWp)
+        with obs.span("poa.wait", **leaf):
+            jax.block_until_ready(wanted)
+        with obs.span("poa.get", **leaf):
+            fetched = fetch_global(wanted)
+        with obs.span("poa.decode", **leaf):
+            host = self._unpack_fetch(launch, fetched, collect)
+            resume = collect is not None and self._mostly_unconverged(
+                launch, host)
+        if resume:
+            # a mostly-unconverged group (noisy data rarely reaches an
+            # exact fixed point) continues its remaining rounds on the
+            # state already resident on device — no repack, no
+            # re-upload; its own dispatch and fetch spans follow
+            Lq, Lb, steps, Lq2 = launch["geom"]
+            launch["rounds"] = self.rounds - STAGE_A_ROUNDS
+            self._rounds(launch, Lq, Lb, steps, Lq2)
+            self._finish_group(launch, trim, results, collect=None)
+            return
+        with obs.span("poa.decode", **leaf):
+            self._decode_group(launch, host, trim, results, collect)
+
+    def _unpack_fetch(self, launch, fetched, collect) -> dict:
+        """The fetched arrays by name (``frozen`` / ``conv`` / ``bg`` /
+        ``ed`` only where they were fetched)."""
+        nWp = launch["nWp"]
+        host = {}
         if "fetch2" in launch:
+            mat, meta = fetched
             nWr = launch["nd"] * nWp
             ndt = launch["nd"] * (4 + nWp)
             B_all = launch["nd"] * launch["B"]
-            bcodes = (mat & 7).astype(np.uint8)
-            covs = mat >> 3
+            host["bcodes"] = (mat & 7).astype(np.uint8)
+            host["covs"] = mat >> 3
             offs = np.cumsum([nWr, nWr, nWr, nWr, ndt, B_all])
-            blen, ever, frozen_h, conv_h, dropped, bg_h, ed_h = \
-                np.split(meta, offs)
-            ever = ever.astype(bool)
-            dropped = dropped.reshape(launch["nd"], 4 + nWp)
+            (host["blen"], ever, host["frozen"], host["conv"], dropped,
+             host["bg"], host["ed"]) = np.split(meta, offs)
+            host["ever"] = ever.astype(bool)
+            host["dropped"] = dropped.reshape(launch["nd"], 4 + nWp)
         else:
-            bcodes, blen, covs, ever, dropped = fetched[:5]
+            (host["bcodes"], host["blen"], host["covs"], host["ever"],
+             host["dropped"]) = fetched[:5]
             if collect is not None:
-                frozen_h, conv_h, bg_h, ed_h = fetched[5:]
+                (host["frozen"], host["conv"], host["bg"],
+                 host["ed"]) = fetched[5:]
         from .. import sanitize
         if sanitize.enabled():
             sanitize.check_consensus_canaries(
-                bcodes, blen, covs, Lb=launch["geom"][1],
+                host["bcodes"], host["blen"], host["covs"],
+                Lb=launch["geom"][1],
                 context=f"consensus group (nWp={nWp})")
+        return host
+
+    def _mostly_unconverged(self, launch, host) -> bool:
+        """Stage A's decision point: repack the stragglers only when
+        few survive."""
+        shards, nWp = launch["shards"], launch["nWp"]
+        conv_h, frozen_h = host["conv"], host["frozen"]
+        n_real = sum(len(sh) for sh in shards)
+        n_surv = 0
+        for s, sh in enumerate(shards):
+            for wi in range(len(sh)):
+                row = s * nWp + wi
+                if not conv_h[row] and not frozen_h[row]:
+                    n_surv += 1
+        return n_surv > STAGE_B_MAX_SURVIVOR_FRAC * n_real
+
+    def _decode_group(self, launch, host, trim: bool, results,
+                      collect) -> None:
+        """Consensus bytes + trim of a fetched group; stage A's
+        stragglers go to ``collect`` undecoded."""
+        shards, nWp = launch["shards"], launch["nWp"]
+        bcodes, blen, covs = host["bcodes"], host["blen"], host["covs"]
+        ever, dropped = host["ever"], host["dropped"]
         if collect is not None:
-            # decision point: repack the stragglers only when few survive;
-            # a mostly-unconverged group (noisy data rarely reaches an
-            # exact fixed point) continues its remaining rounds on the
-            # state already resident on device — no repack, no re-upload
-            n_real = sum(len(sh) for sh in shards)
-            n_surv = 0
-            for s, sh in enumerate(shards):
-                for wi in range(len(sh)):
-                    row = s * nWp + wi
-                    if not conv_h[row] and not frozen_h[row]:
-                        n_surv += 1
-            if n_surv > STAGE_B_MAX_SURVIVOR_FRAC * n_real:
-                Lq, Lb, steps, Lq2 = launch["geom"]
-                launch["rounds"] = self.rounds - STAGE_A_ROUNDS
-                self._rounds(launch, Lq, Lb, steps, Lq2)
-                self._finish_group(launch, trim, results, collect=None)
-                return
+            frozen_h, conv_h = host["frozen"], host["conv"]
+            bg_h, ed_h = host["bg"], host["ed"]
         self.stats["dropped_layers"] += int(dropped[:, 0].sum())
         self.stats["sweep_truncated"] += int(dropped[:, 1].sum())
         self.stats["ins_overflow"] += int(dropped[:, 2].sum())

@@ -1,0 +1,533 @@
+"""The device-occupancy ledger (racon_tpu.obs.device_time), its report
+section (schema v12), the leaf spans under dispatch and fetch, the run
+boundary's timer hygiene and the ``gaps`` command.
+
+The arithmetic is driven with a fake clock (hand-made submit / complete
+rows, no device); one module-scoped series of tiny in-process CLI jobs
+(both device engines on their XLA twins, recording off / on / on again /
+with ``--trace``) feeds every case that needs a real run."""
+
+import contextlib
+import io
+import json
+import pathlib
+import sys
+import threading
+import time
+
+import pytest
+
+from racon_tpu.obs import device_time, gaps, metrics, report, trace
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+MS = 1_000_000
+
+
+@pytest.fixture
+def clean_trace():
+    trace.deactivate()
+    device_time.reset()
+    yield
+    trace.deactivate()
+    device_time.reset()
+
+
+# ------------------------------------------------- arithmetic, fake clock
+
+def test_in_order_queue_busy_idle_head_tail():
+    """One device, three programs: the second was submitted while the
+    first still ran (it occupies from the first's completion), the third
+    after a gap; head before the first, tail after the last."""
+    rows = [("0", "exec", "a", "main", 100 * MS, 300 * MS),
+            ("0", "exec", "b", "main", 150 * MS, 450 * MS),
+            ("0", "exec", "a", "main", 600 * MS, 700 * MS)]
+    out = device_time.account(rows, {}, 0, 1000 * MS, "main")
+    assert out["window_s"] == 1.0
+    assert out["busy_s"] == pytest.approx(0.45)     # 100-450, 600-700
+    assert out["idle_s"] == pytest.approx(0.55)
+    assert out["head_idle_s"] == pytest.approx(0.1)
+    assert out["tail_idle_s"] == pytest.approx(0.3)
+    assert out["programs"] == 3
+    assert out["by_program"] == {
+        "a": {"count": 2, "device_s": pytest.approx(0.3)},
+        "b": {"count": 1, "device_s": pytest.approx(0.15)}}
+    # no span anywhere: every idle second is unattributed
+    assert out["idle_by"] == {"unattributed": pytest.approx(0.55)}
+    assert out["busy_s"] + out["idle_s"] == pytest.approx(out["window_s"])
+    # the gaps, longest first
+    assert [(a, b) for a, b, _ in out["gaps"]] == [
+        (700 * MS, 1000 * MS), (450 * MS, 600 * MS), (0, 100 * MS)]
+
+
+def test_overlapping_h2d_and_exec_union():
+    """A transfer that runs beside a program adds nothing to busy; one
+    that sticks out of it does. Each kind chains on its own queue."""
+    rows = [("0", "exec", "k", "main", 0, 400 * MS),
+            ("0", "h2d", "put", "main", 100 * MS, 200 * MS),
+            ("0", "h2d", "put", "main", 350 * MS, 500 * MS),
+            ("0", "exec", "k", "main", 380 * MS, 900 * MS)]
+    out = device_time.account(rows, {}, 0, 1000 * MS, "main")
+    assert out["busy_s"] == pytest.approx(0.9)
+    assert out["idle_s"] == pytest.approx(0.1)
+    assert out["head_idle_s"] == 0 and out["tail_idle_s"] == 0.1
+    # the second exec waited for the first: it occupies 400-900, not
+    # 380-900; the transfers occupy their own intervals
+    assert out["by_program"]["k"]["device_s"] == pytest.approx(0.9)
+    assert out["by_program"]["put"]["device_s"] == pytest.approx(0.25)
+
+
+def test_two_devices_rows_and_means():
+    """``--chips N``: one row set per device ordinal; the top level is
+    the mean over devices, so busy + idle is still the window."""
+    rows = [("0", "exec", "k", "w0", 0, 800 * MS),
+            ("1", "exec", "k", "w1", 500 * MS, 700 * MS)]
+    spans = {"w0": [("poa.pack", 0, 1000 * MS)],
+             "w1": [("align.pack", 0, 400 * MS)]}
+    out = device_time.account(rows, spans, 0, 1000 * MS, "w0")
+    assert set(out["devices"]) == {"0", "1"}
+    assert out["devices"]["0"]["busy_s"] == 0.8
+    assert out["devices"]["1"]["busy_s"] == 0.2
+    assert out["devices"]["1"]["head_idle_s"] == 0.5
+    assert out["busy_s"] == pytest.approx(0.5)
+    assert out["idle_s"] == pytest.approx(0.5)
+    # device 1's head is its own feeding thread's: 400 ms in align.pack,
+    # 100 ms in no span; both tails are the report thread's (w0)
+    assert out["devices"]["1"]["idle_by"] == {
+        "align.pack": 0.4, "poa.pack": 0.3, "unattributed": 0.1}
+    assert out["devices"]["0"]["idle_by"] == {
+        "poa.pack": 0.2, "unattributed": 0.0}
+    assert sum(out["idle_by"].values()) == pytest.approx(out["idle_s"])
+    assert out["by_program"]["k"] == {"count": 2, "device_s": 1.0}
+
+
+def test_idle_goes_to_innermost_span_of_the_submitting_thread():
+    """The gap before a submission is cut by the spans of the thread
+    that made it — innermost first — while another thread's spans over
+    the same interval get nothing."""
+    rows = [("0", "exec", "k", "feeder", 0, 100 * MS),
+            ("0", "exec", "k", "feeder", 500 * MS, 600 * MS)]
+    spans = {
+        "feeder": [("align", 0, 600 * MS),
+                   ("align.dispatch", 150 * MS, 500 * MS),
+                   ("align.pack", 200 * MS, 450 * MS),
+                   ("compile.trace", 250 * MS, 300 * MS)],
+        "builder": [("build.windows", 0, 1000 * MS)],
+    }
+    out = device_time.account(rows, spans, 0, 600 * MS, "feeder")
+    assert out["idle_s"] == pytest.approx(0.4)
+    assert out["idle_by"] == {
+        "align": 0.05, "align.dispatch": 0.1, "align.pack": 0.2,
+        "compile.trace": 0.05, "unattributed": 0.0}
+    assert "build.windows" not in out["idle_by"]
+    (a, b, cut), = out["gaps"]
+    assert (a, b) == (100 * MS, 500 * MS)
+    assert cut == {"align": 0.05, "align.dispatch": 0.1,
+                   "align.pack": 0.2, "compile.trace": 0.05}
+
+
+def test_warm_up_program_is_busy_but_takes_no_blame():
+    """A warm-up thread's dummy program occupies the device (the same
+    in-order queue as the real programs) but feeds nothing: the idle
+    before it is charged to the thread of the next real submission, and
+    it does not end the head."""
+    rows = [("0", "warm", "k", "warmer", 100 * MS, 200 * MS),
+            ("0", "exec", "k", "feeder", 150 * MS, 500 * MS)]
+    spans = {"feeder": [("parse.reads", 0, 150 * MS)],
+             "warmer": [("exec.shard", 0, 1000 * MS)]}
+    out = device_time.account(rows, spans, 0, 500 * MS, "feeder")
+    assert out["busy_s"] == 0.4 and out["idle_s"] == 0.1
+    assert out["head_idle_s"] == 0.1
+    assert out["idle_by"] == {"parse.reads": 0.1, "unattributed": 0.0}
+    # one queue: the real program waited for the warm one
+    assert out["by_program"]["k"] == {"count": 2, "device_s": 0.4}
+
+
+def test_nothing_submitted_is_all_head_and_open_spans_count():
+    """A job that never touched the device: the whole window is head
+    idle, charged to the report thread — a span still open at report
+    time (``t1`` None) is cut like a finished one."""
+    spans = {"main": [("exec.shard", 200 * MS, None)]}
+    out = device_time.account([], spans, 0, 1000 * MS, "main")
+    assert out["busy_s"] == 0 and out["idle_s"] == 1.0
+    assert out["head_idle_s"] == 1.0 and out["tail_idle_s"] == 0
+    assert out["idle_by"] == {"exec.shard": 0.8, "unattributed": 0.2}
+    assert out["programs"] == 0 and out["devices"] == {}
+
+
+# --------------------------------------------------- the live mechanism
+
+class _FakeArray:
+    """Stands in for a small device output: ready when told."""
+
+    class _Dev:
+        id = 0
+
+    def __init__(self):
+        self.ready = threading.Event()
+
+    def devices(self):
+        return {self._Dev()}
+
+    def block_until_ready(self):
+        assert self.ready.wait(10)
+
+
+def test_live_ledger_charges_the_feeding_threads_span(clean_trace):
+    """Real threads, real watcher: the main thread sits in a span and
+    then submits; a second thread is busy in another span the whole
+    time. The idle before the submission is the main thread's span."""
+    from racon_tpu import obs
+
+    trace.new_run()
+    trace.activate()
+    t0 = time.perf_counter()
+    stop = threading.Event()
+
+    def elsewhere():
+        with obs.span("build.windows"):
+            stop.wait(10)
+
+    other = threading.Thread(target=elsewhere, name="t-elsewhere")
+    other.start()
+    with obs.span("align.pack"):
+        time.sleep(0.15)
+    watch = _FakeArray()
+    device_time.submit("exec", "_k", watch)
+    assert device_time.watcher_threads() == ["racon-devwatch-0-exec"]
+    time.sleep(0.05)
+    watch.ready.set()
+    stop.set()
+    other.join()
+    sec = device_time.summary(window_s=time.perf_counter() - t0)
+    assert sec["programs"] == 1
+    assert sec["timeline"][0][:4] == ["0", "exec", "_k", "MainThread"]
+    assert sec["by_program"]["_k"]["device_s"] >= 0.05
+    assert sec["idle_by"]["align.pack"] >= 0.14
+    assert "build.windows" not in sec["idle_by"]
+    assert sum(sec["idle_by"].values()) == pytest.approx(sec["idle_s"],
+                                                         abs=1e-5)
+    assert sec["busy_s"] + sec["idle_s"] == pytest.approx(sec["window_s"],
+                                                          abs=1e-5)
+    # the registry holds the same seconds, and a second build of the
+    # report does not add them again
+    again = device_time.summary(window_s=time.perf_counter() - t0)
+    assert metrics.timer_s("idle.align.pack") == pytest.approx(
+        again["idle_by"]["align.pack"])
+    assert again["idle_by"]["align.pack"] == pytest.approx(
+        sec["idle_by"]["align.pack"], abs=1e-3)
+    assert sec["clock"] == trace.clock() and sec["clock"]["unix_ns"] > 0
+
+
+def test_off_means_off(clean_trace):
+    """No report and no trace asked for: the shared no-op span, no
+    ledger entry, no watcher thread started by a submission."""
+    from racon_tpu import obs
+
+    before = set(device_time.watcher_threads())
+    assert obs.span("align.pack") is trace.NULL_SPAN
+    t0 = time.perf_counter()
+    for _ in range(100_000):
+        device_time.submit("exec", "_k", None)  # never touches `watch`
+    assert time.perf_counter() - t0 < 1.0
+    assert set(device_time.watcher_threads()) == before
+    sec = report.build_report("cli", wall_s=1.0)["device_time"]
+    assert sec["programs"] == 0 and sec["busy_s"] == 0
+    assert sec["timeline"] == [] and sec["idle_by"] == {"unattributed": 0}
+
+
+def test_compile_stages_are_backdated_spans(clean_trace):
+    """The compile listener back-dates one event per stage onto the
+    compiling thread's ring; a stage nested in another gives the outer
+    timer only its self time; cache lookups and hits are counted."""
+    from racon_tpu.obs import compilewatch
+
+    metrics.clear("compile.")
+    trace.new_run()
+    trace.activate()
+    ev = "/jax/core/compile/"
+    compilewatch._on_duration(ev + "jaxpr_trace_duration", 0.010)
+    compilewatch._on_duration(ev + "jaxpr_trace_duration", 0.050)  # outer
+    compilewatch._on_duration(ev + "jaxpr_to_mlir_module_duration", 0.002)
+    compilewatch._on_event(
+        "/jax/compilation_cache/compile_requests_use_cache")
+    compilewatch._on_event("/jax/compilation_cache/cache_hits")
+    compilewatch._on_event("/jax/some/other/event")
+    assert metrics.timer_s("compile.jax_s") == pytest.approx(0.062)
+    # 10 ms inner + 40 ms self of the outer, not 60
+    assert metrics.timer_s("compile.trace") == pytest.approx(0.050,
+                                                             abs=2e-3)
+    assert metrics.timer_s("compile.lower") == pytest.approx(0.002)
+    assert metrics.counter("compile.cache_requests") == 1
+    assert metrics.counter("compile.cache_hits") == 1
+    names = [n for n, _, _ in
+             trace.snapshot_events(trace.current_buf())]
+    assert names == ["compile.trace", "compile.trace", "compile.lower"]
+    metrics.clear("compile.")
+
+
+# ------------------------------------------------------ schema v12 / v11
+
+def test_v12_validates_and_requires_device_time():
+    rep = report.build_report("cli", wall_s=0.5)
+    assert rep["schema_version"] == 12
+    assert report.validate_report(rep) == []
+    broken = {k: v for k, v in rep.items() if k != "device_time"}
+    assert any("device_time" in e for e in report.validate_report(broken))
+    bad = dict(rep, device_time=dict(rep["device_time"], busy_s="long"))
+    assert any("busy_s" in e for e in report.validate_report(bad))
+    bad = dict(rep, device_time={k: v for k, v in
+                                 rep["device_time"].items()
+                                 if k != "timeline"})
+    assert any("timeline" in e for e in report.validate_report(bad))
+    bad = dict(rep, device_time=dict(rep["device_time"],
+                                     timeline=[["0", "exec"]]))
+    assert any("timeline" in e for e in report.validate_report(bad))
+
+
+def test_stored_v11_report_still_validates_as_v11():
+    """A report the chip wrote under schema v11 (PR 24's traced job):
+    held to the v11 key sets — valid as it is, invalid with a v12
+    section it could not have had."""
+    v11 = json.loads((REPO / "tests" / "data" /
+                      "run_report_v11.json").read_bytes())
+    assert v11["schema_version"] == 11 and "device_time" not in v11
+    assert report.validate_report(v11) == []
+    extra = dict(v11, device_time=device_time.empty_section())
+    assert any("device_time" in e for e in report.validate_report(extra))
+    assert any("schema_version" in e for e in
+               report.validate_report(dict(v11, schema_version=10)))
+
+
+def test_job_scope_report_sees_only_its_submissions(clean_trace):
+    """A resident-service job's report is built from its metric scope:
+    another job's submissions are not in its section."""
+    trace.new_run()
+    trace.activate()
+    t0 = time.perf_counter()
+    time.sleep(0.01)    # a job's first submission is not its first act
+    for scope in ("job.a.", "job.b.", "job.a."):
+        metrics.set_scope(scope)
+        try:
+            w = _FakeArray()
+            w.ready.set()
+            device_time.submit("exec", "_k", w)
+        finally:
+            metrics.set_scope(None)
+    rep = report.build_report("job", wall_s=time.perf_counter() - t0,
+                              scope="job.a.")
+    assert report.validate_report(rep) == []
+    assert rep["device_time"]["programs"] == 2
+    assert "idle.unattributed" in rep["metrics"]["timers"]
+    other = report.build_report("job", wall_s=time.perf_counter() - t0,
+                                scope="job.none.")
+    assert other["device_time"]["programs"] == 0
+    metrics.clear("job.")
+
+
+# ------------------------------------------------- a real tiny CLI series
+
+@contextlib.contextmanager
+def _stdout_to(path):
+    """The CLI writes its FASTA to ``sys.stdout.buffer``."""
+    saved = sys.stdout
+    with open(path, "wb") as fh:
+        sys.stdout = io.TextIOWrapper(fh, write_through=True)
+        try:
+            yield
+        finally:
+            sys.stdout.flush()
+            sys.stdout.detach()
+            sys.stdout = saved
+
+
+@pytest.fixture(scope="module")
+def cli_series(tmp_path_factory):
+    """Four jobs on the same inputs in THIS process, both device engines
+    on: recording off, ``--run-report`` twice, ``--run-report`` +
+    ``--trace``. ``{tag: {"fasta": bytes, "report": dict | None}}``."""
+    sys.path.insert(0, str(REPO / "tests"))
+    from test_columnar_init import write_synthetic_assembly
+    from racon_tpu import cli
+
+    td = tmp_path_factory.mktemp("device_time_cli")
+    rp, pp, lp = write_synthetic_assembly(td, seed=41, n_contigs=1,
+                                          contig=2000)
+    base = ["-t", "2", "-c", "1", "--tpualigner-batches", "1"]
+    out = {}
+    trace.deactivate()
+    for tag, extra in (("off", []), ("on1", ["report"]),
+                       ("on2", ["report"]), ("traced", ["report", "trace"])):
+        fasta = td / f"{tag}.fasta"
+        rep = td / f"{tag}.report.json"
+        argv = list(base)
+        if "report" in extra:
+            argv += ["--run-report", str(rep)]
+        if "trace" in extra:
+            argv += ["--trace", str(td / f"{tag}.trace.json")]
+        with _stdout_to(str(fasta)):
+            rc = cli.main([*argv, str(rp), str(pp), str(lp)])
+        assert rc == 0
+        out[tag] = {"fasta": fasta.read_bytes(),
+                    "report": (json.loads(rep.read_bytes())
+                               if rep.exists() else None),
+                    "trace": td / f"{tag}.trace.json"}
+    trace.deactivate()
+    return out
+
+
+def test_fasta_bytes_equal_with_recording_on_off_and_traced(cli_series):
+    assert cli_series["off"]["fasta"].startswith(b">")
+    assert cli_series["on1"]["fasta"] == cli_series["off"]["fasta"]
+    assert cli_series["on2"]["fasta"] == cli_series["off"]["fasta"]
+    assert cli_series["traced"]["fasta"] == cli_series["off"]["fasta"]
+    assert cli_series["off"]["report"] is None
+
+
+@pytest.mark.parametrize("tag", ["on1", "on2", "traced"])
+def test_cli_report_idle_sums_and_window(cli_series, tag):
+    rep = cli_series[tag]["report"]
+    assert report.validate_report(rep) == []
+    dt = rep["device_time"]
+    assert dt["programs"] > 0 and dt["busy_s"] > 0
+    assert dt["window_s"] == pytest.approx(rep["wall_s"], abs=2e-3)
+    assert dt["busy_s"] + dt["idle_s"] == pytest.approx(dt["window_s"],
+                                                        abs=1e-5)
+    assert sum(dt["idle_by"].values()) == pytest.approx(dt["idle_s"],
+                                                        abs=1e-4)
+    timers = rep["metrics"]["timers"]
+    idle = {k[len("idle."):]: v for k, v in timers.items()
+            if k.startswith("idle.")}
+    assert idle == dt["idle_by"]
+    assert dt["head_idle_s"] + dt["tail_idle_s"] <= dt["idle_s"] + 1e-6
+    assert dt["dropped"] == 0
+    assert len(dt["timeline"]) == dt["programs"]
+    assert {r[1] for r in dt["timeline"]} == {"exec", "h2d"}
+    for row in dt["timeline"]:
+        assert row[4] <= row[5]
+    assert dt["clock"]["perf_ns"] > 0 and dt["clock"]["unix_ns"] > 0
+    # every idle.<span> name is idle. + a registered span
+    from racon_tpu import contracts
+    assert set(idle) - {"unattributed"} <= contracts.SPANS
+
+
+@pytest.mark.parametrize("parent,leaves", [
+    ("align.dispatch", ("align.pack", "align.put", "align.launch")),
+    ("align.fetch", ("align.wait", "align.get", "align.decode")),
+    ("poa.pack", ("poa.put",)),
+    ("poa.fetch", ("poa.wait", "poa.get", "poa.decode")),
+])
+def test_leaf_spans_sum_to_no_more_than_their_parent(cli_series, parent,
+                                                     leaves):
+    for tag in ("on1", "on2", "traced"):
+        timers = cli_series[tag]["report"]["metrics"]["timers"]
+        assert all(leaf in timers for leaf in leaves), (tag, leaves)
+        total = sum(timers[leaf] for leaf in leaves)
+        assert total <= timers[parent] * 1.01 + 1e-4, (tag, parent)
+        assert total > 0
+
+
+def test_second_job_in_one_process_reports_its_own_aggregates(cli_series):
+    """The aggregate ``align`` / ``consensus`` span timers used to leak
+    across runs in one process (no dotted run prefix matches them)."""
+    for tag in ("on2", "traced"):
+        rep = cli_series[tag]["report"]
+        timers = rep["metrics"]["timers"]
+        assert 0 < timers["align"] <= rep["wall_s"]
+        assert 0 < timers["consensus"] <= rep["wall_s"]
+        # and the ledger holds this job's submissions only
+        first = cli_series["on1"]["report"]["device_time"]["programs"]
+        assert rep["device_time"]["programs"] == first
+
+
+def test_trace_export_carries_the_clock_pair(cli_series):
+    doc = json.loads(cli_series["traced"]["trace"].read_bytes())
+    clock = doc["metadata"]["clock"]
+    rep = cli_series["traced"]["report"]
+    assert clock == rep["device_time"]["clock"]
+    assert abs(clock["unix_ns"] / 1e9 - rep["started_unix"]) < 5.0
+    names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
+    assert {"align.pack", "align.put", "align.launch", "align.wait",
+            "align.get", "align.decode", "poa.put", "poa.wait",
+            "poa.get", "poa.decode"} <= names
+    # the trace holds this job's spans only: ts 0 is the job's begin
+    assert all(e["ts"] >= 0 for e in doc["traceEvents"]
+               if e.get("ph") == "X")
+
+
+# ---------------------------------------------------------- the gaps tool
+
+def _synthetic_pair(offset_ns: int):
+    """A report's device_time and a device trace of the same made-up
+    job: the host observes each completion ``offset + jitter`` after
+    the device ended it."""
+    dev = [("jit__build_rows_packed2(11)", 1_000 * MS, 200 * MS),
+           ("jit__pallas_align_chain(12)", 1_200 * MS, 300 * MS),
+           ("jit_convert_element_type(13)", 1_900 * MS, 1 * MS),
+           ("jit__build_rows_packed2(14)", 2_000 * MS, 200 * MS),
+           ("jit__pallas_align_chain(15)", 2_200 * MS, 400 * MS)]
+    ops = [("fusion.1", 1_000 * MS, 200 * MS),
+           ("custom-call.2", 1_200 * MS, 100 * MS),
+           ("custom-call.2", 1_350 * MS, 150 * MS),     # 50 ms inside gap
+           ("convert.3", 1_900 * MS, 1 * MS),
+           ("fusion.1", 2_000 * MS, 200 * MS),
+           ("custom-call.2", 2_200 * MS, 400 * MS)]
+    events = {"0": {"XLA Modules": dev, "XLA Ops": ops,
+                    "Async XLA Ops": [("copy-start", 1_300 * MS, 20 * MS)]}}
+    jitter = [0, 2 * MS, 1 * MS, 3 * MS]
+    names = ["_build_rows_packed2", "_pallas_align_chain"] * 2
+    ends = [1_200 * MS, 1_500 * MS, 2_200 * MS, 2_600 * MS]
+    timeline = [["0", "exec", n, "MainThread", e + offset_ns - 50 * MS,
+                 e + offset_ns + j]
+                for n, e, j in zip(names, ends, jitter)]
+    timeline.insert(0, ["0", "h2d", "align.put", "MainThread",
+                        900 * MS + offset_ns, 950 * MS + offset_ns])
+    host_gap = [1_500 * MS + offset_ns, 2_000 * MS + offset_ns,
+                {"align.pack": 0.3, "align.put": 0.15,
+                 "unattributed": 0.05}]
+    section = dict(device_time.empty_section(), timeline=timeline,
+                   gaps=[host_gap], programs=5)
+    return {"device_time": section}, events
+
+
+def test_gaps_recovers_a_planted_offset_and_names_the_gap():
+    planted = 7_654_321_000
+    rep, events = _synthetic_pair(planted)
+    res = gaps.analyze(rep, events)
+    assert res["offset_ns"] == planted
+    assert res["pairs"] == 4
+    assert res["residual_ns"]["max"] == 3 * MS
+    assert res["unpaired"] == {
+        "jit_convert_element_type": {"count": 1, "seconds": 0.001}}
+    between = [g for g in res["gaps"] if g["group"].startswith("before:")]
+    inside = [g for g in res["gaps"] if g["group"].startswith("inside:")]
+    # 1500-1900 before the eager helper, 1901-2000 before the next chunk
+    assert [round(g["seconds"], 3) for g in between] == [0.4, 0.099]
+    assert between[0]["group"] == "before:jit_convert_element_type"
+    assert between[0]["spans"]["align.pack"] == pytest.approx(0.24)
+    assert between[0]["named_s"] == pytest.approx(0.4)
+    (g,) = inside
+    assert g["group"] == "inside:jit__pallas_align_chain"
+    assert g["seconds"] == pytest.approx(0.05)
+    assert g["async_covered_s"] == pytest.approx(0.02)
+    text = gaps.render(res)
+    assert "clock offset" in text and "before:jit_convert_element_type" \
+        in text
+
+
+def test_gaps_refuses_a_pairing_whose_names_or_counts_differ(tmp_path):
+    rep, events = _synthetic_pair(10**9)
+    renamed = json.loads(json.dumps(rep))
+    renamed["device_time"]["timeline"][1][2] = "_build_rows_packed"
+    with pytest.raises(gaps.GapsError, match="no program"):
+        gaps.analyze(renamed, events)
+    fewer = json.loads(json.dumps(rep))
+    del fewer["device_time"]["timeline"][-1]
+    with pytest.raises(gaps.GapsError, match="times"):
+        gaps.analyze(fewer, events)
+    # the command: exit 1 and a message, exit 0 and the table
+    rp, tp = tmp_path / "rep.json", tmp_path / "trace_events.json"
+    rp.write_text(json.dumps(renamed))
+    tp.write_text(json.dumps(events))
+    assert report._main(["gaps", str(rp), str(tp)]) == 1
+    rp.write_text(json.dumps(rep))
+    assert report._main(["gaps", str(rp), str(tp)]) == 0
