@@ -62,7 +62,7 @@ METRIC_NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)*$")
 METRICS: FrozenSet[str] = frozenset((
     # aligner wavefront arenas + dispatch accounting
     "align.chunks", "align.lanes_occupied", "align.lanes_total",
-    "align.steps_wasted", "align.wavefront_work",
+    "align.packed_ahead", "align.steps_wasted", "align.wavefront_work",
     "aligner.band_escalated", "aligner.capacity_scale",
     "aligner.fallback_band", "aligner.fallback_length",
     "aligner.ladder_narrow", "aligner.pallas_chunks",

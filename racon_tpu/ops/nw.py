@@ -26,7 +26,7 @@ Reference call-site parity: replaces edlib/cudaaligner behind
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -740,6 +740,29 @@ from .. import faults, obs
 from ..obs import device_time, metrics
 
 
+class _PackedChunk(NamedTuple):
+    """A chunk after the host half of its launch
+    (``TpuAligner._pack_chunk``): nothing of it is on the device yet."""
+    pairs: object             # list or slot dict: ``pairs[i]`` -> (q, t)
+    chunk: Sequence[int]
+    max_len: int
+    band: int
+    bp_meta: Optional[tuple]
+    n: np.ndarray
+    m: np.ndarray
+    seqs: Tuple[np.ndarray, np.ndarray]
+    kind: str                 # "2bit" | "nibble" | "raw"
+    bp_host: Optional[tuple]
+    steps: int
+    sw: bool
+
+    @property
+    def dirs_bytes(self) -> int:
+        """What the chunk's direction matrix will hold on the device:
+        the bytes the in-flight budget counts."""
+        return self.n.shape[0] * self.steps * (self.band // 8)
+
+
 class TpuAligner(PallasDispatchMixin):
     """Batched device aligner with on-device traceback and host fallback.
 
@@ -1222,78 +1245,95 @@ class TpuAligner(PallasDispatchMixin):
                 results[i] = arr
 
     def _launch_chunk(self, pairs, chunk, max_len, band, bp_meta=None):
-        """Span-wrapped :meth:`_launch_chunk_impl` — the dispatch half
-        of the aligner's dispatch-vs-fetch split (host pack + async
-        kernel dispatch; the device computes after this returns)."""
-        faults.check("align.dispatch")
-        with self._pinned(), obs.span("align.dispatch", pairs=len(chunk),
-                                      max_len=max_len, band=band):
-            return self._launch_chunk_impl(pairs, chunk, max_len, band,
-                                           bp_meta)
+        """The dispatch half of the aligner's dispatch-vs-fetch split:
+        :meth:`_pack_chunk` and :meth:`_submit_chunk` back to back (the
+        device computes after this returns). The ragged stream calls
+        the two halves itself, with its wait for room between them."""
+        return self._submit_chunk(
+            self._pack_chunk(pairs, chunk, max_len, band, bp_meta))
 
-    def _launch_chunk_impl(self, pairs, chunk, max_len, band,
-                           bp_meta=None):
-        """Pack a chunk and dispatch its kernels; returns the in-flight
-        handle consumed by ``_finish_chunk``. Device work proceeds
-        asynchronously after dispatch.
+    def _pack_chunk(self, pairs, chunk, max_len, band,
+                    bp_meta=None) -> "_PackedChunk":
+        """The host half of a launch: everything that touches no
+        device, so it may run while an earlier chunk holds the whole
+        direction-matrix budget.
 
         Sequences cross the host link as dense ``B * max_len`` byte
         blocks; the banded row layout (reversal, band offsets, padding) is
         built on device (:func:`_build_rows`) — the padded row arrays are
         ~3x the raw bases, so building them there cuts the bytes sent."""
-        # the three leaves of align.dispatch: host packing, the
-        # host->device puts, the jit calls until they return
+        faults.check("align.dispatch")
         leaf = dict(pairs=len(chunk), max_len=max_len, band=band)
-        with obs.span("align.pack", **leaf):
+        with self._pinned(), obs.span("align.dispatch", **leaf), \
+                obs.span("align.pack", **leaf):
             sw = self._swar_choice(max_len)
-            n, m, seqs, kind, bp_host = self._pack_chunk(
+            n, m, seqs, kind, bp_host = self._pack_blocks(
                 pairs, chunk, max_len, bp_meta, sw)
             steps = _sweep_bound(int((n + m).max()), max_len)
             self._count_arena(n, m, len(chunk), steps, band)
-        # multi-host: every process packs the (deterministic) chunk and
-        # materializes only its addressable shards of the global arrays
-        # (the flat char blocks shard evenly too: B is a mesh multiple,
-        # so [B * max_len] splits on row boundaries — max_len is a
-        # multiple of 4, so the 2-bit blocks split evenly as well)
-        from ..parallel import to_global
-        put = ((lambda a: to_global(self.mesh, a)) if self.mesh is not None
-               else jnp.asarray)
-        with obs.span("align.put", **leaf):
-            nd, md = put(n), put(m)
-            bp_dev = None if bp_host is None else [put(a) for a in bp_host]
-            q_d, t_d = put(seqs[0]), put(seqs[1])
-        device_time.submit("h2d", "align.put", t_d)
-        with obs.span("align.launch", **leaf):
-            build = {"2bit": _build_rows_packed2, "nibble": _build_rows_packed,
-                     "raw": _build_rows}[kind]
-            qrp, tp = build(q_d, t_d, nd, md, max_len=max_len, band=band)
-            device_time.submit("exec", build.__name__, tp)
-            args = (qrp, tp, nd, md)
-            B = n.shape[0]
-            use_pallas = self._use_pallas((max_len, band, steps, B))
-            if use_pallas:
-                from .pallas_nw import pallas_swar_ok
-                # the packed Mosaic kernel's XOR+mask equality reads
-                # 4-bit codes, so raw-byte chunks (alphabet > 15, rows
-                # not remapped) must never take it — bytes differing
-                # only in bits 4-7 would compare equal there
-                sw = sw and kind != "raw" and pallas_swar_ok()
-            # no try/except around the dispatch: a Mosaic kernel that
-            # does not compile or run for this shape fails the run (the
-            # jit error names the function and shapes)
-            out = self._dispatch(args, max_len, band, steps, use_pallas,
-                                 sw)
-            # the score vector, never the tables: the watcher must hold
-            # nothing the direction-matrix budget counts
-            device_time.submit(
-                "exec", "sharded_align" if self.mesh is not None
-                else "_pallas_align_chain" if use_pallas
-                else "align_chain", out[1])
-            if bp_dev is not None:
-                out = self._attach_bp(out, nd, md, bp_dev, bp_meta,
-                                      max_len)
-                device_time.submit("exec", "_breaking_points_kernel",
-                                   out[1])
+        return _PackedChunk(pairs, chunk, max_len, band, bp_meta, n, m,
+                            seqs, kind, bp_host, steps, sw)
+
+    def _submit_chunk(self, packed: "_PackedChunk"):
+        """The device half of a launch: put a packed chunk's blocks and
+        dispatch its kernels; returns the in-flight handle consumed by
+        ``_finish_chunk``. Device work proceeds asynchronously after
+        dispatch."""
+        (pairs, chunk, max_len, band, bp_meta, n, m, seqs, kind, bp_host,
+         steps, sw) = packed
+        # the other two leaves of align.dispatch (align.pack is the
+        # first): the host->device puts, the jit calls until they return
+        leaf = dict(pairs=len(chunk), max_len=max_len, band=band)
+        with self._pinned(), obs.span("align.dispatch", **leaf):
+            # multi-host: every process packs the (deterministic) chunk
+            # and materializes only its addressable shards of the global
+            # arrays (the flat char blocks shard evenly too: B is a mesh
+            # multiple, so [B * max_len] splits on row boundaries —
+            # max_len is a multiple of 4, so the 2-bit blocks split
+            # evenly as well)
+            from ..parallel import to_global
+            put = ((lambda a: to_global(self.mesh, a))
+                   if self.mesh is not None else jnp.asarray)
+            with obs.span("align.put", **leaf):
+                nd, md = put(n), put(m)
+                bp_dev = (None if bp_host is None
+                          else [put(a) for a in bp_host])
+                q_d, t_d = put(seqs[0]), put(seqs[1])
+            device_time.submit("h2d", "align.put", t_d)
+            with obs.span("align.launch", **leaf):
+                build = {"2bit": _build_rows_packed2,
+                         "nibble": _build_rows_packed,
+                         "raw": _build_rows}[kind]
+                qrp, tp = build(q_d, t_d, nd, md, max_len=max_len,
+                                band=band)
+                device_time.submit("exec", build.__name__, tp)
+                args = (qrp, tp, nd, md)
+                B = n.shape[0]
+                use_pallas = self._use_pallas((max_len, band, steps, B))
+                if use_pallas:
+                    from .pallas_nw import pallas_swar_ok
+                    # the packed Mosaic kernel's XOR+mask equality reads
+                    # 4-bit codes, so raw-byte chunks (alphabet > 15,
+                    # rows not remapped) must never take it — bytes
+                    # differing only in bits 4-7 would compare equal
+                    # there
+                    sw = sw and kind != "raw" and pallas_swar_ok()
+                # no try/except around the dispatch: a Mosaic kernel
+                # that does not compile or run for this shape fails the
+                # run (the jit error names the function and shapes)
+                out = self._dispatch(args, max_len, band, steps,
+                                     use_pallas, sw)
+                # the score vector, never the tables: the watcher must
+                # hold nothing the direction-matrix budget counts
+                device_time.submit(
+                    "exec", "sharded_align" if self.mesh is not None
+                    else "_pallas_align_chain" if use_pallas
+                    else "align_chain", out[1])
+                if bp_dev is not None:
+                    out = self._attach_bp(out, nd, md, bp_dev, bp_meta,
+                                          max_len)
+                    device_time.submit("exec", "_breaking_points_kernel",
+                                       out[1])
         # counted on the path actually taken: the Pallas-level
         # decision can differ from the XLA-level one
         self.stats["swar_chunks"] += int(sw)
@@ -1303,8 +1343,8 @@ class TpuAligner(PallasDispatchMixin):
         metrics.inc("aligner.pallas_chunks", int(use_pallas))
         return chunk, pairs, n, m, out, max_len
 
-    def _pack_chunk(self, pairs, chunk, max_len, bp_meta, sw: bool):
-        """The host half of a launch: ``(n, m, (q, t) packed sequence
+    def _pack_blocks(self, pairs, chunk, max_len, bp_meta, sw: bool):
+        """A chunk's host arrays: ``(n, m, (q, t) packed sequence
         blocks, their kind, the bp kernel's host inputs | None)``;
         ``sw``: the bucket may run packed lanes (2-bit blocks then)."""
         # Pad the batch to a power of two: B is part of the compiled shape,
@@ -1599,7 +1639,7 @@ class TpuAligner(PallasDispatchMixin):
             cap = self._chunk_cap(steps, bd)
             # the launcher's own batch-padding rule (plain pow2 here:
             # warm-up never runs under a mesh) — warmup-coverage keeps
-            # this shared with _launch_chunk_impl
+            # this shared with _pack_blocks
             B = self._pad_batch(min(cap, est_pairs))
             shapes.append((max_len, bd, steps, B, window_length))
         return shapes
@@ -1632,7 +1672,7 @@ class TpuAligner(PallasDispatchMixin):
         def _compile_one(max_len, band, steps, B, w):
             # the availability probes compile and run kernels, so they
             # belong on this thread too (same choice order as
-            # _launch_chunk_impl: ACGT chunks take the 2-bit path);
+            # _pack_chunk / _submit_chunk: ACGT chunks take the 2-bit path);
             # probed directly rather than via _swar_choice so the warm
             # thread never writes the stats dict the main thread owns
             from .swar import swar_fits, swar_ok
@@ -1728,13 +1768,21 @@ class _AlignStream:
     batch-fill shape, ``cudabatch.cpp:54-62``; ``reduce_capacity``
     halves the arena under OOM backpressure).
 
-    Full chunks dispatch ASYNCHRONOUSLY the moment they close: host
-    packing of the next slice overlaps device compute of the previous
-    chunks, and fetches happen only when the in-flight byte budget
-    forces one or at :meth:`finish` — the double-buffered dispatch that
-    keeps the per-chunk host round trip off the critical path (chunks
-    that each take the whole budget run one at a time: the budget
-    bounds what the device HOLDS). Band escapes re-enter
+    Full chunks dispatch ASYNCHRONOUSLY the moment they close, in two
+    halves (:meth:`_launch`): the host pack of chunk k+1
+    (``TpuAligner._pack_chunk``, no device touched) runs FIRST, while
+    the chunks in flight compute; only then is room made under the
+    in-flight byte budget (a fetch of chunk k, when k+1 does not fit
+    beside it), and only after that do the puts and the kernel
+    dispatch follow (``_submit_chunk``). The budget bounds what the
+    device HOLDS, so put and launch wait for room — chunks that each
+    take the whole budget still run one at a time on the device, never
+    k+1's row arrays beside k's direction matrix — but the host pack
+    holds nothing there and does not wait: per chunk the feeding
+    thread pays max(pack k+1, device k), not their sum. Counter
+    ``align.packed_ahead``: chunks whose submit had to fetch an earlier
+    chunk after their pack. Otherwise fetches happen only at
+    :meth:`finish` or under the pair bound. Band escapes re-enter
     the pending classes at their escalated rung and re-dispatch
     *batched*; geometry strictly escalates, so the drain loop
     terminates. Accepted alignments are byte-identical at every rung
@@ -1883,18 +1931,26 @@ class _AlignStream:
 
     def _launch(self, cls, chunk, max_len: int, band: int) -> None:
         eng = self.eng
-        q0, t0 = self.pairs[chunk[0]]     # head = chunk's longest pair
-        steps = _sweep_bound(len(q0) + len(t0), max_len)
-        nbytes = eng._pad_batch(len(chunk)) * steps * (band // 8)
+        # the host half first: packing holds nothing on the device, so
+        # it runs while the chunks in flight compute. The chunk's
+        # members were fixed by _drain; a fetch below cannot change them
+        # (its escapees land in ``pending``)
+        packed = eng._pack_chunk(self.pairs, chunk, max_len, band,
+                                 self._bp_meta())
+        nbytes = packed.dirs_bytes
         # the direction-matrix budget bounds what is HELD on the device,
-        # so room is made BEFORE the dispatch allocates the new chunk's
-        # matrix: launching first and fetching after let two
-        # budget-sized chunks (8 GiB each) coexist on a 16 GB chip
+        # so room is made BEFORE the puts and the dispatch allocate the
+        # new chunk's row arrays and matrix: launching first and
+        # fetching after let two budget-sized chunks (8 GiB each)
+        # coexist on a 16 GB chip
+        ahead = False
         while (self.inflight
                and self.inflight_bytes + nbytes > eng.dirs_budget_cap):
             self._finish_oldest()
-        launched = eng._launch_chunk(self.pairs, chunk, max_len, band,
-                                     self._bp_meta())
+            ahead = True
+        # packed while a chunk it could not sit beside was in flight
+        metrics.inc("align.packed_ahead", int(ahead))
+        launched = eng._submit_chunk(packed)
         entry = {"cls": cls, "chunk": chunk, "launched": launched,
                  "bytes": nbytes}
         self.inflight.append(entry)

@@ -156,6 +156,140 @@ def test_f_mode_short_reads_parity():
     assert eng.stats["chunks"] >= 1
 
 
+def _record_halves(eng, sess, events):
+    """Wrap the engine's pack / submit / ``_finish_chunk`` so that each
+    appends ``(what, chunk id)`` on entry and ``(what + "<", id)`` on
+    return; a submit also records whether the session's in-flight bytes
+    plus the new chunk's fit the budget at that moment."""
+    ids = {}
+
+    def cid(chunk):
+        return ids.setdefault(id(chunk), len(ids))
+
+    pack, submit, finish = (eng._pack_chunk, eng._submit_chunk,
+                            eng._finish_chunk)
+
+    def rec_pack(pairs, chunk, *a, **kw):
+        events.append(("pack", cid(chunk)))
+        out = pack(pairs, chunk, *a, **kw)
+        events.append(("pack<", cid(chunk)))
+        return out
+
+    def rec_submit(packed):
+        fits = (not sess.inflight
+                or sess.inflight_bytes + packed.dirs_bytes
+                <= eng.dirs_budget_cap)
+        events.append(("submit", cid(packed.chunk), fits))
+        out = submit(packed)
+        events.append(("submit<", cid(packed.chunk)))
+        return out
+
+    def rec_finish(launched, *a, **kw):
+        events.append(("finish", cid(launched[0])))
+        finish(launched, *a, **kw)
+        events.append(("finish<", cid(launched[0])))
+
+    eng._pack_chunk, eng._submit_chunk, eng._finish_chunk = (
+        rec_pack, rec_submit, rec_finish)
+
+
+def _packed_ahead(events):
+    """Chunk ids whose pack returned, then at least one earlier chunk
+    was fetched, then their submit began — checking on the way that the
+    three halves never nest and each chunk runs pack, submit, finish in
+    that order."""
+    ahead, open_what, stage = set(), None, {}
+    fetched_since_pack = {}
+    for what, c, *_ in events:
+        if what.endswith("<"):
+            assert open_what == (what[:-1], c), events
+            open_what = None
+            if what == "pack<":
+                fetched_since_pack[c] = 0
+            continue
+        assert open_what is None, events     # no half inside another
+        open_what = (what, c)
+        assert stage.get(c) == {"pack": None, "submit": "pack",
+                                "finish": "submit"}[what], events
+        stage[c] = what
+        if what == "finish":
+            for k in fetched_since_pack:
+                fetched_since_pack[k] += 1
+        elif what == "submit" and fetched_since_pack.pop(c):
+            ahead.add(c)
+    assert open_what is None
+    assert set(stage.values()) == {"finish"}
+    return ahead
+
+
+def test_pack_runs_ahead_of_the_fetch_and_submit_waits_for_room():
+    """A budget that admits one full chunk at a time: chunk k+1 is
+    packed while chunk k is still in flight, chunk k is fetched only
+    then, and the puts and the dispatch of k+1 start after that fetch
+    returned — never two chunks on the device that do not fit the
+    budget together."""
+    metrics.clear_run()
+    rng = np.random.default_rng(91)
+    pairs, metas, errors = _mixed_pairs(rng, n=40, lo=120, hi=250)
+    ref = _engine(False, False).breaking_points_batch(
+        pairs, metas, 100, errors=errors)
+    metrics.clear_run()
+
+    # eight pairs of the smallest bucket's longest sweep per chunk
+    eng = _engine(ladder=False, max_dirs_bytes=8 * 512 * (128 // 8))
+    sess = eng.bp_stream(100, total=len(pairs))
+    events = []
+    _record_halves(eng, sess, events)
+    sess.feed(pairs, metas, errors)
+    got = sess.finish()
+    assert _bp_equal(got, ref)
+
+    ahead = _packed_ahead(events)
+    order = [c for what, c, *_ in events if what == "pack"]
+    assert len(order) == eng.stats["chunks"] >= 5
+    assert all(fits for what, _, *fits in events if what == "submit")
+    # every chunk that found a chunk in flight it could not sit beside
+    # was packed before that chunk's fetch began
+    assert len(ahead) >= len(order) // 2
+    for c in ahead:
+        i_pack = events.index(("pack<", c))
+        i_sub = next(i for i, e in enumerate(events)
+                     if e[:2] == ("submit", c))
+        between = events[i_pack + 1:i_sub]
+        assert between and {w for w, *_ in between} == {"finish",
+                                                        "finish<"}
+        assert all(k < c for _, k in between)   # earlier chunks only
+    # the counter that says the reorder engaged
+    from racon_tpu import contracts
+    assert "align.packed_ahead" in contracts.METRICS
+    assert metrics.counter("align.packed_ahead") == len(ahead)
+    assert metrics.counter("align.chunks") == len(order)
+
+
+def test_packed_ahead_is_zero_where_the_budget_never_forces_a_fetch():
+    """F-mode short reads under the default budget: chunks are cheap in
+    direction-matrix bytes, nothing is fetched to make room, so pack and
+    submit run back to back as before and the counter stays 0 (present:
+    a ratio over ``align.chunks`` then reads 0, not absent)."""
+    metrics.clear_run()
+    rng = np.random.default_rng(31)
+    pairs, metas, errors = _mixed_pairs(rng, n=40, lo=30, hi=90)
+    eng = _engine()
+    sess = eng.bp_stream(50, total=len(pairs))
+    events = []
+    _record_halves(eng, sess, events)
+    sess.feed(pairs, metas, errors)
+    sess.finish()
+    assert eng.stats["chunks"] >= 1
+    assert _packed_ahead(events) == set()
+    assert metrics.counter("align.packed_ahead") == 0
+    assert "align.packed_ahead" in metrics.snapshot()["counters"]
+    # back to back: a pack's return is followed by its own submit
+    for i, e in enumerate(events):
+        if e[0] == "pack<":
+            assert events[i + 1][:2] == ("submit", e[1])
+
+
 def test_reduce_capacity_redispatch_parity():
     """The exec ladder's OOM-backpressure rung on the align arena: a
     capacity-halved engine re-dispatches smaller chunks with

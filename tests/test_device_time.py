@@ -125,6 +125,52 @@ def test_idle_goes_to_innermost_span_of_the_submitting_thread():
                    "align.pack": 0.2, "compile.trace": 0.05}
 
 
+def test_a_pack_run_ahead_is_charged_only_for_what_outlasts_the_chunk():
+    """The align stream packs chunk k+1 while chunk k is on the device
+    (its own ``align.dispatch`` span, before the fetch of k), then puts
+    and launches under a second one. ``idle.align.pack`` is the part of
+    a pack that outlasts the chunk in flight: 100 ms of the first pack
+    here, nothing of the second, which ends while its predecessor still
+    runs."""
+    rows = [("0", "exec", "k", "feeder", 0, 200 * MS),
+            ("0", "exec", "k", "feeder", 340 * MS, 700 * MS),
+            ("0", "exec", "k", "feeder", 760 * MS, 1000 * MS)]
+    spans = {"feeder": [
+        ("align", 0, 1000 * MS),
+        # chunk 1: packed 10-300 while chunk 0 ran until 200
+        ("align.dispatch", 10 * MS, 300 * MS),
+        ("align.pack", 10 * MS, 300 * MS),
+        ("align.fetch", 300 * MS, 320 * MS),
+        ("align.wait", 300 * MS, 300 * MS),
+        ("align.get", 300 * MS, 305 * MS),
+        ("align.decode", 305 * MS, 320 * MS),
+        ("align.dispatch", 320 * MS, 340 * MS),
+        ("align.put", 320 * MS, 330 * MS),
+        ("align.launch", 330 * MS, 340 * MS),
+        # chunk 2: packed 350-600, hidden under chunk 1 (340-700)
+        ("align.dispatch", 350 * MS, 600 * MS),
+        ("align.pack", 350 * MS, 600 * MS),
+        ("align.fetch", 600 * MS, 740 * MS),
+        ("align.wait", 600 * MS, 700 * MS),
+        ("align.get", 700 * MS, 710 * MS),
+        ("align.decode", 710 * MS, 740 * MS),
+        ("align.dispatch", 740 * MS, 760 * MS),
+        ("align.put", 740 * MS, 750 * MS),
+        ("align.launch", 750 * MS, 760 * MS)]}
+    out = device_time.account(rows, spans, 0, 1000 * MS, "feeder")
+    assert out["busy_s"] == pytest.approx(0.8)
+    assert out["idle_s"] == pytest.approx(0.2)      # 200-340, 700-760
+    # (the parents are covered by their leaves here: no self time)
+    assert out["idle_by"] == {
+        "align": 0.0, "align.pack": 0.1, "align.wait": 0.0,
+        "align.get": 0.015, "align.decode": 0.045,
+        "align.put": 0.02, "align.launch": 0.02, "unattributed": 0.0}
+    assert [(a, b) for a, b, _ in out["gaps"]] == [
+        (200 * MS, 340 * MS), (700 * MS, 760 * MS)]
+    assert out["gaps"][1][2] == {"align.get": 0.01, "align.decode": 0.03,
+                                 "align.put": 0.01, "align.launch": 0.01}
+
+
 def test_warm_up_program_is_busy_but_takes_no_blame():
     """A warm-up thread's dummy program occupies the device (the same
     in-order queue as the real programs) but feeds nothing: the idle
@@ -424,6 +470,39 @@ def test_leaf_spans_sum_to_no_more_than_their_parent(cli_series, parent,
         total = sum(timers[leaf] for leaf in leaves)
         assert total <= timers[parent] * 1.01 + 1e-4, (tag, parent)
         assert total > 0
+
+
+def test_every_chunk_opens_two_dispatch_spans_and_pack_is_in_the_first(
+        cli_series):
+    """A chunk's launch runs in two halves: ``align.pack`` under one
+    ``align.dispatch`` span, ``align.put`` + ``align.launch`` under a
+    later one on the same thread."""
+    doc = json.loads(cli_series["traced"]["trace"].read_bytes())
+    chunks = cli_series["traced"]["report"]["metrics"]["counters"][
+        "align.chunks"]
+    by = {}
+    for e in doc["traceEvents"]:
+        if e.get("ph") == "X" and e["name"].startswith("align."):
+            by.setdefault(e["name"], []).append(e)
+    assert len(by["align.pack"]) == len(by["align.put"]) == chunks > 0
+    assert len(by["align.dispatch"]) == 2 * chunks
+
+    def parent(leaf):
+        # same thread, and the child's interval inside the parent's
+        # (1 us of slack: the export rounds begin and duration apart)
+        (found,) = [d for d in by["align.dispatch"]
+                    if d["tid"] == leaf["tid"]
+                    and d["ts"] <= leaf["ts"] + 1
+                    and leaf["ts"] + leaf["dur"]
+                    <= d["ts"] + d["dur"] + 1]
+        return found
+
+    for pack, put, launch in zip(by["align.pack"], by["align.put"],
+                                 by["align.launch"]):
+        first, second = parent(pack), parent(put)
+        assert first is not second
+        assert parent(launch) is second
+        assert first["ts"] + first["dur"] <= second["ts"] + 1
 
 
 def test_second_job_in_one_process_reports_its_own_aggregates(cli_series):
