@@ -67,6 +67,9 @@ METRICS: FrozenSet[str] = frozenset((
     "aligner.fallback_band", "aligner.fallback_length",
     "aligner.ladder_narrow", "aligner.pallas_chunks",
     "aligner.swar_chunks", "aligner.swar_guard_int32",
+    # window build: the read pool, and how much of it was ready before
+    # the layer assembly had to wait for it
+    "build.pool_bytes", "build.pool_bytes_ahead",
     # XLA compile attribution + JAX's persistent-cache lookups
     "compile.backend_total", "compile.cache_hits",
     "compile.cache_requests", "compile.jax_s",
@@ -170,6 +173,9 @@ SPANS: FrozenSet[str] = frozenset((
     "align.wait", "align.get", "align.decode",
     "bp.decode",
     "build.backbone", "build.store", "build.windows",
+    # the half of the layer assembly that needs no breaking point
+    # (beside the aligner, or inline), and the wait for it at the barrier
+    "build.prepare", "build.prepare_wait",
     # the compile listener's back-dated stages (obs/compilewatch.py)
     "compile.backend", "compile.lower", "compile.trace",
     "consensus", "consensus.feed", "consensus.finish", "consensus.run",

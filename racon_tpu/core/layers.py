@@ -34,6 +34,36 @@ for _i, _b in enumerate(b"ACGT"):
     _CODE_LUT[_b] = _i
 
 
+# one quality byte's ``weight << 3`` (phred-33 clipped at 0) and one base's
+# code, as lanes: prepare's two table look-ups per pooled base
+_WEIGHT_LANES = (np.maximum(np.arange(256) - 33, 0) << 3).astype(np.uint16)
+_CODE_LANES = _CODE_LUT.astype(np.uint16)
+_SLICE = 1 << 20  # pool bases per numpy call of prepare (a few ms each)
+
+
+class PreparedPool:
+    """What :meth:`LayerStore.prepare` makes of reads + overlaps alone:
+    ``pool``/``qpool``/``qpw_pool`` as :class:`LayerStore` keeps them,
+    per overlap its pool offset ``ov_off`` and whether it has qualities
+    ``hq_ov``, the smallest quality byte of a quality-bearing base
+    ``q_min`` (255 when there is none), and the wrapping prefix sums
+    ``qsum`` over ``qpool`` (``len(qpool) + 1`` entries) — the only
+    field the store does not keep: its user drops it after the
+    mean-PHRED filter."""
+
+    __slots__ = ("pool", "qpool", "qpw_pool", "ov_off", "hq_ov", "q_min",
+                 "qsum")
+
+    def __init__(self, pool, qpool, qpw_pool, ov_off, hq_ov, q_min, qsum):
+        self.pool = pool
+        self.qpool = qpool
+        self.qpw_pool = qpw_pool
+        self.ov_off = ov_off
+        self.hq_ov = hq_ov
+        self.q_min = q_min
+        self.qsum = qsum
+
+
 class LayerStore:
     """One run's layers, columnar. Per-layer arrays are window-major
     (sorted by ``win_id``, stable in overlap-stream order within a
@@ -68,49 +98,47 @@ class LayerStore:
         return len(self.src)
 
     @classmethod
-    def build(cls, data_refs: Sequence[bytes],
-              qual_refs: Sequence[Optional[bytes]],
-              ov: np.ndarray, qb: np.ndarray, qe: np.ndarray,
-              win_id: np.ndarray, begin: np.ndarray, end: np.ndarray,
-              n_windows: int) -> "LayerStore":
-        """Vectorized store build from the per-layer columnar arrays of
-        ``_assemble_layers`` (already window-major sorted).
+    def build(cls, prep: "PreparedPool", ov: np.ndarray, qb: np.ndarray,
+              qe: np.ndarray, win_id: np.ndarray, begin: np.ndarray,
+              end: np.ndarray, n_windows: int) -> "LayerStore":
+        """The store over a prepared pool, from the per-layer columnar
+        arrays of ``_assemble_layers`` (already window-major sorted):
+        index arithmetic only. The pool holds every overlap's read, so a
+        read whose rows were all filtered out is pooled and addressed by
+        no row."""
+        ov = np.asarray(ov, np.int64)
+        qb = np.asarray(qb, np.int64)
+        win_id = np.asarray(win_id, np.int64)
+        return cls(prep.pool, prep.qpool, prep.qpw_pool,
+                   prep.ov_off[ov] + qb, np.asarray(qe, np.int64) - qb,
+                   np.asarray(begin, np.int64), np.asarray(end, np.int64),
+                   win_id, prep.hq_ov[ov],
+                   np.searchsorted(win_id, np.arange(n_windows + 1)))
+
+    @staticmethod
+    def prepare(data_refs: Sequence[bytes],
+                qual_refs: Sequence[Optional[bytes]]) -> "PreparedPool":
+        """The half of the store that needs no breaking point: the
+        byte/quality/packed-lane pool over EVERY overlap and the quality
+        prefix sums the mean-PHRED filter looks up.
 
         ``data_refs``/``qual_refs`` are per-overlap references into the
         read set (forward or reverse-complement orientation); the pool
         deduplicates them by object identity, so a read orientation
-        referenced by many overlaps is pooled once."""
-        ov = np.asarray(ov, np.int64)
-        used = np.unique(ov) if len(ov) else np.zeros(0, np.int64)
-        (pool, qpool, qpw_pool, ov_off, hq_ov,
-         _has_q_base) = cls._build_pool(data_refs, qual_refs, used)
-
-        src = ov_off[ov] + np.asarray(qb, np.int64)
-        length = (np.asarray(qe, np.int64)
-                  - np.asarray(qb, np.int64)).astype(np.int64)
-        row_bounds = np.searchsorted(
-            np.asarray(win_id, np.int64), np.arange(n_windows + 1))
-        return cls(pool, qpool, qpw_pool, src, length,
-                   np.asarray(begin, np.int64), np.asarray(end, np.int64),
-                   np.asarray(win_id, np.int64), hq_ov[ov], row_bounds)
-
-    @classmethod
-    def _build_pool(cls, data_refs: Sequence[bytes],
-                    qual_refs: Sequence[Optional[bytes]],
-                    used: np.ndarray):
-        """Identity-deduplicated byte/quality/packed-lane pool over the
-        overlap indices in ``used`` — the shared core of :meth:`build`
-        and the device-resident assemble path (which pools every overlap
-        up front, before the device filter decides which rows survive).
-        Returns ``(pool, qpool, qpw_pool, ov_off, hq_ov, has_q_base)``."""
+        referenced by many overlaps is pooled once. The polisher runs
+        this beside the aligner's pack loop, so the passes over the pool
+        are numpy calls on bounded slices that allocate nothing: each
+        holds the interpreter lock for a few ms at most, and none makes
+        the allocator map and unmap pool-sized temporaries under the
+        pack loop's own."""
         n_ov = len(data_refs)
         off_of_obj = {}
         parts: List[bytes] = []
         qparts: List[bytes] = []
+        part_hq: List[bool] = []
         pos = 0
-        ov_off = np.full(n_ov, -1, np.int64)
-        for oi in used:
-            d = data_refs[oi]
+        ov_off = np.zeros(n_ov, np.int64)
+        for oi, d in enumerate(data_refs):
             key = id(d)
             off = off_of_obj.get(key)
             if off is None:
@@ -118,28 +146,47 @@ class LayerStore:
                 off_of_obj[key] = off
                 parts.append(d)
                 q = qual_refs[oi]
+                part_hq.append(q is not None)
                 qparts.append(q if q is not None else b"\x00" * len(d))
                 pos += len(d)
             ov_off[oi] = off
-        pool = (np.frombuffer(b"".join(parts), np.uint8)
-                if parts else np.zeros(0, np.uint8))
-        qpool = (np.frombuffer(b"".join(qparts), np.uint8)
-                 if qparts else np.zeros(0, np.uint8))
-        # packed device lanes for the WHOLE pool, once: the per-group
-        # packer gather then reads finished uint16 lanes
-        hq_ov = np.fromiter((q is not None for q in qual_refs),
-                            bool, n_ov) if n_ov else np.zeros(0, bool)
-        has_q_base = np.zeros(len(pool), bool)
-        for oi in used:
-            if qual_refs[oi] is not None:
-                o = ov_off[oi]
-                has_q_base[o:o + len(data_refs[oi])] = True
-        weights = np.where(
-            has_q_base,
-            np.maximum(qpool.astype(np.int16) - 33, 0), 1)
-        qpw_pool = ((weights.astype(np.uint16) << 3)
-                    | _CODE_LUT[pool]).astype(np.uint16)
-        return pool, qpool, qpw_pool, ov_off, hq_ov, has_q_base
+        pool = np.frombuffer(b"".join(parts), np.uint8)
+        qpool = np.frombuffer(b"".join(qparts), np.uint8)
+        hq_ov = np.fromiter((q is not None for q in qual_refs), bool, n_ov)
+
+        # packed device lanes for the WHOLE pool, once (the per-group
+        # packer gather then reads finished uint16 lanes), and a span's
+        # quality sum as qsum[end] - qsum[begin]: unsigned sums wrap, and
+        # the difference is exact while one span's true sum fits the
+        # dtype (255 * the longest read bounds it). Both written in
+        # place, slice by slice: no temporary the size of the pool
+        part_len = np.fromiter(map(len, parts), np.int64, len(parts))
+        part_off = np.cumsum(part_len) - part_len
+        has_q = np.asarray(part_hq, bool)
+        longest = int(part_len.max(initial=0))
+        qsum = np.zeros(pos + 1,
+                        np.uint32 if 255 * longest < 1 << 32 else np.uint64)
+        qpw_pool = np.empty(pos, np.uint16)
+        codes = np.empty(min(pos, _SLICE), np.uint16)
+        for a in range(0, pos, _SLICE):
+            b = min(a + _SLICE, pos)
+            lanes = qpw_pool[a:b]
+            np.take(_WEIGHT_LANES, qpool[a:b], mode="clip", out=lanes)
+            np.take(_CODE_LANES, pool[a:b], mode="clip", out=codes[:b - a])
+            lanes |= codes[:b - a]
+            np.cumsum(qpool[a:b], dtype=qsum.dtype, out=qsum[a + 1:b + 1])
+            qsum[a + 1:b + 1] += qsum[a]
+        # a read without qualities weighs 1 per base (its qpool bytes are
+        # 0, so no weight bit is set yet)
+        for i in np.flatnonzero(~has_q):
+            qpw_pool[part_off[i]:part_off[i] + part_len[i]] |= 1 << 3
+        # per read its smallest quality byte, in one pass (an empty read
+        # has no segment of its own)
+        real = part_len > 0
+        q_min = int(np.minimum.reduceat(qpool, part_off[real])
+                    [has_q[real]].min(initial=255))
+        return PreparedPool(pool, qpool, qpw_pool, ov_off, hq_ov, q_min,
+                            qsum)
 
     # ------------------------------------------------------ device packing
 

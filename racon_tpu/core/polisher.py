@@ -52,9 +52,24 @@ from ..io import parsers
 from ..obs import metrics
 from ..utils.logger import Logger
 from .backends import make_aligner, make_consensus
+from .layers import LayerStore, PreparedPool
 from .overlap import Overlap, decode_breaking_points_batch
 from .sequence import Sequence
 from .window import Window, WindowType
+
+
+class _PrepareAhead:
+    """One :meth:`Polisher._prepare_layers` call on a thread of its own:
+    the overlap list it serves, the thread, and what the call returned
+    or raised (written by the thread, read after its join)."""
+
+    __slots__ = ("overlaps", "thread", "result", "error")
+
+    def __init__(self, overlaps):
+        self.overlaps = overlaps
+        self.thread: Optional[threading.Thread] = None
+        self.result: Optional[PreparedPool] = None
+        self.error: Optional[BaseException] = None
 
 
 class PolisherType(enum.Enum):
@@ -172,6 +187,9 @@ class Polisher:
         # _stitch uses for the lane-upload-saved accounting
         self._resident = flags.get_bool("RACON_TPU_RESIDENT")
         self._resident_info: Dict[str, float] = {}
+        # _prepare_layers started ahead of the aligner (_start_prepare),
+        # until _assemble_layers takes it
+        self._prepare_ahead: Optional[_PrepareAhead] = None
 
     # ---------------------------------------------------------- initialize
 
@@ -316,7 +334,21 @@ class Polisher:
             self.timings["parse_s"] = round(
                 time.perf_counter() - t_parse, 3)
 
-            self.find_overlap_breaking_points(overlaps)
+            # the overlap set is complete and its reads are transmuted:
+            # with a thread to spare, the half of the layer assembly that
+            # needs no breaking point runs beside the aligner (the
+            # streamed feed above, still receiving overlaps while it
+            # aligns, leaves it to _assemble_layers)
+            if self.num_threads > 1:
+                self._start_prepare(overlaps)
+            try:
+                self.find_overlap_breaking_points(overlaps)
+            except BaseException as e:
+                # a wedged run is abandoned as run() abandons its
+                # producer; any other failure retires the thread first
+                self._drop_prepare(
+                    wait=not isinstance(e, faults.StallError))
+                raise
 
         # backbone windows build AFTER alignment: a failed alignment then
         # leaves self.windows empty, so the double-init guard stays
@@ -782,7 +814,68 @@ class Polisher:
                 qual_refs.append(seq.quality)
         return data_refs, qual_refs
 
-    def _filter_layer_rows(self, qual_refs, counts, bp, pair_ov, t_ids):
+    def _prepare_layers(self, overlaps: List[Overlap]) -> PreparedPool:
+        """The half of the layer assembly that reads no breaking point:
+        the read pool over every overlap and the quality prefix sums
+        (:meth:`LayerStore.prepare`). ONE function with two call times:
+        beside the aligner where :meth:`_initialize_core` could start it
+        ahead, else inline in :meth:`_assemble_layers`."""
+        with obs.span("build.prepare", overlaps=len(overlaps)):
+            prep = LayerStore.prepare(*self._layer_refs(overlaps))
+        metrics.inc("build.pool_bytes", int(prep.pool.nbytes))
+        return prep
+
+    def _start_prepare(self, overlaps: List[Overlap]) -> None:
+        """Run :meth:`_prepare_layers` on a thread of its own;
+        :meth:`_assemble_layers` joins it (or :meth:`_drop_prepare`)."""
+        task = _PrepareAhead(overlaps)
+        # the metrics scope is thread-local: re-declare the caller's, as
+        # run()'s producer does
+        job_scope = metrics.get_scope()
+
+        def work():
+            metrics.set_scope(job_scope)
+            try:
+                task.result = self._prepare_layers(overlaps)
+            # graftlint: disable=swallowed-exception (re-raised on the thread that joins)
+            except BaseException as e:
+                task.error = e
+
+        task.thread = threading.Thread(target=work, name="racon-prepare",
+                                       daemon=True)
+        task.thread.start()
+        # graftlint: disable=lock-discipline (written before the builder exists, taken by the one builder; see _initialize_core)
+        self._prepare_ahead = task
+
+    def _drop_prepare(self, wait: bool) -> None:
+        """Forget a prepare started ahead (the run failed before its
+        barrier); ``wait`` retires its thread first."""
+        # graftlint: disable=lock-discipline (one builder thread per polisher; see _initialize_core)
+        task, self._prepare_ahead = self._prepare_ahead, None
+        if task is not None and wait:
+            task.thread.join()
+
+    def _take_prepared(self, overlaps: List[Overlap]) -> PreparedPool:
+        """The barrier between the two halves: the prepare started
+        ahead for ``overlaps`` (waited for if unfinished, its exception
+        re-raised here), else the same call inline.
+        ``build.pool_bytes_ahead`` counts the pool bytes that were ready
+        before anything had to wait for them."""
+        # graftlint: disable=lock-discipline (one builder thread per polisher; see _initialize_core)
+        task, self._prepare_ahead = self._prepare_ahead, None
+        if task is None or task.overlaps is not overlaps:
+            metrics.inc("build.pool_bytes_ahead", 0)
+            return self._prepare_layers(overlaps)
+        done = not task.thread.is_alive()
+        with obs.span("build.prepare_wait"):
+            task.thread.join()
+        if task.error is not None:
+            raise task.error
+        metrics.inc("build.pool_bytes_ahead",
+                    int(task.result.pool.nbytes) if done else 0)
+        return task.result
+
+    def _filter_layer_rows(self, prep: PreparedPool, bp, pair_ov, t_ids):
         """The vectorized filter core of :meth:`_assemble_layers` —
         min-span, mean-PHRED and window arithmetic over one concatenated
         (P, 4) breaking-point matrix. THE single-source host oracle: the
@@ -791,7 +884,6 @@ class Polisher:
         subset (rejected/CIGAR pairs). Returns ``(keep, win_id,
         layer_begin, layer_end)`` aligned with ``bp``'s rows."""
         window_length = self.window_length
-        n_ov = len(counts)
         t_first, q_first = bp[:, 0], bp[:, 1]
         t_endx, q_endx = bp[:, 2], bp[:, 3]
         span = q_endx - q_first
@@ -799,44 +891,17 @@ class Polisher:
         # min-span filter: same float compare as the legacy per-pair loop
         keep = ~(span < 0.02 * window_length)
 
-        # mean-PHRED filter via per-read quality prefix sums: integer
+        # mean-PHRED filter as a lookup into the prepared prefix sums
+        # over the quality pool (each overlap's read at ov_off): integer
         # sums are exact in float64, so sums/span - 33.0 reproduces the
-        # legacy  qual[b:e].mean() - 33.0  bit-for-bit. Overlaps process
-        # in bounded slices whose quality bytes concatenate into ONE
-        # prefix-sum array each (a cumsum per overlap costs more in call
-        # overhead than the sums themselves).
-        offs = np.zeros(n_ov + 1, dtype=np.int64)
-        np.cumsum(counts, out=offs[1:])
-        qthr = self.quality_threshold
-        budget = 8 << 20  # quality bytes per slice (bounds the transient)
-        i = 0
-        while i < n_ov:
-            j, total = i, 0
-            while j < n_ov and (j == i or total < budget):
-                if qual_refs[j] is not None:
-                    total += len(qual_refs[j])
-                j += 1
-            if total:
-                base = np.full(j - i, -1, dtype=np.int64)
-                parts = []
-                pos = 0
-                for k in range(i, j):
-                    qual = qual_refs[k]
-                    if qual is None:
-                        continue
-                    base[k - i] = pos
-                    parts.append(np.frombuffer(qual, dtype=np.uint8))
-                    pos += len(qual)
-                csum = np.zeros(pos + 1, dtype=np.int64)
-                np.cumsum(np.concatenate(parts), dtype=np.int64,
-                          out=csum[1:])
-                pair_base = np.repeat(base, counts[i:j])
-                sel = np.flatnonzero(pair_base >= 0) + int(offs[i])
-                shift = pair_base[pair_base >= 0]
-                sums = (csum[q_endx[sel] + shift]
-                        - csum[q_first[sel] + shift])
-                keep[sel] &= (sums / span[sel] - 33.0) >= qthr
-            i = j
+        # legacy  qual[b:e].mean() - 33.0  bit-for-bit
+        sel = np.flatnonzero(prep.hq_ov[pair_ov])
+        if sel.size:
+            base = prep.ov_off[pair_ov[sel]]
+            sums = (prep.qsum[base + q_endx[sel]]
+                    - prep.qsum[base + q_first[sel]]).astype(np.int64)
+            keep[sel] &= ((sums / span[sel] - 33.0)
+                          >= self.quality_threshold)
 
         rank = t_first // window_length
         win_id = self._id_to_first_window[t_ids[pair_ov]] + rank
@@ -846,7 +911,8 @@ class Polisher:
         keep &= layer_begin != layer_end
         return keep, win_id, layer_begin, layer_end
 
-    def _assemble_layers_resident(self, overlaps: List[Overlap], emit,
+    def _assemble_layers_resident(self, overlaps: List[Overlap],
+                                  prep: PreparedPool, emit,
                                   chunk_windows: int, t_build) -> bool:
         """Device-resident layer assembly (round 19): derive window
         assignment and per-window layer rows ON DEVICE from the align
@@ -884,7 +950,6 @@ class Polisher:
             return bail("non-integer quality threshold")
 
         from ..ops import nw as _nw
-        from .layers import LayerStore
         window_length = self.window_length
         n_ov = len(overlaps)
         n_win = len(self.windows)
@@ -893,18 +958,8 @@ class Polisher:
         self.targets_coverages = np.bincount(
             t_ids, minlength=self.targets_size).tolist()
 
-        data_refs, qual_refs = self._layer_refs(overlaps)
-        # pool EVERY overlap up front (identity-deduplicated superset of
-        # the host path's kept-row pool — per-row results are identical;
-        # store semantics never require pool minimality)
-        t_store = time.thread_time()
-        with obs.span("build.store", rows=n_ov):
-            (pool, qpool, qpw_pool, ov_off, hq_ov,
-             has_q_base) = LayerStore._build_pool(
-                data_refs, qual_refs, np.arange(n_ov))
-        self.timings["layer_store_s"] = round(
-            time.thread_time() - t_store, 3)
-        if has_q_base.any() and int(qpool[has_q_base].min()) < 33:
+        ov_off, hq_ov, qpw_pool = prep.ov_off, prep.hq_ov, prep.qpw_pool
+        if prep.q_min < 33:
             return bail("quality bytes below phred+33")
 
         t_derive = time.perf_counter()
@@ -980,7 +1035,7 @@ class Polisher:
                  for i in np.flatnonzero(counts_host)]).astype(np.int64)
             pair_ov_h = np.repeat(np.arange(n_ov), counts_host)
             keep_h, win_h, lb_h, le_h = self._filter_layer_rows(
-                qual_refs, counts_host, bp_h, pair_ov_h, t_ids)
+                prep, bp_h, pair_ov_h, t_ids)
             kidx = np.flatnonzero(keep_h)
             host_flat = np.stack(
                 [win_h[kidx], pair_ov_h[kidx], bp_h[kidx, 1],
@@ -990,6 +1045,7 @@ class Polisher:
         else:
             host_flat = np.zeros((0, 6), np.int32)
             host_counts = np.zeros(n_ov, np.int64)
+        prep.qsum = None
         cum_host = np.zeros(n_ov + 1, np.int64)
         np.cumsum(host_counts, out=cum_host[1:])
         host_mask = np.ones(n_ov, bool)
@@ -1028,7 +1084,7 @@ class Polisher:
                 raise ValueError("layer begin and end positions are invalid")
 
         store = LayerStore(
-            pool, qpool, qpw_pool, ov_off[ov] + q_first,
+            prep.pool, prep.qpool, qpw_pool, ov_off[ov] + q_first,
             q_endx - q_first, layer_begin, layer_end, win_id, hq_ov[ov],
             np.searchsorted(win_id, np.arange(n_win + 1)),
             dev_qpw=dev_pool)
@@ -1060,10 +1116,13 @@ class Polisher:
 
     def _assemble_layers(self, overlaps: List[Overlap], emit=None,
                          chunk_windows: int = 0) -> None:
-        """Columnar layer assembly: one concatenated (P, 4) breaking-point
-        matrix, vectorized min-span/mean-PHRED filters and window
-        arithmetic, a single stable argsort grouping layers by window, and
-        a tight slice-and-append loop over only the surviving rows.
+        """Columnar layer assembly, the half that needs breaking points
+        (the other half, :meth:`_prepare_layers`, is taken at the
+        barrier below: joined if it ran beside the aligner, else run
+        here): one concatenated (P, 4) breaking-point matrix, vectorized
+        min-span/mean-PHRED filters and window arithmetic, a single
+        stable argsort grouping layers by window, and a tight attach
+        loop over the covered windows.
 
         ``emit(first_window, end_window)`` (optional) is called after
         every ``chunk_windows``-sized window range has all its layers —
@@ -1073,8 +1132,15 @@ class Polisher:
         t_build = time.perf_counter()
         if self._id_to_first_window is None:
             self._build_backbone_windows()
+        try:
+            prep = self._take_prepared(overlaps)
+        except BaseException:
+            # as a failed alignment leaves it: a retry re-initializes
+            # graftlint: disable=lock-discipline (one builder thread per polisher; see _initialize_core)
+            self.windows = []
+            raise
         if self._resident and self._assemble_layers_resident(
-                overlaps, emit, chunk_windows, t_build):
+                overlaps, prep, emit, chunk_windows, t_build):
             return
         window_length = self.window_length
         n_ov = len(overlaps)
@@ -1102,9 +1168,9 @@ class Polisher:
              and len(o.breaking_points)]).astype(np.int64)
         pair_ov = np.repeat(np.arange(n_ov), counts)
         q_first, q_endx = bp[:, 1], bp[:, 3]
-        data_refs, qual_refs = self._layer_refs(overlaps)
         keep, win_id, layer_begin, layer_end = self._filter_layer_rows(
-            qual_refs, counts, bp, pair_ov, t_ids)
+            prep, bp, pair_ov, t_ids)
+        prep.qsum = None
 
         kept = np.flatnonzero(keep)
         if kept.size:
@@ -1122,17 +1188,16 @@ class Polisher:
         if not chunk_windows:
             chunk_windows = n_win
         # columnar layer storage (round 10): ONE deduplicated read pool
-        # plus flat (offset, len, begin, end) rows replace the per-layer
-        # slice-and-append loop that used to dominate init CPU
-        # (layer_append_s); windows get an O(1) lazy view and the device
-        # packers gather their lane blocks straight from the pool
-        from .layers import LayerStore
+        # (prepared above or ahead) plus flat (offset, len, begin, end)
+        # rows replace the per-layer slice-and-append loop that used to
+        # dominate init CPU (layer_append_s); windows get an O(1) lazy
+        # view and the device packers gather their lane blocks straight
+        # from the pool
         t_store = time.thread_time()
         with obs.span("build.store", rows=int(order.size)):
             store = LayerStore.build(
-                data_refs, qual_refs, pair_ov[order], q_first[order],
-                q_endx[order], sorted_win, layer_begin[order],
-                layer_end[order], n_win)
+                prep, pair_ov[order], q_first[order], q_endx[order],
+                sorted_win, layer_begin[order], layer_end[order], n_win)
         self.timings["layer_store_s"] = round(
             time.thread_time() - t_store, 3)
         t_append = time.thread_time()
@@ -1142,8 +1207,9 @@ class Polisher:
         # session (CPU/native engines, mesh runs) start polishing the
         # first range while later ranges are still attaching — the
         # round-7 init->polish overlap contract survives the columnar
-        # store (whose vectorized build above is the only remaining
-        # pre-emission serial section). thread_time keeps a blocking
+        # store (the breaking-point filter and sort above are the only
+        # remaining pre-emission serial section once the pool was
+        # prepared ahead). thread_time keeps a blocking
         # emit (bounded queue put) out of the append accounting.
         for w0 in range(0, n_win, chunk_windows):
             w1 = min(w0 + chunk_windows, n_win)

@@ -218,6 +218,127 @@ def test_columnar_releases_breaking_points():
     assert all(o.breaking_points is None for o in overlaps)
 
 
+# ------------------------------------------------ prepare / finish halves
+
+# (window_length, quality_threshold, type, random_state kwargs): the
+# seeds and the filters-fire / fragment / dummy-quality cases above
+PREPARE_CASES = {
+    **{f"seed{s}": ([50, 100, 500][s % 3], [10.0, 12.5][s % 2],
+                    PolisherType.C, dict(seed=s)) for s in range(6)},
+    "filters_fire": (500, 43.0, PolisherType.C, dict(seed=11)),
+    "fragment_multi": (100, 10.0, PolisherType.F,
+                       dict(seed=99, multi=True)),
+    "dummy_quality": (100, 10.0, PolisherType.C,
+                      dict(seed=7, with_quality=False)),
+}
+
+
+def store_of(p):
+    """The one LayerStore the polisher's covered windows share."""
+    stores = {id(w.layer_view[0]): w.layer_view[0] for w in p.windows
+              if w.layer_view[0] is not None}
+    assert len(stores) == 1
+    return next(iter(stores.values()))
+
+
+def lanes_of(data, quality):
+    """``weight << 3 | code`` of one layer from its bytes, the packer's
+    definition written out independently of the pool."""
+    code = np.full(len(data), 4, np.uint16)
+    arr = np.frombuffer(data, np.uint8)
+    for i, b in enumerate(b"ACGT"):
+        code[arr == b] = i
+    weight = (np.ones(len(data), np.uint16) if quality is None else
+              np.maximum(np.frombuffer(quality, np.uint8).astype(np.int16)
+                         - 33, 0).astype(np.uint16))
+    return (weight << 3) | code
+
+
+@pytest.mark.parametrize("case", sorted(PREPARE_CASES))
+def test_prepared_ahead_inline_and_legacy_agree(case):
+    """One prepare, two call times: started on its own thread before the
+    assembly (as _initialize_core does beside the aligner) or run inline
+    at the barrier. Both stores are equal field by field, every row
+    addresses the bytes the legacy loop sliced, the packed lane blocks
+    are those bytes' lanes, and the materialised windows are the
+    legacy's."""
+    wl, qthr, type_, kw = PREPARE_CASES[case]
+    sequences, nt, overlaps = random_state(window_length=wl, **kw)
+
+    def fresh():
+        p = make_polisher(wl, qthr, type_)
+        p.sequences = list(sequences)
+        p.targets_size = nt
+        p._window_type = WindowType.TGS
+        return p, clone_overlaps(overlaps)
+
+    pa, ova = fresh()
+    pa._start_prepare(ova)
+    pa._assemble_layers(ova)
+    assert pa._prepare_ahead is None
+    pb, ovb = fresh()
+    pb._assemble_layers(ovb)
+    pc, ovc = fresh()
+    pc._build_backbone_windows()
+    pc._build_windows_legacy(ovc)
+
+    sa, sb = store_of(pa), store_of(pb)
+    for field in ("pool", "qpool", "qpw_pool", "src", "length", "begin",
+                  "end", "win_id", "has_qual", "row_bounds"):
+        assert np.array_equal(getattr(sa, field), getattr(sb, field)), field
+    # rows against the legacy loop's slices, window by window, BEFORE
+    # anything materialises the lazy views
+    Lq = int(sa.length.max())
+    block = sa.gather_qpw(np.arange(sa.n_rows), Lq)
+    assert np.array_equal(block, sb.gather_qpw(np.arange(sb.n_rows), Lq))
+    r = 0
+    for wi, wc in enumerate(pc.windows):
+        assert sa.row_bounds[wi] == r
+        for data, quality in zip(wc.sequences[1:], wc.qualities[1:]):
+            s, ln = int(sa.src[r]), int(sa.length[r])
+            assert sa.pool[s:s + ln].tobytes() == data
+            assert bool(sa.has_qual[r]) == (quality is not None)
+            if quality is not None:
+                assert sa.qpool[s:s + ln].tobytes() == quality
+            assert np.array_equal(block[r, :ln], lanes_of(data, quality))
+            assert not block[r, ln:].any()
+            r += 1
+    assert r == sa.n_rows > 0
+    assert_windows_identical(pa, pc)
+    assert_windows_identical(pb, pc)
+
+
+def test_read_with_every_row_filtered_is_pooled_and_changes_no_window():
+    """The pool holds every overlap's read, not only the reads of rows
+    that survive: a read whose rows all fail the mean-PHRED filter adds
+    its bytes to the pool, shifts offsets, and leaves every window's
+    layers as they are without it."""
+    sequences, nt, overlaps = random_state(2, 100)
+    victim = next(o for o in overlaps
+                  if sequences[o.q_id].quality is not None)
+    read = sequences[victim.q_id]
+    sequences = list(sequences)
+    sequences[victim.q_id] = Sequence(read.name, read.data,
+                                      b'"' * len(read.data))  # Q1
+    with_ = build_with(make_polisher(100), sequences, nt,
+                       clone_overlaps(overlaps), legacy=False)
+    without = build_with(
+        make_polisher(100), sequences, nt,
+        clone_overlaps([o for o in overlaps if o is not victim]),
+        legacy=False)
+    s1, s0 = store_of(with_), store_of(without)
+    assert len(s1.pool) == len(s0.pool) + len(read.data)
+    assert s1.n_rows == s0.n_rows
+    assert not np.array_equal(s1.src, s0.src)       # offsets did shift
+    Lq = int(s1.length.max())
+    assert np.array_equal(s1.gather_qpw(np.arange(s1.n_rows), Lq),
+                          s0.gather_qpw(np.arange(s0.n_rows), Lq))
+    for w1, w0 in zip(with_.windows, without.windows):
+        assert w1.sequences == w0.sequences
+        assert w1.qualities == w0.qualities
+        assert w1.positions == w0.positions
+
+
 # ---------------------------------------------------------------- run()
 
 def write_synthetic_assembly(tmp_path, seed=23, n_contigs=2, contig=3000):
@@ -360,3 +481,150 @@ def test_double_initialize_warns_on_stderr(tmp_path, capsys):
     cap = capsys.readouterr()
     assert "already initialized" in cap.err
     assert cap.out == ""
+
+
+# ------------------------------------- prepare beside the aligner, in run()
+
+def prepare_threads():
+    import threading
+    return [t for t in threading.enumerate()
+            if t.name in ("racon-prepare", "racon-layers") and t.is_alive()]
+
+
+def pool_counters():
+    from racon_tpu.obs import metrics
+    return (metrics.counter("build.pool_bytes"),
+            metrics.counter("build.pool_bytes_ahead"))
+
+
+@pytest.fixture(scope="module")
+def assembly(tmp_path_factory):
+    from racon_tpu.core.polisher import create_polisher
+
+    tmp = tmp_path_factory.mktemp("prepare")
+    paths = [str(x) for x in write_synthetic_assembly(tmp, seed=29)]
+    want = polished_bytes(create_polisher(*paths, num_threads=1).run(True))
+    assert len(want) == 2
+    return paths, want
+
+
+@pytest.mark.parametrize("surface,threads,ahead", [
+    ("run", 4, "all"), ("split", 4, "all"), ("run", 4, "late"),
+    ("run", 1, "inline"), ("split", 1, "inline")])
+def test_pool_bytes_ahead_and_equal_fasta(assembly, monkeypatch, surface,
+                                          threads, ahead):
+    """Overlaps from a file and a thread to spare: prepare runs beside
+    the aligner and all of the pool is ready at the barrier
+    (``build.pool_bytes_ahead == build.pool_bytes``); a prepare still
+    running at the barrier is waited for and counts nothing as ahead;
+    ``num_threads == 1`` calls it inline (0). run() and initialize() +
+    polish() give the same bytes every way."""
+    import threading
+
+    from racon_tpu.core.layers import LayerStore
+    from racon_tpu.core.polisher import create_polisher
+
+    paths, want = assembly
+    p = create_polisher(*paths, num_threads=threads)
+    real_align = p.aligner.align_batch
+    real_prepare = LayerStore.prepare
+    at_barrier = threading.Event()
+
+    if ahead == "all":
+        def align_after_prepare(pairs, *a, **kw):
+            # the align phase outlasts prepare, as it does at real sizes
+            p._prepare_ahead.thread.join(10)
+            return real_align(pairs, *a, **kw)
+        monkeypatch.setattr(p.aligner, "align_batch", align_after_prepare)
+    elif ahead == "late":
+        real_take = p._take_prepared
+
+        def take(overlaps):
+            at_barrier.set()
+            return real_take(overlaps)
+
+        def slow_prepare(*refs):
+            assert at_barrier.wait(10)      # still running at the barrier
+            return real_prepare(*refs)
+        monkeypatch.setattr(p, "_take_prepared", take)
+        monkeypatch.setattr(LayerStore, "prepare", staticmethod(slow_prepare))
+
+    b0, a0 = pool_counters()
+    if surface == "run":
+        got = p.run(True)
+    else:
+        p.initialize()
+        got = p.polish(True)
+    b1, a1 = pool_counters()
+    assert polished_bytes(got) == want
+    assert b1 - b0 > 0
+    assert a1 - a0 == (b1 - b0 if ahead == "all" else 0)
+    assert p._prepare_ahead is None and not prepare_threads()
+
+
+def test_streamed_auto_overlaps_prepare_inline(assembly):
+    """--overlaps auto on the streamed chain feed: overlaps are still
+    arriving while the aligner runs, so prepare is called at the
+    barrier, where its work always was."""
+    from racon_tpu.core.polisher import create_polisher
+    from racon_tpu.io import parsers
+    from racon_tpu.obs import metrics
+
+    (reads, _, layout), _ = assembly
+    b0, a0 = pool_counters()
+    metrics.set_gauge("overlap.streamed", 0)
+    p = create_polisher(reads, parsers.AUTO_OVERLAPS, layout, num_threads=4)
+    assert len(p.run(True)) == 2
+    assert metrics.gauge("overlap.streamed") == 1
+    b1, a1 = pool_counters()
+    assert b1 - b0 > 0 and a1 - a0 == 0
+    assert not prepare_threads()
+
+
+@pytest.mark.parametrize("surface", ["run", "initialize"])
+def test_prepare_fault_surfaces_and_leaves_polisher_reinitializable(
+        assembly, monkeypatch, surface):
+    """An exception inside prepare (on its own thread) is re-raised on
+    the thread that joins it and reaches the caller of run() /
+    initialize(); no thread survives it, and the same object
+    initializes again from scratch."""
+    from racon_tpu.core.layers import LayerStore
+    from racon_tpu.core.polisher import create_polisher
+
+    paths, want = assembly
+    p = create_polisher(*paths, num_threads=4)
+    real_prepare = LayerStore.prepare
+
+    def broken(*refs):
+        raise RuntimeError("injected prepare fault")
+
+    monkeypatch.setattr(LayerStore, "prepare", staticmethod(broken))
+    with pytest.raises(RuntimeError, match="injected prepare fault"):
+        p.run(True) if surface == "run" else p.initialize()
+    assert not prepare_threads()
+    assert p.windows == [] and p._prepare_ahead is None
+    monkeypatch.setattr(LayerStore, "prepare", staticmethod(real_prepare))
+    if surface == "run":
+        got = p.run(True)
+    else:
+        p.initialize()
+        got = p.polish(True)
+    assert polished_bytes(got) == want
+
+
+def test_failed_alignment_retires_the_prepare_thread(assembly):
+    """The aligner fails while prepare runs beside it: the thread is
+    joined before the fault propagates and nothing prepared is kept."""
+    from racon_tpu.core.polisher import create_polisher
+
+    paths, _ = assembly
+    p = create_polisher(*paths, num_threads=4)
+
+    def failing(pairs, *a, **kw):
+        raise RuntimeError("injected aligner fault")
+
+    p.aligner.align_batch = failing
+    with pytest.raises(RuntimeError, match="injected aligner"):
+        p.run(True)
+    assert not prepare_threads()
+    assert p.windows == [] and p._prepare_ahead is None

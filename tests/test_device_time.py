@@ -171,6 +171,37 @@ def test_a_pack_run_ahead_is_charged_only_for_what_outlasts_the_chunk():
                                  "align.put": 0.01, "align.launch": 0.01}
 
 
+def test_wait_for_an_unfinished_prepare_is_the_consumers_queue_get():
+    """The hand-off from the aligner to the consensus stream. The
+    builder thread stands in ``build.prepare_wait`` for a prepare that
+    is still running on its own thread; neither submits anything. The
+    idle before the first consensus group is cut by the spans of the
+    consumer, which ends it: ``idle.queue.get`` while it waits for the
+    first range, exactly as when the builder itself built the pool,
+    then the first group's pack. ``idle_build_s`` keeps its meaning."""
+    rows = [("0", "exec", "_pallas_align_chain", "main", 0, 100 * MS),
+            ("0", "exec", "_refine_loop_packed", "main", 500 * MS,
+             600 * MS)]
+    spans = {
+        "main": [("align", 0, 100 * MS),
+                 ("consensus", 110 * MS, 600 * MS),
+                 ("queue.get", 120 * MS, 400 * MS),
+                 ("consensus.feed", 400 * MS, 500 * MS),
+                 ("poa.pack", 410 * MS, 500 * MS)],
+        "racon-layers": [("build.windows", 110 * MS, 600 * MS),
+                         ("build.prepare_wait", 115 * MS, 300 * MS),
+                         ("build.store", 350 * MS, 360 * MS),
+                         ("queue.put", 399 * MS, 400 * MS)],
+        "racon-prepare": [("build.prepare", 10 * MS, 300 * MS)],
+    }
+    out = device_time.account(rows, spans, 0, 600 * MS, "main")
+    assert out["idle_s"] == pytest.approx(0.4)
+    assert out["idle_by"] == {
+        "align": 0.0, "consensus": 0.01, "queue.get": 0.28,
+        "consensus.feed": 0.01, "poa.pack": 0.09, "unattributed": 0.01}
+    assert not any(k.startswith("build.") for k in out["idle_by"])
+
+
 def test_warm_up_program_is_busy_but_takes_no_blame():
     """A warm-up thread's dummy program occupies the device (the same
     in-order queue as the real programs) but feeds nothing: the idle
@@ -503,6 +534,23 @@ def test_every_chunk_opens_two_dispatch_spans_and_pack_is_in_the_first(
         assert first is not second
         assert parent(launch) is second
         assert first["ts"] + first["dur"] <= second["ts"] + 1
+
+
+@pytest.mark.parametrize("tag", ["on1", "on2", "traced"])
+def test_cli_report_carries_the_prepare_spans_and_counters(cli_series, tag):
+    """``-t 2`` with overlaps from a file: prepare ran on its own
+    thread beside the aligner (never inside ``build.store``, never
+    charged with device idle), the barrier recorded its wait, and each
+    job's report counts its own pool once."""
+    m = cli_series[tag]["report"]["metrics"]
+    assert m["timers"]["build.prepare"] > 0
+    assert "build.prepare_wait" in m["timers"]
+    assert "idle.build.prepare" not in m["timers"]
+    pool = m["counters"]["build.pool_bytes"]
+    assert pool > 0
+    assert pool == cli_series["on1"]["report"]["metrics"]["counters"][
+        "build.pool_bytes"]
+    assert m["counters"]["build.pool_bytes_ahead"] in (0, pool)
 
 
 def test_second_job_in_one_process_reports_its_own_aggregates(cli_series):
