@@ -2,7 +2,7 @@
 # Style/compile gate (analog of ci/checks/style.sh).
 set -e
 cd "$(dirname "$0")/../.."
-python -m compileall -q racon_tpu tests bench.py __graft_entry__.py
+python -m compileall -q racon_tpu tests __graft_entry__.py
 # no tabs in Python sources; 100-col hard ceiling
 ! grep -rn "$(printf '\t')" racon_tpu --include='*.py'
 python - <<'PY'
@@ -18,7 +18,7 @@ PY
 # not in the image — graftlint (the tools/analysis shard) is the hard
 # correctness gate either way
 if command -v ruff >/dev/null 2>&1; then
-    ruff check racon_tpu tools tests bench.py
+    ruff check racon_tpu tools tests
 else
     echo "style: ruff not installed, baseline skipped"
 fi

@@ -25,7 +25,7 @@ lint_t0=$SECONDS
 python -m tools.analysis --selftest
 python -m tools.analysis --quiet --timings \
   --json /tmp/graftlint_findings.json \
-  racon_tpu tests tools bench.py
+  racon_tpu tests tools
 echo "graftlint gate (selftest + repo-wide, 21 rules): $((SECONDS - lint_t0))s (budget 30s; artifact /tmp/graftlint_findings.json)"
 # the README env-flags table (racon_tpu/flags.py) and the README lint
 # rule table (tools/analysis --rules-md) are generated and must not

@@ -19,11 +19,11 @@ row arrays end-to-end (device tables -> ``Overlap.breaking_points`` ->
 one concatenated (P, 4) matrix), the min-span and mean-PHRED layer filters
 and all window arithmetic run vectorized over that matrix (quality means
 via per-read prefix sums), and layers group into windows through a single
-stable argsort — the per-overlap/per-pair Python loops the r5 bench showed
-dominating wall-clock are gone. ``run()`` additionally pipelines
-initialize -> polish: the layer assembly streams completed window ranges
-through a bounded queue into the consensus engine, while the background
-consensus warm-up compile overlaps the device alignment (reference
+stable argsort — no per-overlap/per-pair Python loop. ``run()``
+additionally pipelines initialize -> polish: the layer assembly streams
+completed window ranges through a bounded queue into the consensus
+engine, while the background consensus warm-up compile overlaps the
+device alignment (reference
 analog: the CUDA polisher overlaps its aligner batches with host work
 and streams windows into the polisher, ``cudapolisher.cpp:86-228``).
 
@@ -179,7 +179,8 @@ class Polisher:
         self._window_lengths: Optional[np.ndarray] = None
         self._backbone_s = 0.0
         # init-phase wall-clock breakdown (parse_s, align_s, bp_decode_s,
-        # build_windows_s, pipeline_overlap_saved_s) — bench.py records it
+        # build_windows_s, pipeline_overlap_saved_s): the run report's
+        # ``phases`` section
         self.timings: Dict[str, float] = {}
         # device-resident align->consensus dataflow (round 19): accepted
         # breaking points stay on device and layer rows derive there;
@@ -381,11 +382,10 @@ class Polisher:
             (p if p < self.targets_size else -1 for p in read_pos),
             np.int64, raw_index)
         k = max(4, min(16, flags.get_int("RACON_TPU_OVERLAP_K")))
-        if flags.get_bool("RACON_TPU_WARMUP"):
-            # race the chain-arena compile against host seeding/matching
-            est_len = max((len(s) for s in read_seqs), default=0)
-            overlap_seed.warmup_async(est_len, len(read_seqs))
-            chain_ops.warmup_async(max(1, est_len // 8), raw_index, k=k)
+        # race the chain-arena compile against host seeding/matching
+        est_len = max((len(s) for s in read_seqs), default=0)
+        overlap_seed.warmup_async(est_len, len(read_seqs))
+        chain_ops.warmup_async(max(1, est_len // 8), raw_index, k=k)
         # graftlint: disable=jit-shape-hazard (k is a run-constant flag value clipped to 4..16 — one compile per run)
         rows = chain_ops.find_overlaps(read_seqs, target_seqs,
                                        read_self_t, k=k)
@@ -411,11 +411,11 @@ class Polisher:
         loop from the overlap/target histograms: the first consensus
         compile (~16 s) then hides inside the device overlap alignment
         instead of stalling polish(). Skipped for tiny inputs (the
-        compile would outlive the whole run) and via RACON_TPU_WARMUP=0;
-        a wrong shape estimate only wastes a background compile (see
-        TpuPoaConsensus.warmup_async)."""
+        compile would outlive the whole run) and for engines that
+        offer no warm-up; a wrong shape estimate only wastes a
+        background compile (see TpuPoaConsensus.warmup_async)."""
         warm = getattr(self.consensus, "warmup_async", None)
-        if warm is None or not flags.get_bool("RACON_TPU_WARMUP"):
+        if warm is None:
             return
         targets_bases = sum(len(self.sequences[i].data)
                             for i in range(self.targets_size))
@@ -474,11 +474,10 @@ class Polisher:
             (p if p < self.targets_size else -1 for p in read_pos),
             np.int64, raw_index)
         k = max(4, min(16, flags.get_int("RACON_TPU_OVERLAP_K")))
-        if flags.get_bool("RACON_TPU_WARMUP"):
-            # race the chain-arena compile against host seeding/matching
-            est_len = max((len(s) for s in read_seqs), default=0)
-            overlap_seed.warmup_async(est_len, len(read_seqs))
-            chain_ops.warmup_async(max(1, est_len // 8), raw_index, k=k)
+        # race the chain-arena compile against host seeding/matching
+        est_len = max((len(s) for s in read_seqs), default=0)
+        overlap_seed.warmup_async(est_len, len(read_seqs))
+        chain_ops.warmup_async(max(1, est_len // 8), raw_index, k=k)
 
         state = {"est_pairs": 0}
 
@@ -675,7 +674,7 @@ class Polisher:
         under the phase.
 
         ``bp_stream`` can return None even on a streaming-capable
-        backend (mesh runs, ``RACON_TPU_ALIGN_RAGGED=0``) — then there
+        backend (mesh runs) — then there
         is no session to pipeline into, so drain the producer and take
         the barrier path, same as a sessionless backend."""
         sess = self.aligner.bp_stream(
@@ -921,8 +920,8 @@ class Polisher:
         :class:`LayerStore` directly from it — no per-chunk bp fetch, no
         host filter sweep, no host argsort. Byte-identical to the host
         path by construction (the derive kernel mirrors
-        :meth:`_filter_layer_rows` exactly; the parity suite and bench
-        assert it).
+        :meth:`_filter_layer_rows` exactly; the parity suite asserts
+        it).
 
         Returns True when it handled the assembly. Returns False —
         after host-decoding every device handle, so the caller's host
@@ -1219,9 +1218,8 @@ class Polisher:
                     windows[wi].attach_layers(store, r0, r1)
             if emit is not None:
                 emit(w0, w1)
-        # the attach loop is all that remains of the old per-layer
-        # append cost — recorded under the same key so BENCH rounds stay
-        # comparable across the columnar transition
+        # the attach loop is all that remains of the per-layer append
+        # cost (the run report's ``layer_append_s`` phase)
         self.timings["layer_append_s"] = round(
             time.thread_time() - t_append, 3)
 

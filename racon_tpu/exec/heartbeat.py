@@ -29,7 +29,7 @@ from typing import Optional
 from .. import flags, sanitize
 from ..obs import metrics
 from ..obs.metrics import peak_rss_bytes  # noqa: F401  (re-export: the
-#   canonical implementation moved into the obs registry module; bench,
+#   canonical implementation moved into the obs registry module;
 #   rampler and the runner keep importing it from here)
 
 

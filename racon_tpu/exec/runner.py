@@ -47,8 +47,7 @@ directory) drain one manifest together.
 
 Completed parts finally merge back into target-file order, which makes
 the output byte-identical to a single-shot run — the invariance proofs
-live in ``tests/test_exec.py``, ``tests/test_faults.py`` and
-``bench.py``.
+live in ``tests/test_exec.py`` and ``tests/test_faults.py``.
 """
 
 from __future__ import annotations
@@ -380,7 +379,7 @@ class ShardRunner:
                     f"chip workers ({topo.describe()['device_kind']})")
         return self._slots
 
-    # back-compat internals (tests/bench poke the round-12 names): the
+    # back-compat internals (tests poke the round-12 names): the
     # primary slot's engine pairs
     @property
     def _engines(self):
@@ -527,8 +526,8 @@ class ShardRunner:
             "shards": [dict(e) for e in manifest["shards"]],
         }
         # machine-readable run report next to the manifest (same durable
-        # write protocol): BENCH entries, the heartbeat and future
-        # service-mode job accounting are all views over this artifact.
+        # write protocol): the heartbeat and service-mode job
+        # accounting are views over this artifact.
         # An explicit --shard-dir (or a quarantine) keeps it on disk; a
         # derived work dir takes it down with the rest of a fully
         # successful run — pass --run-report for a copy that survives.

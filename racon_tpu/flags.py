@@ -1,7 +1,7 @@
 """Central registry of every ``RACON_TPU_*`` environment flag.
 
 This module is the **single sanctioned reader** of ``RACON_TPU_*``
-environment variables: every flag the package (and its tests/benches)
+environment variables: every flag the package (and its tests)
 consults is declared here with a type, default and one-line doc, and all
 call sites go through :func:`raw` / :func:`get_bool` / :func:`get_int` /
 :func:`get_float` / :func:`get_str`.  The ``graftlint`` rule
@@ -60,23 +60,6 @@ REGISTRY: Dict[str, Flag] = _declare([
     Flag("RACON_TPU_SWAR", "1", "bool",
          "Packed SWAR kernels (int16x2 score lanes, 2-bit bases); set 0 "
          "to force the int32 path for A/B measurement."),
-    Flag("RACON_TPU_DYNBOUND", "1", "bool",
-         "Per-block dynamic sweep bounds in the Pallas kernels; set 0 to "
-         "run every block at the static bound for A/B measurement."),
-    Flag("RACON_TPU_ALIGN_RAGGED", "1", "bool",
-         "Ragged pair packing in the device aligner: pairs bucket by "
-         "their own sweep cost and chunks greedy-fill a fixed "
-         "direction-matrix arena through the streaming _AlignStream "
-         "session (double-buffered dispatch/fetch) instead of one "
-         "batch cap per length bucket; set 0 to force the bucketed "
-         "wave driver for A/B measurement."),
-    Flag("RACON_TPU_BAND_LADDER", "1", "bool",
-         "Adaptive alignment band ladder: each pair's starting band is "
-         "seeded from its overlap's estimated divergence (quantized to "
-         "a 1.5x-step rung ladder from 64 up to its bucket band) and "
-         "escapees re-dispatch batched at the rung >= 2x the failed "
-         "band; set 0 to start every pair at its bucket's full band "
-         "for A/B measurement."),
     Flag("RACON_TPU_RESIDENT", "0", "bool",
          "Device-resident align->consensus dataflow: accepted breaking-"
          "point tables stay on device, window assignment and per-window "
@@ -87,20 +70,6 @@ REGISTRY: Dict[str, Flag] = _declare([
          "lanes. Byte-identical to the host path (the parity oracle); "
          "falls back per-run when a precondition fails (mesh sharding, "
          "fractional quality threshold, sub-33 quality bytes)."),
-    Flag("RACON_TPU_RAGGED", "1", "bool",
-         "Ragged window packing in the consensus engine: windows bucket "
-         "by their own size and groups greedy-fill a fixed lane arena "
-         "instead of padding every window to the global bucket maxima; "
-         "set 0 to force the padded single-geometry path for A/B "
-         "measurement."),
-    Flag("RACON_TPU_MATMUL_VOTES", "1", "bool",
-         "Emit consensus column/insertion votes through int8xint8->int32 "
-         "MXU matmuls (exact at any depth, no insertion fold overflow); "
-         "set 0 to restore the f32 one-hot matmul + packed scatter for "
-         "A/B measurement."),
-    Flag("RACON_TPU_WARMUP", "1", "bool",
-         "Background warm-up compilation of the consensus refinement "
-         "loop during Polisher.initialize(); set 0 to disable."),
     # ------------------------------------------------------- compile cache
     Flag("RACON_TPU_NO_COMPILE_CACHE", "0", "bool",
          "Set to disable the persistent XLA compilation cache (its "
@@ -251,17 +220,6 @@ REGISTRY: Dict[str, Flag] = _declare([
          "Gateway placement-loop poll interval in seconds: how often "
          "the fleet scheduler re-scans tenant queues, host heartbeats "
          "and in-flight job status between placement events."),
-    Flag("RACON_TPU_BENCH_FLEET", "2", "float",
-         "bench.py fleet-serving workload size in Mbp: mixed-tenant "
-         "open-loop load over a 3-host fleet (3 serve subprocesses) "
-         "behind one gateway — per-tenant fleet_p50_s/fleet_p95_s, the "
-         "isolation ratio vs an idle-fleet baseline, and migration-to-"
-         "first-result after a member SIGKILL, every result "
-         "byte-identical to its one-shot CLI run (0 disables)."),
-    Flag("RACON_TPU_BENCH_FLEET_JOBS", "12", "int",
-         "How many open-loop job submissions per tenant the fleet "
-         "bench drives through the gateway (the isolation metric's "
-         "sample size)."),
     # ------------------------------------------------ first-party overlapper
     Flag("RACON_TPU_OVERLAP", "", "str",
          "Overlap source override: 'auto' runs the first-party "
@@ -297,56 +255,12 @@ REGISTRY: Dict[str, Flag] = _declare([
          "stream per query group into the align session instead of "
          "phase-barriering (byte-identical either way; set 0 to force "
          "the bucketed barrier path for A/B measurement)."),
-    Flag("RACON_TPU_OVERLAP_CACHE", "1", "bool",
-         "Target seed-table cache: key the target minimizer table by "
-         "(content fingerprint, k, w) and reuse it across shards of "
-         "one run and across serve jobs on the same target set "
-         "(hits/misses counted in the run report's overlap section "
-         "and credited to the dataflow bytes ledger)."),
-    # -------------------------------------------------------- tests, bench
+    # --------------------------------------------------------------- tests
     Flag("RACON_TPU_SLOW", "0", "bool",
          "Enable the slow (tier-2) test set."),
     Flag("RACON_TPU_TEST_REAL", "0", "bool",
          "Run tests on the real accelerator instead of forcing the "
          "8-virtual-device CPU mesh."),
-    Flag("RACON_TPU_BENCH_SCALE", "1", "float",
-         "bench.py scaling-probe workload size in Mbp (0 disables)."),
-    Flag("RACON_TPU_BENCH_PIPELINE", "10", "float",
-         "bench.py end-to-end pipeline workload size in Mbp "
-         "(0 disables)."),
-    Flag("RACON_TPU_BENCH_FUSED", "1", "bool",
-         "bench.py fused run()-vs-split A/B (and its bit-identity "
-         "assert); set 0 to skip."),
-    Flag("RACON_TPU_BENCH_RESIDENT", "1", "bool",
-         "bench.py resident-dataflow A/B (RACON_TPU_RESIDENT=1 vs the "
-         "host align->consensus handoff, with its byte-identity assert "
-         "and the dataflow bytes ledger); set 0 to skip."),
-    Flag("RACON_TPU_BENCH_SHARDS", "100", "float",
-         "bench.py streaming shard-runner workload size in Mbp for the "
-         "scaling-curve entry (includes a 4-shard-vs-single-shot "
-         "bit-identity assert at a smaller scale; 0 disables)."),
-    Flag("RACON_TPU_BENCH_MULTICHIP", "2", "float",
-         "bench.py multi-chip scaling-curve workload size in Mbp "
-         "(Mbp/s vs chip count through the CLI chip scheduler, with a "
-         "1-chip-vs-all-chips byte-identity assert; on a single-device "
-         "host the points run on per-point virtual CPU meshes; 0 "
-         "disables)."),
-    Flag("RACON_TPU_BENCH_SERVICE", "5", "float",
-         "bench.py resident-service workload size in Mbp: p50/p95 job "
-         "latency and compile fraction across sequential submissions "
-         "of one polish job to a resident racon --serve server, plus "
-         "a cold one-shot CLI baseline and a byte-identity assert "
-         "(0 disables)."),
-    Flag("RACON_TPU_BENCH_SERVICE_JOBS", "100", "int",
-         "How many sequential job submissions the resident-service "
-         "bench drives through one server (the acceptance metric's "
-         "sample size)."),
-    Flag("RACON_TPU_BENCH_OVERLAP", "1", "float",
-         "bench.py first-party overlapper workload size in Mbp: "
-         "overlapper Mbp/s with seed/chain occupancy, plus an "
-         "--overlaps auto vs minimap2-style-PAF-fed polish A/B "
-         "asserting edit distance to truth within noise and auto-mode "
-         "rerun byte-identity (0 disables)."),
 ])
 
 

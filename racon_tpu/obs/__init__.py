@@ -9,7 +9,7 @@ Four layers over one registry:
   change output bytes.
 - **metrics** (:mod:`.metrics`) — THE process-wide registry of named
   counters/gauges/timers.  Producers (engines, sanitizer, logger,
-  polisher queue) publish; the heartbeat, ``consensus_stats``, bench
+  polisher queue) publish; the heartbeat, ``consensus_stats``
   and the run report read.
 - **run reports** (:mod:`.report`) — schema-versioned
   ``run_report.json`` per CLI/exec run (``--run-report FILE`` /

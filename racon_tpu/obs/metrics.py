@@ -3,8 +3,8 @@ gauge and timer the pipeline publishes.
 
 Before this module, telemetry lived in five ad-hoc surfaces (stage lines
 in ``utils/logger.py``, the exec heartbeat's ``update(...)`` plumbing,
-``PhaseRetraceBudget`` class globals, the per-engine ``stats`` dicts and
-bench.py's hand-rolled JSON).  Those surfaces now all *read* this
+``PhaseRetraceBudget`` class globals and the per-engine ``stats``
+dicts).  Those surfaces now all *read* this
 registry; producers publish with :func:`inc` / :func:`set_gauge` /
 :func:`add_time` at the same sites that update their local state.
 
@@ -158,7 +158,7 @@ def timer_s(name: str, default: float = 0.0) -> float:
 def group(prefix: str) -> Dict[str, Number]:
     """Every metric under ``prefix`` (all three kinds merged), keyed by
     the name with the prefix stripped — e.g. ``group("retrace.")`` is
-    the per-phase jit-retrace delta dict the heartbeat and bench print."""
+    the per-phase jit-retrace delta dict the heartbeat prints."""
     out: Dict[str, Number] = {}
     with _lock:
         for store in (_counters, _gauges, _timers):
@@ -191,11 +191,11 @@ _RUN_PREFIXES = contracts.RUN_PREFIXES
 
 def clear_run() -> None:
     """Drop every per-run metric (:data:`_RUN_PREFIXES`) — called at
-    run boundaries (``obs.begin``, ``ShardRunner.run``, bench legs) so
+    run boundaries (``obs.begin``, ``ShardRunner.run``) so
     back-to-back runs in one process each report their own numbers
     instead of process-lifetime accumulations.  Job-scoped metrics
     (``job.<id>.*``) are deliberately NOT touched: a run boundary in
-    one thread (a service job starting, a bench leg) must never wipe a
+    one thread (a service job starting) must never wipe a
     concurrent job's in-flight gauges — that is :func:`clear_job`'s
     call, made by the job's own lifecycle.
 

@@ -1,9 +1,9 @@
 """Machine-readable run reports: one schema-versioned ``run_report.json``
 per CLI/exec run.
 
-BENCH entries, the exec heartbeat and any future service-mode job
-accounting are all *views* over this artifact: per-phase wall clock,
-the dispatch-vs-fetch split (from the span timers), pair-arena
+The exec heartbeat, the benchmark's per-layer metrics and any
+service-mode job accounting are all *views* over this artifact:
+per-phase wall clock, the dispatch-vs-fetch split (from the span timers), pair-arena
 occupancy, jit-retrace deltas, bounded-queue stall time, the swallowed-
 fault suppression counts, peak RSS, and (for exec runs) one row per
 shard.  Everything is pulled from the single metrics registry
@@ -83,7 +83,7 @@ from .. import contracts
 # ("join_dispatch_s"/"join_fetch_s" from the ``overlap.join.*`` span
 # timers) and its counted bail-outs ("join_bailouts" — the host-oracle
 # ladder, never silent), and the target seed-table cache accounting
-# ("cache_hits"/"cache_misses", RACON_TPU_OVERLAP_CACHE).
+# ("cache_hits"/"cache_misses").
 # v11 (round 23): the "fleet" section became required — fleet-serving
 # counters from the multi-tenant gateway (``gateway.*``/``fleet.*``
 # metrics): admission outcomes at the TCP front door, jobs placed on

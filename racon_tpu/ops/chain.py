@@ -649,7 +649,7 @@ class _ChainStream:
         metrics.inc("overlap.lanes_total", B * S)
         metrics.inc("overlap.lanes_occupied", int(counts.sum()))
         metrics.inc("overlap.chunks", 1)
-        # mirrored legacy names (bench/report compat with the barrier path)
+        # mirrored legacy names (run-report compat with the barrier path)
         metrics.inc("overlap.chain_lanes_total", B * S)
         metrics.inc("overlap.chain_lanes_occupied", int(counts.sum()))
         while (len(self.inflight) > CHAIN_INFLIGHT
@@ -762,7 +762,7 @@ def _empty_rows() -> Dict[str, np.ndarray]:
 
 
 def _resolve_params(k, w, max_occ, min_seeds, resident, device_join,
-                    ragged, cache):
+                    ragged):
     from .. import flags
     k = flags.get_int("RACON_TPU_OVERLAP_K") if k is None else k
     w = flags.get_int("RACON_TPU_OVERLAP_W") if w is None else w
@@ -776,11 +776,9 @@ def _resolve_params(k, w, max_occ, min_seeds, resident, device_join,
         device_join = flags.get_bool("RACON_TPU_OVERLAP_DEVICE_JOIN")
     if ragged is None:
         ragged = flags.get_bool("RACON_TPU_OVERLAP_RAGGED")
-    if cache is None:
-        cache = flags.get_bool("RACON_TPU_OVERLAP_CACHE")
     k = max(4, min(16, k))  # uint32 canonical codes hold 2k bits
     w = max(1, w)
-    return k, w, max_occ, min_seeds, resident, device_join, ragged, cache
+    return k, w, max_occ, min_seeds, resident, device_join, ragged
 
 
 def _seed_and_join(read_seqs, target_seqs, read_self_t, qlens, *,
@@ -831,7 +829,7 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
                         min_seeds: Optional[int] = None,
                         resident: Optional[bool] = None,
                         device_join: Optional[bool] = None,
-                        cache: Optional[bool] = None
+                        cache: bool = True
                         ) -> Iterator[Dict[str, np.ndarray]]:
     """Streaming overlapper driver: yield canonical overlap rows per
     query group (ascending query ordinal) as chain chunks resolve.
@@ -842,9 +840,8 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
     kept between chaining and alignment streams away. Concatenating
     every yield reproduces :func:`find_overlaps` byte-for-byte (the
     global sort's primary key is the query ordinal)."""
-    (k, w, max_occ, min_seeds, resident, device_join, _,
-     cache) = _resolve_params(k, w, max_occ, min_seeds, resident,
-                              device_join, None, cache)
+    k, w, max_occ, min_seeds, resident, device_join, _ = _resolve_params(
+        k, w, max_occ, min_seeds, resident, device_join, None)
     qlens = np.fromiter((len(s) for s in read_seqs), np.int64,
                         len(read_seqs))
     hits = _seed_and_join(
@@ -929,7 +926,7 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
                   resident: Optional[bool] = None,
                   device_join: Optional[bool] = None,
                   ragged: Optional[bool] = None,
-                  cache: Optional[bool] = None
+                  cache: bool = True
                   ) -> Dict[str, np.ndarray]:
     """The full first-party overlapper: seed both pools, match, chain,
     and emit forward-strand ``Overlap``-shaped rows.
@@ -947,9 +944,9 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
     ragged stream's per-group emission; ``ragged=False`` runs the
     phase-barriered ``chain_pairs`` A/B leg. Both orders are the same
     canonical order, so output bytes never depend on the flag."""
-    (k, w, max_occ, min_seeds, resident, device_join, ragged,
-     cache) = _resolve_params(k, w, max_occ, min_seeds, resident,
-                              device_join, ragged, cache)
+    (k, w, max_occ, min_seeds, resident, device_join,
+     ragged) = _resolve_params(k, w, max_occ, min_seeds, resident,
+                               device_join, ragged)
     if ragged:
         parts = list(iter_overlap_groups(
             read_seqs, target_seqs, read_self_t, k=k, w=w,

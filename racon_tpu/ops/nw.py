@@ -775,8 +775,8 @@ class TpuAligner(PallasDispatchMixin):
     def __init__(self, fallback=None, buckets=BUCKETS,
                  max_dirs_bytes=MAX_DIRS_BYTES, mesh=None,
                  num_batches: int = 1, use_swar: bool = True,
-                 device=None, use_ragged=None, use_ladder=None):
-        from .. import flags
+                 device=None, use_ragged: bool = True,
+                 use_ladder: bool = True):
         self.fallback = fallback
         self.buckets = buckets
         self.max_dirs_bytes = max_dirs_bytes
@@ -797,18 +797,17 @@ class TpuAligner(PallasDispatchMixin):
         # availability probe (swar.swar_ok) — both identical-output, so
         # this knob only exists for A/B measurement and escape hatches.
         self.use_swar = use_swar
-        # ragged pair packing (round 17, on by default off-mesh; ctor
-        # arg or RACON_TPU_ALIGN_RAGGED=0 restores the bucketed wave
-        # driver): pairs greedy-fill a fixed direction-matrix arena by
-        # their own sweep cost through the streaming _AlignStream
-        # session — the aligner analog of poa._ConsensusStream
-        self.use_ragged = (flags.get_bool("RACON_TPU_ALIGN_RAGGED")
-                           if use_ragged is None else use_ragged)
-        # adaptive band ladder (round 17; RACON_TPU_BAND_LADDER=0 for
-        # A/B): seed each pair's band from its overlap's estimated
-        # divergence, escalate escapees batched — see BAND_RUNGS
-        self.use_ladder = (flags.get_bool("RACON_TPU_BAND_LADDER")
-                           if use_ladder is None else use_ladder)
+        # ragged pair packing: pairs greedy-fill a fixed
+        # direction-matrix arena by their own sweep cost through the
+        # streaming _AlignStream session — the aligner analog of
+        # poa._ConsensusStream. A mesh takes the bucketed wave driver
+        # whatever this says; False selects it off-mesh (tests)
+        self.use_ragged = use_ragged
+        # adaptive band ladder: seed each pair's band from its
+        # overlap's estimated divergence, escalate escapees batched —
+        # see BAND_RUNGS; False starts every pair at its bucket's full
+        # band (tests)
+        self.use_ladder = use_ladder
         # memory backpressure (round 12 ladder parity, round 17): a
         # device RESOURCE_EXHAUSTED halves the effective direction-
         # matrix budget (reduce_capacity) and the chunk re-dispatches —
@@ -827,9 +826,8 @@ class TpuAligner(PallasDispatchMixin):
         # lanes_total count every dispatched wavefront arena (occupied
         # = sum of real pairs' n+m anti-diagonals, total = B x steps
         # per launch); steps_wasted is their gap and wavefront_work
-        # (total x band, summed over rungs) is the banded-DP cost the
-        # bench A/B grid records — replacing the blind device/
-        # band_escalated counts as the aligner's efficiency signal
+        # (total x band, summed over rungs) is the banded-DP cost —
+        # the aligner's efficiency signal
         self.stats = {"device": 0, "fallback_length": 0, "fallback_band": 0,
                       "band_escalated": 0, "swar_chunks": 0,
                       "swar_guard_int32": 0, "chunks": 0,
@@ -1035,7 +1033,7 @@ class TpuAligner(PallasDispatchMixin):
 
     def align_batch(self, pairs: Sequence[Tuple[bytes, bytes]],
                     progress=None, errors=None) -> List[str]:
-        """CIGAR strings for every pair (test/bench surface; the pipeline
+        """CIGAR strings for every pair (test surface; the pipeline
         uses :meth:`breaking_points_batch`, which never fetches the op
         stream). ``errors`` optionally carries per-pair divergence
         estimates for the band ladder (overlap ``error`` values)."""
@@ -1068,7 +1066,7 @@ class TpuAligner(PallasDispatchMixin):
         escalations and the host fallback, and returns breaking points
         for every fed pair in feed order. ``Polisher._align_need`` feeds
         this directly. Returns None when the ragged packer is
-        unavailable (mesh runs, ``RACON_TPU_ALIGN_RAGGED=0``) — callers
+        unavailable (mesh runs, ``use_ragged=False``) — callers
         then fall back to per-slice :meth:`breaking_points_batch`."""
         if not self.use_ragged or self.mesh is not None:
             return None

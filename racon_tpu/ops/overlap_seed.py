@@ -189,7 +189,7 @@ def _iter_chunks(seqs: List[bytes], k: int, w: int
             yield sid, s0, s[s0:end], n_here
 
 
-# target seed-table cache (RACON_TPU_OVERLAP_CACHE): the target set is
+# target seed-table cache: the target set is
 # identical across every shard of one run and across serve jobs naming
 # the same draft, so the table is keyed by a content fingerprint +
 # (k, w) and rebuilt only when the inputs actually change. Entries are
@@ -233,8 +233,8 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
     path fetches the full masks and compacts with numpy. Both produce
     identical tables (tests assert the parity).
 
-    ``cache=True`` (the target side of the overlapper under
-    ``RACON_TPU_OVERLAP_CACHE``) consults the fingerprint-keyed table
+    ``cache=True`` (the target side of the overlapper) consults the
+    fingerprint-keyed table
     cache first: a hit skips packing, kernels, and fetches entirely —
     counted in ``overlap.cache_hits`` and credited to
     ``dataflow.bytes_avoided`` at the table's own wire size."""

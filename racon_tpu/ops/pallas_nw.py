@@ -57,11 +57,6 @@ import jax.experimental.pallas.tpu as pltpu
 _BIG = 1 << 28
 # extra tail lanes so aligned-window loads never run off the char arrays
 _LOAD_PAD = 256
-# Per-block dynamic sweep bounds (traced loop trip counts): blocks stop
-# at their longest pair's sweep. Off-switch for A/B measurement — traced
-# trip counts can inhibit Mosaic's static loop optimizations.
-from .. import flags as _flags
-DYNAMIC_BOUND = _flags.get_bool("RACON_TPU_DYNBOUND")
 # pair-block (sublane) caps: the TPU grid is sequential, so bigger blocks
 # amortize per-step loop/DMA overhead across more pairs; 64 measured best
 # on v5e for both kernels (32 leaves ~30% on the table, 128 regresses the
@@ -273,11 +268,8 @@ def _fwd_kernel(qrp_ref, tp_ref, n_ref, m_ref, dirs_ref, score_ref,
     # powers of two <= 256, so one quantum divides the other
     QB = max(out_quant, F * PER)
     assert QB % 128 == 0 and QB % (F * PER) == 0, (F, PER)
-    if DYNAMIC_BOUND:
-        maxnm = jnp.max(nn + mm)
-        bound = jnp.minimum(jnp.int32(S), ((maxnm + QB - 1) // QB) * QB)
-    else:
-        bound = jnp.int32(S)
+    maxnm = jnp.max(nn + mm)
+    bound = jnp.minimum(jnp.int32(S), ((maxnm + QB - 1) // QB) * QB)
 
     # split the sweep at a == c: boundary rows/columns can only appear on
     # wavefronts a <= c (i == 0 needs I0 < U, j == 0 needs J0 <= 0), so
@@ -468,11 +460,8 @@ def _fwd_kernel_swar(qrp_ref, tp_ref, n_ref, m_ref, dirs_ref, score_ref,
 
     QB = max(out_quant, F * PER)
     assert QB % 128 == 0 and QB % (F * PER) == 0, (F, PER)
-    if DYNAMIC_BOUND:
-        maxnm = jnp.max(nn + mm)
-        bound = jnp.minimum(jnp.int32(S), ((maxnm + QB - 1) // QB) * QB)
-    else:
-        bound = jnp.int32(S)
+    maxnm = jnp.max(nn + mm)
+    bound = jnp.minimum(jnp.int32(S), ((maxnm + QB - 1) // QB) * QB)
 
     ksplit = jnp.minimum(jnp.int32(c // 2), bound // 2)
     carry = lax.fori_loop(
@@ -619,13 +608,9 @@ def _walk_start(nn, mm, chunk_dma, blank_group, *, S: int, C: int,
     walk needs 4 chunks per 128-byte-aligned store) so consumers see
     exactly what the XLA walk emits there, and prefetch the first live
     chunk's DMA (skipped entirely when the block has nothing to walk)."""
-    if DYNAMIC_BOUND:
-        maxnm = jnp.max(nn + mm)
-        k0 = (S - jnp.minimum(jnp.int32(S),
-                              ((maxnm + C - 1) // C) * C)) // C
-        k0 = (k0 // group_chunks) * group_chunks
-    else:
-        k0 = jnp.int32(0)
+    maxnm = jnp.max(nn + mm)
+    k0 = (S - jnp.minimum(jnp.int32(S), ((maxnm + C - 1) // C) * C)) // C
+    k0 = (k0 // group_chunks) * group_chunks
 
     def blank(g, _):
         blank_group(g)

@@ -456,8 +456,8 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
         # (:func:`_expand_rows`; destinations ``L-1-col`` are strictly
         # increasing over ranks). Replaces the fold + packed scatter:
         # the scatter engine was the slowest op in the round, and the
-        # fold cap's overflow events (``ins_overflow``, 265 in the r05
-        # 96-window bench) are structurally impossible here.
+        # fold cap's overflow events (``ins_overflow``) are structurally
+        # impossible here.
         iaddr = idx - L * CH
         icol = iaddr // (K * CH)
         isub = iaddr - icol * (K * CH)    # slot*CH + ch
@@ -535,7 +535,7 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
         w2 = iw.reshape(rows, G * IC)
         (f2, w2), alive2 = _compact_rows(
             ialive.reshape(rows, G * IC), (f2, w2), G * IC)
-        # per-window overflow attribution (the r05 bare counter hid WHICH
+        # per-window overflow attribution (a bare counter hides WHICH
         # window's vote density tripped the uncapped-scatter fallback):
         # an overflowing lane's flat address f2 still encodes its window
         # as f2 // INS, so one tiny scatter tallies them per window
@@ -1226,8 +1226,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
                  max_depth: int = 200, band: int = BAND, rounds: int = 6,
                  mesh=None, ins_theta: float = 0.25, del_beta: float = 0.65,
                  num_batches: int = 1, use_swar: bool = True,
-                 use_matmul_votes: Optional[bool] = None,
-                 use_ragged: Optional[bool] = None, device=None):
+                 use_matmul_votes: bool = True,
+                 use_ragged: bool = True, device=None):
         self.fallback = fallback
         # per-engine chip pin (mutually exclusive with a mesh): the
         # in-process chip scheduler builds one consensus engine per
@@ -1235,19 +1235,16 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # jax.default_device(device) so this engine's whole working set
         # lives on its chip (PallasDispatchMixin._pinned)
         self.device = device
-        # int8/i32 MXU vote reduction (on by default; ctor arg or
-        # RACON_TPU_MATMUL_VOTES=0 restores the f32-matmul + packed
-        # scatter for A/B): exact integer accumulation, no fold cap —
-        # ins_overflow is structurally 0 on this path
-        self.use_matmul_votes = (flags.get_bool("RACON_TPU_MATMUL_VOTES")
-                                 if use_matmul_votes is None
-                                 else use_matmul_votes)
-        # ragged window packing (on by default off-mesh; ctor arg or
-        # RACON_TPU_RAGGED=0 restores the single-geometry padded path):
-        # windows bucket by their own size, groups greedy-fill a fixed
-        # lane arena — the cudabatch batch-fill design (SURVEY §L3)
-        self.use_ragged = (flags.get_bool("RACON_TPU_RAGGED")
-                           if use_ragged is None else use_ragged)
+        # int8/i32 MXU vote reduction: exact integer accumulation, no
+        # fold cap — ins_overflow is structurally 0 on this path; False
+        # selects the f32-matmul + packed scatter (tests)
+        self.use_matmul_votes = use_matmul_votes
+        # ragged window packing: windows bucket by their own size,
+        # groups greedy-fill a fixed lane arena — the cudabatch
+        # batch-fill design (SURVEY §L3). A mesh takes the
+        # single-geometry padded path whatever this says; False selects
+        # it off-mesh (tests)
+        self.use_ragged = use_ragged
         # device ceiling (companion to the K_INS/CH caps in the module
         # docstring): the insertion accumulator is exact on both paths
         # (u32-pair scatter / int32 matmul), so the binding limit is the
@@ -1322,7 +1319,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # — must cost nothing, not a redundant background compile
         self._warmed_shapes: set = set()
         # wavefront_steps: executed (post-gating) DP anti-diagonal steps,
-        # the honest numerator for utilization estimates (bench.py);
+        # the honest numerator for utilization estimates;
         # lanes_occupied/lanes_total/groups/group_windows: real packing
         # efficiency of every dispatched pair arena (occupied lanes =
         # sum of real layer lengths, total = B x Lq per launch) — the
@@ -1335,11 +1332,10 @@ class TpuPoaConsensus(PallasDispatchMixin):
                       "lanes_occupied": 0, "lanes_total": 0,
                       "groups": 0, "group_windows": 0,
                       "lane_upload_saved_bytes": 0}
-        # per-window attribution of the ins_overflow counter (round 19,
-        # keyed by result index): the r05 bench showed a bare 265 with
-        # no way to tell WHICH window's insertion density tripped the
+        # per-window attribution of the ins_overflow counter (keyed by
+        # result index): WHICH window's insertion density tripped the
         # uncapped-scatter fallback — kept out of ``stats`` so numeric
-        # consumers (bench JSON, stat-reset loops) stay untouched
+        # consumers (the run report, stat-reset loops) stay untouched
         self.ins_overflow_by_window: dict = {}
 
     # the floor keeps groups large enough that per-group fixed costs
@@ -1417,10 +1413,10 @@ class TpuPoaConsensus(PallasDispatchMixin):
     def run(self, windows, trim: bool, progress=None) -> List[bool]:
         """Consensus over a window batch. Default routing is the ragged
         packer (:meth:`stream` — per-size-bucket geometry with greedy
-        arena fill); ``use_ragged=False`` / ``RACON_TPU_RAGGED=0`` or a
-        device mesh take the padded single-geometry path. Outputs are
-        bit-identical across the two (windows are independent and the
-        vote accumulation is exact at any grouping)."""
+        arena fill); ``use_ragged=False`` or a device mesh take the
+        padded single-geometry path. Outputs are bit-identical across
+        the two (windows are independent and the vote accumulation is
+        exact at any grouping)."""
         if self.use_ragged and self.mesh is None:
             sess = self.stream(trim)
             sess.feed(windows)
@@ -1439,7 +1435,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
         fed window in feed order. The ``Polisher.run()`` bounded queue
         feeds this directly, so the device never idles on the host
         between window ranges (double-buffered dispatch). Returns None
-        when the ragged packer is unavailable (mesh runs, flag off) —
+        when the ragged packer is unavailable (mesh runs,
+        ``use_ragged=False``) —
         callers then fall back to per-batch :meth:`run` calls.
 
         ``band_hint``: optional backbone-length upper bound used to
@@ -1452,10 +1449,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
         return _ConsensusStream(self, trim, band_hint)
 
     def _warn_dropped(self, before: dict) -> None:
-        """One-line per-run visibility for silently dropped layers
-        (scale_stats.dropped_layers was 4943 at BENCH_r05 with no
-        warning): depth-cap drops and rejected layer alignments both
-        land in the counter."""
+        """One-line per-run visibility for dropped layers: depth-cap
+        drops and rejected layer alignments both land in the counter."""
         d = self.stats["dropped_layers"] - before.get("dropped_layers", 0)
         if d > 0:
             from ..utils.logger import warn
@@ -1909,7 +1904,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 else:
                     qpw[dest] = store.gather_qpw(rows, Lq)
 
-            # hand-built windows (tests, benches): the round-7 join-and-
+            # hand-built windows (tests): the round-7 join-and-
             # LUT path over just their layers
             if legacy:
                 lay = [(s, q) for wi in legacy

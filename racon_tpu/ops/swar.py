@@ -183,7 +183,7 @@ def swar_ok() -> bool:
     global _SWAR_OK
     from .. import flags
     if not flags.get_bool("RACON_TPU_SWAR"):
-        return False  # global escape hatch / A-B switch, like DYNBOUND
+        return False  # the operator's explicit way past the packed kernels
     if _SWAR_OK is None:
         from .nw import _nw_wavefront_kernel, _walk_ops_kernel
 

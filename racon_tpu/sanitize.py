@@ -217,7 +217,7 @@ class PhaseRetraceBudget:
     ``RACON_TPU_SANITIZE_RETRACE_BUDGET``). The delta is **always**
     measured and published to the metrics registry as the gauge
     ``retrace.<phase>`` on a clean exit (the scan walks already-imported
-    modules — microseconds per phase — so bench.py reports and the
+    modules — microseconds per phase — so the run report and the
     shard runner's heartbeat line read compile churn from the one
     registry without paying for shadow execution); the budget itself is
     only *enforced* when the sanitizer is armed.

@@ -1,7 +1,7 @@
 """PolishServer: the long-lived polishing daemon (``racon --serve``).
 
 Every one-shot ``racon`` invocation pays the cold XLA compile
-(16–80 s at BENCH r04/r05) for kernels whose warm dispatch is
+(``setup_s`` in ``PERF.md``) for kernels whose warm dispatch is
 sub-second — fatal for heavy traffic of small jobs (one user's plasmid
 or amplicon panel).  The reference amortizes exactly this cost by
 reusing its cudapoa/cudaaligner batch objects across fills (SURVEY

@@ -60,6 +60,7 @@ class Logger:
     def log(self, message: str | None = None) -> None:
         now = time.perf_counter()
         if message is None:
+            # graftlint: disable=lock-discipline (main path alone restarts a stage; others read)
             self._stage_start = now
             return
         print(f"{message} {now - self._stage_start:.6f} s", file=self._stream)

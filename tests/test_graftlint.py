@@ -2,6 +2,7 @@
 repo-wide zero-findings gate, and the flags-registry contract."""
 
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -28,7 +29,7 @@ def test_repo_is_clean():
     (and the support trees CI lints)."""
     proc = subprocess.run(
         [sys.executable, "-m", "tools.analysis", "--quiet",
-         "racon_tpu", "tests", "tools", "bench.py"],
+         "racon_tpu", "tests", "tools"],
         cwd=REPO, capture_output=True, text=True)
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
@@ -199,3 +200,29 @@ def test_readme_table_is_current():
     table exactly (regenerate with `python -m racon_tpu.flags`)."""
     assert flags.check_readme(str(REPO / "README.md")), \
         "stale README flags table — run `python -m racon_tpu.flags`"
+
+
+_README_TREES = ("racon_tpu/", "tests/", "tools/", "benchmark/", "ci/")
+_README_ROOT_SUFFIXES = (".py", ".json", ".jsonl", ".md")
+
+
+def _readme_file_tokens(text):
+    """Backticked README tokens that name a file of this repository: a
+    path under one of the source trees, or a root-level name with a
+    source/record suffix (a trailing ``:line`` is cut)."""
+    out = []
+    for tok in re.findall(r"`([^`\s]+)`", text):
+        tok = re.sub(r":\d+(-\d+)?$", "", tok)
+        if tok.startswith(_README_TREES) or (
+                "/" not in tok and tok.endswith(_README_ROOT_SUFFIXES)):
+            out.append(tok)
+    return out
+
+
+def test_readme_names_only_files_that_exist():
+    """Every file the README names exists in the tree, so a deletion
+    cannot leave the first document a reader opens pointing at it."""
+    tokens = _readme_file_tokens((REPO / "README.md").read_text())
+    assert len(tokens) >= 20, tokens  # the extractor still sees paths
+    missing = sorted({t for t in tokens if not (REPO / t).exists()})
+    assert not missing, missing

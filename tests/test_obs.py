@@ -570,8 +570,8 @@ def test_run_boundary_clears_per_run_metrics():
 
 def test_exec_run_is_isolated_from_prior_registry_state(
         synthetic_inputs, tmp_path):
-    """A ShardRunner.run() in a process that already polished (bench,
-    tests, service mode) must report ITS pack/dispatch numbers, not the
+    """A ShardRunner.run() in a process that already polished
+    (tests, service mode) must report ITS pack/dispatch numbers, not the
     process-lifetime accumulation."""
     from racon_tpu.exec import ShardRunner
 

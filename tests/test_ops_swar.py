@@ -336,8 +336,8 @@ def test_max_depth_cap_lifted_past_511():
     count field; the widened accumulator moved the ceiling to the f32
     matmul-exactness bound (2047), and the round-10 int8/int32 matmul
     vote path removes that bound at the default scores — the cap moves
-    to a conservative 65535 (explicit use_matmul_votes so the test is
-    independent of the RACON_TPU_MATMUL_VOTES env)."""
+    to a conservative 65535 (both values of use_matmul_votes given
+    explicitly)."""
     from racon_tpu.ops.poa import TpuPoaConsensus
 
     assert TpuPoaConsensus(3, -5, -4, max_depth=4096,

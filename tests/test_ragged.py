@@ -155,8 +155,8 @@ def test_ragged_reject_parity_oversized_layers():
     whose layers exceed the padded path's pair buffer (Lq from the
     batch-global backbone maximum) goes to the CPU fallback there — the
     ragged packer must NOT quietly polish it on device in a bigger
-    bucket, or the two paths diverge on exactly the stress shapes the
-    scale bench asserts on."""
+    bucket, or the two paths diverge on exactly the stress shapes
+    ``tests/test_scale_stress.py`` asserts on."""
     rng = np.random.default_rng(55)
     windows = _mixed_windows(rng, n_w=8)
     # one window with layers far past Lq_pad = L_pad + band (~640 for

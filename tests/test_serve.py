@@ -499,7 +499,7 @@ def test_interleaved_job_scopes_stay_disjoint():
             barrier.wait()
             if name == "B":
                 # the one-run-per-process assumption under test: a run
-                # boundary inside job B (obs.begin / a bench leg)...
+                # boundary inside job B (obs.begin)...
                 metrics.clear_run()
             barrier.wait()
             results[name] = {

@@ -12,7 +12,7 @@ origin classification of the values reaching them (see
 :mod:`tools.analysis.concurrency` /
 :mod:`tools.analysis.compilesurface`).  Run it as::
 
-    python -m tools.analysis racon_tpu tests tools bench.py
+    python -m tools.analysis racon_tpu tests tools
     python -m tools.analysis --selftest        # fixture-based rule tests
     python -m tools.analysis --list            # rule inventory
     python -m tools.analysis --json PATH       # machine JSON on stdout

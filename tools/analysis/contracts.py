@@ -63,7 +63,7 @@ class MetricRegistryRule(Rule):
     EMITTERS = {"inc", "set_gauge", "add_time"}
 
     def applies(self, rel: str) -> bool:
-        return ((rel.startswith("racon_tpu/") or rel == "bench.py")
+        return (rel.startswith("racon_tpu/")
                 and rel != "racon_tpu/obs/metrics.py"
                 and rel.endswith(".py"))
 

@@ -1,4 +1,4 @@
-"""Vectorized ONT-like read/assembly simulator for the pipeline bench.
+"""Vectorized ONT-like read/assembly simulator (tests, ``chip_smoke.py``).
 
 Generates, for a random truth genome: a draft assembly (the polishing
 target, mutated from truth like a raw-read-consensus layout), a read set
@@ -121,10 +121,10 @@ def simulate(mbp: float, seed: int = 23, coverage: int = 30,
 def write_inputs(mbp: float, out_dir: str, seed: int = 23,
                  coverage: int = 30, n_contigs: int = 0) -> dict:
     """Generate and write the input triple (+ truth contigs) to
-    ``out_dir``. Exists as a CLI so benches can generate big workloads in
+    ``out_dir``. Exists as a CLI so a caller can generate big workloads in
     a THROWAWAY subprocess: a 100 Mbp set materializes several GB of read
     bytes, and generating in-process would bake that into the parent's
-    peak RSS — exactly the number the shard-runner bench budgets."""
+    peak RSS — exactly the number ``--max-ram`` budgets."""
     import os
 
     reads, paf, contigs, truths = simulate(mbp, seed=seed,
