@@ -89,7 +89,7 @@ MAX_INFLIGHT_PAIRS = 4 * MAX_CHUNK_PAIRS
 # only because the Mosaic sweep+walk is one program
 # (_pallas_align_chain) and the stream makes room before it dispatches
 # (_AlignStream._launch) — tests/test_chip_compile.py holds both. The
-# value itself is to be re-measured on the chip (ROADMAP S5).
+# value itself is to be re-measured on the chip.
 MAX_DIRS_BYTES = 8 * 1024 * 1024 * 1024
 
 @functools.partial(jax.jit, static_argnames=("max_len", "band", "steps",
@@ -357,77 +357,53 @@ def _pallas_align_chain(qrp, tp, n, m, *, max_len: int, band: int,
     return ops_packed, score, fi, fj
 
 
-def _row_layout(n, m, *, max_len: int, band: int):
-    """Shared offset/validity math for the banded NW row layout: qrp holds
-    the reversed query ending at column ``c + max_len``, tp the forward
-    target at offset ``c`` — exactly the layout the host used to pack."""
+def _banded_rows(qcat, tcat, n, m, *, max_len: int, band: int, bits: int):
+    """The banded NW row layout from flat blocks of ``bits``-wide codes
+    (pair k's at ``k * max_len``; ``8 // bits`` per byte, LSB-first):
+    qrp holds the reversed query ending at column ``c + max_len``, tp
+    the forward target at offset ``c`` — exactly the layout the host
+    used to pack; codes past ``n`` / ``m`` become the pad code 0. A
+    shifted copy (unpack, mask, reverse, pad), all streaming
+    element-wise work: XLA's element-wise gather runs orders of
+    magnitude under the memory system's rate."""
     B = n.shape[0]
-    c = band // 2
-    width = c + max_len + band
-    pos = jnp.arange(width, dtype=jnp.int32)[None, :]
-    row0 = (jnp.arange(B, dtype=jnp.int32) * max_len)[:, None]
-    qoff = c + max_len - 1 - pos  # reversed: column c+j holds q[...-j]
-    toff = pos - c
-    return (row0, (qoff, (qoff >= 0) & (qoff < n[:, None])),
-            (toff, (toff >= 0) & (toff < m[:, None])))
+    per = 8 // bits
+    shifts = jnp.arange(per, dtype=jnp.uint8) * bits
+    col = jnp.arange(max_len, dtype=jnp.int32)[None, :]
+
+    def codes(cat, length):
+        c = (cat.reshape(B, max_len // per, 1) >> shifts) & ((1 << bits) - 1)
+        return jnp.where(col < length[:, None], c.reshape(B, max_len),
+                         jnp.uint8(0))
+
+    pad = ((0, 0), (band // 2, band))
+    return jnp.pad(codes(qcat, n)[:, ::-1], pad), jnp.pad(codes(tcat, m), pad)
 
 
 @functools.partial(jax.jit, static_argnames=("max_len", "band"))
 def _build_rows(qcat, tcat, n, m, *, max_len: int, band: int):
     """Build the banded NW row layout on device from dense byte blocks
     (pair k's query/target at ``k * max_len``)."""
-    B = n.shape[0]
-    row0, qlay, tlay = _row_layout(n, m, max_len=max_len, band=band)
-
-    def fill(cat, lay):
-        off, valid = lay
-        src = row0 + jnp.clip(off, 0, max_len - 1)
-        w = src.shape[1]
-        return jnp.where(valid, jnp.take(cat, src.reshape(-1)
-                                         ).reshape(B, w), jnp.uint8(0))
-
-    return fill(qcat, qlay), fill(tcat, tlay)
+    return _banded_rows(qcat, tcat, n, m, max_len=max_len, band=band, bits=8)
 
 
 @functools.partial(jax.jit, static_argnames=("max_len", "band"))
 def _build_rows_packed(q4, t4, n, m, *, max_len: int, band: int):
     """``_build_rows`` over nibble-packed inputs (two 4-bit codes per
-    byte; code 0 is padding). Unpacking is a shift/mask on the gathered
-    byte, so the wide row arrays never cross the host link."""
-    B = n.shape[0]
-    row0, qlay, tlay = _row_layout(n, m, max_len=max_len, band=band)
-
-    def unpack(cat4, lay):
-        off, valid = lay
-        src = row0 + jnp.clip(off, 0, max_len - 1)
-        w = src.shape[1]
-        byte = jnp.take(cat4, (src // 2).reshape(-1)).reshape(B, w)
-        code = (byte >> ((src % 2) * 4).astype(jnp.uint8)) & 0xF
-        return jnp.where(valid, code.astype(jnp.uint8), jnp.uint8(0))
-
-    return unpack(q4, qlay), unpack(t4, tlay)
+    byte; code 0 is padding). Unpacking is a shift/mask on the device,
+    so the wide row arrays never cross the host link."""
+    return _banded_rows(q4, t4, n, m, max_len=max_len, band=band, bits=4)
 
 
 @functools.partial(jax.jit, static_argnames=("max_len", "band"))
 def _build_rows_packed2(q2, t2, n, m, *, max_len: int, band: int):
     """``_build_rows`` over 2-bit-packed inputs (four codes per byte, 16
     per int32 word — the SWAR transfer format for chunks whose alphabet
-    fits 4 symbols). The gathered byte count drops 4x vs raw and 2x vs
-    the nibble pack; code 0 doubles as padding, which is sound because
-    the wavefront kernel only consumes characters at interior cells
-    (pad lanes' direction codes are never read by any walk)."""
-    B = n.shape[0]
-    row0, qlay, tlay = _row_layout(n, m, max_len=max_len, band=band)
-
-    def unpack(cat2, lay):
-        off, valid = lay
-        src = row0 + jnp.clip(off, 0, max_len - 1)
-        w = src.shape[1]
-        byte = jnp.take(cat2, (src // 4).reshape(-1)).reshape(B, w)
-        code = (byte >> ((src % 4) * 2).astype(jnp.uint8)) & 3
-        return jnp.where(valid, code.astype(jnp.uint8), jnp.uint8(0))
-
-    return unpack(q2, qlay), unpack(t2, tlay)
+    fits 4 symbols). The bytes sent drop 4x vs raw and 2x vs the nibble
+    pack; code 0 doubles as padding, which is sound because the
+    wavefront kernel only consumes characters at interior cells (pad
+    lanes' direction codes are never read by any walk)."""
+    return _banded_rows(q2, t2, n, m, max_len=max_len, band=band, bits=2)
 
 
 def _sweep_bound(max_nm: int, max_len: int) -> int:

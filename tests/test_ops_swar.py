@@ -13,7 +13,8 @@ import pytest
 import jax.numpy as jnp
 
 from racon_tpu.ops import swar
-from racon_tpu.ops.nw import (_build_rows_packed, _build_rows_packed2,
+from racon_tpu.ops.nw import (BAND_RUNGS, BUCKETS, _build_rows,
+                              _build_rows_packed, _build_rows_packed2,
                               _nw_wavefront_kernel, _walk_ops_kernel,
                               TpuAligner)
 
@@ -213,6 +214,74 @@ def test_build_rows_packed2_matches_nibble_rows():
                           np.where(qr4 > 0, qr4 - 1, 0).astype(np.uint8))
     assert np.array_equal(np.asarray(tp2),
                           np.where(tp4 > 0, tp4 - 1, 0).astype(np.uint8))
+
+
+# the three row builders: (entry point, codes per byte)
+ROW_BUILDERS = {"raw": (_build_rows, 1), "nibble": (_build_rows_packed, 2),
+                "2bit": (_build_rows_packed2, 4)}
+# (max_len, band) of BUCKETS x BAND_RUNGS: the smallest bucket at the
+# smallest rung, every bucket up to 8192 at its own band, two rungs under
+ROW_GEOMETRIES = [(256, 64), (256, 128), (1024, 384), (4096, 1024),
+                  (4096, 256), (8192, 2048), (8192, 768)]
+
+
+def _rows_oracle(block, per, lengths, max_len, band, reverse):
+    """One side of the banded row layout in plain numpy, pair by pair:
+    unpack ``per`` codes per byte LSB-first; the query's first
+    ``length`` codes reversed, ending at column ``c + max_len``, or the
+    target's from column ``c``; every other byte is the pad code 0."""
+    B, c, bits = len(lengths), band // 2, 8 // per
+    pos = np.arange(B * max_len)
+    codes = ((block[pos // per] >> (bits * (pos % per)))
+             & ((1 << bits) - 1)).astype(np.uint8).reshape(B, max_len)
+    rows = np.zeros((B, c + max_len + band), np.uint8)
+    for k, length in enumerate(lengths):
+        if reverse:
+            rows[k, c + max_len - length:c + max_len] = \
+                codes[k, :length][::-1]
+        else:
+            rows[k, c:c + length] = codes[k, :length]
+    return rows
+
+
+@pytest.mark.parametrize("max_len,band", ROW_GEOMETRIES)
+@pytest.mark.parametrize("kind", sorted(ROW_BUILDERS))
+def test_build_rows_match_the_numpy_oracle(kind, max_len, band):
+    """Each builder against the oracle: full and one-base lengths,
+    random ones, and random (non-zero) bytes past every length — the
+    mask must hold, the Mosaic sweep reads these bytes as they are."""
+    assert max_len in dict(BUCKETS) and band in BAND_RUNGS
+    build, per = ROW_BUILDERS[kind]
+    rng = np.random.default_rng(max_len + band + per)
+    B = 8
+    n = rng.integers(1, max_len + 1, B).astype(np.int32)
+    m = rng.integers(1, max_len + 1, B).astype(np.int32)
+    n[0], m[0] = max_len, 1
+    n[1], m[1] = 1, max_len
+    qb = rng.integers(0, 256, B * max_len // per).astype(np.uint8)
+    tb = rng.integers(0, 256, B * max_len // per).astype(np.uint8)
+    qrp, tp = build(jnp.asarray(qb), jnp.asarray(tb), jnp.asarray(n),
+                    jnp.asarray(m), max_len=max_len, band=band)
+    assert qrp.dtype == tp.dtype == jnp.uint8
+    assert np.array_equal(
+        np.asarray(qrp), _rows_oracle(qb, per, n, max_len, band, True))
+    assert np.array_equal(
+        np.asarray(tp), _rows_oracle(tb, per, m, max_len, band, False))
+
+
+@pytest.mark.parametrize("kind", sorted(ROW_BUILDERS))
+def test_build_rows_lower_without_a_gather(kind):
+    """The layout is a shifted copy. As one element-wise gather per
+    output byte it cost more device time than the Mosaic aligner it
+    feeds (PERF.md, PR 32): that form must not come back unnoticed."""
+    build, per = ROW_BUILDERS[kind]
+    max_len, band, B = 4096, 1024, 16
+    blk = jnp.zeros((B * max_len // per,), jnp.uint8)
+    lens = jnp.ones((B,), jnp.int32)
+    text = build.lower(blk, blk, lens, lens, max_len=max_len,
+                       band=band).as_text()
+    assert "gather" not in text
+    assert "stablehlo.pad" in text and "stablehlo.reverse" in text
 
 
 def test_pallas_swar_kernel_interpret_parity():
