@@ -17,6 +17,7 @@ import time
 
 from . import __version__, flags, obs
 from .core.polisher import PolisherType, create_polisher
+from .io import parsers
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -35,11 +36,20 @@ def build_parser() -> argparse.ArgumentParser:
                         "overlaps between sequences and targets, or the "
                         "literal 'auto' to compute overlaps in-process "
                         "with the first-party minimizer-chain overlapper "
-                        "(no external mapper needed; see also "
-                        "RACON_TPU_OVERLAP*)")
+                        "(no external mapper needed; --overlaps auto "
+                        "says the same with a file named here)")
     p.add_argument("target_sequences", nargs="?", default=None,
                    help="FASTA/FASTQ file (may be "
                         "gzipped) with targets to correct")
+    p.add_argument("--overlaps", dest="overlaps_mode",
+                   choices=("file", "auto"), default="file",
+                   help="where the overlaps come from: 'file' (default) "
+                        "follows the positional overlaps argument; "
+                        "'auto' computes them in-process from the "
+                        "sequences and the targets with the first-party "
+                        "overlapper, exactly as the positional literal "
+                        "'auto' does, and never opens the file named "
+                        "there")
     p.add_argument("-u", "--include-unpolished", action="store_true",
                    help="output unpolished target sequences")
     p.add_argument("-f", "--fragment-correction", action="store_true",
@@ -388,6 +398,10 @@ def main(argv=None) -> int:
     if args.chips < 0:
         parser.error(f"--chips must be >= 0 (got {args.chips}); "
                      f"0 means automatic")
+    if args.overlaps_mode == "auto" and args.overlaps:
+        # one way to decide the mode: from here on every path (one-shot,
+        # shard runner, planner, --submit) sees the positional sentinel
+        args.overlaps = parsers.AUTO_OVERLAPS
 
     trace_path, report_path = _obs_paths(args)
     obs.begin(trace_path, report_path)

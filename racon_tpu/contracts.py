@@ -106,6 +106,8 @@ METRICS: FrozenSet[str] = frozenset((
     "overlap.freq_capped_buckets", "overlap.join_bailouts",
     "overlap.lanes_occupied", "overlap.lanes_total",
     "overlap.minimizers", "overlap.mode_auto",
+    # reads offered to the overlapper / reads with a row after the filter
+    "overlap.queries", "overlap.queries_kept",
     "overlap.seed_lanes_occupied", "overlap.seed_lanes_total",
     "overlap.stream_feed", "overlap.stream_groups", "overlap.streamed",
     # bounded init->polish queue

@@ -137,6 +137,16 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
                     stall_escalation=stall_escalation)
 
 
+def _est_chain_seeds(est_len: int) -> int:
+    """Seeds the densest candidate pair of a read set whose longest
+    read has ``est_len`` bases will hold, for the chain warm-up: a
+    k-mer of 15 survives a 12 % read against a 10 % draft about one
+    time in thirty, so of a read's ``est_len / 3`` minimizers one in
+    32 is a fair ceiling (2 Mbp at 30x: longest read 8.2 kb, densest
+    pair under 256 seeds)."""
+    return max(1, est_len // 96)
+
+
 class Polisher:
     def __init__(self, sequences_path, overlaps_path, target_path, type_,
                  window_length, quality_threshold, error_threshold, trim,
@@ -310,6 +320,9 @@ class Polisher:
             with obs.span("overlap.filter"):
                 if not self.prefiltered_overlaps:
                     overlaps = self._filter_overlaps(overlaps)
+                if auto_mode:
+                    metrics.inc("overlap.queries_kept",
+                                len({o.q_id for o in overlaps}))
             if not overlaps:
                 raise ValueError("empty overlap set")
 
@@ -374,6 +387,7 @@ class Polisher:
         from ..ops import chain as chain_ops
         from ..ops import overlap_seed
         metrics.set_gauge("overlap.mode_auto", 1)
+        metrics.inc("overlap.queries", raw_index)
         read_pos = [id_to_id[i << 1] for i in range(raw_index)]
         read_seqs = [self.sequences[p].data for p in read_pos]
         target_seqs = [self.sequences[i].data
@@ -385,7 +399,7 @@ class Polisher:
         # race the chain-arena compile against host seeding/matching
         est_len = max((len(s) for s in read_seqs), default=0)
         overlap_seed.warmup_async(est_len, len(read_seqs))
-        chain_ops.warmup_async(max(1, est_len // 8), raw_index, k=k)
+        chain_ops.warmup_async(_est_chain_seeds(est_len), raw_index, k=k)
         # graftlint: disable=jit-shape-hazard (k is a run-constant flag value clipped to 4..16 — one compile per run)
         rows = chain_ops.find_overlaps(read_seqs, target_seqs,
                                        read_self_t, k=k)
@@ -466,6 +480,7 @@ class Polisher:
         from ..ops import overlap_seed
         metrics.set_gauge("overlap.mode_auto", 1)
         metrics.set_gauge("overlap.streamed", 1)
+        metrics.inc("overlap.queries", raw_index)
         read_pos = [id_to_id[i << 1] for i in range(raw_index)]
         read_seqs = [self.sequences[p].data for p in read_pos]
         target_seqs = [self.sequences[i].data
@@ -477,7 +492,7 @@ class Polisher:
         # race the chain-arena compile against host seeding/matching
         est_len = max((len(s) for s in read_seqs), default=0)
         overlap_seed.warmup_async(est_len, len(read_seqs))
-        chain_ops.warmup_async(max(1, est_len // 8), raw_index, k=k)
+        chain_ops.warmup_async(_est_chain_seeds(est_len), raw_index, k=k)
 
         state = {"est_pairs": 0}
 
@@ -494,6 +509,8 @@ class Polisher:
                     if o.length >= best.length:
                         best = o
                 kept = [best]
+            # a run is one query's rows (the stream emits per query)
+            metrics.inc("overlap.queries_kept", int(bool(kept)))
             for o in kept:
                 if o.strand:
                     has_reverse[o.q_id] = True
@@ -1556,6 +1573,9 @@ class Polisher:
                 num_polished = 0
                 polished_data = []
 
+        drain = getattr(self.consensus, "drain_warmup", None)
+        if drain is not None:
+            drain()
         log.log("[racon_tpu::Polisher::polish] generated consensus")
         log.total("[racon_tpu::Polisher::] total =")
         self.windows = []

@@ -221,12 +221,6 @@ REGISTRY: Dict[str, Flag] = _declare([
          "the fleet scheduler re-scans tenant queues, host heartbeats "
          "and in-flight job status between placement events."),
     # ------------------------------------------------ first-party overlapper
-    Flag("RACON_TPU_OVERLAP", "", "str",
-         "Overlap source override: 'auto' runs the first-party "
-         "minimizer-seed + chain overlapper in-process regardless of "
-         "the overlaps CLI argument; 'paf' (or unset) follows the "
-         "positional argument, which itself accepts the literal "
-         "sentinel 'auto'."),
     Flag("RACON_TPU_OVERLAP_K", "15", "int",
          "Overlapper minimizer k-mer length (4..16; canonical codes "
          "live in uint32)."),

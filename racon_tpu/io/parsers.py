@@ -35,20 +35,16 @@ AUTO_OVERLAPS = "auto"
 
 
 def is_auto_overlaps(path: str) -> bool:
-    """True when ``path`` is the ``--overlaps auto`` sentinel (no
-    overlaps file exists; the overlapper generates rows in memory)."""
+    """True when ``path`` is the sentinel ``auto`` (no overlaps file is
+    read; the overlapper generates rows in memory)."""
     return path == AUTO_OVERLAPS
 
 
 def overlaps_mode(path: str) -> str:
-    """The effective overlap source for an overlaps argument: ``auto``
-    when the sentinel is given or ``RACON_TPU_OVERLAP=auto`` overrides
-    a file path, else ``paf`` (precomputed-file mode)."""
-    if is_auto_overlaps(path):
-        return "auto"
-    from .. import flags
-    forced = flags.get_str("RACON_TPU_OVERLAP").strip().lower()
-    return "auto" if forced == "auto" else "paf"
+    """The overlap source an overlaps argument names: ``auto`` for the
+    sentinel (what the CLI's ``--overlaps auto`` puts in the file's
+    place), else ``paf`` (precomputed-file mode)."""
+    return "auto" if is_auto_overlaps(path) else "paf"
 
 
 class ParseError(ValueError):

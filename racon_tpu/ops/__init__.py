@@ -88,3 +88,11 @@ if not _flags.get_bool("RACON_TPU_NO_COMPILE_CACHE"):
 from ..obs import compilewatch as _compilewatch  # noqa: E402
 
 _compilewatch.arm()
+
+# The compilers free what they allocated into glibc's per-thread arenas,
+# which keep it resident: give it back as each backend compile ends
+# (utils/heap.py), or the process's peak follows how many programs
+# missed the cache.
+from ..utils import heap as _heap  # noqa: E402
+
+_heap.arm()

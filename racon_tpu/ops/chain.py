@@ -5,22 +5,28 @@ Consumes the flat minimizer tables from :mod:`.overlap_seed` and emits
 ``Overlap``-compatible rows:
 
 - **matching** runs on device by default (``RACON_TPU_OVERLAP_DEVICE_JOIN``):
-  both tables sort by hash once on device (``lax.sort``), per-hash
-  occurrence totals derive from searchsorted run bounds so super-hot
-  repeat buckets over the occurrence cap drop whole (counted in
-  ``overlap.freq_capped_buckets`` — never silent), kept entries compact
-  to a sorted prefix, and the read→target join expands into hits via
-  the ragged searchsorted ramp, self-hit suppression, strand-flip of
-  query coordinates, and a device 5-key sort — so under
-  ``RACON_TPU_RESIDENT=1`` the matched ``(tp, qc)`` seed coordinates
-  never visit the host at all and feed the chain kernel directly. The
+  the target table sorts by hash on the host (it is the small side),
+  every read minimizer — in table order, never sorted — is looked up
+  among its distinct hashes on the device through a directory over the
+  hash's top bits, each target bucket's read occurrences are counted
+  by a scatter-add so super-hot repeat
+  buckets over the occurrence cap drop whole (counted in
+  ``overlap.freq_capped_buckets`` — never silent), and the read→target
+  join expands into hits via the ragged searchsorted ramp, self-hit
+  suppression and strand-flip of query coordinates; the host puts the
+  hits (a few per cent of the read table) in candidate-pair order. No
+  device sort: each ``lax.sort`` of this join took the chip's compiler
+  40 to 200 s (PR 34). The
   numpy :func:`match_seeds` stays as the byte-parity oracle AND the
   bail-out ladder target (empty tables, arena-overflow table or hit
   counts — counted in ``overlap.join_bailouts``, never approximation);
   hit 5-tuples are unique by construction (tables dedupe on (seq, pos)),
-  so any ascending sort produces the oracle's exact lexsort order.
-- **chaining** is the device DP: pairs ragged-pack by pow2 seed-count
-  bucket into fixed ``[B, S]`` arenas through :class:`_ChainStream` —
+  so any ascending sort produces the oracle's exact lexsort order. The
+  oracles themselves (``match_seeds``, ``chain_np``) are the plain
+  reference's, :mod:`racon_tpu.models.overlap`.
+- **chaining** is the device DP: pairs ragged-pack by seed-count class
+  (powers of 4) into the one ``[B, S]`` arena of their class through
+  :class:`_ChainStream` —
   greedy chunk fill by each pair's own seed-count cost, double-buffered
   dispatch/fetch behind an in-flight budget, per-pair results invariant
   to feed batching (the ``_AlignStream`` discipline, warmed via
@@ -55,26 +61,25 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import obs
-from ..obs import metrics
+# the plain reference owns the definitions: the chain DP's constants
+# and the numpy join (the bail-out ladder's target)
+from ..models.overlap import (BAND_DIAG, CHAIN_LOOKBACK, GAP_UNIT,
+                              MAX_GAP, match_seeds)
+from ..models.overlap import NEG as _NEG
+from ..obs import device_time, metrics
 from ..parallel import fetch_global
 from . import overlap_seed
 
-# chain DP shape/score constants (module-level: one compile surface)
-CHAIN_LOOKBACK = 16       # bounded predecessor window H
-MAX_GAP = 10_000          # max per-axis seed gap inside one chain
-BAND_DIAG = 512           # max |dq - dt| diagonal drift
-GAP_UNIT = 16             # score scale: 1 matched base = GAP_UNIT,
-                          # 1 gap base costs 1 (i.e. 1/16 of a match)
-_NEG = -(1 << 30)         # masked-lane score sentinel
-# chain-arena budget in cells (ts/qs operands and the scan history all
-# scale with B*S)
-CHAIN_ARENA_CELLS = 1 << 21
-DEFAULT_MAX_OCC = 64
-DEFAULT_MIN_SEEDS = 4
+# chain-arena size in cells (ts/qs operands and the scan history all
+# scale with B*S): every launch of a seed class is this one arena
+CHAIN_ARENA_CELLS = 1 << 19
 # device-join arena bounds: padded table entries / expanded hits past
 # these bail to the host oracle (counted, never silent) so one
-# pathological input can't demand an unbounded device sort
-JOIN_TABLE_CELLS = 1 << 25
+# pathological input can't demand an unbounded device arena. 2^26 table
+# cells hold a 30x bacterial read set (2 Mbp: 20 M read minimizers pad
+# to 2^25, beside 2^20 of the draft's) in about 1.2 GB of operands, ramp
+# and look-up temporaries
+JOIN_TABLE_CELLS = 1 << 26
 JOIN_MAX_HITS = 1 << 26
 # in-flight chain chunks before a fetch is forced (double buffering:
 # the device works chunk N while the host packs N+1 and fetches N-1)
@@ -83,44 +88,42 @@ CHAIN_INFLIGHT = 2
 
 # -------------------------------------------------------------- geometry
 
-def _seed_bucket(n: int) -> int:
-    """pow2 seed-list bucket for one candidate pair (floor 16) — the
-    quantizer both dispatch and :func:`_warmup_shapes` derive the
-    arena's S axis from."""
-    b = 16
+def _class_of(n: int, floor: int, step: int) -> int:
+    """The smallest ``floor * step**i`` that holds ``n``."""
+    b = floor
     while b < n:
-        b *= 2
+        b *= step
     return b
 
 
-def _pair_batch(S: int, n: int) -> int:
-    """pow2 pair-batch cap for one chain launch against the fixed
-    :data:`CHAIN_ARENA_CELLS` arena (companion of :func:`_seed_bucket`;
-    shared with warm-up)."""
-    want = min(max(1, n), max(1, CHAIN_ARENA_CELLS // max(1, S)))
-    b = 1
-    while b < want:
-        b *= 2
-    return b
+def _seed_bucket(n: int) -> int:
+    """Seed-list class for one candidate pair: powers of 4 from 16 —
+    the quantizer both dispatch and :func:`_warmup_shapes` derive the
+    arena's S axis from. Coarse on purpose (PR 34): a class is a
+    compiled program, and a bacterial read set spans three of these
+    where it spanned six powers of 2."""
+    return _class_of(n, 16, 4)
+
+
+def _pair_batch(S: int) -> int:
+    """Lanes of one chain launch of seed class ``S``: the whole
+    :data:`CHAIN_ARENA_CELLS` arena, always — a tail chunk pads to it,
+    so a class has ONE geometry whatever the input (companion of
+    :func:`_seed_bucket`; shared with warm-up)."""
+    return max(1, CHAIN_ARENA_CELLS // max(1, S))
 
 
 def _table_pad(n: int) -> int:
     """pow2 padded length of one minimizer table on the device-join
     path (floor 64) — the quantizer both the join dispatch and
     :func:`_warmup_shapes` derive sort geometry from."""
-    b = 64
-    while b < n:
-        b *= 2
-    return b
+    return _class_of(n, 64, 2)
 
 
 def _hits_pad(n: int) -> int:
     """pow2 padded length of the expanded hit arena (floor 256; same
     role as :func:`_table_pad` for the join's second kernel)."""
-    b = 256
-    while b < n:
-        b *= 2
-    return b
+    return _class_of(n, 256, 2)
 
 
 # ---------------------------------------------------------------- kernel
@@ -196,95 +199,99 @@ def _chain_kernel(ts, qs, ns, *, S: int, k: int):
 
 # --------------------------------------------------------- device join
 
-def _compact_sorted(h, a, b, c, keep):
-    """Order-preserving device compaction of kept table entries to a
-    sorted prefix: the cumsum-rank scatter (overlap_seed._compact_kernel
-    idiom). Dropped entries all park on one spill slot past the end;
-    un-scattered tail slots keep the ``_HASH_MAX`` init, so the prefix
-    plus tail is still ascending and searchsorted-safe."""
-    n = h.shape[0]
-    rank = jnp.cumsum(keep.astype(jnp.int32))
-    nk = rank[-1]
-    idx = jnp.where(keep, rank - 1, jnp.int32(n))
-    out_h = jnp.full((n + 1,), np.uint32(overlap_seed._HASH_MAX),
-                     jnp.uint32).at[idx].set(h)
-    out_a = jnp.zeros((n + 1,), jnp.int32).at[idx].set(a)
-    out_b = jnp.zeros((n + 1,), jnp.int32).at[idx].set(b)
-    out_c = jnp.zeros((n + 1,), jnp.int32).at[idx].set(c)
-    return out_h[:n], out_a[:n], out_b[:n], out_c[:n], nk
+def _cumsum_long(x):
+    """Inclusive prefix sum of a long 1-D int32 vector as two short
+    scans (rows of 4096, then the row totals): the same values as
+    ``jnp.cumsum``, a fraction of its compile time at 2^25 entries."""
+    n = x.shape[0]
+    if n < (1 << 13):
+        return jnp.cumsum(x)
+    rows = x.reshape(-1, 1 << 12)
+    inner = jnp.cumsum(rows, axis=1)
+    tot = inner[:, -1]
+    return (inner + (jnp.cumsum(tot) - tot)[:, None]).reshape(-1)
 
 
-@jax.jit
-def _join_sort_kernel(rh, rid, rpos, rstr, th, tid, tpos, tstr, max_occ):
-    """Device half one of the seed join: sort both padded tables by
-    hash, derive per-hash occurrence totals (both tables) from
-    searchsorted run bounds, drop super-hot buckets whole, compact the
-    survivors to sorted prefixes, and emit the read→target searchsorted
-    join ramp (``lo``/``cnt``/inclusive ``offs``).
+# look-up rounds inside one directory bucket, at least: four find a hash
+# among up to 15 distinct ones, which a bucket of the half-loaded
+# directory holds for no table of mixed hashes (a table that does hold
+# more gets the rounds it needs, and a program of its own)
+JOIN_BUCKET_STEPS = 4
 
-    Pad slots carry ``_HASH_MAX``, which no real table entry can (the
-    seed builder filters it), so they sort to the tail and the validity
-    masks are pure hash compares. Returns the compacted tables, the
-    ramp, the total hit count and the unique-hot-hash count — only the
-    two scalars need fetching before the expansion kernel launches."""
+
+@functools.partial(jax.jit, static_argnames=("steps",))
+def _join_ramp_kernel(rh, uh, ucount, dstart, max_occ, *, steps: int):
+    """Device half one of the seed join: look every read minimizer up
+    among the target's distinct hashes, count each one's read
+    occurrences, drop super-hot buckets whole, and emit the read→target
+    join ramp (``u``/``cnt``/inclusive ``offs``).
+
+    ``rh`` is the padded read table's hashes in table order — the read
+    side is never sorted. The target side comes sorted from the host
+    (:func:`_sorted_target`): ``uh`` its distinct hashes, ``ucount``
+    each one's entries, and ``dstart`` a directory over the hash's top
+    bits (``uh[dstart[p]:dstart[p + 1]]`` share prefix ``p``), so a
+    look-up is two directory reads and ``steps`` rounds of a search
+    inside one short bucket (``2**steps`` exceeds the longest). On the chip a gather
+    over the 2^25 padded read minimizers costs 0.29 s, so the two
+    21-round binary searches this replaced were 12 of a job's 40 s
+    (PR 34). Pad slots carry ``_HASH_MAX``, which no real entry can
+    (the seed builder filters it). No sort and no long scan but one
+    prefix sum: the sorts all this replaced took the chip's compiler
+    minutes. Returns the ramp, the total hit count and the count of
+    target buckets dropped — only the two scalars need fetching before
+    the expansion launches."""
     hmax = np.uint32(overlap_seed._HASH_MAX)
-    rh, rid, rpos, rstr = lax.sort((rh, rid, rpos, rstr), num_keys=1)
-    th, tid, tpos, tstr = lax.sort((th, tid, tpos, tstr), num_keys=1)
-    rr = (jnp.searchsorted(rh, rh, side="right")
-          - jnp.searchsorted(rh, rh, side="left"))
-    rt = (jnp.searchsorted(th, rh, side="right")
-          - jnp.searchsorted(th, rh, side="left"))
-    tt = (jnp.searchsorted(th, th, side="right")
-          - jnp.searchsorted(th, th, side="left"))
-    tr = (jnp.searchsorted(rh, th, side="right")
-          - jnp.searchsorted(rh, th, side="left"))
-    valid_r = rh != hmax
-    valid_t = th != hmax
-    hot_r = (rr + rt) > max_occ
-    hot_t = (tt + tr) > max_occ
-    # unique hot hashes across the union (numpy oracle's freq_capped):
-    # first occurrence in reads, plus first-in-targets absent from reads
-    first_r = valid_r & jnp.concatenate(
-        [jnp.ones(1, bool), rh[1:] != rh[:-1]])
-    first_t = valid_t & jnp.concatenate(
-        [jnp.ones(1, bool), th[1:] != th[:-1]])
-    capped = (jnp.sum((first_r & hot_r).astype(jnp.int32))
-              + jnp.sum((first_t & hot_t & (tr == 0)).astype(jnp.int32)))
-    rh, rid, rpos, rstr, nr = _compact_sorted(
-        rh, rid, rpos, rstr, valid_r & ~hot_r)
-    th, tid, tpos, tstr, nt = _compact_sorted(
-        th, tid, tpos, tstr, valid_t & ~hot_t)
-    lo = jnp.searchsorted(th, rh, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(th, rh, side="right").astype(jnp.int32)
-    live = jnp.arange(rh.shape[0], dtype=jnp.int32) < nr
-    cnt = jnp.where(live, hi - lo, jnp.int32(0))
-    offs = jnp.cumsum(cnt)
-    return (rid, rpos, rstr, tid, tpos, tstr, lo, cnt, offs,
-            offs[-1], capped)
+    U = uh.shape[0]
+    bits = (dstart.shape[0] - 1).bit_length() - 1
+    p = (rh >> np.uint32(32 - bits)).astype(jnp.int32)
+    lo, end = dstart[p], dstart[p + 1]
+    hi = end
+    # lower bound of rh in its bucket; ``eq`` follows ``hi``: whether
+    # the hash it last moved to is rh itself
+    eq = jnp.zeros(rh.shape, jnp.bool_)
+    for _ in range(steps):
+        mid = (lo + hi) >> 1
+        v = uh[jnp.minimum(mid, U - 1)]
+        right = (lo < hi) & (v < rh)
+        left = (lo < hi) & ~right
+        eq = jnp.where(left, v == rh, eq)
+        hi = jnp.where(left, mid, hi)
+        lo = jnp.where(right, mid + 1, lo)
+    matched = (rh != hmax) & (lo < end) & eq
+    # read occurrences per distinct target hash (misses park past it)
+    tr = jnp.zeros((U + 1,), jnp.int32).at[
+        jnp.where(matched, lo, jnp.int32(U))].add(1)[:U]
+    hot = (uh != hmax) & ((ucount + tr) > max_occ)
+    capped = jnp.sum(hot.astype(jnp.int32))
+    u = jnp.minimum(lo, U - 1)
+    cnt = jnp.where(matched & ~hot[u], ucount[u], jnp.int32(0))
+    offs = _cumsum_long(cnt)
+    return u, cnt, offs, offs[-1], capped
 
 
 _I32_MAX = np.int32(0x7FFFFFFF)
 
 
 @functools.partial(jax.jit, static_argnames=("E", "k"))
-def _join_expand_kernel(rid, rpos, rstr, tid, tpos, tstr, lo, cnt, offs,
-                        total, read_self_t, qlens, *, E: int, k: int):
+def _join_expand_kernel(rid, rpos, rstr, tid, tpos, tstr, ustart, u, cnt,
+                        offs, total, read_self_t, qlens, *, E: int, k: int):
     """Device half two: expand the join ramp into hit rows, drop self
-    hits, flip reverse-strand query coordinates, and sort by the
-    oracle's 5-key order ``(q, t, rel, tp, qc)`` on device.
-
-    Hit 5-tuples are unique (the seed tables dedupe on (seq, pos)), so
-    this unstable ascending sort reproduces numpy's stable lexsort
-    byte-for-byte; dropped rows take all-sentinel keys and cluster past
-    ``n_valid``, which is never fetched."""
+    hits and flip reverse-strand query coordinates. A dropped or dead
+    row takes ``q = INT32_MAX``; the rows come out in ramp order and
+    the host puts them in the oracle's ``(q, t, rel, tp, qc)`` order
+    (a 5-key device sort of the hits took the chip's compiler over
+    three minutes; the host's lexsort of a bacterial read set's 1.2 M
+    hits takes a fraction of a second)."""
     e = jnp.arange(E, dtype=jnp.int32)
     live = e < total
     # ragged ramp: hit e belongs to the read entry whose inclusive
-    # cumsum first exceeds e, at target offset lo + (e - run_begin)
+    # cumsum first exceeds e, at its bucket's first target slot
+    # (``ustart`` of its distinct hash) + (e - run_begin)
     ridx = jnp.clip(jnp.searchsorted(offs, e, side="right"),
                     0, rid.shape[0] - 1).astype(jnp.int32)
     begin = offs[ridx] - cnt[ridx]
-    tix = jnp.clip(lo[ridx] + (e - begin), 0, tid.shape[0] - 1)
+    tix = jnp.clip(ustart[u[ridx]] + (e - begin), 0, tid.shape[0] - 1)
     q = rid[ridx]
     qp = rpos[ridx]
     t = tid[tix]
@@ -293,26 +300,43 @@ def _join_expand_kernel(rid, rpos, rstr, tid, tpos, tstr, lo, cnt, offs,
     qsafe = jnp.clip(q, 0, read_self_t.shape[0] - 1)
     keep = live & (t != read_self_t[qsafe])
     qc = jnp.where(rel == 1, qlens[qsafe] - qp - jnp.int32(k), qp)
-    s = jnp.where(keep, jnp.int32(0), _I32_MAX)
-    ks = lax.sort((jnp.where(keep, q, _I32_MAX) | s,
-                   t | s, rel | s, tp | s, qc | s), num_keys=5)
-    return ks[0], ks[1], ks[2], ks[3], ks[4], jnp.sum(keep.astype(jnp.int32))
+    return (jnp.where(keep, q, _I32_MAX), t, rel, tp, qc,
+            jnp.sum(keep.astype(jnp.int32)))
 
 
-def _pad_table(table, n_pad: int):
-    """Host-side pow2 padding of one (hash, id, pos, strand) table for
-    the device sort: pad slots take the ``_HASH_MAX`` sentinel (no real
-    entry carries it) and strand widens to int32."""
+def _pad_to(a: np.ndarray, n_pad: int, fill, dtype) -> np.ndarray:
+    out = np.full(n_pad, fill, dtype)
+    out[:a.size] = a
+    return out
+
+
+def _sorted_target(table, n_pad: int):
+    """The target table sorted by hash on the host (stable, as the
+    oracle sorts it; the draft's table is small beside the reads'),
+    padded for the device: the entries' ``(id, pos, strand)``, then per
+    distinct hash ``(hash, first entry, entries)``, then the directory
+    of :func:`_join_ramp_kernel` — twice as many buckets as padded
+    entries, so at most half loaded — and its longest bucket."""
     h, sid, pos, strand = table
-    hp = np.full(n_pad, np.uint32(overlap_seed._HASH_MAX), np.uint32)
-    ip = np.zeros(n_pad, np.int32)
-    pp = np.zeros(n_pad, np.int32)
-    sp = np.zeros(n_pad, np.int32)
-    hp[:h.size] = h
-    ip[:h.size] = sid
-    pp[:h.size] = pos
-    sp[:h.size] = strand.astype(np.int32)
-    return hp, ip, pp, sp
+    order = np.argsort(h, kind="stable")
+    h = h[order]
+    first = np.ones(h.size, bool)
+    first[1:] = h[1:] != h[:-1]
+    ustart = np.flatnonzero(first)
+    uh = h[ustart]
+    ucount = np.diff(np.append(ustart, h.size))
+    bits = n_pad.bit_length()
+    per_bucket = np.bincount((uh >> np.uint32(32 - bits)).astype(np.int64),
+                             minlength=1 << bits)
+    dstart = np.zeros((1 << bits) + 1, np.int32)
+    np.cumsum(per_bucket, out=dstart[1:])
+    hmax = np.uint32(overlap_seed._HASH_MAX)
+    entries = tuple(_pad_to(a[order], n_pad, 0, np.int32)
+                    for a in (sid, pos, strand))
+    distinct = (_pad_to(uh, n_pad, hmax, np.uint32),
+                _pad_to(ustart, n_pad, 0, np.int32),
+                _pad_to(ucount, n_pad, 0, np.int32))
+    return entries, distinct, dstart, int(per_bucket.max())
 
 
 def join_seeds(read_table, target_table, read_self_t: np.ndarray,
@@ -323,12 +347,10 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
     :func:`match_seeds` oracle otherwise.
 
     Returns ``(hits, freq_capped)``. ``hits`` always carries host
-    ``q``/``t``/``rel`` int64 arrays (the group/pair boundary keys the
-    host scheduler needs either way) plus EITHER host ``tp``/``qc``
-    int64 arrays (oracle layout) OR, under ``resident=True`` on the
-    device path, device ``tp_dev``/``qc_dev`` int32 arrays the chain
-    stream gathers from directly — the matched seed coordinates then
-    never visit the host (ledgered in ``dataflow.bytes_avoided``).
+    ``q``/``t``/``rel``/``tp``/``qc`` int64 arrays in the oracle's
+    order; under ``resident=True`` on the device path also device
+    ``tp_dev``/``qc_dev`` int32 arrays the chain stream gathers from
+    directly.
 
     The bail-out ladder (empty tables, padded tables over
     :data:`JOIN_TABLE_CELLS`, hit counts over :data:`JOIN_MAX_HITS`,
@@ -351,17 +373,35 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
         return _oracle(bail=True)
     # graftlint: disable=warmup-coverage (the join runs ONCE per run immediately after seeding produces the very sizes these pow2 buckets quantize — there is no earlier moment to warm them from)
     R2, T2 = _table_pad(rh.size), _table_pad(th.size)
-    if R2 + T2 > JOIN_TABLE_CELLS or R2 * max(1, max_occ) >= (1 << 31):
+    # a kept read entry joins fewer than max_occ target entries (its
+    # bucket would have dropped whole) and a pad slot joins none, so
+    # the int32 ramp holds while real entries x max_occ stays under 2^31
+    if R2 + T2 > JOIN_TABLE_CELLS \
+            or int(rh.size) * max(1, max_occ) >= (1 << 31):
         # rung 2: table arena overflow / int32 ramp overflow risk
         return _oracle(bail=True)
 
-    rpad = _pad_table(read_table, R2)
-    tpad = _pad_table(target_table, T2)
+    hmax = np.uint32(overlap_seed._HASH_MAX)
+    _, rid_h, rpos_h, rstr_h = read_table
+    entries_h, (uh_h, ustart_h, ucount_h), dstart_h, longest = \
+        _sorted_target(target_table, T2)
+    steps = max(JOIN_BUCKET_STEPS, longest.bit_length())
     with obs.span("overlap.join.dispatch", reads=int(rh.size),
                   targets=int(th.size)):
-        # graftlint: disable=jit-shape-hazard (R2/T2 are the pow2 _table_pad buckets)
-        (rid, rpos, rstr, tid, tpos, tstr, lo, cnt, offs, total_d,
-         capped_d) = _join_sort_kernel(*rpad, *tpad, np.int32(max_occ))
+        rh_d = jnp.asarray(_pad_to(rh, R2, hmax, np.uint32))
+        uh_d, ucount_d, dstart_d = (jnp.asarray(a) for a in (
+            uh_h, ucount_h, dstart_h))
+        device_time.submit("h2d", "overlap.join.put", dstart_d)
+        # graftlint: disable=jit-shape-hazard (R2/T2 are the pow2 _table_pad buckets; steps is JOIN_BUCKET_STEPS for every table of mixed hashes)
+        u_d, cnt, offs, total_d, capped_d = _join_ramp_kernel(
+            rh_d, uh_d, ucount_d, dstart_d, np.int32(max_occ), steps=steps)
+        device_time.submit("exec", "_join_ramp_kernel", total_d)
+        # the expansion's operands cross while the ramp runs
+        sides_d = [jnp.asarray(a) for a in (
+            _pad_to(rid_h, R2, 0, np.int32),
+            _pad_to(rpos_h, R2, 0, np.int32),
+            _pad_to(rstr_h, R2, 0, np.int32), *entries_h, ustart_h)]
+        device_time.submit("h2d", "overlap.join.put", sides_d[-1])
     with obs.span("overlap.join.fetch"):
         total, capped = (int(x) for x in fetch_global([total_d, capped_d]))
     metrics.inc("dataflow.bytes_fetched", 8)
@@ -376,132 +416,35 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
 
     # graftlint: disable=warmup-coverage (the expand geometry is the join's own counted output — pow2-bucketed, knowable only mid-join)
     E = _hits_pad(total)
+    # the per-read vectors pad to a pow2 too: the expand program's
+    # shapes are then (R2, T2, E, this), none the read count itself
+    Q2 = _table_pad(read_self_t.size)
     with obs.span("overlap.join.dispatch", hits=total):
         # graftlint: disable=jit-shape-hazard (E is the pow2 _hits_pad bucket; k is a run-constant flag value — one compile per run)
-        q_d, t_d, rel_d, tp_d, qc_d, nv_d = _join_expand_kernel(
-            rid, rpos, rstr, tid, tpos, tstr, lo, cnt, offs,
-            jnp.int32(total), read_self_t.astype(np.int32),
-            qlens.astype(np.int32), E=E, k=k)
+        out_d = _join_expand_kernel(
+            *sides_d, u_d, cnt, offs, np.int32(total),
+            _pad_to(read_self_t, Q2, -1, np.int32),
+            _pad_to(qlens, Q2, 0, np.int32), E=E, k=k)
+        device_time.submit("exec", "_join_expand_kernel", out_d[5])
     with obs.span("overlap.join.fetch"):
-        n = int(fetch_global([nv_d])[0])
-        if resident:
-            q_h, t_h, rel_h = fetch_global(
-                [q_d[:n], t_d[:n], rel_d[:n]])
-        else:
-            q_h, t_h, rel_h, tp_h, qc_h = fetch_global(
-                [q_d[:n], t_d[:n], rel_d[:n], tp_d[:n], qc_d[:n]])
-    hits: Dict[str, object] = {"q": q_h.astype(np.int64),
-                               "t": t_h.astype(np.int64),
-                               "rel": rel_h.astype(np.int64)}
+        # whole arenas, cut on the host: a device slice [:n] is a
+        # program per hit count, new with every input
+        q_h, t_h, rel_h, tp_h, qc_h, _ = fetch_global(list(out_d))
+        keep = q_h != _I32_MAX
+        cols = [c[keep].astype(np.int64)
+                for c in (q_h, t_h, rel_h, tp_h, qc_h)]
+        # hit 5-tuples are unique (the tables dedupe on (seq, pos)), so
+        # this is the oracle's order exactly
+        order = np.lexsort(cols[::-1])
+        q_h, t_h, rel_h, tp_h, qc_h = (c[order] for c in cols)
+    metrics.inc("dataflow.bytes_fetched", 20 * E + 4)
+    hits: Dict[str, object] = {"q": q_h, "t": t_h, "rel": rel_h,
+                               "tp": tp_h, "qc": qc_h}
     if resident:
-        hits["tp_dev"] = tp_d
-        hits["qc_dev"] = qc_d
-        metrics.inc("dataflow.bytes_fetched", 12 * n + 4)
-        metrics.inc("dataflow.bytes_avoided", 8 * n)
-    else:
-        hits["tp"] = tp_h.astype(np.int64)
-        hits["qc"] = qc_h.astype(np.int64)
+        # the chain stream gathers its arenas on the device from these
+        hits["tp_dev"] = jnp.asarray(tp_h.astype(np.int32))
+        hits["qc_dev"] = jnp.asarray(qc_h.astype(np.int32))
     return hits, capped
-
-
-def match_seeds(read_table, target_table, read_self_t: np.ndarray,
-                qlens: np.ndarray, *, k: int, max_occ: int
-                ) -> Tuple[Dict[str, np.ndarray], int]:
-    """Sorted-hash intersection of the two minimizer tables.
-
-    Returns ``(hits, freq_capped)`` where ``hits`` holds per-hit
-    parallel arrays — ``q`` (read ordinal), ``t`` (target index),
-    ``rel`` (relative strand), ``tp`` (target seed pos), ``qc`` (query
-    seed pos, already flipped for reverse-strand hits) — lexsorted by
-    ``(q, t, rel, tp, qc)`` so candidate pairs are consecutive runs.
-    Buckets whose total occurrence count (both tables) exceeds
-    ``max_occ`` drop whole; ``freq_capped`` counts them."""
-    rh, rid, rpos, rstr = read_table
-    th, tid, tpos, tstr = target_table
-    empty = {key: np.zeros(0, np.int64) for key in
-             ("q", "t", "rel", "tp", "qc")}
-    if rh.size == 0 or th.size == 0:
-        return empty, 0
-
-    ro = np.argsort(rh, kind="stable")
-    rh, rid, rpos, rstr = rh[ro], rid[ro], rpos[ro], rstr[ro]
-    to = np.argsort(th, kind="stable")
-    th, tid, tpos, tstr = th[to], tid[to], tpos[to], tstr[to]
-
-    uh, uc = np.unique(np.concatenate([rh, th]), return_counts=True)
-    hot = uc > max_occ
-    freq_capped = int(hot.sum())
-    keep_r = ~hot[np.searchsorted(uh, rh)]
-    keep_t = ~hot[np.searchsorted(uh, th)]
-    rh, rid, rpos, rstr = rh[keep_r], rid[keep_r], rpos[keep_r], rstr[keep_r]
-    th, tid, tpos, tstr = th[keep_t], tid[keep_t], tpos[keep_t], tstr[keep_t]
-    if rh.size == 0 or th.size == 0:
-        return empty, freq_capped
-
-    lo = np.searchsorted(th, rh, "left")
-    hi = np.searchsorted(th, rh, "right")
-    cnt = (hi - lo).astype(np.int64)
-    total = int(cnt.sum())
-    if total == 0:
-        return empty, freq_capped
-    ridx = np.repeat(np.arange(rh.size, dtype=np.int64), cnt)
-    ramp = np.arange(total, dtype=np.int64) - np.repeat(
-        np.cumsum(cnt) - cnt, cnt)
-    tidx = np.repeat(lo.astype(np.int64), cnt) + ramp
-
-    q = rid[ridx].astype(np.int64)
-    t = tid[tidx].astype(np.int64)
-    rel = (rstr[ridx] != tstr[tidx]).astype(np.int64)
-    tp = tpos[tidx].astype(np.int64)
-    qp = rpos[ridx].astype(np.int64)
-    notself = t != read_self_t[q]
-    q, t, rel, tp, qp = (q[notself], t[notself], rel[notself],
-                         tp[notself], qp[notself])
-    qc = np.where(rel == 1, qlens[q] - qp - k, qp)
-    order = np.lexsort((qc, tp, rel, t, q))
-    return ({"q": q[order], "t": t[order], "rel": rel[order],
-             "tp": tp[order], "qc": qc[order]}, freq_capped)
-
-
-# ---------------------------------------------------------- numpy oracle
-
-def chain_np(ts: np.ndarray, qs: np.ndarray, k: int
-             ) -> Tuple[int, int, int, int, int, int]:
-    """Pure-python/numpy chain oracle with exactly the kernel's
-    semantics: integer scoring, bounded lookback, nearest-predecessor
-    strict-> tie-break, lowest-index best-end tie-break. Returns
-    ``(score, n_chained, q_lo, q_hi, t_lo, t_hi)``."""
-    n = len(ts)
-    if n == 0:
-        return (_NEG, 0, 0, 0, 0, 0)
-    start = k * GAP_UNIT
-    f = [0] * n
-    par = [0] * n
-    for i in range(n):
-        best, arg = _NEG, -1
-        for off in range(1, CHAIN_LOOKBACK + 1):  # nearest first
-            j = i - off
-            if j < 0:
-                break
-            dt, dq = ts[i] - ts[j], qs[i] - qs[j]
-            gap = abs(dq - dt)
-            if dt < 1 or dq < 1 or dt > MAX_GAP or dq > MAX_GAP \
-                    or gap > BAND_DIAG:
-                continue
-            cand = f[j] + min(k, dq, dt) * GAP_UNIT - gap
-            if cand > best:  # strict: ties keep the nearer predecessor
-                best, arg = cand, off
-        f[i] = max(start, best)
-        par[i] = arg if best > start else 0
-    end = int(np.argmax(np.asarray(f)))
-    cur, cnt = end, 0
-    while True:
-        cnt += 1
-        if par[cur] == 0:
-            break
-        cur -= par[cur]
-    return (f[end], cnt, int(qs[cur]), int(qs[end]),
-            int(ts[cur]), int(ts[end]))
 
 
 # -------------------------------------------------------------- chaining
@@ -541,6 +484,16 @@ def _pack_lanes(tp: np.ndarray, qc: np.ndarray, starts: np.ndarray,
     return ts, qs
 
 
+def _put_lanes(tp: np.ndarray, qc: np.ndarray, starts: np.ndarray,
+               counts: np.ndarray, S: int, B: int):
+    """:func:`_pack_lanes`, put on the device and entered in the
+    occupancy ledger."""
+    ts, qs = (jnp.asarray(a) for a in _pack_lanes(tp, qc, starts, counts,
+                                                  S, B))
+    device_time.submit("h2d", "overlap.chain.put", qs)
+    return ts, qs
+
+
 @functools.partial(jax.jit, static_argnames=("S",))
 def _gather_pairs_kernel(tp_dev, qc_dev, starts, counts, *, S: int):
     """Device fill of one ``[B, S]`` chain arena straight from the
@@ -559,20 +512,20 @@ class _ChainStream:
     ``nw._AlignStream`` / ``poa._ConsensusStream``.
 
     Candidate pairs arrive through :meth:`add` (cost = their own seed
-    count) and class into pow2 seed-count buckets; each bucket
-    greedy-fills fixed ``[B, S]`` arenas against the
-    :data:`CHAIN_ARENA_CELLS` budget and dispatches a chunk the moment
+    count) and class into seed-count buckets (powers of 4); each bucket
+    greedy-fills the one ``[B, S]`` arena of its class
+    (:data:`CHAIN_ARENA_CELLS` cells) and dispatches a chunk the moment
     it fills, ASYNCHRONOUSLY — host packing of later pairs overlaps
     device DP of earlier chunks, and fetches happen only when the
     in-flight budget (:data:`CHAIN_INFLIGHT` chunks / 2 arenas of
     cells) forces one or at :meth:`finish`. The DP is per-lane
-    independent and each pair always lands in the same pow2 bucket, so
+    independent and each pair always lands in the same bucket, so
     per-pair rows are invariant to feed batching — the property the
     streamed/barriered byte-identity contract rests on.
 
     ``tp``/``qc`` may be host arrays (vectorized masked gather) or the
-    resident join's device arrays (:func:`_gather_pairs_kernel` — the
-    seed coordinates never visit the host). ``on_row(pid, row)`` fires
+    resident join's device copies (:func:`_gather_pairs_kernel` fills
+    the arena on the device). ``on_row(pid, row)`` fires
     as each pair's ``[6]`` summary row lands, in deterministic
     (chunk-completion) order — the group streamer's completion
     signal."""
@@ -613,7 +566,7 @@ class _ChainStream:
             # (S, B) geometry per chunk is the bucket's full arena cap,
             # so the warm ladder covers every full chunk
             entries.sort(key=lambda e: (-e[0], e[1]))
-            cap = _pair_batch(S, CHAIN_ARENA_CELLS)
+            cap = _pair_batch(S)
             while entries:
                 if not final and len(entries) < cap:
                     break
@@ -624,25 +577,24 @@ class _ChainStream:
                 self.pending[S] = entries
 
     def _launch(self, chunk: List[Tuple[int, int, int]], S: int) -> None:
-        B = _pair_batch(S, len(chunk))
+        B = _pair_batch(S)
         starts = np.zeros(B, np.int64)
         counts = np.zeros(B, np.int64)
         for lane, (c, _, s0) in enumerate(chunk):
             starts[lane] = s0
             counts[lane] = c
         with obs.span("overlap.chain.dispatch", pairs=len(chunk)):
+            ns = counts.astype(np.int32)
             if self.device_src:
-                # graftlint: disable=jit-shape-hazard (S is the pow2 _seed_bucket rung)
+                # graftlint: disable=jit-shape-hazard (S is the pow4 _seed_bucket rung)
                 ts, qs = _gather_pairs_kernel(
-                    self.tp, self.qc, starts.astype(np.int32),
-                    counts.astype(np.int32), S=S)
-                ns = counts.astype(np.int32)
+                    self.tp, self.qc, starts.astype(np.int32), ns, S=S)
+                device_time.submit("exec", "_gather_pairs_kernel", ts)
             else:
-                ts, qs = _pack_lanes(self.tp, self.qc, starts, counts,
-                                     S, B)
-                ns = counts.astype(np.int32)
-            # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow2 bucket)
+                ts, qs = _put_lanes(self.tp, self.qc, starts, counts, S, B)
+            # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow4 bucket)
             out = _chain_kernel(ts, qs, ns, S=S, k=self.k)
+            device_time.submit("exec", "_chain_kernel", out)
         self.inflight.append({"chunk": chunk, "out": out,
                               "cells": B * S})
         self.inflight_cells += B * S
@@ -714,21 +666,21 @@ def chain_pairs(hits: Dict[str, np.ndarray], *, k: int, min_seeds: int
     rows_out = np.zeros((starts.size, 6), np.int64)
     for S in sorted(by_bucket):
         members = by_bucket[S]
-        cap = _pair_batch(S, len(members))
+        cap = B = _pair_batch(S)
         for begin in range(0, len(members), cap):
             part = members[begin:begin + cap]
-            B = _pair_batch(S, len(part))
             pstarts = np.zeros(B, np.int64)
             pcounts = np.zeros(B, np.int64)
             for lane, m in enumerate(part):
                 pstarts[lane] = starts[m]
                 pcounts[lane] = counts[m]
-            ts, qs = _pack_lanes(hits["tp"], hits["qc"],
-                                 pstarts, pcounts, S, B)
             ns = pcounts.astype(np.int32)
             with obs.span("overlap.chain.dispatch", pairs=len(part)):
-                # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow2 bucket)
+                ts, qs = _put_lanes(hits["tp"], hits["qc"], pstarts, pcounts,
+                                    S, B)
+                # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow4 bucket)
                 out = _chain_kernel(ts, qs, ns, S=S, k=k)
+                device_time.submit("exec", "_chain_kernel", out)
             with obs.span("overlap.chain.fetch", pairs=len(part)):
                 out_np = fetch_global([out])[0]
             rows_out[part] = out_np[:len(part)].astype(np.int64)
@@ -1057,27 +1009,24 @@ _warmed_shapes: set = set()
 
 def _warmup_shapes(est_seeds: int, est_pairs: int
                    ) -> List[Tuple[int, int]]:
-    """The ``(S, B)`` chain-arena geometries a run with ~``est_pairs``
-    candidate pairs of ~``est_seeds`` seeds dispatches — derived with
-    the same :func:`_seed_bucket` / :func:`_pair_batch` quantizers the
-    dispatch path uses (consumed by :func:`warmup_async`).
+    """The ``(S, B)`` chain-arena geometries a run whose densest
+    candidate pair holds about ``est_seeds`` seeds dispatches — derived
+    with the same :func:`_seed_bucket` / :func:`_pair_batch` quantizers
+    the dispatch path uses (consumed by :func:`warmup_async`).
 
-    The ragged :class:`_ChainStream` buckets each pair by its *own*
-    seed count, so real runs dispatch a short ladder of seed classes
-    below the top bucket; the warm set covers the top rung and up to
-    three halvings (floor 16) at the batch size the arena fill yields
-    for each class."""
+    The ragged :class:`_ChainStream` classes each pair by its *own*
+    seed count, so a run dispatches the top class and the ones below
+    it: the warm set is the top rung and two below (floor 16), each at
+    its one arena geometry."""
     if est_seeds <= 0 or est_pairs <= 0:
         return []
     shapes: List[Tuple[int, int]] = []
     S = _seed_bucket(est_seeds)
-    for _ in range(4):
-        shape = (S, _pair_batch(S, est_pairs))
-        if shape not in shapes:
-            shapes.append(shape)
+    for _ in range(3):
+        shapes.append((S, _pair_batch(S)))
         if S <= 16:
             break
-        S //= 2
+        S //= 4
     return shapes
 
 
@@ -1095,6 +1044,8 @@ def warmup_async(est_seeds: int, est_pairs: int, k: int = 15):
         z = np.zeros((B, S), np.int32)
         # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow2 bucket)
         out = _chain_kernel(z, z, np.zeros(B, np.int32), S=S, k=kk)
+        # the dummy occupies the device like any program: kind "warm"
+        device_time.submit("warm", "_chain_kernel", out)
         jax.block_until_ready(out)
 
     def _run():

@@ -6,26 +6,30 @@ mapper (minimap2), so PAF/MHAP/SAM parsing is its entire ingest story.
 chain overlapper (ROADMAP item 5); this module is the seeding half:
 
 - sequences pack host-side into 2-bit code arrays (A/C/G/T → 0..3,
-  anything else → 4, which invalidates every k-mer covering it) and
-  bucket by pow2 length into fixed-shape ``[B, L]`` batches, one compile
-  per bucket geometry — the same arena discipline as ``nw._AlignStream``;
+  anything else → 4, which invalidates every k-mer covering it), every
+  one cut into slices that fit a row of :data:`SEED_ROW` bases, and the
+  rows fill ONE fixed ``[SEED_BATCH, SEED_ROW]`` arena: one geometry,
+  hence one compiled program, for every input (reads of any length,
+  contigs, the tail batch — PR 34: the pow2 length and batch classes
+  this replaced compiled a program per class and new ones per input);
 - one jit'd pass per batch builds forward and reverse-complement k-mer
   codes (k static shifted slices), takes the strand-canonical minimum
   (``fwd == rc`` palindrome ties are skipped, like minimap2), scrambles
   it through an invertible 32-bit finalizer so rank ties don't follow
   base composition, and selects each w-window's leftmost minimum with a
   strict-< iterative sweep (deterministic: no argmin tie ambiguity);
-- selected positions scatter into a per-position mask; the host (or,
+- the windows' picks mark a per-position mask; the host (or,
   under ``RACON_TPU_RESIDENT=1``, a device compaction kernel that ships
   only the selected entries over the link) flattens the batch into one
   flat ``(hash, seq_id, pos, strand)`` table for the matcher
   (:mod:`racon_tpu.ops.chain`).
 
-Long sequences (contig targets) are sliced into bounded window-start
-spans so the arena never scales with contig length; slices overlap by
-``k + w - 2`` bases and each window is owned by exactly one slice, so
-the union equals the whole-sequence scan (the numpy oracle
-:func:`minimizers_np` asserts this in tests/test_overlapper.py).
+Sequences longer than a row (contig targets, long reads) are sliced
+into bounded window-start spans so the arena never scales with sequence
+length; slices overlap by ``k + w - 2`` bases and each window is owned
+by exactly one slice, so the union equals the whole-sequence scan (the
+plain reference :func:`racon_tpu.models.overlap.minimizers_np` asserts
+this in tests/test_overlapper.py).
 """
 
 from __future__ import annotations
@@ -42,7 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from .. import obs
-from ..obs import metrics
+from ..models.overlap import BASE_LUT as _BASE_LUT
+from ..models.overlap import HASH_MAX as _HASH_MAX
+from ..obs import device_time, metrics
 from ..parallel import fetch_global
 
 # defaults mirrored by the RACON_TPU_OVERLAP_K/W flags (k=15/w=5: ONT
@@ -50,45 +56,23 @@ from ..parallel import fetch_global
 DEFAULT_K = 15
 DEFAULT_W = 5
 # minimizer-arena budget in cells: every per-position working array
-# (codes, fwd/rc kmers, hashes, mask) is B*L, so the batch cap derives
-# from this one constant
+# (codes, fwd/rc kmers, hashes, mask) is B*L
 SEED_ARENA_CELLS = 1 << 22
-# window starts per kernel launch for one long sequence: contigs slice
-# into spans this size (plus k+w-2 overlap bases) so the arena never
-# scales with contig length
-SEED_SLICE = 1 << 17
-# flat-table sentinel: invalid k-mer slots (ambiguous base in window,
-# fwd==rc palindrome tie, past the sequence end) never win a window
-_HASH_MAX = 0xFFFFFFFF
-
-_BASE_LUT = np.full(256, 4, np.uint8)
-for _i, _b in enumerate(b"ACGT"):
-    _BASE_LUT[_b] = _i
-for _i, _b in enumerate(b"acgt"):
-    _BASE_LUT[_b] = _i
+# the arena's ONE geometry. A row holds one slice of one sequence:
+# SEED_ROW - (k + w - 2) window starts plus the k + w - 2 bases the last
+# of them reads. 8 kb keeps a typical long read to one or two rows (a
+# tail row is the only padding a sequence costs) and a launch's full
+# fetch (6 bytes a cell) at 24 MiB
+SEED_ROW = 1 << 13
+SEED_BATCH = SEED_ARENA_CELLS // SEED_ROW
 
 
 # -------------------------------------------------------------- geometry
 
-def _len_bucket(n: int) -> int:
-    """pow2 length bucket for one code chunk (floor 64 so every bucket
-    admits a full k+w window) — the ONE quantizer both the dispatch
-    path and :func:`_warmup_shapes` derive chunk length from."""
-    b = 64
-    while b < n:
-        b *= 2
-    return b
-
-
-def _seed_batch(L: int, n: int) -> int:
-    """pow2 batch cap for one minimizer launch against the fixed
-    :data:`SEED_ARENA_CELLS` arena (companion quantizer of
-    :func:`_len_bucket`; shared with warm-up)."""
-    want = min(max(1, n), max(1, SEED_ARENA_CELLS // max(1, L)))
-    b = 1
-    while b < want:
-        b *= 2
-    return b
+def _slice_starts(k: int, w: int) -> int:
+    """Window starts one row owns: the row less the ``k + w - 2`` bases
+    the last window reads past its start."""
+    return SEED_ROW - (k + w - 2)
 
 
 # --------------------------------------------------------------- kernels
@@ -112,7 +96,9 @@ def _minimizer_kernel(codes, lens, nwin, *, k: int, w: int, L: int):
     ``lens`` bounds each row's real bases, ``nwin`` its owned window
     starts (slice discipline: overlap-region windows belong to the next
     slice). Returns ``(hash [B, P] uint32, strand [B, P] bool,
-    selected [B, P] bool)`` with ``P = L - k + 1``."""
+    selected [B, P] bool, selected per row [B] int32)`` with ``P = L -
+    k + 1``; the last is the small output the occupancy ledger
+    watches."""
     P = L - k + 1
     B = codes.shape[0]
     base = codes.astype(jnp.uint32)
@@ -135,21 +121,24 @@ def _minimizer_kernel(codes, lens, nwin, *, k: int, w: int, L: int):
     # leftmost strict-< windowed minimum over w consecutive k-mer slots
     W = P - w + 1
     minv = h[:, 0:W]
-    minp = jnp.zeros((B, W), jnp.int32)
+    pick = jnp.zeros((B, W), jnp.int32)
     for j in range(1, w):
         cand = h[:, j:j + W]
         take = cand < minv
         minv = jnp.where(take, cand, minv)
-        minp = jnp.where(take, jnp.int32(j), minp)
-    minp = minp + pos[None, :W]
+        pick = jnp.where(take, jnp.int32(j), pick)
     wvalid = (pos[None, :W] < nwin[:, None]) \
         & (pos[None, :W] + (w + k - 1) <= lens[:, None]) \
         & (minv != jnp.uint32(_HASH_MAX))
-    # scatter each window's pick; invalid windows park on the P slot
-    tgt = jnp.where(wvalid, minp, jnp.int32(P))
-    sel = jnp.zeros((B, P + 1), jnp.bool_)
-    sel = sel.at[jnp.arange(B, dtype=jnp.int32)[:, None], tgt].set(True)
-    return h, strand, sel[:, :P]
+    # slot p is selected when a valid window j slots to its left picked
+    # offset j: w shifted compares, no scatter (with a scatter of the
+    # arena's 4 M picks this program took the chip's compiler 15.7 s,
+    # on the feeding thread's path; PR 34)
+    pick = jnp.where(wvalid, pick, jnp.int32(-1))
+    sel = jnp.zeros((B, P), jnp.bool_)
+    for j in range(w):
+        sel = sel | jnp.pad(pick == j, ((0, 0), (j, w - 1 - j)))
+    return h, strand, sel, jnp.sum(sel.astype(jnp.int32), axis=1)
 
 
 @jax.jit
@@ -176,15 +165,17 @@ def _compact_kernel(h, strand, sel):
 
 def _iter_chunks(seqs: List[bytes], k: int, w: int
                  ) -> Iterator[Tuple[int, int, bytes, int]]:
-    """``(seq_id, window_start_offset, byte_slice, n_windows)`` chunks:
-    whole short sequences, bounded overlapping slices of long ones."""
+    """``(seq_id, window_start_offset, byte_slice, n_windows)`` chunks
+    in ``(seq_id, offset)`` order: whole short sequences, bounded
+    overlapping slices of those longer than a row."""
+    span = _slice_starts(k, w)
     for sid, s in enumerate(seqs):
         L = len(s)
         if L < k + w - 1:
             continue  # no complete window fits
         n_total = L - (k + w - 1) + 1
-        for s0 in range(0, n_total, SEED_SLICE):
-            n_here = min(SEED_SLICE, n_total - s0)
+        for s0 in range(0, n_total, span):
+            n_here = min(span, n_total - s0)
             end = min(L, s0 + n_here + (k + w - 2))
             yield sid, s0, s[s0:end], n_here
 
@@ -252,64 +243,63 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
             metrics.inc("dataflow.bytes_avoided", int(hit[0].size) * 10)
             return hit
         metrics.inc("overlap.cache_misses")
-    by_bucket: dict = {}
-    for chunk in _iter_chunks(seqs, k, w):
-        by_bucket.setdefault(_len_bucket(len(chunk[2])), []).append(chunk)
+    chunks = list(_iter_chunks(seqs, k, w))
+    B, L = SEED_BATCH, SEED_ROW
 
     hs: List[np.ndarray] = []
     ids: List[np.ndarray] = []
     ps: List[np.ndarray] = []
     ss: List[np.ndarray] = []
-    for L in sorted(by_bucket):
-        chunks = by_bucket[L]
-        B_cap = _seed_batch(L, len(chunks))
-        for begin in range(0, len(chunks), B_cap):
-            part = chunks[begin:begin + B_cap]
-            B = _seed_batch(L, len(part))
-            codes = np.full((B, L), 4, np.uint8)
-            lens = np.zeros(B, np.int32)
-            nwin = np.zeros(B, np.int32)
-            for i, (_, _, blob, n_here) in enumerate(part):
-                arr = _BASE_LUT[np.frombuffer(blob, np.uint8)]
-                codes[i, :arr.size] = arr
-                lens[i] = arr.size
-                nwin[i] = n_here
-            with obs.span("overlap.seed.dispatch", rows=len(part)):
-                # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the pow2 bucket)
-                h, strand, sel = _minimizer_kernel(codes, lens, nwin,
-                                                   k=k, w=w, L=L)
-                if resident:
-                    h, row, pcol, strand, total = _compact_kernel(
-                        h, strand, sel)
+    for begin in range(0, len(chunks), B):
+        part = chunks[begin:begin + B]
+        codes = np.full((B, L), 4, np.uint8)
+        lens = np.zeros(B, np.int32)
+        nwin = np.zeros(B, np.int32)
+        for i, (_, _, blob, n_here) in enumerate(part):
+            arr = _BASE_LUT[np.frombuffer(blob, np.uint8)]
+            codes[i, :arr.size] = arr
+            lens[i] = arr.size
+            nwin[i] = n_here
+        with obs.span("overlap.seed.dispatch", rows=len(part)):
+            codes_d = jnp.asarray(codes)
+            device_time.submit("h2d", "overlap.seed.put", codes_d)
+            # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the one row length)
+            h, strand, sel, nsel = _minimizer_kernel(codes_d, lens, nwin,
+                                                     k=k, w=w, L=L)
+            device_time.submit("exec", "_minimizer_kernel", nsel)
             if resident:
-                with obs.span("overlap.seed.fetch", rows=len(part)):
-                    n_host = fetch_global([total])[0]
-                    n = int(n_host)
-                    h_np, rows, cols, s_np = fetch_global(
-                        [h[:n], row[:n], pcol[:n], strand[:n]])
-                fetched = n * 10  # 4 + 4 + 1 + 1 bytes per entry
-                metrics.inc("dataflow.bytes_fetched", fetched)
-                metrics.inc("dataflow.bytes_avoided",
-                            max(0, B * (L - k + 1) * 6 - fetched))
-            else:
-                with obs.span("overlap.seed.fetch", rows=len(part)):
-                    h_full, sel_np, s_full = fetch_global(
-                        [h, sel, strand])
-                rows, cols = np.nonzero(sel_np)
-                h_np = h_full[rows, cols]
-                s_np = s_full[rows, cols]
-            keep = h_np != np.uint32(_HASH_MAX)
-            rows, cols = rows[keep], cols[keep]
-            chunk_ids = np.fromiter((c[0] for c in part), np.int32,
-                                    len(part))
-            chunk_off = np.fromiter((c[1] for c in part), np.int32,
-                                    len(part))
-            hs.append(h_np[keep])
-            ids.append(chunk_ids[rows])
-            ps.append(chunk_off[rows] + cols.astype(np.int32))
-            ss.append(np.asarray(s_np)[keep])
-            metrics.inc("overlap.seed_lanes_total", B * L)
-            metrics.inc("overlap.seed_lanes_occupied", int(lens.sum()))
+                h, row, pcol, strand, total = _compact_kernel(
+                    h, strand, sel)
+                device_time.submit("exec", "_compact_kernel", total)
+        if resident:
+            with obs.span("overlap.seed.fetch", rows=len(part)):
+                n_host = fetch_global([total])[0]
+                n = int(n_host)
+                h_np, rows, cols, s_np = fetch_global(
+                    [h[:n], row[:n], pcol[:n], strand[:n]])
+            fetched = n * 10  # 4 + 4 + 1 + 1 bytes per entry
+            metrics.inc("dataflow.bytes_fetched", fetched)
+            metrics.inc("dataflow.bytes_avoided",
+                        max(0, B * (L - k + 1) * 6 - fetched))
+        else:
+            with obs.span("overlap.seed.fetch", rows=len(part)):
+                h_full, sel_np, s_full = fetch_global(
+                    [h, sel, strand])
+            rows, cols = np.nonzero(sel_np)
+            h_np = h_full[rows, cols]
+            s_np = s_full[rows, cols]
+        keep = h_np != np.uint32(_HASH_MAX)
+        rows, cols = rows[keep], cols[keep]
+        chunk_ids = np.fromiter((c[0] for c in part), np.int32,
+                                len(part))
+        chunk_off = np.fromiter((c[1] for c in part), np.int32,
+                                len(part))
+        hs.append(h_np[keep])
+        ids.append(chunk_ids[rows])
+        ps.append(chunk_off[rows] + cols.astype(np.int32))
+        ss.append(np.asarray(s_np)[keep])
+        metrics.inc("overlap.seed_lanes_total", B * L)
+        metrics.inc("overlap.seed_lanes_occupied", int(lens.sum()))
     if not hs:
         z = np.zeros(0, np.int32)
         table = (np.zeros(0, np.uint32), z, z, np.zeros(0, bool))
@@ -320,15 +310,23 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
     id_all = np.concatenate(ids)
     p_all = np.concatenate(ps)
     s_all = np.concatenate(ss)
-    # canonical (seq_id, pos) order, deduping the one legitimate repeat
-    # source: a position selected by windows on both sides of a slice
-    # boundary emits once per slice
-    order = np.lexsort((p_all, id_all))
-    h_all, id_all, p_all, s_all = (h_all[order], id_all[order],
-                                   p_all[order], s_all[order])
+    # canonical (seq_id, pos) order. Rows come in (seq_id, offset)
+    # order and a window's minimizer never lies left of the previous
+    # window's, so the walk above is that order already; the one
+    # repeat — a position picked by windows on both sides of a slice
+    # boundary emits once per slice — sits beside its twin
+    key = (id_all.astype(np.int64) << 32) | p_all
+    if key.size > 1 and not bool(np.all(key[1:] >= key[:-1])):
+        order = np.argsort(key, kind="stable")
+        h_all, id_all, p_all, s_all = (h_all[order], id_all[order],
+                                       p_all[order], s_all[order])
+        key = key[order]
     uniq = np.ones(h_all.size, bool)
-    uniq[1:] = (id_all[1:] != id_all[:-1]) | (p_all[1:] != p_all[:-1])
-    table = (h_all[uniq], id_all[uniq], p_all[uniq], s_all[uniq])
+    uniq[1:] = key[1:] != key[:-1]
+    if not uniq.all():
+        h_all, id_all, p_all, s_all = (h_all[uniq], id_all[uniq],
+                                       p_all[uniq], s_all[uniq])
+    table = (h_all, id_all, p_all, s_all)
     metrics.inc("overlap.minimizers", int(table[0].size))
     if ckey is not None:
         _table_cache_put(ckey, table)
@@ -349,15 +347,11 @@ _warmed_shapes: set = set()
 
 
 def _warmup_shapes(est_len: int, est_seqs: int) -> List[Tuple[int, int]]:
-    """The ``(L, B)`` batch geometries a run over ``est_seqs`` sequences
-    of roughly ``est_len`` bases dispatches — derived with the same
-    :func:`_len_bucket` / :func:`_seed_batch` quantizers the driver
-    uses (ONE source of truth, consumed by :func:`warmup_async`)."""
+    """The ``(L, B)`` batch geometries a run dispatches: the one arena,
+    whatever the estimates (zero estimates: nothing to warm)."""
     if est_len <= 0 or est_seqs <= 0:
         return []
-    chunk_len = min(est_len, SEED_SLICE + DEFAULT_K + DEFAULT_W - 2)
-    L = _len_bucket(chunk_len)
-    return [(L, _seed_batch(L, est_seqs))]
+    return [(SEED_ROW, SEED_BATCH)]
 
 
 def warmup_async(est_len: int, est_seqs: int,
@@ -379,7 +373,9 @@ def warmup_async(est_len: int, est_seqs: int,
         ones = np.ones(B, np.int32)
         # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the pow2 bucket)
         out = _minimizer_kernel(codes, ones, ones, k=kk, w=ww, L=L)
-        jax.block_until_ready(out[0])
+        # the dummy occupies the device like any program: kind "warm"
+        device_time.submit("warm", "_minimizer_kernel", out[3])
+        jax.block_until_ready(out[3])
 
     def _run():
         for L, B, kk, ww in shapes:
@@ -398,44 +394,3 @@ def warmup_async(est_len: int, est_seqs: int,
                           name="racon-seed-warmup")
     th.start()
     return th
-
-
-# --------------------------------------------------------- numpy oracle
-
-def minimizers_np(seq: bytes, k: int = DEFAULT_K, w: int = DEFAULT_W
-                  ) -> List[Tuple[int, int, int]]:
-    """Pure-numpy single-sequence oracle: sorted-by-position
-    ``(hash, pos, strand)`` triples with exactly the kernel's
-    semantics (canonical min, fmix32, palindrome/ambiguity skips,
-    leftmost strict-< window minimum)."""
-    codes = _BASE_LUT[np.frombuffer(seq, np.uint8)]
-    L = codes.size
-    if L < k + w - 1:
-        return []
-    P = L - k + 1
-    f = np.zeros(P, np.uint32)
-    r = np.zeros(P, np.uint32)
-    bad = np.zeros(P, bool)
-    for j in range(k):
-        c = codes[j:j + P].astype(np.uint32)
-        bad |= c > 3
-        cc = c & np.uint32(3)
-        f = (f << np.uint32(2)) | cc
-        r = (r >> np.uint32(2)) | ((np.uint32(3) - cc)
-                                   << np.uint32(2 * (k - 1)))
-    strand = r < f
-    h = np.minimum(f, r)
-    h = h ^ (h >> np.uint32(16))
-    h = h * np.uint32(0x85EBCA6B)
-    h = h ^ (h >> np.uint32(13))
-    h = h * np.uint32(0xC2B2AE35)
-    h = h ^ (h >> np.uint32(16))
-    h = np.where(bad | (f == r), np.uint32(_HASH_MAX), h)
-    sel = np.zeros(P, bool)
-    for s in range(P - w + 1):
-        win = h[s:s + w]
-        m = int(win.min())
-        if m != _HASH_MAX:
-            sel[s + int(np.argmax(win == m))] = True
-    return [(int(h[p]), int(p), int(strand[p]))
-            for p in np.flatnonzero(sel)]

@@ -223,3 +223,53 @@ def test_consensus_vote_kernel_compiles(chip, band):
     _compile(pallas_nw.pallas_nw_fwd.lower(
         rows, rows, lens, lens, max_len=Lq, band=band_, steps=steps,
         use_swar=True))
+
+
+# ------------------------------------------------------ the overlapper
+
+def test_overlapper_minimizer_program_compiles(chip):
+    """The one minimizer arena (PR 34: every input seeds through this
+    single geometry)."""
+    from racon_tpu.ops import overlap_seed as seed
+    B, L = seed.SEED_BATCH, seed.SEED_ROW
+    _, _, total = _compile(seed._minimizer_kernel.lower(
+        chip((B, L), jnp.uint8), chip((B,), jnp.int32),
+        chip((B,), jnp.int32), k=15, w=5, L=L))
+    assert total < HBM_BYTES // 8
+
+
+def test_overlapper_join_programs_fit_the_chip(chip):
+    """The device join at the tables of a 30x 2 Mbp read set (20 M read
+    minimizers pad to 2^25, the draft's 0.67 M to 2^20 under a directory
+    of 2^21 buckets; 1.2 M hits pad to 2^21): the look-up, the bucket
+    counts and the ramp in one program, then the expansion. Neither
+    holds a sort (PR 34: the sorts they replaced took this compiler 70,
+    44 and 198 s)."""
+    from racon_tpu.ops import chain
+    R2, T2 = chain._table_pad(20_000_000), chain._table_pad(670_000)
+    assert R2 + T2 <= chain.JOIN_TABLE_CELLS
+    u32, i32 = jnp.uint32, jnp.int32
+    ramp, _, total = _compile(chain._join_ramp_kernel.lower(
+        chip((R2,), u32), chip((T2,), u32), chip((T2,), i32),
+        chip((2 * T2 + 1,), i32), chip((), i32),
+        steps=chain.JOIN_BUCKET_STEPS))
+    assert total < HBM_BYTES // 2
+    assert "sort" not in ramp.as_text().lower()
+    E, Q2 = chain._hits_pad(1_210_178), chain._table_pad(8571)
+    _, _, total = _compile(chain._join_expand_kernel.lower(
+        chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
+        chip((T2,), i32), chip((T2,), i32), chip((T2,), i32),
+        chip((T2,), i32),
+        chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
+        chip((), i32), chip((Q2,), i32), chip((Q2,), i32), E=E, k=15))
+    assert total < HBM_BYTES // 2
+
+
+@pytest.mark.parametrize("S", [16, 64, 256, 1024])
+def test_overlapper_chain_program_compiles(chip, S):
+    from racon_tpu.ops import chain
+    B = chain._pair_batch(S)
+    _, _, total = _compile(chain._chain_kernel.lower(
+        chip((B, S), jnp.int32), chip((B, S), jnp.int32),
+        chip((B,), jnp.int32), S=S, k=15))
+    assert total < HBM_BYTES // 8

@@ -485,6 +485,26 @@ def test_warmup_async_compiles_and_engine_still_exact():
         assert a.consensus == b.consensus
 
 
+def test_drain_warmup_ends_the_background_compile_with_its_job():
+    """A job that started a warm-up compile ends only when the compile
+    has (PR 34: left running it was charged to the process's next job);
+    the polisher drains it at the end of every job."""
+    import inspect
+    import threading
+    import time
+
+    from racon_tpu.core.polisher import Polisher
+    from racon_tpu.ops.poa import TpuPoaConsensus
+
+    eng = TpuPoaConsensus(3, -5, -4)
+    eng.drain_warmup()                      # nothing started: returns
+    eng._warmup = threading.Thread(target=time.sleep, args=(0.3,))
+    eng._warmup.start()
+    eng.drain_warmup()
+    assert not eng._warmup.is_alive()
+    assert "drain_warmup" in inspect.getsource(Polisher._stitch)
+
+
 def test_warmup_skipped_for_empty_estimates():
     from racon_tpu.ops.poa import TpuPoaConsensus
 

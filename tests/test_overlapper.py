@@ -22,6 +22,7 @@ from racon_tpu.exec import ShardRunner
 from racon_tpu.exec.index import build_index_readsonly, write_auto_paf
 from racon_tpu.exec.planner import estimate_job_cost
 from racon_tpu.io import parsers
+from racon_tpu.models import overlap as reference
 from racon_tpu.obs import metrics
 from racon_tpu.ops import chain, overlap_seed
 
@@ -62,7 +63,7 @@ def test_minimizer_matches_numpy_oracle():
             got = table_rows(overlap_seed.build_seed_table(
                 [seq], k=k, w=w))
             want = [(0, p, h, bool(s))
-                    for h, p, s in overlap_seed.minimizers_np(seq, k, w)]
+                    for h, p, s in reference.minimizers_np(seq, k, w)]
             assert got == want, (k, w, trial, n)
 
 
@@ -73,8 +74,8 @@ def test_minimizer_strand_canonical():
     rng = np.random.default_rng(12)
     k, w = 15, 5
     seq = rand_seq(rng, 1200)
-    fwd = overlap_seed.minimizers_np(seq, k, w)
-    rev = overlap_seed.minimizers_np(revcomp(seq), k, w)
+    fwd = reference.minimizers_np(seq, k, w)
+    rev = reference.minimizers_np(revcomp(seq), k, w)
     L = len(seq)
     # windowed selection differs at the edges, but every interior
     # minimizer must appear mirrored; compare the intersection both ways
@@ -88,12 +89,12 @@ def test_minimizer_strand_canonical():
 def test_minimizer_slice_boundary_dedup(monkeypatch):
     """Long sequences are seeded in bounded overlapping slices; a
     minimizer selected by windows on both sides of a slice boundary
-    must emit ONCE. Shrinking SEED_SLICE forces many boundaries through
-    a short sequence so the dedup is exercised cheaply."""
+    must emit ONCE. Shrinking the arena's row forces many boundaries
+    through a short sequence so the dedup is exercised cheaply."""
     rng = np.random.default_rng(13)
     seq = rand_seq(rng, 700)
     want = table_rows(overlap_seed.build_seed_table([seq]))
-    monkeypatch.setattr(overlap_seed, "SEED_SLICE", 64)
+    monkeypatch.setattr(overlap_seed, "SEED_ROW", 96)
     got = table_rows(overlap_seed.build_seed_table([seq]))
     assert got == want
 
@@ -124,8 +125,8 @@ def test_chain_kernel_matches_numpy_oracle():
     chained span)."""
     rng = np.random.default_rng(21)
     k = 15
-    for S in (16, 32):
-        B = chain._pair_batch(S, 3)
+    for S in (16, 64):
+        B = chain._pair_batch(S)
         ts = np.zeros((B, S), np.int32)
         qs = np.zeros((B, S), np.int32)
         ns = np.zeros(B, np.int32)
@@ -137,7 +138,7 @@ def test_chain_kernel_matches_numpy_oracle():
         out = np.asarray(chain._chain_kernel(ts, qs, ns, S=S, k=k))
         for lane in range(3):
             n = int(ns[lane])
-            want = chain.chain_np(ts[lane, :n], qs[lane, :n], k)
+            want = reference.chain_np(ts[lane, :n], qs[lane, :n], k)
             assert out[lane].tolist() == list(want), (S, lane)
 
 
@@ -185,10 +186,10 @@ def test_freq_cap_accounting():
     tt = overlap_seed.build_seed_table(reads)
     self_t = np.full(12, -1, np.int64)
     qlens = np.full(12, 400, np.int64)
-    hits, capped = chain.match_seeds(rt, tt, self_t, qlens,
+    hits, capped = reference.match_seeds(rt, tt, self_t, qlens,
                                      k=15, max_occ=4)
     assert capped > 0 and hits["q"].size == 0
-    hits2, capped2 = chain.match_seeds(rt, tt, self_t, qlens,
+    hits2, capped2 = reference.match_seeds(rt, tt, self_t, qlens,
                                        k=15, max_occ=64)
     assert capped2 == 0 and hits2["q"].size > 0
 
@@ -249,7 +250,7 @@ def test_device_join_matches_oracle():
                           rng.integers(0, n_targets, n_reads),
                           -1).astype(np.int64)
         qlens = rng.integers(4100, 6000, n_reads).astype(np.int64)
-        want, capped_w = chain.match_seeds(rt, tt, self_t, qlens,
+        want, capped_w = reference.match_seeds(rt, tt, self_t, qlens,
                                            k=15, max_occ=max_occ)
         got, capped_g = chain.join_seeds(rt, tt, self_t, qlens, k=15,
                                          max_occ=max_occ,
@@ -262,18 +263,19 @@ def test_device_join_matches_oracle():
 
 
 def test_device_join_resident_layout():
-    """Under ``resident=True`` the join keeps the matched seed
-    coordinates on device (``tp_dev``/``qc_dev``); their valid prefix
-    must equal the oracle's host ``tp``/``qc`` columns."""
+    """Under ``resident=True`` the join also hands the matched seed
+    coordinates over on the device (``tp_dev``/``qc_dev``) for the chain
+    stream's device gather; they must equal the oracle's host
+    ``tp``/``qc`` columns."""
     rng = np.random.default_rng(32)
     rt = rand_table(rng, 6, 400, 150)
     tt = rand_table(rng, 3, 400, 150)
     self_t = np.full(6, -1, np.int64)
     qlens = np.full(6, 5000, np.int64)
-    want, _ = chain.match_seeds(rt, tt, self_t, qlens, k=15, max_occ=32)
+    want, _ = reference.match_seeds(rt, tt, self_t, qlens, k=15, max_occ=32)
     got, _ = chain.join_seeds(rt, tt, self_t, qlens, k=15, max_occ=32,
                               device_join=True, resident=True)
-    assert "tp_dev" in got and "qc_dev" in got and "tp" not in got
+    assert "tp_dev" in got and "qc_dev" in got
     n = got["q"].size
     assert n == want["q"].size > 0
     assert np.array_equal(np.asarray(got["tp_dev"])[:n].astype(np.int64),
@@ -312,7 +314,7 @@ def test_chain_stream_feed_batching_invariance():
     tt = overlap_seed.build_seed_table([target])
     self_t = np.full(len(reads), -1, np.int64)
     qlens = np.fromiter((len(r) for r in reads), np.int64, len(reads))
-    hits, _ = chain.match_seeds(rt, tt, self_t, qlens, k=15, max_occ=64)
+    hits, _ = reference.match_seeds(rt, tt, self_t, qlens, k=15, max_occ=64)
     starts, _, counts = chain._pair_runs(hits)
     jobs = [(p, int(starts[p]), int(counts[p]))
             for p in range(starts.size)]
@@ -404,7 +406,7 @@ def test_warmup_shape_cache():
 
     before_c = len(chain._warmed_shapes)
     ladder = chain._warmup_shapes(24, 5)
-    assert 1 <= len(ladder) <= 4
+    assert 1 <= len(ladder) <= 3
     th_c = chain.warmup_async(24, 5, k=9)
     assert th_c is not None
     th_c.join(60.0)
