@@ -5,7 +5,8 @@
 # subprocess — with the ASan runtime preloaded, since CPython itself is
 # not ASan-built — exercises the two threaded/streaming paths with the
 # ugliest memory behaviour: the bp.cpp thread-pool breaking-points
-# decoder and the chunked-inflate gzip sequence parser. Any heap
+# decoder, the chunked-inflate gzip sequence parser and the lane-block
+# row copier (lanes.cpp: a memcpy per row). Any heap
 # overflow / UB the sanitizers see aborts the process (UBSan runs with
 # -fno-sanitize-recover), failing this check. Skips cleanly when the
 # toolchain has no ASan runtime.
@@ -64,6 +65,21 @@ recs = native.parse_seqfile(tmp, True)
 assert len(recs) == 20 and recs[0][1] == long_seq
 pathlib.Path(tmp).unlink()
 print("streaming gzip parser under ASan/UBSan: ok", file=sys.stderr)
+
+# 3) lanes.cpp: the consensus lane-block row copier (a memcpy per row):
+#    a row that ends at the pool's last lane, one cut at Lq, an empty
+#    one sitting at len(pool), and the last row of the block
+import numpy as np
+
+pool = np.arange(1000, dtype=np.uint16)
+out = np.zeros((4, 64), np.uint16)
+native.copy_lane_rows(pool, np.array([990, 0, 1000, 936]),
+                      np.array([10, 500, 0, 64]), np.array([3, 0, 1, 2]),
+                      out)
+assert out[3, :10].tolist() == list(range(990, 1000))
+assert not out[3, 10:].any() and not out[1].any()
+assert out[0].tolist() == list(range(64)) and out[2, -1] == 999
+print("lane-block row copier under ASan/UBSan: ok", file=sys.stderr)
 PY
 
 echo "native sanitize: OK"

@@ -79,6 +79,9 @@ METRICS: FrozenSet[str] = frozenset((
     "consensus.groups", "consensus.ins_overflow",
     "consensus.ins_overflow_windows", "consensus.lanes_occupied",
     "consensus.lanes_total", "consensus.pallas_groups",
+    # pair rows whose lanes came from a columnar store, and those of
+    # them the native row copier wrote into the group's block
+    "consensus.lane_rows", "consensus.lane_rows_copied",
     "consensus.swar_guard_int32",
     "consensus.sweep_truncated", "consensus.wavefront_steps",
     # device-resident align->consensus dataflow
@@ -191,10 +194,16 @@ SPANS: FrozenSet[str] = frozenset((
     "parse.overlaps", "parse.reads", "parse.targets",
     "poa.dispatch", "poa.fetch", "poa.pack", "poa.stage_b",
     # leaves of poa.pack / poa.fetch
-    "poa.put", "poa.wait", "poa.get", "poa.decode",
+    "poa.put", "poa.lanes", "poa.wait", "poa.get", "poa.decode",
     "queue.get", "queue.put",
     "stitch", "transmute",
 ))
+
+# leaves that time a stretch of their parent and take none of its device
+# idle: the occupancy ledger (obs/device_time.py) reads through them, so
+# the parent's ``idle.<span>`` timer, and every metric that sums it, is
+# what it was before the leaf existed
+TIMER_ONLY_SPANS: FrozenSet[str] = frozenset(("poa.lanes",))
 
 # ------------------------------------------------------------ fault sites
 

@@ -9,10 +9,11 @@ module removes it. ``Polisher._assemble_layers`` builds a single
 orientation plus flat per-layer ``(src, length, begin, end, win_id)``
 arrays — and attaches each covered window an O(1) ``(store, row range)``
 view. Window assembly becomes pure index arithmetic, and the consensus
-packers build their device buffers with **one vectorized gather per
-group** (:meth:`LayerStore.gather_qpw`) straight from the precomputed
-packed ``weight << 3 | code`` pool, instead of re-deriving codes and
-weights from thousands of small bytes objects per pack.
+packers write their device buffers **once, by one row copy per layer**
+(:meth:`LayerStore.gather_qpw`: a native memcpy per pair row into the
+group's own block) straight from the precomputed packed
+``weight << 3 | code`` pool, instead of re-deriving codes and weights
+from thousands of small bytes objects per pack.
 
 The CPU engines (and any direct ``window.sequences`` consumer) see the
 exact bytes they always did: :class:`~racon_tpu.core.window.Window`
@@ -28,6 +29,8 @@ from __future__ import annotations
 from typing import List, Optional, Sequence
 
 import numpy as np
+
+from .. import native
 
 _CODE_LUT = np.full(256, 4, dtype=np.uint8)  # non-ACGT -> N code (4)
 for _i, _b in enumerate(b"ACGT"):
@@ -155,11 +158,11 @@ class LayerStore:
         hq_ov = np.fromiter((q is not None for q in qual_refs), bool, n_ov)
 
         # packed device lanes for the WHOLE pool, once (the per-group
-        # packer gather then reads finished uint16 lanes), and a span's
-        # quality sum as qsum[end] - qsum[begin]: unsigned sums wrap, and
-        # the difference is exact while one span's true sum fits the
-        # dtype (255 * the longest read bounds it). Both written in
-        # place, slice by slice: no temporary the size of the pool
+        # packer then copies finished uint16 lanes, row by row), and a
+        # span's quality sum as qsum[end] - qsum[begin]: unsigned sums
+        # wrap, and the difference is exact while one span's true sum
+        # fits the dtype (255 * the longest read bounds it). Both written
+        # in place, slice by slice: no temporary the size of the pool
         part_len = np.fromiter(map(len, parts), np.int64, len(parts))
         part_off = np.cumsum(part_len) - part_len
         has_q = np.asarray(part_hq, bool)
@@ -190,17 +193,33 @@ class LayerStore:
 
     # ------------------------------------------------------ device packing
 
-    def gather_qpw(self, rows: np.ndarray, Lq: int) -> np.ndarray:
-        """One vectorized gather: the packed ``weight << 3 | code``
-        uint16 lane block [len(rows), Lq] for the given layer rows —
-        exactly the array ``TpuPoaConsensus._pack_shard`` ships to the
-        device (rows shorter than ``Lq`` zero-padded)."""
-        lens = self.length[rows]
-        pos = np.arange(Lq, dtype=np.int64)[None, :]
-        valid = pos < lens[:, None]
-        srcs = (self.src[rows][:, None]
-                + np.minimum(pos, np.maximum(lens[:, None] - 1, 0)))
-        return np.where(valid, self.qpw_pool[srcs], 0).astype(np.uint16)
+    def gather_qpw(self, rows: np.ndarray, Lq: int,
+                   out: Optional[np.ndarray] = None,
+                   dest: Optional[np.ndarray] = None) -> np.ndarray:
+        """The packed ``weight << 3 | code`` uint16 lane block
+        [len(rows), Lq] for the given layer rows — exactly the array
+        ``TpuPoaConsensus._pack_shard`` ships to the device (a row
+        shorter than ``Lq`` zero-padded, a longer one cut at ``Lq``).
+
+        With ``out`` (a zeroed C-contiguous [B, Lq] uint16 block) and
+        ``dest``, layer ``rows[i]`` is written into ``out[dest[i]]`` and
+        ``out`` is returned: the packer's block is written once, in
+        place. Either way the lanes land by row copies — the native
+        copier's memcpys, or per-row slice assignments where the native
+        core is not available — never through a [rows, Lq] index
+        matrix."""
+        src, lens = self.src[rows], self.length[rows]
+        if out is None:
+            out = np.zeros((len(src), Lq), np.uint16)
+            dest = np.arange(len(src))
+        if native.available():
+            native.copy_lane_rows(self.qpw_pool, src, lens, dest, out)
+        else:
+            pool = self.qpw_pool
+            for d, s, n in zip(np.asarray(dest).tolist(), src.tolist(),
+                               np.minimum(lens, Lq).tolist()):
+                out[d, :n] = pool[s:s + n]
+        return out
 
     # ---------------------------------------------------- materialization
 

@@ -5,10 +5,11 @@ cached next to the sources and rebuilt when any .cpp is newer.
 
 ``RACON_TPU_NATIVE_SANITIZE=1`` selects an ASan/UBSan build instead
 (``-fsanitize=address,undefined``, separate cached .so): the CI smoke
-``ci/checks/native_sanitize.sh`` runs the bp.cpp thread-pool decoder and
-the streaming gzip parser under it. Loading the sanitized object needs
-the ASan runtime preloaded (``LD_PRELOAD=$(g++ -print-file-name=
-libasan.so)``), so the variant is chosen per process at first load.
+``ci/checks/native_sanitize.sh`` runs the bp.cpp thread-pool decoder, the
+streaming gzip parser and the lanes.cpp row copier under it. Loading the
+sanitized object needs the ASan runtime preloaded (``LD_PRELOAD=$(g++
+-print-file-name=libasan.so)``), so the variant is chosen per process at
+first load.
 """
 
 from __future__ import annotations
@@ -223,6 +224,12 @@ def load():
         ctypes.POINTER(ctypes.c_int64), ctypes.c_int64, ctypes.c_int64,
         ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_int64)]
+    lib.rt_copy_lane_rows.restype = None
+    lib.rt_copy_lane_rows.argtypes = [
+        ctypes.c_int64, ctypes.POINTER(ctypes.c_uint16),
+        ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
+        ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_uint16)]
     _lib = lib
     return _lib
 
@@ -378,6 +385,41 @@ def bp_from_cigar_batch(cigars, q_offs, t_begins, t_ends,
         counts.ctypes.data_as(i64p))
     return [out[int(offs[i]) * 4: (int(offs[i]) + int(counts[i])) * 4]
             .reshape(-1, 4) for i in range(count)]
+
+
+def copy_lane_rows(pool, src, length, dest, out) -> None:
+    """Write the consensus lane block by row copies: for row ``r``,
+    ``min(length[r], Lq)`` uint16 lanes from ``pool[src[r]:]`` into
+    ``out[dest[r]]`` (``out`` a C-contiguous ``[B, Lq]`` uint16 block the
+    caller zeroed: lanes past a row's length are left alone). A row that
+    reaches outside the pool or the block raises IndexError before
+    anything is written, as the fancy-indexed numpy gather did."""
+    import numpy as np
+
+    lib = load()
+    if lib is None:
+        raise NativeBuildError("native library unavailable")
+    if (pool.dtype != np.uint16 or out.dtype != np.uint16 or out.ndim != 2
+            or not pool.flags.c_contiguous or not out.flags.c_contiguous):
+        raise ValueError("copy_lane_rows wants C-contiguous uint16 arrays")
+    src = np.ascontiguousarray(src, dtype=np.int64)
+    dest = np.ascontiguousarray(dest, dtype=np.int64)
+    length = np.minimum(np.asarray(length, np.int64), out.shape[1])
+    count = len(src)
+    if not len(length) == len(dest) == count:
+        raise ValueError("copy_lane_rows: one src, length and dest a row")
+    if not count:
+        return
+    if (src.min() < 0 or length.min() < 0
+            or (src + length).max() > len(pool)
+            or dest.min() < 0 or dest.max() >= out.shape[0]):
+        raise IndexError("lane row outside the pool or the block")
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    u16p = ctypes.POINTER(ctypes.c_uint16)
+    lib.rt_copy_lane_rows(
+        count, pool.ctypes.data_as(u16p), src.ctypes.data_as(i64p),
+        length.ctypes.data_as(i64p), dest.ctypes.data_as(i64p),
+        out.shape[1], out.ctypes.data_as(u16p))
 
 
 def parse_seqfile(path: str, is_fastq: bool):

@@ -35,8 +35,9 @@ submission ended it (head: the thread of the first submission; tail:
 the thread that writes the report; after a ``warm`` row: the thread of
 the next real submission) and cut by that thread's innermost
 spans open in it, from the span rings (:mod:`.trace`); time in no span
-goes to ``unattributed``. The result is the run report's
-``device_time`` section and the timers ``idle.<span>`` /
+goes to ``unattributed``. A leaf in ``contracts.TIMER_ONLY_SPANS`` is
+read through: its stretch stays its parent's. The result is the run
+report's ``device_time`` section and the timers ``idle.<span>`` /
 ``idle.unattributed``, which by construction sum to the idle seconds.
 
 All stamps are ``time.perf_counter_ns()``, the spans' clock; the
@@ -53,6 +54,7 @@ import time
 from typing import Dict, List
 
 from . import metrics, trace
+from .. import contracts
 
 MAX_ENTRIES = 1 << 16       # ledger bound (a long-lived server); oldest go
 TIMELINE_ROWS = 256         # rows a report carries; the rest are counted
@@ -290,8 +292,10 @@ def account(rows: list, spans: dict, start_ns: int, end_ns: int,
 
     def flat(thread):
         if thread not in flats:
-            flats[thread] = _flatten(spans.get(thread, ()), start_ns,
-                                     end_ns)
+            flats[thread] = _flatten(
+                [ev for ev in spans.get(thread, ())
+                 if ev[0] not in contracts.TIMER_ONLY_SPANS],
+                start_ns, end_ns)
         return flats[thread]
 
     by_device: Dict[str, list] = {}

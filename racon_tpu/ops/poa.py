@@ -83,7 +83,7 @@ from jax import lax
 
 from .nw import _nw_wavefront_kernel, _walk_ops_kernel
 from .pallas_nw import PallasDispatchMixin
-from .. import faults, flags, obs, sanitize
+from .. import faults, flags, native, obs, sanitize
 from ..core.window import WindowType
 from ..obs import device_time, metrics
 
@@ -100,8 +100,9 @@ GROW = 256
 # fixed per-batch memory, cudapolisher.cpp:219-228). Every group costs
 # one dispatch and one blocking host fetch, a per-group overhead larger
 # groups amortize; the vote accumulation's MXU matmul grows with
-# B x n_windows. The value predates the current host link and is to be
-# re-measured on the chip (ROADMAP S4).
+# B x n_windows. The value predates the current host link and has not
+# been re-measured on the chip; the device stays empty while the first
+# group of this size is packed (ROADMAP S6: first-group latency).
 MAX_GROUP_PAIRS = 32768
 # Ragged-packing lane arena (round 10, the cudabatch greedy batch-fill
 # analog, SURVEY §L3): a group greedy-fills windows until its pair rows
@@ -909,7 +910,7 @@ class _Work:
     (``win.layer_view`` attached by the polisher) keep ``rows`` indices
     into the shared :class:`~racon_tpu.core.layers.LayerStore` plus the
     store's flat ``lens``/``begin``/``end`` slices — the packer then
-    builds the whole group's lane block with one vectorized pool gather;
+    writes the whole group's lane block by one row copy per layer;
     hand-built windows (``add_layer``) keep the legacy bytes tuples and
     pack through the join-and-LUT path."""
 
@@ -1857,23 +1858,18 @@ class TpuPoaConsensus(PallasDispatchMixin):
         zeros. Otherwise the third return is None.
         """
         n = np.ones(B, np.int32)
-        # packed layer lanes: weight << 3 | code per base (codes 3 bits,
-        # phred weights <= 93 in 7) — codes and weights travel as ONE
-        # uint16 array, the format both vote emitters consume directly
-        qpw = np.zeros((B, Lq), np.uint16)
         bg = np.zeros(B, np.int32)
         ed = np.zeros(B, np.int32)
         win_of = np.full(B, nWp - 1, np.int32)  # padding -> sink window
         real = np.zeros(B, bool)
-        dev_spec = None
 
         counts = np.array([w.n_layers for _, w in items], np.int64)
-        k = int(counts.sum())
+        offs = np.zeros(len(items) + 1, np.int64)
+        np.cumsum(counts, out=offs[1:])
+        k = int(offs[-1])
         if k:
             # per-pair metadata straight from the works' flat arrays —
             # no per-layer Python loop in either storage mode
-            offs = np.zeros(len(items) + 1, np.int64)
-            np.cumsum(counts, out=offs[1:])
             lens = np.concatenate([w.lens for _, w in items])
             bb_len = np.repeat([len(w.backbone) for _, w in items], counts)
             n[:k] = lens
@@ -1883,64 +1879,10 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 [w.ends for _, w in items]), bb_len - 1)
             win_of[:k] = np.repeat(np.arange(len(items)), counts)
             real[:k] = True
-
-            # columnar windows: ONE vectorized pool gather per store
-            # lands every layer's finished uint16 lanes (codes + phred
-            # weights were packed once at store build)
-            by_store = {}
-            legacy = []
-            for wi, (_, w) in enumerate(items):
-                if not w.n_layers:
-                    continue
-                if w.store is not None:
-                    by_store.setdefault(id(w.store), []).append(wi)
-                else:
-                    legacy.append(wi)
-            for wis in by_store.values():
-                store = items[wis[0]][1].store
-                rows = np.concatenate([items[wi][1].rows for wi in wis])
-                dest = np.concatenate(
-                    [np.arange(offs[wi], offs[wi + 1]) for wi in wis])
-                if (allow_dev and len(by_store) == 1 and not legacy
-                        and store.dev_qpw is not None):
-                    # resident dataflow: ship 8-byte gather rows, not
-                    # 2*Lq-byte lanes — the device reads the pool it
-                    # already holds
-                    src0_full = np.zeros(B, np.int32)
-                    lens_full = np.zeros(B, np.int32)
-                    src0_full[dest] = store.src[rows]
-                    lens_full[dest] = store.length[rows]
-                    dev_spec = (store.dev_qpw, src0_full, lens_full)
-                else:
-                    qpw[dest] = store.gather_qpw(rows, Lq)
-
-            # hand-built windows (tests): the round-7 join-and-
-            # LUT path over just their layers
-            if legacy:
-                lay = [(s, q) for wi in legacy
-                       for s, q, _, _ in items[wi][1].layers]
-                cat = np.frombuffer(b"".join(s for s, _ in lay), np.uint8)
-                codes_cat = _CODE_LUT[cat]
-                llens = np.array([len(s) for s, _ in lay], np.int64)
-                starts = np.concatenate(([0], np.cumsum(llens)[:-1]))
-                pos = np.arange(Lq)[None, :]
-                valid = pos < llens[:, None]
-                src = starts[:, None] + np.minimum(pos, llens[:, None] - 1)
-                qual_cat = np.frombuffer(
-                    b"".join((q if q is not None else b"\x22" * len(s))
-                             for s, q in lay), np.uint8)
-                # integral weights: phred-33 (clipped at 0 — a quality
-                # byte below '!' would otherwise wrap) or 1 for
-                # no-quality
-                weights = np.maximum(qual_cat[src].astype(np.int16) - 33, 0)
-                has_q = np.array([q is not None for _, q in lay])
-                weights = np.where(has_q[:, None], weights, 1)
-                dest = np.concatenate(
-                    [np.arange(offs[wi], offs[wi + 1]) for wi in legacy])
-                qpw[dest] = np.where(
-                    valid,
-                    (weights.astype(np.uint16) << 3) | codes_cat[src],
-                    0).astype(np.uint16)
+        # a leaf of poa.pack: the lane block's construction (a timer
+        # only: device idle under it stays poa.pack's, contracts.py)
+        with obs.span("poa.lanes", pairs=k):
+            qpw, dev_spec = self._pack_lanes(items, offs, Lq, B, allow_dev)
 
         bcodes = np.zeros((nWp, Lb), np.uint8)
         bweights = np.zeros((nWp, Lb), np.float32)
@@ -1979,6 +1921,77 @@ class TpuPoaConsensus(PallasDispatchMixin):
         return (n, qpw, win_of, real, bg, ed), \
                (bcodes, bweights, blen, covs, ever), dev_spec
 
+    def _pack_lanes(self, items, offs, Lq, B, allow_dev):
+        """One shard's ``[B, Lq]`` lane block, written once: ``weight <<
+        3 | code`` per base (codes 3 bits, phred weights <= 93 in 7) —
+        codes and weights travel as ONE uint16 array, the format both
+        vote emitters consume directly. Window ``wi``'s layers land in
+        rows ``offs[wi]:offs[wi + 1]``; rows and lanes beyond stay 0.
+        Returns ``(qpw, dev_spec)`` (see :meth:`_pack_shard`)."""
+        qpw = np.zeros((B, Lq), np.uint16)
+        dev_spec = None
+        # columnar windows: one row copy per layer, straight into this
+        # block, lands every layer's finished uint16 lanes (codes +
+        # phred weights were packed once at store build)
+        by_store = {}
+        legacy = []
+        for wi, (_, w) in enumerate(items):
+            if not w.n_layers:
+                continue
+            if w.store is not None:
+                by_store.setdefault(id(w.store), []).append(wi)
+            else:
+                legacy.append(wi)
+        for wis in by_store.values():
+            store = items[wis[0]][1].store
+            rows = np.concatenate([items[wi][1].rows for wi in wis])
+            dest = np.concatenate(
+                [np.arange(offs[wi], offs[wi + 1]) for wi in wis])
+            metrics.inc("consensus.lane_rows", len(rows))
+            if (allow_dev and len(by_store) == 1 and not legacy
+                    and store.dev_qpw is not None):
+                # resident dataflow: ship 8-byte gather rows, not
+                # 2*Lq-byte lanes — the device reads the pool it
+                # already holds
+                src0_full = np.zeros(B, np.int32)
+                lens_full = np.zeros(B, np.int32)
+                src0_full[dest] = store.src[rows]
+                lens_full[dest] = store.length[rows]
+                dev_spec = (store.dev_qpw, src0_full, lens_full)
+            else:
+                store.gather_qpw(rows, Lq, out=qpw, dest=dest)
+                if native.available():
+                    metrics.inc("consensus.lane_rows_copied", len(rows))
+
+        # hand-built windows (tests): the round-7 join-and-
+        # LUT path over just their layers
+        if legacy:
+            lay = [(s, q) for wi in legacy
+                   for s, q, _, _ in items[wi][1].layers]
+            cat = np.frombuffer(b"".join(s for s, _ in lay), np.uint8)
+            codes_cat = _CODE_LUT[cat]
+            llens = np.array([len(s) for s, _ in lay], np.int64)
+            starts = np.concatenate(([0], np.cumsum(llens)[:-1]))
+            pos = np.arange(Lq)[None, :]
+            valid = pos < llens[:, None]
+            src = starts[:, None] + np.minimum(pos, llens[:, None] - 1)
+            qual_cat = np.frombuffer(
+                b"".join((q if q is not None else b"\x22" * len(s))
+                         for s, q in lay), np.uint8)
+            # integral weights: phred-33 (clipped at 0 — a quality
+            # byte below '!' would otherwise wrap) or 1 for
+            # no-quality
+            weights = np.maximum(qual_cat[src].astype(np.int16) - 33, 0)
+            has_q = np.array([q is not None for _, q in lay])
+            weights = np.where(has_q[:, None], weights, 1)
+            dest = np.concatenate(
+                [np.arange(offs[wi], offs[wi + 1]) for wi in legacy])
+            qpw[dest] = np.where(
+                valid,
+                (weights.astype(np.uint16) << 3) | codes_cat[src],
+                0).astype(np.uint16)
+        return qpw, dev_spec
+
     def _launch_group_impl(self, live, Lq, Lb, overrides=None):
         """Pack one window group (per-mesh-shard when a mesh is set — pairs
         of a window never cross shards, so votes stay shard-local) into the
@@ -2007,8 +2020,15 @@ class TpuPoaConsensus(PallasDispatchMixin):
         packs = [self._pack_shard(sh, Lq, B, nWp, Lb, overrides,
                                   allow_dev=allow_dev)
                  for sh in shards]
-        pair_np = [np.concatenate([p[0][a] for p in packs])
-                   for a in range(6)]
+        # one shard (every run without a mesh): the shard's arrays ARE
+        # the group's, handed to the put as they were written
+        if nd == 1:
+            pair_np, win_np = list(packs[0][0]), list(packs[0][1])
+        else:
+            pair_np = [np.concatenate([p[0][a] for p in packs])
+                       for a in range(6)]
+            win_np = [np.concatenate([p[1][a] for p in packs])
+                      for a in range(5)]
         # occupancy telemetry (round 10): real lane occupancy of this
         # launch's pair arena — occupied = sum of real layer lengths,
         # total = padded rows x the bucket's lane width
@@ -2024,8 +2044,6 @@ class TpuPoaConsensus(PallasDispatchMixin):
         metrics.inc("consensus.lanes_total", lanes)
         metrics.inc("consensus.groups")
         metrics.inc("consensus.group_windows", len(live))
-        win_np = [np.concatenate([p[1][a] for p in packs])
-                  for a in range(5)]
         # single-host: plain device puts; multi-host: every process packs
         # the (deterministic) full arrays and materializes only its
         # addressable shards of the global array
@@ -2033,8 +2051,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
         put = ((lambda a: to_global(self.mesh, a)) if self.mesh is not None
                else jnp.asarray)
         dev_spec = packs[0][2] if allow_dev else None
-        # the one leaf of poa.pack that is separable: the host->device
-        # puts (and, on the resident path, the lane gather's launch)
+        # the other leaf of poa.pack: the host->device puts (and, on
+        # the resident path, the lane gather's launch)
         with obs.span("poa.put", windows=len(live)):
             state, static = self._put_group(put, pair_np, win_np, dev_spec,
                                             nd, nWp, B, Lq)

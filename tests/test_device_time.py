@@ -76,6 +76,23 @@ def test_overlapping_h2d_and_exec_union():
     assert out["by_program"]["put"]["device_s"] == pytest.approx(0.25)
 
 
+def test_a_timer_only_leaf_leaves_its_idle_with_its_parent():
+    """``poa.lanes`` times the lane block inside ``poa.pack``; the idle
+    under it stays ``idle.poa.pack``, the timer the consensus feed's
+    idle metric has always summed. ``poa.put`` keeps its own."""
+    from racon_tpu import contracts
+    assert "poa.lanes" in contracts.TIMER_ONLY_SPANS <= contracts.SPANS
+    rows = [("0", "exec", "k", "feeder", 0, 100 * MS),
+            ("0", "h2d", "poa.put", "feeder", 500 * MS, 600 * MS)]
+    spans = {"feeder": [("poa.pack", 150 * MS, 550 * MS),
+                        ("poa.lanes", 200 * MS, 400 * MS),
+                        ("poa.put", 450 * MS, 550 * MS)]}
+    out = device_time.account(rows, spans, 0, 600 * MS, "feeder")
+    assert out["idle_s"] == pytest.approx(0.4)
+    assert out["idle_by"] == {"poa.pack": 0.3, "poa.put": 0.05,
+                              "unattributed": 0.05}
+
+
 def test_two_devices_rows_and_means():
     """``--chips N``: one row set per device ordinal; the top level is
     the mean over devices, so busy + idle is still the window."""
@@ -490,7 +507,7 @@ def test_cli_report_idle_sums_and_window(cli_series, tag):
 @pytest.mark.parametrize("parent,leaves", [
     ("align.dispatch", ("align.pack", "align.put", "align.launch")),
     ("align.fetch", ("align.wait", "align.get", "align.decode")),
-    ("poa.pack", ("poa.put",)),
+    ("poa.pack", ("poa.put", "poa.lanes")),
     ("poa.fetch", ("poa.wait", "poa.get", "poa.decode")),
 ])
 def test_leaf_spans_sum_to_no_more_than_their_parent(cli_series, parent,
