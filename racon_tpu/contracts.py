@@ -79,6 +79,10 @@ METRICS: FrozenSet[str] = frozenset((
     "consensus.groups", "consensus.ins_overflow",
     "consensus.ins_overflow_windows", "consensus.lanes_occupied",
     "consensus.lanes_total", "consensus.pallas_groups",
+    # the depth cap: windows and layers offered to the packer (before
+    # the cap; dropped_layers counts the layers past it) and windows
+    # that lost at least one
+    "consensus.layers", "consensus.windows", "consensus.windows_capped",
     # pair rows whose lanes came from a columnar store, and those of
     # them the native row copier wrote into the group's block
     "consensus.lane_rows", "consensus.lane_rows_copied",
@@ -113,6 +117,8 @@ METRICS: FrozenSet[str] = frozenset((
     "overlap.queries", "overlap.queries_kept",
     "overlap.seed_lanes_occupied", "overlap.seed_lanes_total",
     "overlap.stream_feed", "overlap.stream_groups", "overlap.streamed",
+    # the read set's window type (WindowType.value: 0 NGS, 1 TGS)
+    "polisher.window_type",
     # bounded init->polish queue
     "queue.consumer_wait_s", "queue.depth", "queue.producer_wait_s",
     # runtime sanitizer
@@ -132,6 +138,7 @@ METRICS: FrozenSet[str] = frozenset((
 # prefix must land here (the suffix is a runtime value — a chip
 # ordinal, a phase, a fault class/site, a swallowed-exception context)
 DYNAMIC_METRIC_PREFIXES: Tuple[str, ...] = (
+    "align.pairs_by_bucket.",  # .<max_len>: pairs dispatched per bucket
     "compile.",          # compile.<fn> per-function compile counts
     "device.",           # device.<ordinal>.shards/.mbp/.polish_s/...
     "faults.",           # faults.<class> taxonomy counts
@@ -163,7 +170,7 @@ RUN_PREFIXES: Tuple[str, ...] = (
     "align.", "aligner.", "poa.", "consensus.", "queue.", "retrace.",
     "retrace_total.", "swallowed.", "trace.", "parse.", "overlap.",
     "transmute", "bp.", "build.", "stitch", "exec.", "faults.",
-    "lease.", "device.", "compile.", "dataflow.", "idle.",
+    "lease.", "device.", "compile.", "dataflow.", "idle.", "polisher.",
 )
 
 # ------------------------------------------------------------- span names

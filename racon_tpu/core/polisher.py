@@ -281,13 +281,12 @@ class Polisher:
         if raw_index == 0:
             raise ValueError("empty sequences set")
 
-        self._window_type = (WindowType.NGS
-                             if total_len / raw_index <= 1000
-                             else WindowType.TGS)
+        self._window_type = WindowType.of_reads(total_len, raw_index)
         if self._window_type_override is not None:
             # shard runs pin the heuristic to the whole-input decision:
             # a shard's read subset must not flip NGS/TGS mid-assembly
             self._window_type = self._window_type_override
+        metrics.set_gauge("polisher.window_type", self._window_type.value)
 
         log.log("[racon_tpu::Polisher::initialize] loaded sequences")
         log.log()
@@ -336,7 +335,8 @@ class Polisher:
             log.log()
 
             self._kick_consensus_warmup(
-                sum(o.length // self.window_length + 1 for o in overlaps))
+                sum(o.length // self.window_length + 1 for o in overlaps),
+                max(o.length for o in overlaps))
             self._transmute_all(has_name, has_data, has_reverse)
 
             # builder-path writes (here through _assemble_layers) run on
@@ -420,14 +420,19 @@ class Polisher:
                         "overlaps (first-party overlapper)")
         return overlaps
 
-    def _kick_consensus_warmup(self, est_pairs: int) -> None:
+    def _kick_consensus_warmup(self, est_pairs: int,
+                               longest_overlap: int = 0) -> None:
         """Background warm-up compilation of the consensus refinement
         loop from the overlap/target histograms: the first consensus
         compile (~16 s) then hides inside the device overlap alignment
         instead of stalling polish(). Skipped for tiny inputs (the
         compile would outlive the whole run) and for engines that
         offer no warm-up; a wrong shape estimate only wastes a
-        background compile (see TpuPoaConsensus.warmup_async)."""
+        background compile (see TpuPoaConsensus.warmup_async).
+        ``longest_overlap`` (where the overlaps are known) bounds a
+        layer from above: no layer of a 150-base read set fills a
+        window, and the engine's sweep and vote widths follow the
+        layers it is given, so the warm-up has to be told."""
         warm = getattr(self.consensus, "warmup_async", None)
         if warm is None:
             return
@@ -439,27 +444,40 @@ class Polisher:
         # than the compile the warm-up would race to hide
         if est_pairs >= 16384:
             warm(self.window_length, est_pairs, est_windows,
+                 est_layer_len=min(longest_overlap,
+                                   self.window_length + 64),
                  est_contigs=self.targets_size)
 
     def _transmute_all(self, has_name, has_data, has_reverse) -> None:
         """transmute-parallelism (reference P3: one future per sequence,
         ``polisher.cpp:368-377``): revcomp materialization is a numpy
         LUT-take + flip (``sequence.py``), which releases the GIL on
-        real read lengths, so a thread pool parallelizes it (chunked —
-        per-item futures cost more than most transmutes)."""
+        real read lengths, so a thread pool parallelizes it — one task
+        per contiguous slice of the read set, never one per read (a
+        future costs more than most transmutes), and no pool at all for
+        a short-read set (NGS: mean read length <= 1000): a 150-base
+        transmute never lets go of the interpreter lock, and eight
+        threads passing it round took 16 s (a task a read) and 5 s
+        (sliced) on the chip host for the 0.6 s of work in 345,000
+        reads."""
+        seqs = self.sequences
+
+        def work(lo: int, hi: int) -> None:
+            for i in range(lo, hi):
+                seqs[i].transmute(has_name[i], has_data[i], has_reverse[i])
+
         with obs.span("transmute"):
-            if self.num_threads > 1 and len(self.sequences) > 64:
+            if (self.num_threads > 1 and len(seqs) > 64
+                    and self._window_type is WindowType.TGS):
                 from concurrent.futures import ThreadPoolExecutor
+                step = -(-len(seqs) // (4 * self.num_threads))
                 with ThreadPoolExecutor(self.num_threads) as pool:
-                    list(pool.map(
-                        lambda iv: iv[1].transmute(has_name[iv[0]],
-                                                   has_data[iv[0]],
-                                                   has_reverse[iv[0]]),
-                        enumerate(self.sequences), chunksize=64))
+                    for f in [pool.submit(work, lo,
+                                          min(lo + step, len(seqs)))
+                              for lo in range(0, len(seqs), step)]:
+                        f.result()
             else:
-                for i, seq in enumerate(self.sequences):
-                    seq.transmute(has_name[i], has_data[i],
-                                  has_reverse[i])
+                work(0, len(seqs))
 
     def _generate_overlaps_stream(self, raw_index: int,
                                   name_to_id: Dict[bytes, int],

@@ -18,6 +18,9 @@ import numpy as np
 _COMPLEMENT = bytes.maketrans(b"ACGT", b"TGCA")
 _COMPLEMENT_LUT = np.frombuffer(bytes(range(256)).translate(_COMPLEMENT),
                                 np.uint8)
+# reads shorter than this reverse-complement without numpy (see
+# Sequence.create_reverse_complement)
+_NUMPY_REVCOMP_MIN = 1024
 
 
 class Sequence:
@@ -33,7 +36,7 @@ class Sequence:
         self.name = name
         self.data = data.upper()
         # Drop all-'!' placeholder qualities (minimap2 -Q emits those).
-        if quality is not None and any(q != 0x21 for q in quality):
+        if quality is not None and quality.count(b"!") != len(quality):
             self.quality: Optional[bytes] = quality
         else:
             self.quality = None
@@ -60,9 +63,16 @@ class Sequence:
             return
         # numpy LUT + flip: byte-identical to bytes.translate()[::-1] but
         # releases the GIL on large arrays, so the polisher's transmute
-        # thread pool (reference P3) parallelizes for real
-        arr = np.frombuffer(self.data, np.uint8)
-        self._reverse_complement = _COMPLEMENT_LUT[arr][::-1].tobytes()
+        # thread pool (reference P3) parallelizes for real. A short read
+        # is all call overhead there (five numpy calls for 150 bytes):
+        # it takes the two bytes methods
+        if len(self.data) < _NUMPY_REVCOMP_MIN:
+            self._reverse_complement = \
+                self.data.translate(_COMPLEMENT)[::-1]
+        else:
+            arr = np.frombuffer(self.data, np.uint8)
+            self._reverse_complement = \
+                _COMPLEMENT_LUT[arr][::-1].tobytes()
         self._reverse_quality = (self.quality[::-1]
                                  if self.quality is not None else None)
 

@@ -23,6 +23,13 @@ class WindowType(enum.Enum):
     NGS = 0  # short accurate reads (mean length <= 1000)
     TGS = 1  # long noisy reads
 
+    @classmethod
+    def of_reads(cls, total_bases: int, n_reads: int) -> "WindowType":
+        """The reference's heuristic (``src/polisher.cpp:275-276``): a
+        read set whose mean length is at most 1000 bases polishes NGS
+        windows (no coverage trim of a window's ends), any other TGS."""
+        return cls.NGS if total_bases / n_reads <= 1000 else cls.TGS
+
 
 class Window:
     """Layers live either as real bytes lists (``add_layer``) or as a

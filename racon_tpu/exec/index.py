@@ -191,8 +191,7 @@ def build_index(sequences_path: str, overlaps_path: str, target_path: str,
     if not read_names:
         raise ValueError("empty sequences set")
     read_spans = np.asarray(spans, np.int64).reshape(-1, 3)
-    window_type = (WindowType.NGS
-                   if total_len / len(read_names) <= 1000 else WindowType.TGS)
+    window_type = WindowType.of_reads(total_len, len(read_names))
     # PAF/SAM queries resolve by name, later duplicates winning
     read_ids: Dict[bytes, int] = {n: i for i, n in enumerate(read_names)}
 
@@ -377,9 +376,7 @@ def build_index_readsonly(sequences_path: str,
     if not read_names:
         raise ValueError("empty sequences set")
     read_spans = np.asarray(spans, np.int64).reshape(-1, 3)
-    window_type = (WindowType.NGS
-                   if total_len / len(read_names) <= 1000
-                   else WindowType.TGS)
+    window_type = WindowType.of_reads(total_len, len(read_names))
     idx = RunIndex(sequences_path, parsers.AUTO_OVERLAPS, target_path,
                    "paf", targets, read_spans, read_names, window_type)
     idx.uniform_read_bases = total_len

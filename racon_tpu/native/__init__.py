@@ -230,6 +230,10 @@ def load():
         ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_int64),
         ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
         ctypes.POINTER(ctypes.c_uint16)]
+    lib.rt_copy_byte_rows.restype = None
+    lib.rt_copy_byte_rows.argtypes = [
+        ctypes.c_int64, ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64, ctypes.POINTER(ctypes.c_uint8)]
     _lib = lib
     return _lib
 
@@ -422,10 +426,41 @@ def copy_lane_rows(pool, src, length, dest, out) -> None:
         out.shape[1], out.ctypes.data_as(u16p))
 
 
+def copy_byte_rows(pool: bytes, length, out) -> None:
+    """Write the aligner's sequence block by row copies: ``pool`` holds
+    the rows' bytes back to back, row ``r`` (``length[r]`` of them) goes
+    to the head of ``out[r]`` (``out`` a C-contiguous ``[rows, row_len]``
+    uint8 block the caller zeroed). A row longer than ``row_len`` or a
+    pool of another size than the lengths' sum raises before anything
+    is written."""
+    import numpy as np
+
+    lib = load()
+    if lib is None:
+        raise NativeBuildError("native library unavailable")
+    if out.dtype != np.uint8 or out.ndim != 2 or not out.flags.c_contiguous:
+        raise ValueError("copy_byte_rows wants a C-contiguous uint8 block")
+    length = np.ascontiguousarray(length, dtype=np.int64)
+    count = len(length)
+    if not count:
+        return
+    if count > out.shape[0] or length.min() < 0 \
+            or length.max() > out.shape[1] or int(length.sum()) != len(pool):
+        raise IndexError("byte row outside the pool or the block")
+    lib.rt_copy_byte_rows(
+        count, pool, length.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        out.shape[1], out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
+
+
 def parse_seqfile(path: str, is_fastq: bool):
     """Parse a (possibly gzipped) FASTA/FASTQ file natively; returns a
     list of (name, data, quality|None) byte tuples. Raises ValueError on
-    malformed input (same conditions as the Python parsers)."""
+    malformed input (same conditions as the Python parsers). Prefers
+    the CPython extension (the tuples built in C); the ctypes route
+    below is the fallback."""
+    ext = load_ext()
+    if ext is not None:
+        return ext.parse_seqfile(path, is_fastq)
     lib = load()
     if lib is None:
         raise NativeBuildError("native library unavailable")

@@ -697,6 +697,40 @@ class _DevBp:
         return rows[valid].astype(np.int32)
 
 
+def _alphabet(pools) -> np.ndarray:
+    """The distinct byte values of ``pools`` (bytes objects), sorted,
+    without 0 (the blocks' pad). ACGT are looked for by ``in`` and only
+    what is left after deleting them is counted, so a chunk of plain
+    bases is never histogrammed."""
+    present = np.zeros(256, bool)
+    for pool in pools:
+        for base in b"ACGT":
+            if not present[base] and base in pool:
+                present[base] = True
+        rest = pool.translate(None, b"ACGT")
+        if rest:
+            present[np.frombuffer(rest, np.uint8)] = True
+    present[0] = False
+    return np.flatnonzero(present)
+
+
+def _copy_rows(pool: bytes, lens, block) -> None:
+    """Row ``k`` of the zeroed ``[B, max_len]`` uint8 ``block`` gets the
+    next ``lens[k]`` bytes of ``pool`` (the rows' bytes back to back) at
+    its head: one native memcpy per row (a chunk of short reads is
+    65,536 rows a side), or a slice assignment per row where the native
+    core is absent."""
+    from .. import native
+    if native.available():
+        native.copy_byte_rows(pool, lens, block)
+        return
+    flat = np.frombuffer(pool, dtype=np.uint8)
+    at = 0
+    for k, ln in enumerate(np.asarray(lens).tolist()):
+        block[k, :ln] = flat[at:at + ln]
+        at += ln
+
+
 def _ops_to_cigar(path: np.ndarray) -> str:
     """Run-length encode a backward-order op path into a CIGAR string
     (callers pre-filter ``ops < 3`` — the Pallas walk interleaves
@@ -1245,6 +1279,9 @@ class TpuAligner(PallasDispatchMixin):
                 pairs, chunk, max_len, bp_meta, sw)
             steps = _sweep_bound(int((n + m).max()), max_len)
             self._count_arena(n, m, len(chunk), steps, band)
+            # which bucket the pairs took (an escapee counts again at
+            # the bucket of its re-dispatch)
+            metrics.inc(f"align.pairs_by_bucket.{max_len}", len(chunk))
         return _PackedChunk(pairs, chunk, max_len, band, bp_meta, n, m,
                             seqs, kind, bp_host, steps, sw)
 
@@ -1286,12 +1323,14 @@ class TpuAligner(PallasDispatchMixin):
                 use_pallas = self._use_pallas((max_len, band, steps, B))
                 if use_pallas:
                     from .pallas_nw import pallas_swar_ok
+                    from .swar import mosaic_swar_fits
                     # the packed Mosaic kernel's XOR+mask equality reads
                     # 4-bit codes, so raw-byte chunks (alphabet > 15,
                     # rows not remapped) must never take it — bytes
                     # differing only in bits 4-7 would compare equal
-                    # there
-                    sw = sw and kind != "raw" and pallas_swar_ok()
+                    # there; nor may a band under its geometry guard
+                    sw = (sw and kind != "raw" and mosaic_swar_fits(band)
+                          and pallas_swar_ok())
                 # no try/except around the dispatch: a Mosaic kernel
                 # that does not compile or run for this shape fails the
                 # run (the jit error names the function and shapes)
@@ -1324,42 +1363,47 @@ class TpuAligner(PallasDispatchMixin):
         # Pad the batch to a power of two: B is part of the compiled shape,
         # so arbitrary batch sizes would recompile the kernels every call.
         B = self._pad_batch(len(chunk))
-        qcat = np.zeros(B * max_len, dtype=np.uint8)
-        tcat = np.zeros(B * max_len, dtype=np.uint8)
+        C = len(chunk)
         n = np.ones(B, dtype=np.int32)
         m = np.ones(B, dtype=np.int32)
-        for k, idx in enumerate(chunk):
-            qb, tb = pairs[idx]
-            qcat[k * max_len: k * max_len + len(qb)] = \
-                np.frombuffer(qb, dtype=np.uint8)
-            tcat[k * max_len: k * max_len + len(tb)] = \
-                np.frombuffer(tb, dtype=np.uint8)
-            n[k], m[k] = len(qb), len(tb)
+        pools = []
+        for side, lens in enumerate((n, m)):
+            spans = [pairs[idx][side] for idx in chunk]
+            lens[:C] = np.fromiter(map(len, spans), np.int64, C)
+            pools.append(b"".join(spans))
 
         # host->device bytes are the bottleneck on thin links: when the
         # chunk's alphabet fits 4 symbols (ACGT does) and the SWAR path
         # is live, remap to 2-bit codes packed 16 per int32 word (4x
         # fewer bytes than raw); up to 15 symbols (ACGTN does) remap to
         # nibble codes (2x). Equality-preserving bijections either way —
-        # the kernels only ever compare characters for equality.
-        hist = np.bincount(qcat, minlength=256)
-        hist += np.bincount(tcat, minlength=256)
-        alphabet = np.flatnonzero(hist[1:]) + 1  # O(N), no sort; 0 is pad
+        # the kernels only ever compare characters for equality. The
+        # remap runs over the spans back to back (``bytes.translate``),
+        # before the rows are spread into their padded blocks: a chunk
+        # of 65,536 short pairs is 10 MB of bases in 17 MB of block
+        alphabet = _alphabet(pools)
+        lut = np.zeros(256, np.uint8)      # 0 is pad, and stays 0
         if sw and len(alphabet) <= 4:
-            from .swar import pack_bases_2bit
-            lut = np.zeros(256, np.uint8)
             lut[alphabet] = np.arange(len(alphabet), dtype=np.uint8)
             kind = "2bit"
-            seqs = (pack_bases_2bit(lut[qcat]), pack_bases_2bit(lut[tcat]))
         elif len(alphabet) <= 15:
-            lut = np.zeros(256, np.uint8)
             lut[alphabet] = np.arange(1, len(alphabet) + 1, dtype=np.uint8)
-            q4 = lut[qcat]
-            t4 = lut[tcat]
             kind = "nibble"
-            seqs = (q4[0::2] | (q4[1::2] << 4), t4[0::2] | (t4[1::2] << 4))
         else:
             kind = "raw"
+        qcat, tcat = (np.zeros(B * max_len, dtype=np.uint8)
+                      for _ in range(2))
+        for pool, lens, cat in zip(pools, (n, m), (qcat, tcat)):
+            if kind != "raw":
+                pool = pool.translate(lut.tobytes())
+            _copy_rows(pool, lens[:C], cat.reshape(B, max_len))
+        if kind == "2bit":
+            from .swar import pack_bases_2bit
+            seqs = (pack_bases_2bit(qcat), pack_bases_2bit(tcat))
+        elif kind == "nibble":
+            seqs = (qcat[0::2] | (qcat[1::2] << 4),
+                    tcat[0::2] | (tcat[1::2] << 4))
+        else:
             seqs = (qcat, tcat)
         bp_host = None
         if bp_meta is not None:
@@ -1367,13 +1411,12 @@ class TpuAligner(PallasDispatchMixin):
             w, metas = bp_meta
             first_rel = np.zeros(B, np.int32)
             nb = np.ones(B, np.int32)
-            for k, idx in enumerate(chunk):
-                t_begin, _ = metas[idx]
-                t_end = t_begin + len(pairs[idx][1])
-                n_reg = (t_end - 1) // w - t_begin // w
-                nb[k] = n_reg + 1
-                first_rel[k] = ((t_begin // w + 1) * w - 1 - t_begin
-                                if n_reg else m[k] - 1)
+            t_begin = np.fromiter((metas[idx][0] for idx in chunk),
+                                  np.int64, C)
+            n_reg = (t_begin + m[:C] - 1) // w - t_begin // w
+            nb[:C] = n_reg + 1
+            first_rel[:C] = np.where(
+                n_reg != 0, (t_begin // w + 1) * w - 1 - t_begin, m[:C] - 1)
             bp_host = (first_rel, nb)
         return n, m, seqs, kind, bp_host
 
@@ -1551,11 +1594,9 @@ class TpuAligner(PallasDispatchMixin):
         if seen.any():
             self._observe_divergence(score_h[seen],
                                      np.maximum(n_h, m_h)[seen])
-        tb = np.fromiter((metas[idx][0] for idx in chunk), np.int64, C)
-        qo = np.fromiter((metas[idx][1] for idx in chunk), np.int64, C)
-        te = tb + np.fromiter((len(pairs[idx][1]) for idx in chunk),
-                              np.int64, C)
-        n_reg = (te - 1) // w - tb // w
+        tb, qo = np.array([metas[idx] for idx in chunk],
+                          np.int64).reshape(C, 2).T
+        n_reg = (tb + m_h - 1) // w - tb // w
         if resident:
             devc = _DevChunkBp(out[0], out[1], w, max_len)
             # dataflow accounting: the gate scalars crossed the link,
@@ -1581,13 +1622,15 @@ class TpuAligner(PallasDispatchMixin):
              tb[:, None] + (lp >> 14) + 1, qo[:, None] + (lp & 0x3FFF) + 1],
             axis=-1)
         flat = rows[valid].astype(np.int32)
-        parts = np.split(flat, np.cumsum(valid.sum(axis=1))[:-1])
-        for k, idx in enumerate(chunk):
-            if accept[k]:
-                results[idx] = parts[k]
-                self.stats["device"] += 1
+        ends = np.cumsum(valid.sum(axis=1)).tolist()
+        begin = 0
+        for idx, ok, end in zip(chunk, accept.tolist(), ends):
+            if ok:
+                results[idx] = flat[begin:end]
             else:
                 reject.append(idx)
+            begin = end
+        self.stats["device"] += int(accept.sum())
 
     # ------------------------------------------------------------- warm-up
 
@@ -1668,7 +1711,8 @@ class TpuAligner(PallasDispatchMixin):
             use_pallas = self._use_pallas((max_len, band, steps, B))
             if use_pallas and sw:
                 from .pallas_nw import pallas_swar_ok
-                sw = pallas_swar_ok()
+                from .swar import mosaic_swar_fits
+                sw = mosaic_swar_fits(band) and pallas_swar_ok()
             out = align_chain(qrp, tp, n, m, max_len=max_len, band=band,
                               steps=steps, use_pallas=use_pallas,
                               use_swar=sw)
@@ -1842,15 +1886,29 @@ class _AlignStream:
         """Seed buffered pairs into (bucket, band) geometry classes
         with the estimator's CURRENT knowledge."""
         eng = self.eng
+        # the estimator learns only at a fetch, so within one call a
+        # seed is a function of (lengths, error): short reads share a
+        # few hundred such keys among their hundreds of thousands of
+        # pairs, and each key is seeded once
+        seeds: dict = {}
+        narrow = 0
         for slot, err in buffered:
             q, t = self.pairs[slot]
-            g = eng._seed_geometry(len(q), len(t), err)
+            key = (len(q), len(t), err)
+            if key not in seeds:
+                seeds[key] = eng._seed_geometry(*key, record=False)
+            g = seeds[key]
             if g is None:
                 eng.stats["fallback_length"] += 1
                 metrics.inc("aligner.fallback_length")
                 self.reject.append(slot)
             else:
+                narrow += g[1] < eng.buckets[g[0]][1]
                 self.pending.setdefault(g, []).append(slot)
+        if narrow:
+            # _seed_geometry's own count, taken once per call
+            eng.stats["ladder_narrow"] += narrow
+            metrics.inc("aligner.ladder_narrow", narrow)
 
     def _flush(self, final: bool) -> None:
         eng = self.eng
@@ -1886,8 +1944,12 @@ class _AlignStream:
             # longest first: a chunk's compiled sweep bound tracks its
             # OWN head, so similar-length pairs share chunks and short
             # tail chunks shrink their steps AND grow their batch
-            slots.sort(key=lambda s: -(len(self.pairs[s][0])
-                                       + len(self.pairs[s][1])))
+            # (the keys in one pass, then a stable descending sort:
+            # the order a key of minus the cost gave, without a Python
+            # call a pair inside the sort)
+            cost = {s: len(q) + len(t)
+                    for s, (q, t) in zip(slots, map(self.pairs.get, slots))}
+            slots.sort(key=cost.__getitem__, reverse=True)
             while slots:
                 q0, t0 = self.pairs[slots[0]]
                 steps = _sweep_bound(len(q0) + len(t0), max_len)

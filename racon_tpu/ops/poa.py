@@ -923,10 +923,16 @@ class _Work:
         self.backbone = win.backbone
         self.bqual = win.backbone_quality
         total = win.layer_count
-        over = total - max_depth
-        if over > 0:
+        # the depth cap keeps a window's first ``max_depth`` layers in
+        # arrival (overlap-stream) order and drops the rest; the
+        # counters are written for every window, zeros included
+        over = max(0, total - max_depth)
+        if over:
             stats["dropped_layers"] += over
-            metrics.inc("consensus.dropped_layers", over)
+        metrics.inc("consensus.windows")
+        metrics.inc("consensus.layers", total)
+        metrics.inc("consensus.dropped_layers", over)
+        metrics.inc("consensus.windows_capped", int(over > 0))
         depth = min(total, max_depth)
         self.n_seqs = total + 1
         self.n_layers = depth

@@ -113,6 +113,30 @@ def swar_fits(max_len: int) -> bool:
     return max_len + 2 < BIG16
 
 
+# The packed Mosaic forward kernel (``pallas_nw._fwd_kernel_swar``) keeps a
+# wavefront in ``band / 4`` 32-bit words a pair, and on the chip it is
+# right only where that is whole 128-lane registers (PR 37, against the XLA
+# kernel on the same device: bands 512 and 1024 right at 8 and at 64 rows a
+# block; 128, 192, 256 wrong at 64 rows; 384 and 768 wrong at 8 rows too).
+# Where it is wrong it scores every substitution 0 and walks the length
+# difference off at the pair's head: 25,010 of a short-read chunk's 65,536
+# pairs at (256, 128 / 96 / 64), the probes' own batch too once tiled to
+# 64 rows (its 8 rows alone pass, which is all ``pallas_swar_ok()`` runs).
+# The int32 kernel is right at every geometry tried, and under one
+# register a row it needs no more of them (64 int32 lanes or 32 packed
+# words are one register either way), so bands under 512 take it. Band
+# 768, which the long-read cells' narrowest rung runs, is left packed
+# here: routing it moves those cells' bytes and device seconds, which is
+# a change of its own (PERF.md section 7).
+MOSAIC_SWAR_MIN_BAND = 512
+
+
+def mosaic_swar_fits(band: int) -> bool:
+    """Geometry guard of the packed MOSAIC forward kernel (the XLA
+    packed kernel has no such limit): False -> the int32 Mosaic kernel."""
+    return band >= MOSAIC_SWAR_MIN_BAND
+
+
 # geometry of the availability probes' one small bucket
 PROBE_MAX_LEN, PROBE_BAND = 256, 128
 

@@ -170,6 +170,42 @@ def test_aligner_chunk_program_fits_the_chip(chip, max_len, band, steps):
             w=500, NW=max_len // 500 + 2))
 
 
+# the short-read cell (bact-sr150-50x: 345 k pairs of 100-150 bases)
+# runs the smallest bucket only, at its three bands: the probe's and
+# the cold seeds' at the bucket band, the ladder's two rungs after it;
+# every chunk at the pair cap (``MAX_CHUNK_PAIRS``), not the byte budget
+SHORT_READ_BANDS = [128, 96, 64]
+
+
+@pytest.mark.parametrize("band", SHORT_READ_BANDS)
+def test_short_read_chunk_program_fits_the_chip(chip, band):
+    """``_pallas_align_chain`` at ``(256, band)`` with 65,536 pairs a
+    chunk, and its two neighbours: a refused kernel here would fail the
+    cell's every run, since nothing falls back to XLA on the chip. The
+    int32 forward kernel: bands under ``swar.MOSAIC_SWAR_MIN_BAND``
+    never take the packed one (wrong there on the chip, PR 37)."""
+    max_len, bucket_band = nw.BUCKETS[0]
+    assert (max_len, bucket_band) == (256, 128)
+    assert band == bucket_band or band in nw.BAND_RUNGS
+    eng = nw.TpuAligner(fallback=None, num_batches=1)
+    steps = nw._sweep_bound(2 * 150, max_len)
+    B = eng._chunk_cap(steps, band)
+    assert (steps, B) == (512, nw.MAX_CHUNK_PAIRS)
+    assert not swar.mosaic_swar_fits(band)
+    rows, lens = _rows(chip, B, max_len, band)
+    compiled, _, total = _compile(nw._pallas_align_chain.lower(
+        rows, rows, lens, lens, max_len=max_len, band=band, steps=steps,
+        use_swar=False))
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert total < HBM_BYTES // 8, f"{total / GIB:.2f} GiB"
+    blk = chip((B * max_len // 4,), jnp.uint8)
+    _compile(nw._build_rows_packed2.lower(blk, blk, lens, lens,
+                                          max_len=max_len, band=band))
+    _compile(nw._breaking_points_kernel.lower(
+        chip((B, steps // 4), jnp.uint8), lens, lens, lens, lens,
+        w=500, NW=max_len // 500 + 2))
+
+
 def _consensus_engine(band=poa.BAND):
     return poa.TpuPoaConsensus(3, -5, -4, fallback=None, band=band)
 
@@ -202,6 +238,26 @@ def test_consensus_group_program_fits_the_chip(chip):
     assert "tpu_custom_call" in compiled.as_text()
     # the group's packed inputs wait in flight beside the running
     # program (MAX_INFLIGHT_BYTES of them at most)
+    assert total + poa.MAX_INFLIGHT_BYTES < HBM_BYTES, \
+        f"{total / GIB:.2f} GiB"
+
+
+def test_short_read_consensus_group_program_fits_the_chip(chip):
+    """The group the short-read cell forms: 32,768 rows of at most 150
+    bases in ``Lq`` 1,024, 200 to a window, so 256 window rows where
+    the long-read cells have 2,048, a 384-step sweep and a 256-lane
+    vote. The warm-up derives it from the longest overlap."""
+    eng = _consensus_engine()
+    Lq, Lb, band, steps, Lq2, B, nWp, rounds = eng._warmup_shapes(
+        500, 345_000, 2_001, 150, 1)[0]
+    assert (Lq, Lb, band, steps, Lq2, B, nWp) == (
+        1024, 768, 512, 384, 256, poa.MAX_GROUP_PAIRS, 256)
+    compiled, _, total = _compile(poa._refine_loop_packed.lower(
+        *_refine_args(chip, Lq, Lb, B, nWp), rounds=rounds,
+        n_windows=nWp, max_len=Lq, band=band, Lb=Lb, K=poa.K_INS,
+        steps=steps, use_pallas=True, use_swar=True, Lq2=Lq2,
+        scores=eng.scores, matmul_votes=eng.use_matmul_votes))
+    assert "tpu_custom_call" in compiled.as_text()
     assert total + poa.MAX_INFLIGHT_BYTES < HBM_BYTES, \
         f"{total / GIB:.2f} GiB"
 
