@@ -18,10 +18,14 @@ one-block-per-group graph POA, consensus is computed as a
    and weight directly from registers; the XLA path reconstructs them
    from op codes with vectorized prefix sums (``_vote_from_ops``); both
    streams land on bit-identical matrices via the shared TPU-native
-   accumulation ``_accumulate_votes`` (stable binary-routed compaction +
-   per-row alignment + one-hot MXU matmul for the column votes, a folded
-   packed scatter for the rare insertion votes — a flat scatter-add here
-   costs more than the alignment kernels themselves);
+   accumulation ``_accumulate_votes`` (a flat scatter-add here costs
+   more than the alignment kernels themselves): stable binary-routed
+   compaction + per-row alignment + one-hot MXU matmul for the column
+   votes; the rare insertion votes compact ONCE to the ``band // 2``
+   lanes an accepted pair can fill, and from that narrow stream the
+   ``K_INS`` slot planes are routed to their columns and reduced by the
+   same matmul — every routing ladder runs at the width of what it
+   routes, not of the step stream;
 3. consensus = per-column argmax over weighted base votes, a column
    dropped when deletion weight exceeds ``del_beta`` x the summed base
    weights, and insertion slot ``s`` emitted when its summed weight
@@ -225,6 +229,14 @@ def _shift_left(x, sh: int):
     return jnp.pad(x[:, sh:], ((0, 0), (0, sh)))
 
 
+def _fit_lanes(x, width: int):
+    """``x`` cut or zero-padded at the tail to ``width`` lanes."""
+    have = x.shape[1]
+    if have >= width:
+        return x[:, :width]
+    return jnp.pad(x, ((0, 0), (0, width - have)))
+
+
 def _compact_rows(flag, payload, S: int):
     """Stable per-row compaction: move flagged lanes to [0, rank) keeping
     order; unflagged output lanes are zero. ``payload`` is one int32 array
@@ -351,19 +363,29 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
     - **column votes** (M/D steps, one per consumed backbone column, the
       ~98% majority): the r-th column-consuming step of a pair hits
       column ``bg + m - 1 - r``, so a stable per-row compaction
-      (:func:`_compact_rows`) followed by a lane reverse and a per-row
-      shift lands every vote at its absolute column; a one-hot
-      [B, n_windows] matmul (exact: integer values < 2^24 in f32 with
-      HIGHEST precision) then reduces pairs into windows on the MXU;
-    - **insertion votes** (~2%): compacted to the first ``band//2`` lanes
-      (an ok pair has score < band//2, so it cannot carry more insertion
-      steps than that) and scatter-added into a **u32 pair** per address
-      (weight table + count table). The old single-u32 packing (weight
-      bits 0-22, count bits 23-31) silently carried the count into the
-      weight field past 511 votes per address — it was what capped the
-      voting depth at 511; the widened pair is exact to depth 2^32 and
-      the depth ceiling now comes from the f32-exactness of the column
-      matmul (see ``TpuPoaConsensus.__init__``).
+      (:func:`_compact_rows`, over the ``S`` steps) followed by a cut to
+      ``L`` lanes (a pair votes a column once, so its ranks are below
+      ``L``), a lane reverse and a per-row shift at ``L`` lanes lands
+      every vote at its absolute column; a one-hot [B, n_windows]
+      matmul then reduces pairs into windows on the MXU (``matmul_votes``:
+      exact int8 x int8 -> int32, :func:`_int_vote_matmul`; else f32
+      with HIGHEST precision, exact for integer sums < 2^24);
+    - **insertion votes** (~2%): ONE compaction of the step stream, cut
+      to the first ``IC = min(S, band // 2)`` lanes (an ok pair has
+      score < band//2, so it cannot carry more insertion steps than
+      that; rejected pairs are masked out of every reduction), shared
+      by both branches. ``matmul_votes`` (the engine's path): per slot,
+      the narrow stream compacts again (``IC`` lanes), expands onto the
+      absolute column lanes (:func:`_expand_rows` at ``max(IC, L)``
+      lanes) and goes through the same exact matmul as the columns:
+      ``K`` small planes from one stream. Otherwise (tests: the
+      independent reference) the stream is scatter-added into a **u32
+      pair** per address (weight table + count table): the old
+      single-u32 packing (weight bits 0-22, count bits 23-31) silently
+      carried the count into the weight field past 511 votes per
+      address; the widened pair is exact to depth 2^32 and the depth
+      ceiling comes from the column reduction (see
+      ``TpuPoaConsensus.__init__``).
 
     **Score-weighted voting** (the -m/-x/-g contract, the analog of
     cudapoa consuming the CLI scores directly,
@@ -385,7 +407,14 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
     time to keep the competition fair).
 
     Returns (weighted [n_windows, L*(1+K)*CH] f32, unweighted i32,
-    ins_overflow telemetry, per-window overflow counts [n_windows] i32).
+    ins_overflow telemetry, its per-window counts [n_windows] i32).
+    ``ins_overflow`` (``dropped[:, 2]``, counter
+    ``consensus.ins_overflow``) says on each branch that its cap held:
+    with ``matmul_votes`` it counts accepted pairs whose insertion
+    stream reached past lane ``IC`` (votes the cut lost: the score gate
+    makes it 0, and anything else is a fault); on the scatter branch,
+    insertion votes past the fold cap (none lost: that round scattered
+    the uncapped stream).
     """
     B, S = idx.shape
     VOT = L * (1 + K) * CH
@@ -419,11 +448,11 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
     ch = idx & (CH - 1)  # CH is a power of two
     pay = (ch << 13) | jnp.minimum(w, (1 << 13) - 1)
     comp, _ = _compact_rows(col_flag, pay, S)
-    W2 = max(S, L)
-    if W2 > S:
-        comp = jnp.pad(comp, ((0, 0), (0, W2 - S)))
-    rev = jnp.flip(comp, axis=1)
-    aligned = _shift_rows_left(rev, W2 - bg - span_m, W2)[:, :L]
+    # a pair votes each column at most once, so its ranks are < L: the
+    # per-row shift runs at the width of its destination, not of the
+    # step stream
+    rev = jnp.flip(_fit_lanes(comp, L), axis=1)
+    aligned = _shift_rows_left(rev, L - bg - span_m, L)
     a_ch = (aligned >> 13) & (CH - 1)
     onemask = ((win_of[:, None] == jnp.arange(nW, dtype=win_of.dtype))
                & ok[:, None])
@@ -447,33 +476,41 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
         w_cols = jnp.matmul(onehot.T, wop.reshape(B, L * CH), precision=hi)
         c_cols = jnp.matmul(onehot.T, cop.reshape(B, L * CH), precision=hi)
 
+    # ---- insertion votes, level 1 (per pair, both branches): ONE stable
+    # compaction of the step stream, cut to the first IC lanes. An ok
+    # pair has < band//2 edits, hence < band//2 insertion steps — lanes
+    # beyond IC can only hold votes of pairs that are dropped anyway
+    IC = min(S, band // 2)
+    ipay = ((idx - L * CH) << 13) | jnp.minimum(w, (1 << 13) - 1)
+    icomp, ialive = _compact_rows(ins_flag, ipay, S)
+    # the compaction is dense, so lane IC is live exactly when the cut
+    # below loses a vote
+    past_ic = ialive[:, IC] if IC < S else jnp.zeros((B,), bool)
+    icomp = icomp[:, :IC]
+    ialive = ialive[:, :IC]
+
     if matmul_votes:
-        # ---- insertion votes as K aligned slot planes through the same
-        # exact matmul (no scatter): per (pair, junction, slot) there is
-        # at most ONE vote — slots of one insertion run are distinct and
-        # distinct runs sit at distinct junction columns — so each slot
-        # plane compacts in walk order (strictly decreasing junction
-        # column) and RIGHT-expands onto absolute column lanes
-        # (:func:`_expand_rows`; destinations ``L-1-col`` are strictly
-        # increasing over ranks). Replaces the fold + packed scatter:
-        # the scatter engine was the slowest op in the round, and the
-        # fold cap's overflow events (``ins_overflow``) are structurally
-        # impossible here.
-        iaddr = idx - L * CH
-        icol = iaddr // (K * CH)
-        isub = iaddr - icol * (K * CH)    # slot*CH + ch
-        lane = jnp.arange(W2, dtype=jnp.int32)[None, :]
+        # ---- K aligned slot planes through the same exact matmul (no
+        # scatter), routed from the narrow stream: per (pair, junction,
+        # slot) there is at most ONE vote — slots of one insertion run
+        # are distinct and distinct runs sit at distinct junction
+        # columns — so each slot's votes compact again in walk order
+        # (strictly decreasing junction column; IC lanes, not S) and
+        # RIGHT-expand onto absolute column lanes (:func:`_expand_rows`
+        # at the width of the destination; ``L-1-col`` is strictly
+        # increasing over ranks).
+        islot = ((icomp >> 13) // CH) % K
+        W3 = max(IC, L)
+        lane = jnp.arange(W3, dtype=jnp.int32)[None, :]
         plane_w, plane_c = [], []
         for s in range(K):
-            sflag = ins_flag & (isub >= s * CH) & (isub < (s + 1) * CH)
-            ipay = ((icol << 16) | ((isub - s * CH) << 13)
-                    | jnp.minimum(w, (1 << 13) - 1))
-            comp_s, alive_s = _compact_rows(sflag, ipay, S)
-            if W2 > S:
-                comp_s = jnp.pad(comp_s, ((0, 0), (0, W2 - S)))
-                alive_s = jnp.pad(alive_s, ((0, 0), (0, W2 - S)))
-            dist = jnp.where(alive_s, (L - 1) - (comp_s >> 16) - lane, 0)
-            exp_s, _ = _expand_rows(alive_s, comp_s, dist, W2)
+            comp_s, alive_s = _compact_rows(ialive & (islot == s), icomp,
+                                            IC)
+            comp_s = _fit_lanes(comp_s, W3)
+            alive_s = _fit_lanes(alive_s, W3)
+            dist = jnp.where(
+                alive_s, (L - 1) - (comp_s >> 13) // (K * CH) - lane, 0)
+            exp_s, _ = _expand_rows(alive_s, comp_s, dist, W3)
             al_s = jnp.flip(exp_s[:, :L], axis=1)
             ws, cs = _int_vote_matmul(ohT8, (al_s >> 13) & (CH - 1),
                                       al_s & ((1 << 13) - 1), CH)
@@ -486,18 +523,13 @@ def _accumulate_votes(idx, w, ok, win_of, span_m, bg, n, score, *,
         weighted = jnp.concatenate([w_cols, ins_w], axis=1)
         unweighted = jnp.concatenate(
             [c_cols.astype(jnp.int32), ins_c], axis=1)
-        return (weighted, unweighted, jnp.int32(0),
-                jnp.zeros((nW,), jnp.int32))
+        # the reading that says the narrow stream held: accepted pairs
+        # (``ok`` is in ``ohT8``) that lost a vote to the cut, by window
+        ins_ovf_w = jnp.matmul(ohT8, past_ic.astype(jnp.int8)[:, None],
+                               preferred_element_type=jnp.int32)[:, 0]
+        return weighted, unweighted, jnp.sum(ins_ovf_w), ins_ovf_w
 
-    # ---- insertion votes: two-level compaction, then one packed scatter
-    # level 1 (per pair): an ok pair has < band//2 edits, hence < band//2
-    # insertion steps — lanes beyond IC can only hold votes of pairs that
-    # are dropped anyway
-    IC = min(S, band // 2)
-    ipay = ((idx - L * CH) << 13) | jnp.minimum(w, (1 << 13) - 1)
-    icomp, ialive = _compact_rows(ins_flag, ipay, S)
-    icomp = icomp[:, :IC]
-    ialive = ialive[:, :IC]
+    # ---- the level-1 stream through one packed scatter
     iaddr = icomp >> 13
     iw = ((icomp & ((1 << 13) - 1))
           * (ialive & ok[:, None]).astype(jnp.int32))
@@ -647,12 +679,14 @@ def refine_round(n, qpw, win_of, real, bg, ed,
     flag (backbone outgrew Lb), ``conv`` converged flag (backbone
     reproduced itself; layers stop realigning). ``dropped`` accumulates telemetry
     counters ([nd, 4 + n_windows] i32: rejected layer alignments,
-    sweep-truncated spans, fold-overflow insertion votes — which never
-    lose votes, they switch the round to the uncapped scatter — executed
-    post-gating wavefront steps, then the fold overflows attributed to
-    their windows). The single source of truth for the round wiring,
-    wrapped by :func:`refine_loop` (all rounds in one dispatch) and the
-    ``shard_map`` path (``racon_tpu.parallel.sharded_refine_loop``).
+    sweep-truncated spans, insertion overflows — accepted pairs past the
+    ``band // 2`` insertion lanes on the matmul path, 0 unless the score
+    gate failed; fold-cap overflows on the scatter path, which lose no
+    vote — executed post-gating wavefront steps, then the overflows
+    attributed to their windows). The single source of truth for the
+    round wiring, wrapped by :func:`refine_loop` (all rounds in one
+    dispatch) and the ``shard_map`` path
+    (``racon_tpu.parallel.sharded_refine_loop``).
 
     Layer codes and phred weights travel packed (``qpw`` uint16 lanes,
     ``weight << 3 | code`` — one transfer array instead of two, one
@@ -727,11 +761,13 @@ def refine_round(n, qpw, win_of, real, bg, ed,
     # telemetry: [0] total dropped layer alignments, [1] the subset whose
     # span outgrew the sweep bound (n + m > steps keeps the walk from
     # finishing — a quality cliff distinct from band escapes, ADVICE r3),
-    # [2] insertion votes past the fold-compaction cap (not lost — the
-    # round fell back to the uncapped level-1 scatter), [3] executed
-    # wavefront steps (sum of n+m AFTER convergence gating — the honest
-    # numerator for device-utilization estimates: gated pairs do no DP);
-    # columns [4:] attribute the fold overflows of [2] to their windows
+    # [2] insertion overflows (_accumulate_votes: accepted pairs past
+    # the band // 2 insertion lanes on the matmul path, which must read
+    # 0; votes past the fold cap on the scatter path, none lost),
+    # [3] executed wavefront steps (sum of n+m AFTER convergence gating
+    # — the honest numerator for device-utilization estimates: gated
+    # pairs do no DP);
+    # columns [4:] attribute the overflows of [2] to their windows
     dropped = dropped + jnp.concatenate(
         [jnp.stack([jnp.sum((~okp) & real),
                     jnp.sum(real & (n + m > steps)),
@@ -1243,8 +1279,9 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # lives on its chip (PallasDispatchMixin._pinned)
         self.device = device
         # int8/i32 MXU vote reduction: exact integer accumulation, no
-        # fold cap — ins_overflow is structurally 0 on this path; False
-        # selects the f32-matmul + packed scatter (tests)
+        # fold cap — ins_overflow counts accepted pairs past the
+        # band // 2 insertion lanes and reads 0; False selects the
+        # f32-matmul + packed scatter (tests)
         self.use_matmul_votes = use_matmul_votes
         # ragged window packing: windows bucket by their own size,
         # groups greedy-fill a fixed lane arena — the cudabatch

@@ -465,6 +465,208 @@ def test_matmul_votes_deep_address_regression():
     assert np.array_equal(np.asarray(unweighted), np.asarray(unw_ref))
 
 
+def _walk_stream(rng, S, L, K, CH, bg, m, ins_runs, n_del=0):
+    """One pair's backward-walk vote stream, as both emitters write it:
+    the walk runs from column ``bg + m - 1`` down to ``bg``; before it
+    consumes column ``c`` it takes ``ins_runs.get(c, 0)`` insertion
+    steps at junction ``c`` (slot = position in the run, walk order;
+    steps past the K-th are non-votes on the sink), then an M step, or
+    a D step for the first ``n_del`` columns that carry no insertion.
+    Returns (idx [S], w [S], edits) with the sink past the last step."""
+    VOT = L * (1 + K) * CH
+    idx, w, edits = [], [], 0
+    for c in range(bg + m - 1, bg - 1, -1):
+        for r in range(ins_runs.get(c, 0)):
+            edits += 1
+            if r < K:
+                idx.append((L + c * K + r) * CH + int(rng.integers(0, 5)))
+                w.append(int(rng.integers(1, 94)))
+            else:
+                idx.append(VOT)
+                w.append(0)
+        if n_del and c not in ins_runs:
+            n_del -= 1
+            edits += 1
+            idx.append(c * CH + 5)
+        else:
+            idx.append(c * CH + int(rng.integers(0, 5)))
+        w.append(int(rng.integers(1, 94)))
+    assert len(idx) <= S, (len(idx), S)
+    pad = S - len(idx)
+    return (np.array(idx + [VOT] * pad, np.int32),
+            np.array(w + [0] * pad, np.int32), edits)
+
+
+def _runs_for(rng, n_votes, bg, m, K, first_col=None):
+    """Insertion runs (at most K long) carrying ``n_votes`` votes at
+    distinct junctions of [bg, bg + m), ``first_col`` among them."""
+    n_runs = -(-n_votes // K)
+    cols = rng.choice(np.arange(bg, bg + m), size=n_runs, replace=False)
+    if first_col is not None and n_runs and first_col not in cols:
+        cols[0] = first_col
+    runs = {int(c): K for c in cols}
+    if n_votes % K:
+        runs[int(cols[-1])] = n_votes % K
+    assert sum(runs.values()) == n_votes
+    return runs
+
+
+def _ins_room(S, K):
+    """The most insertion votes a stream of S steps holds beside the
+    columns their junctions need (K votes a junction)."""
+    return (S * K) // (K + 1) - K
+
+
+def _vote_streams(S, L, band, seed):
+    """A 32-pair batch over 3 windows: typical pairs, one with IC - 1
+    insertion votes (accepted; as many as S holds where S < IC), pairs
+    with IC and IC + 9 (rejected by the score gate), insertion runs
+    longer than K, a junction with all K slots at column L - 1."""
+    from racon_tpu.ops.poa import CH, K_INS as K
+    rng = np.random.default_rng(seed)
+    B, nW, IC = 32, 3, min(S, band // 2)
+    room = _ins_room(S, K)
+    rows, meta = [], []
+
+    def add(bg, m, runs, n_del=0, mism=0):
+        idx, w, edits = _walk_stream(rng, S, L, K, CH, bg, m, runs, n_del)
+        rows.append((idx, w))
+        meta.append((bg, m, edits + mism))
+
+    def crafted(n_votes):
+        m = min(L, S - n_votes)
+        bg = L - m
+        add(bg, m, _runs_for(rng, n_votes, bg, m, K, first_col=L - 1))
+
+    crafted(min(IC - 1, room))                      # accepted
+    if IC + 9 <= room:
+        crafted(IC)                                 # rejected, none lost
+        crafted(IC + 9)                             # rejected, cut
+    # all K slots at the window's last column and a run of 2 K + 1
+    m = min(L, S // 2)
+    add(L - m, m, {L - 1: K, L - m + 3: 2 * K + 1, L - m: 1}, n_del=3)
+    while len(rows) < B:
+        m = int(rng.integers(8, min(L, S - 40)))
+        bg = int(rng.integers(0, L - m + 1))
+        cols = rng.choice(np.arange(bg, bg + m), size=min(m, 8),
+                          replace=False)
+        runs = {int(c): int(rng.integers(1, K + 3)) for c in cols[:5]}
+        if S - m - sum(runs.values()) < 0:
+            runs = {}
+        add(bg, m, runs, n_del=int(rng.integers(0, 4)),
+            mism=int(rng.integers(0, 30)))
+    idx = np.stack([r[0] for r in rows])
+    w = np.stack([r[1] for r in rows])
+    bg, span_m, score = (np.array(v, np.int32) for v in zip(*meta))
+    win_of = (np.arange(B) % nW).astype(np.int32)
+    n = (span_m + rng.integers(0, 20, B)).astype(np.int32)
+    ok = score < band // 2
+    return idx, w, ok, win_of, span_m, bg, n, score, nW
+
+
+def _scatter_reference(idx, w, ok, win_of, span_m, n, score, nW, L,
+                       scores):
+    """Per-pair scatter of the stream in plain numpy integers (alpha as
+    ``_accumulate_votes`` documents it)."""
+    from racon_tpu.ops.poa import (CH, DEL, K_INS as K, DEFAULT_MATCH,
+                                   DEFAULT_MISMATCH, DEFAULT_GAP)
+    VOT = L * (1 + K) * CH
+    weighted = np.zeros((nW, VOT), np.int64)
+    unweighted = np.zeros((nW, VOT), np.int64)
+    for p in range(idx.shape[0]):
+        if not ok[p]:
+            continue
+        vote = idx[p] < VOT
+        gaps = int(np.sum(vote & ((idx[p] >= L * CH)
+                                  | (idx[p] % CH == DEL))))
+        alpha = 64
+        if scores != (DEFAULT_MATCH, DEFAULT_MISMATCH, DEFAULT_GAP):
+            mis = max(int(score[p]) - gaps, 0)
+            mat = max((int(n[p]) + int(span_m[p]) - gaps) // 2 - mis, 0)
+            cli = np.float32(scores[0] * mat + scores[1] * mis
+                             + scores[2] * gaps)
+            dfl = np.float32(DEFAULT_MATCH * mat + DEFAULT_MISMATCH * mis
+                             + DEFAULT_GAP * gaps)
+            alpha = int(np.clip(np.round(
+                np.float32(64.0) * max(cli, np.float32(0.0))
+                / max(dfl, np.float32(1.0))), 1, 88))
+        for a, wt in zip(idx[p][vote], w[p][vote]):
+            weighted[win_of[p], a] += int(wt) * alpha
+            unweighted[win_of[p], a] += 1
+    return weighted, unweighted
+
+
+@pytest.mark.parametrize("scores", [(3, -5, -4), (8, -6, -8)],
+                         ids=["default", "golden"])
+@pytest.mark.parametrize("S,L,band", [(1280, 768, 512), (384, 768, 512),
+                                      (128, 256, 512), (640, 384, 512)])
+def test_matmul_votes_route_the_narrow_stream_exactly(S, L, band, scores):
+    """The matmul path routes all insertion votes from ONE compaction
+    cut to IC = min(S, band // 2) lanes, then K narrow slot planes, and
+    the column votes at L lanes: equal, element for element, to the
+    scatter branch and to a per-pair numpy scatter at the long-read
+    geometry, the short-read one, S < band // 2 and S > L."""
+    from racon_tpu.ops.poa import CH, K_INS, _accumulate_votes
+
+    idx, w, ok, win_of, span_m, bg, n, score, nW = _vote_streams(
+        S, L, band, seed=S + L)
+    IC, room = min(S, band // 2), _ins_room(S, K_INS)
+    n_ins = np.sum((idx >= L * CH) & (idx < L * (1 + K_INS) * CH), axis=1)
+    assert ok[0] and n_ins[0] == min(IC - 1, room)
+    if IC + 9 <= room:
+        assert n_ins[1] == IC and n_ins[2] > IC and not ok[1:3].any()
+    args = [jnp.asarray(a) for a in
+            (idx, w, ok, win_of, span_m, bg, n, score)]
+    kw = dict(n_windows=nW, L=L, K=K_INS, band=band, scores=scores)
+    wm, um, ovf, ovf_w = _accumulate_votes(*args, matmul_votes=True, **kw)
+    ws, us, _, _ = _accumulate_votes(*args, matmul_votes=False, **kw)
+    wr, ur = _scatter_reference(idx, w, ok, win_of, span_m, n, score, nW,
+                                L, scores)
+    assert ur[:, L * CH:].sum() >= n_ins[0]     # the planes carry votes
+    assert np.array_equal(np.asarray(um), ur)
+    assert np.array_equal(np.asarray(wm).astype(np.int64), wr)
+    assert np.array_equal(np.asarray(um), np.asarray(us))
+    assert np.array_equal(np.asarray(wm), np.asarray(ws))
+    assert int(ovf) == 0 and not np.asarray(ovf_w).any()
+
+
+def test_matmul_votes_count_what_the_cut_loses():
+    """``dropped[:, 2]`` on the matmul path: accepted pairs whose
+    insertion stream reached past lane IC. The score gate makes it 0;
+    a crafted ``ok`` trips it, per window, and a pair with exactly IC
+    votes loses none."""
+    from racon_tpu.ops.poa import K_INS, _accumulate_votes
+
+    S, L, band = 640, 384, 512
+    idx, w, ok, win_of, span_m, bg, n, score, nW = _vote_streams(
+        S, L, band, seed=7)
+    assert not ok[1] and not ok[2]
+    kw = dict(n_windows=nW, L=L, K=K_INS, band=band, matmul_votes=True)
+
+    def run(ok_):
+        args = [jnp.asarray(a) for a in
+                (idx, w, ok_, win_of, span_m, bg, n, score)]
+        wm, um, ovf, ovf_w = _accumulate_votes(*args, **kw)
+        return (np.asarray(wm).astype(np.int64), np.asarray(um), int(ovf),
+                np.asarray(ovf_w))
+
+    *_, ovf, ovf_w = run(ok)
+    assert ovf == 0 and not ovf_w.any()
+    exact = ok.copy()
+    exact[1] = True                    # IC votes: all of them fit
+    wm, um, ovf, ovf_w = run(exact)
+    wr, ur = _scatter_reference(idx, w, exact, win_of, span_m, n, score,
+                                nW, L, (3, -5, -4))
+    assert ovf == 0 and np.array_equal(um, ur) and np.array_equal(wm, wr)
+    over = ok.copy()
+    over[2] = True                     # IC + 9 votes: 9 are cut
+    wm, um, ovf, ovf_w = run(over)
+    _, ur = _scatter_reference(idx, w, over, win_of, span_m, n, score,
+                               nW, L, (3, -5, -4))
+    assert ovf == 1 and ovf_w.tolist() == [0, 0, 1]    # pair 2, window 2
+    assert ur.sum() - um.sum() == 9
+
+
 # --------------------------------------------------------------- warm-up
 
 def test_warmup_async_compiles_and_engine_still_exact():
