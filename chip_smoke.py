@@ -244,9 +244,9 @@ def check_report(rep: dict, n_pairs: int, n_windows: int, out: dict,
                    f"{n_windows} windows went to the host consensus "
                    f"(> {MAX_HOST_FRACTION:.0%})")
     comp = rep["compiles"]
-    out["compiles"] = {"count": comp["count"],
-                       "total_s": comp["total_s"],
-                       "post_warm": comp["post_warm"]}
+    out["compiles"] = {k: comp[k] for k in (
+        "count", "total_s", "post_warm", "wall_s", "eager_programs",
+        "unused_s", "miss_s")}
     if comp["post_warm"]:
         bad.append(f"{comp['post_warm']} post-warm compiles")
     return bad
@@ -382,8 +382,12 @@ def one_chip(args, on_tpu: bool) -> None:
             raise SmokeFailure("second run's FASTA differs from the "
                                "first")
         if comp["count"]:
-            raise SmokeFailure(f"second run compiled {comp['count']} "
-                               f"programs: {comp['by_function']}")
+            names = sorted({f"{r['program']} [{r['geometry'] or r['fn']}]"
+                            for r in comp["programs"]})
+            raise SmokeFailure(
+                f"second run compiled {comp['count']} programs "
+                f"({comp['wall_s']} s as wall, {comp['eager_programs']} "
+                f"of them eager helpers): {names}")
 
 
 def four_chips(args, on_tpu: bool) -> None:

@@ -139,7 +139,6 @@ METRICS: FrozenSet[str] = frozenset((
 # ordinal, a phase, a fault class/site, a swallowed-exception context)
 DYNAMIC_METRIC_PREFIXES: Tuple[str, ...] = (
     "align.pairs_by_bucket.",  # .<max_len>: pairs dispatched per bucket
-    "compile.",          # compile.<fn> per-function compile counts
     "device.",           # device.<ordinal>.shards/.mbp/.polish_s/...
     "faults.",           # faults.<class> taxonomy counts
     "faults.injected.",  # faults.injected.<site>
@@ -188,8 +187,10 @@ SPANS: FrozenSet[str] = frozenset((
     # the half of the layer assembly that needs no breaking point
     # (beside the aligner, or inline), and the wait for it at the barrier
     "build.prepare", "build.prepare_wait",
-    # the compile listener's back-dated stages (obs/compilewatch.py)
-    "compile.backend", "compile.lower", "compile.trace",
+    # the compile listener's back-dated stages (obs/compilewatch.py);
+    # compile.retrieve: the persistent cache's read inside the backend
+    "compile.backend", "compile.lower", "compile.retrieve",
+    "compile.trace",
     "consensus", "consensus.feed", "consensus.finish", "consensus.run",
     "exec.extract", "exec.index", "exec.merge", "exec.plan",
     "exec.shard",
@@ -210,7 +211,8 @@ SPANS: FrozenSet[str] = frozenset((
 # idle: the occupancy ledger (obs/device_time.py) reads through them, so
 # the parent's ``idle.<span>`` timer, and every metric that sums it, is
 # what it was before the leaf existed
-TIMER_ONLY_SPANS: FrozenSet[str] = frozenset(("poa.lanes",))
+TIMER_ONLY_SPANS: FrozenSet[str] = frozenset(("poa.lanes",
+                                               "compile.retrieve"))
 
 # ------------------------------------------------------------ fault sites
 
@@ -232,7 +234,7 @@ FAULT_CLASSES: Tuple[str, ...] = ("transient-io", "device-oom", "stall",
 
 # -------------------------------------------------------- report schema
 
-SCHEMA_VERSION = 12
+SCHEMA_VERSION = 13
 
 # the oldest version validate_report still accepts, as itself: a stored
 # v11 report is held to the v11 key sets
@@ -284,7 +286,10 @@ SECTION_KEYS: Dict[str, Dict[str, int]] = {
         "slot_quarantined": 5,
     },
     "compiles": {"total_s": 7, "count": 7, "post_warm": 7, "sealed": 7,
-                 "by_function": 7, "events": 7},
+                 "by_function": 7, "events": 7,
+                 "programs": 13, "dropped": 13, "wall_s": 13,
+                 "unused": 13, "unused_s": 13, "eager_programs": 13,
+                 "miss_s": 13, "unrowed_s": 13},
     "dataflow": {
         "resident": 8, "bytes_fetched": 8, "bytes_avoided": 8,
         "fallback_pairs": 8, "resident_bailouts": 8,
@@ -313,11 +318,13 @@ SECTION_KEYS: Dict[str, Dict[str, int]] = {
     },
 }
 
-# schema keys REMOVED at a version (key -> (section, removed_in));
-# empty today — a future key retirement lands here so the
-# schema-coherence message can say "stale v<N key" instead of
-# "unknown key"
-REMOVED_KEYS: Dict[str, Tuple[str, int]] = {}
+# schema keys REMOVED at a version (key -> (section, removed_in)): a
+# stored report of an older version still holds them, a newer one must
+# not, and the schema-coherence message says "retired in v<N>" instead
+# of "unknown key". v13: the truncated event list and the roll-up by
+# frame, superseded by compiles.programs
+REMOVED_KEYS: Dict[str, Tuple[str, int]] = {
+    "by_function": ("compiles", 13), "events": ("compiles", 13)}
 
 
 def schema_keys(version: int = SCHEMA_VERSION) -> Dict[str, FrozenSet[str]]:
@@ -327,9 +334,14 @@ def schema_keys(version: int = SCHEMA_VERSION) -> Dict[str, FrozenSet[str]]:
     comment block."""
     out = {"top": frozenset(k for k, v in TOP_KEYS.items()
                             if v <= version)}
+    def retired(section: str, key: str) -> bool:
+        where, since = REMOVED_KEYS.get(key, ("", 0))
+        return where == section and since <= version
+
     for section, keys in SECTION_KEYS.items():
         out[section] = frozenset(k for k, v in keys.items()
-                                 if v <= version)
+                                 if v <= version
+                                 and not retired(section, k))
     return out
 
 

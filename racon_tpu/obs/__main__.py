@@ -1,7 +1,9 @@
 """``python -m racon_tpu.obs --check FILE`` — run-report validation
 (the CI e2e check drives this); ``python -m racon_tpu.obs gaps
 RUN_REPORT DEVICE_TRACE`` — the report's occupancy ledger laid on a
-device trace (:mod:`racon_tpu.obs.gaps`)."""
+device trace (:mod:`racon_tpu.obs.gaps`); ``python -m racon_tpu.obs
+compiles RUN_REPORT`` — the report's compiled programs, one line each,
+and the set-up totals (:mod:`racon_tpu.obs.compilewatch`)."""
 
 import sys
 

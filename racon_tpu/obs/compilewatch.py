@@ -14,21 +14,34 @@ round 14) observes every ``/jax/core/compile/*`` event and:
   duration) onto the compiling thread's ring and into the timers of
   those names, so what the persistent cache saves (backend) reads apart
   from what it does not (trace + lowering). A stage nested in another
-  (a jit traced inside a trace) gives its parent's timer only the
-  parent's self time: the three timers sum to thread time in the
-  compile pipeline, where ``compile.jax_s`` sums every event whole;
+  (a jit traced inside a trace; JAX announces each stage as it begins,
+  the scalar listener keeps the thread's open ones) gives its parent's
+  timer only the parent's self time: the three timers sum to thread
+  time in the compile pipeline, where ``compile.jax_s`` sums every
+  event whole;
 - counts the persistent cache's lookups and hits
   (``compile.cache_requests`` / ``compile.cache_hits``) from JAX's own
-  ``/jax/compilation_cache/*`` events;
-- **attributes** every backend compile to ``(function, shape
-  signature, phase, scope)``: the nearest ``racon_tpu`` frame on the
-  compiling thread's stack names the driving function, its integer
-  geometry locals (``max_len``/``band``/``steps``/``B``/...) form the
-  shape signature, the innermost open obs span is the phase, and the
-  thread's metric scope is the job.  Counters land as
-  ``compile.<fn>`` in the one registry; the full records ride the
-  bounded event ring (:func:`events`) and the run report's required
-  ``compiles`` section (schema v7, :func:`summary`);
+  ``/jax/compilation_cache/*`` events, and back-dates the cache's
+  retrieval seconds as ``compile.retrieve`` — a timer-only leaf inside
+  ``compile.backend``, which keeps meaning "compile or load";
+- writes **one row per compiled program**: the stages JAX ran on one
+  thread for one ``jit`` call that reached the backend, under the name
+  JAX gives every stage listener (``fun_name``: the row's ``program``
+  is the name the device trace prints), with the row's interval on the
+  spans' clock, its stage seconds, whether the persistent cache hit,
+  and who asked — the nearest ``racon_tpu`` frame on the compiling
+  thread's stack (``fn``), its integer geometry locals
+  (``max_len``/``band``/``steps``/``B``/..., the ``signature``), the
+  innermost open obs span (``phase``), the thread and its metric scope.
+  The rows ride a bounded ring (:func:`events`) and the run report's
+  ``compiles`` section (:func:`summary`);
+- **joins rows to dispatches**: the occupancy ledger
+  (:mod:`.device_time`) tells :func:`claim` the program and static
+  geometry of every submission, on the thread that made it; the row a
+  thread has just compiled for that program takes the submission's
+  geometry string as its own, and at report time counts the ``exec``
+  submissions that carry the same pair. A program no submission ever
+  named (an eager ``jnp`` one-liner) keeps ``dispatches`` None;
 - enforces the **warm-path claim** once :func:`seal` is called (the
   resident server seals after its first job completes): a compile
   whose ``(function, signature)`` was never seen pre-seal is a
@@ -45,6 +58,7 @@ Import cost is nil: jax is touched only inside :func:`arm`.
 from __future__ import annotations
 
 import math
+import re
 import sys
 import threading
 import time
@@ -59,7 +73,8 @@ GEOM_LOCALS = ("max_len", "band", "steps", "B", "nWp", "Lq", "Lb",
                "window_length", "est_len", "est_pairs", "max_nm",
                "max_n")
 
-MAX_EVENTS = 256        # bounded event ring (newest kept)
+MAX_ROWS = 4096         # bounded row ring (newest kept; a job compiles
+                        # tens of programs, a cold one a few hundred)
 MAX_VIOLATIONS = 64
 
 # JAX's compile-pipeline stages -> the span each is back-dated as
@@ -68,13 +83,25 @@ STAGE_SPANS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
     "/jax/core/compile/backend_compile_duration": "compile.backend",
 }
+BACKEND = "compile.backend"
+# a row's stage seconds, by the span each sums
+STAGE_KEYS = {"compile.trace": "trace_s", "compile.lower": "lower_s",
+              BACKEND: "backend_s"}
 # JAX's persistent-cache events -> the counter each feeds
-CACHE_COUNTERS = {
-    "/jax/compilation_cache/compile_requests_use_cache":
-        "compile.cache_requests",
-    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
-}
-MAX_UNCLAIMED = 256     # per thread: stage events no parent has claimed
+CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+CACHE_HIT = "/jax/compilation_cache/cache_hits"
+CACHE_COUNTERS = {CACHE_REQUEST: "compile.cache_requests",
+                  CACHE_HIT: "compile.cache_hits"}
+# the cache's own timer of a hit: reading and deserialising the
+# executable (inside backend_compile_duration, which wraps the lookup)
+CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+RETRIEVE = "compile.retrieve"
+MAX_UNCLAIMED = 16      # per thread: finished outermost stages no row took
+MAX_PENDING = 64        # per thread: rows no submission has claimed yet
+
+# a ledger name that stands for several programs dispatched back to
+# back: the XLA twin of the Mosaic ``_pallas_align_chain``
+CHAINS = {"align_chain": ("_nw_wavefront_kernel", "_traceback_kernel")}
 
 _tls = threading.local()
 
@@ -82,9 +109,32 @@ _lock = threading.Lock()
 _armed = False
 _sealed: Optional[str] = None
 _total_count = 0
-_events: List[dict] = []
+_epoch = 0                          # bumped by reset(): a thread's stages
+                                    # and rows of an earlier run are dropped
+_rows: List[dict] = []
+_unrowed_ns: Dict[str, int] = {}    # scope -> stage ns in no row (yet)
 _seen: set = set()                  # (fn, signature) warmed pre-seal
 _violations: List[dict] = []
+
+
+def geometry(**statics) -> str:
+    """The join key of a dispatch: a program's static arguments and
+    batch shape as ``k=v`` pairs, the vocabulary of ``GEOM_LOCALS``
+    first and in its order. The stream's submit site and the warm-up
+    thread both build it with this function from the values they hand
+    the jitted call, so a warm-up row and the dispatch that uses its
+    executable carry the same string."""
+    known = [k for k in GEOM_LOCALS if k in statics]
+    rest = [k for k in statics if k not in GEOM_LOCALS]
+    return ",".join(f"{k}={int(statics[k])}" for k in known + rest)
+
+
+def program_name(fun_name: str) -> str:
+    """``jit(_refine_loop_packed)`` (what JAX hands the lowering and
+    backend listeners) -> ``jit__refine_loop_packed`` (the XLA module,
+    as the device trace prints it)."""
+    m = re.match(r"^(\w+)\((.*)\)$", fun_name)
+    return f"{m.group(1)}_{m.group(2)}" if m else fun_name
 
 
 def _attribute() -> Tuple[str, str]:
@@ -126,62 +176,162 @@ def _attribute() -> Tuple[str, str]:
     return fn, ",".join(parts)
 
 
-def _record_stage(name: str, duration: float) -> None:
+def _mine():
+    """The calling thread's ``(open stages, finished stages no row has
+    taken, rows no submission has claimed)``, started empty at each run
+    boundary (:func:`reset`)."""
+    if getattr(_tls, "epoch", None) != _epoch:
+        _tls.epoch = _epoch
+        _tls.open, _tls.unclaimed, _tls.pending = [], [], []
+    return _tls.open, _tls.unclaimed, _tls.pending
+
+
+def _unrowed(scope: str, ns: int) -> None:
+    with _lock:
+        _unrowed_ns[scope] = _unrowed_ns.get(scope, 0) + ns
+
+
+def _add(total: Dict[str, int], more: Dict[str, int]) -> None:
+    for k, v in more.items():
+        total[k] = total.get(k, 0) + v
+
+
+def _on_scalar(event, value, **kwargs) -> None:
+    """The registered scalar listener: JAX announces every stage as it
+    begins (``LogElapsedTimeContextManager.__enter__``). The frame it
+    opens collects what ends inside the stage — a Mosaic lowering
+    traces thousands of operators — as two numbers, not as a list."""
+    span = STAGE_SPANS.get(str(event))
+    if span is not None:
+        # [span, {span: ns} of the stages that ended inside, their ns]
+        _mine()[0].append([span, {}, 0])
+
+
+def _record_stage(name: str, duration: float, fun_name: str,
+                  scope: str) -> tuple:
     """Back-date one finished stage onto this thread's ring; its timer
-    gets the stage's self time (a stage that ended inside this one was
-    recorded before it: children fire first)."""
+    gets the stage's self time: what ended inside it was handed to its
+    frame as it ended (children fire first). A stage nested in another
+    hands its seconds, by span, up to that one; an outermost stage is
+    kept for the row of the backend stage that follows it. Returns the
+    stage as ``(t0, t1, {span: ns}, span, fun_name)``: its self time
+    plus what it enclosed, so a row sums to the timers."""
     t1 = time.perf_counter_ns()
-    t0 = t1 - int(duration * 1e9)
-    unclaimed = getattr(_tls, "unclaimed", None)
-    if unclaimed is None:
-        unclaimed = _tls.unclaimed = []
+    ns = int(duration * 1e9)
+    stack, unclaimed, _ = _mine()
+    held: Dict[str, int] = {}
     inside = 0
-    while unclaimed and unclaimed[-1][0] >= t0:
-        c0, c1 = unclaimed.pop()
-        inside += c1 - c0
-    unclaimed.append((t0, t1))
-    del unclaimed[:-MAX_UNCLAIMED]
-    trace.record(name, t0, t1, seconds=max(0, t1 - t0 - inside) * 1e-9)
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i][0] == name:
+            # a frame above it never ended (JAX drops a stage's end
+            # only while the interpreter exits): it takes nothing along
+            _, held, inside = stack[i]
+            del stack[i:]
+            break
+    self_ns = max(0, ns - inside)
+    held[name] = held.get(name, 0) + self_ns
+    _unrowed(scope, self_ns)
+    trace.record(name, t1 - ns, t1, seconds=self_ns * 1e-9)
+    entry = (t1 - ns, t1, held, name, fun_name)
+    if stack:
+        stack[-1][2] += ns
+        if name != BACKEND:     # a nested backend's seconds are its row's
+            _add(stack[-1][1], held)
+    else:
+        unclaimed.append(entry)
+        del unclaimed[:-MAX_UNCLAIMED]
+    return entry
+
+
+def _take_row(scope: str, backend: tuple) -> Tuple[int, int, Dict[str, int]]:
+    """One row's ``(t0_ns, t1_ns, {span: ns})``: the backend stage just
+    recorded on this thread and, where it is an outermost one, the
+    lowering and the trace that led to it (the same program's, directly
+    before it among the thread's finished outermost stages)."""
+    t0, t1, ns_by, _, name = backend
+    ns_by = dict(ns_by)
+    unclaimed = _mine()[1]
+    if unclaimed and unclaimed[-1] is backend:
+        unclaimed.pop()
+        for span in ("compile.lower", "compile.trace"):
+            if not unclaimed or unclaimed[-1][3] != span:
+                continue
+            have = unclaimed[-1][4]
+            # lowering carries the backend's name, tracing the
+            # function's (``jit(f)`` / ``f``); a listener driven without
+            # names matches by position alone
+            if name and have and have != name \
+                    and not name.endswith(f"({have})"):
+                continue
+            t0, _, more, _, _ = unclaimed.pop()
+            _add(ns_by, more)
+    _unrowed(scope, -sum(ns_by.values()))
+    return t0, t1, ns_by
 
 
 def _on_event(event, **kwargs) -> None:
     """The registered plain-event listener: the persistent cache's
-    lookups and hits."""
-    name = CACHE_COUNTERS.get(str(event))
+    lookups and hits, counted, and kept for the row of the backend
+    event that follows on this thread."""
+    event = str(event)
+    name = CACHE_COUNTERS.get(event)
     if name is not None:
         metrics.inc(name)
+        _tls.cache = "hit" if event == CACHE_HIT else "miss"
 
 
-def _on_duration(event, duration, **kwargs) -> None:
+def _on_duration(event, duration, fun_name: str = "", **kwargs) -> None:
     """The registered listener: every compile-pipeline stage feeds the
     ``compile.jax_s`` timer (the round-14 serve semantics, verbatim)
-    and its own back-dated span; backend compiles additionally produce
-    one attributed record."""
+    and its own back-dated span; a backend compile closes one row."""
     global _total_count
-    if not str(event).startswith("/jax/core/compile/"):
+    event = str(event)
+    if event == CACHE_RETRIEVAL:
+        t1 = time.perf_counter_ns()
+        trace.record(RETRIEVE, t1 - int(duration * 1e9), t1)
+        _tls.retrieve_s = float(duration)
+        return
+    if not event.startswith("/jax/core/compile/"):
         return
     metrics.add_time("compile.jax_s", duration)
-    stage = STAGE_SPANS.get(str(event))
-    if stage is not None:
-        _record_stage(stage, duration)
-    if "backend_compile" not in str(event):
+    stage = STAGE_SPANS.get(event)
+    if stage is None:
+        return
+    scope = metrics.get_scope() or ""
+    fun_name = str(fun_name or "")
+    entry = _record_stage(stage, duration, fun_name, scope)
+    if stage != BACKEND:
         return
     fn, signature = _attribute()
-    scope = metrics.get_scope() or ""
     phase = trace.current_span() or ""
-    metrics.inc(f"compile.{fn}")
-    # scoped exact count: the event ring is bounded (a job's records
-    # can be evicted by later compiles before its report is built), so
-    # the per-scope `count` reads this counter, not the ring
+    # scoped exact count: the row ring is bounded (a job's rows can be
+    # evicted by later compiles before its report is built), so the
+    # per-scope `count` reads this counter, not the ring
     metrics.inc("compile.backend_total")
-    ev = {"fn": fn, "signature": signature, "phase": phase,
-          "scope": scope, "duration_s": round(float(duration), 4)}
+    t0, t1, ns_by = _take_row(scope, entry)
+    row = {"program": program_name(fun_name), "fn": fn,
+           "signature": signature,
+           # the submission that runs this executable names both
+           # (claim); None: no submission has, an eager helper so far
+           "geometry": "", "submitted_as": None,
+           "thread": threading.current_thread().name, "phase": phase,
+           "scope": scope, "t0_ns": t0, "t1_ns": t1,
+           "retrieve_s": getattr(_tls, "retrieve_s", 0.0),
+           # "none": JAX asked the persistent cache nothing
+           "cache": getattr(_tls, "cache", None) or "none"}
+    for span, key in STAGE_KEYS.items():
+        row[key] = ns_by.get(span, 0) * 1e-9
+    _tls.cache = None
+    _tls.retrieve_s = 0.0
+    pending = _mine()[2]
+    pending.append(row)
+    del pending[:-MAX_PENDING]
     warn_msg = None
     with _lock:
         _total_count += 1
-        _events.append(ev)
-        if len(_events) > MAX_EVENTS:
-            del _events[0]
+        _rows.append(row)
+        if len(_rows) > MAX_ROWS:
+            del _rows[0]
         key = (fn, signature)
         if _sealed is None or not scope:
             # pre-seal, every compile warms.  Post-seal, an UNSCOPED
@@ -192,8 +342,10 @@ def _on_duration(event, duration, **kwargs) -> None:
             # violate the warm-path claim.
             _seen.add(key)
         elif key not in _seen:
-            viol = dict(ev)
-            viol["nearest_warmed"] = _nearest_locked(fn, signature)
+            viol = {"fn": fn, "signature": signature, "phase": phase,
+                    "scope": scope,
+                    "duration_s": round(float(duration), 4),
+                    "nearest_warmed": _nearest_locked(fn, signature)}
             # FIFO-bounded, never refuse the newest: judged scopes are
             # pruned (clear_scope), so the cap only backstops unjudged
             # ones — refusing new records here would silently disarm
@@ -210,6 +362,31 @@ def _on_duration(event, duration, **kwargs) -> None:
     if warn_msg is not None:
         from ..utils.logger import warn
         warn(warn_msg)
+
+
+def claim(name: str, geom: str) -> None:
+    """The occupancy ledger's hook (``device_time.submit``, recording
+    on): the calling thread has just dispatched program ``name`` with
+    static geometry ``geom``. A row this thread compiled for that
+    program inside the span it is in now — the call that compiled it is
+    the call being submitted; an availability probe's compile of the
+    same kernel sits in an earlier span — is that executable's: it
+    takes the submission's name and geometry, the pair :func:`summary`
+    counts dispatches by."""
+    pending = _mine()[2]
+    if not pending:
+        return
+    since = trace.current_span_t0() or 0
+    programs = {"jit_" + p for p in CHAINS.get(name, (name,))}
+    mine = [r for r in pending
+            if r["program"] in programs and r["t0_ns"] >= since]
+    # what is older than the open span is an earlier step's for good
+    pending[:] = [r for r in pending
+                  if r["program"] not in programs and r["t0_ns"] >= since]
+    with _lock:
+        for row in mine:
+            row["geometry"] = geom
+            row["submitted_as"] = name
 
 
 def _sig_ints(signature: str) -> Dict[str, int]:
@@ -271,6 +448,7 @@ def arm() -> bool:
         if not _armed:
             jmon.register_event_duration_secs_listener(_on_duration)
             jmon.register_event_listener(_on_event)
+            jmon.register_scalar_listener(_on_scalar)
             _armed = True
     return True
 
@@ -304,7 +482,7 @@ def clear_scope(scope: str) -> None:
     after a job is JUDGED — counted into its header / asserted — so the
     bounded global list only ever holds unjudged scopes and a
     long-running sanitized server cannot fill it up and quietly stop
-    flagging later jobs).  Events are kept: they are telemetry, and the
+    flagging later jobs).  Rows are kept: they are telemetry, and the
     ring bounds itself."""
     if not scope:
         return
@@ -314,13 +492,15 @@ def clear_scope(scope: str) -> None:
 
 
 def reset() -> None:
-    """Drop recorded events/warmed set/violations and reopen the seal
+    """Drop recorded rows/warmed set/violations and reopen the seal
     (tests and run boundaries that must not inherit attribution)."""
-    global _sealed, _total_count
+    global _sealed, _total_count, _epoch
     with _lock:
         _sealed = None
         _total_count = 0
-        _events.clear()
+        _epoch += 1
+        _rows.clear()
+        _unrowed_ns.clear()
         _seen.clear()
         _violations.clear()
 
@@ -328,11 +508,11 @@ def reset() -> None:
 # ---------------------------------------------------------------- queries
 
 def events(scope: Optional[str] = None) -> List[dict]:
-    """Attributed compile records (bounded ring, oldest first);
+    """The rows, one per compiled program (bounded ring, oldest first);
     ``scope`` filters to one job's."""
     with _lock:
-        return [dict(e) for e in _events
-                if scope is None or e["scope"] == scope]
+        return [dict(r) for r in _rows
+                if scope is None or r["scope"] == scope]
 
 
 def post_warm(scope: Optional[str] = None) -> List[dict]:
@@ -356,34 +536,129 @@ def describe(violations: List[dict]) -> str:
     return "\n".join(lines)
 
 
-def summary(scope: str = "") -> dict:
-    """The run report's required ``compiles`` section (schema v7):
-    total attributed seconds, counts, the post-warm violation count,
-    per-function rollups and the trailing attributed events.  With
-    ``scope``, every piece is filtered to that job's records."""
+def union_s(intervals) -> float:
+    """Seconds covered by at least one of ``(t0_ns, t1_ns)``: compile
+    stages as wall, however many threads ran them at once."""
+    covered, end = 0, None
+    for t0, t1 in sorted(intervals):
+        if end is None or t0 > end:
+            covered += t1 - t0
+            end = t1
+        elif t1 > end:
+            covered += t1 - end
+            end = t1
+    return covered * 1e-9
+
+
+def stage_s(row: dict) -> float:
+    """A row's seconds in the three stage timers."""
+    return row["trace_s"] + row["lower_s"] + row["backend_s"]
+
+
+def summary(scope: str = "", ran: Optional[Dict[tuple, int]] = None
+            ) -> dict:
+    """The run report's required ``compiles`` section (schema v13): one
+    row per compiled program with the ``exec`` submissions that ran it
+    (``ran``: the occupancy ledger's ``dispatch_counts``), and the
+    totals the set-up metrics read.  With ``scope``, every piece is
+    filtered to that job's records."""
+    ran = ran or {}
     with _lock:
-        evs = [e for e in _events if not scope or e["scope"] == scope]
+        rows = [dict(r) for r in _rows
+                if not scope or r["scope"] == scope]
         viol = [v for v in _violations
                 if not scope or v["scope"] == scope]
         total = _total_count
         is_sealed = _sealed is not None
-    by_fn: Dict[str, Dict[str, float]] = {}
-    for e in evs:
-        row = by_fn.setdefault(e["fn"], {"count": 0, "seconds": 0.0})
-        row["count"] += 1
-        row["seconds"] = round(row["seconds"] + e["duration_s"], 4)
+        unrowed = sum(ns for sc, ns in _unrowed_ns.items()
+                      if not scope or sc == scope)
+    # scoped: the exact per-scope counter (the bounded row ring may
+    # have evicted early rows); unscoped: the module total
+    count = total if not scope else int(
+        metrics.counter(scope + "compile.backend_total", len(rows)))
+    for row in rows:
+        del row["scope"]
+        name = row.pop("submitted_as")
+        row["dispatches"] = None if name is None else ran.get(
+            (name, row["geometry"]), 0)
+        for key in ("trace_s", "lower_s", "backend_s", "retrieve_s"):
+            row[key] = round(row[key], 6)
+    rows.sort(key=lambda r: r["t0_ns"])
+    unused = [r for r in rows if r["dispatches"] == 0]
+    # a hit still costs its retrieval: the timer reads 0, not absent,
+    # where nothing was retrieved (a metric sums it in every report)
+    metrics.replace_timers(RETRIEVE, {
+        "": metrics.timer_s(scope + RETRIEVE)}, scope)
     return {
         "total_s": round(metrics.timer_s(scope + "compile.jax_s"), 3),
-        # scoped: the exact per-scope counter (the bounded event ring
-        # may have evicted early records); unscoped: the module total
-        "count": total if not scope else
-        int(metrics.counter(scope + "compile.backend_total",
-                            len(evs))),
+        "count": count,
         "post_warm": len(viol),
         "sealed": 1 if is_sealed else 0,
-        "by_function": by_fn,
-        "events": [{"fn": e["fn"], "signature": e["signature"],
-                    "phase": e["phase"],
-                    "duration_s": e["duration_s"]}
-                   for e in evs[-32:]],
+        "programs": rows,
+        "dropped": max(0, count - len(rows)),
+        "wall_s": round(union_s((r["t0_ns"], r["t1_ns"])
+                                for r in rows), 6),
+        "unused": len(unused),
+        "unused_s": round(float(sum(map(stage_s, unused))), 6),
+        "eager_programs": sum(r["dispatches"] is None for r in rows),
+        "miss_s": round(float(sum(stage_s(r) for r in rows
+                                  if r["cache"] == "miss")), 6),
+        "unrowed_s": round(unrowed * 1e-9, 6),
     }
+
+
+# ------------------------------------------------------------- the table
+
+def table(comp: dict, wall_s: float = 0.0) -> str:
+    """A report's ``compiles`` section as the table an operator reads:
+    one line per program in start order, then the totals."""
+    rows = comp["programs"]
+    origin = min((r["t0_ns"] for r in rows), default=0)
+    lines = [f"{'start_s':>8} {'wall_s':>7} {'trace':>7} {'lower':>6} "
+             f"{'backend':>8} {'retr':>6} cache disp  thread / phase / "
+             f"program [geometry | frame signature]"]
+    for r in rows:
+        disp = "-" if r["dispatches"] is None else str(r["dispatches"])
+        what = r["geometry"] or f"{r['fn']} {r['signature']}".strip()
+        lines.append(
+            f"{(r['t0_ns'] - origin) * 1e-9:8.2f} "
+            f"{(r['t1_ns'] - r['t0_ns']) * 1e-9:7.2f} "
+            f"{r['trace_s']:7.2f} {r['lower_s']:6.2f} "
+            f"{r['backend_s']:8.2f} {r['retrieve_s']:6.2f} "
+            f"{r['cache']:<5} {disp:>4}  {r['thread']} / "
+            f"{r['phase'] or '-'} / {r['program']} [{what}]")
+    staged = sum(map(stage_s, rows))
+    lines += [
+        "",
+        f"{comp['count']} programs ({len(rows)} rows, "
+        f"{comp['dropped']} dropped), {comp['eager_programs']} never "
+        f"submitted to the ledger (eager helpers)",
+        f"stage seconds {staged:.2f} summed over threads "
+        f"(+ {comp['unrowed_s']:.2f} in no row), {comp['wall_s']:.2f} s "
+        f"as wall" + (f" of the job's {wall_s:.2f}" if wall_s else ""),
+        f"cache misses {comp['miss_s']:.2f} s; "
+        f"{comp['unused']} programs no dispatch ran, "
+        f"{comp['unused_s']:.2f} s",
+        f"post-warm compiles {comp['post_warm']} "
+        f"(sealed: {bool(comp['sealed'])})"]
+    return "\n".join(lines)
+
+
+def main(argv) -> int:
+    """``python -m racon_tpu.obs compiles RUN_REPORT``."""
+    import json
+    if len(argv) != 1:
+        print("usage: python -m racon_tpu.obs compiles RUN_REPORT",
+              file=sys.stderr)
+        return 2
+    try:
+        with open(argv[0], "rb") as f:
+            rep = json.loads(f.read())
+        comp = rep["compiles"]
+        comp["programs"]
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        print(f"run report {argv[0]}: no table of programs "
+              f"(schema v13 has one): {e!r}", file=sys.stderr)
+        return 2
+    print(table(comp, float(rep.get("wall_s", 0.0))))
+    return 0

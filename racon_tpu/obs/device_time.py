@@ -7,7 +7,10 @@ device had nothing — what the feeding thread was doing instead.
 transfer, it calls :func:`submit` with the kind (``exec`` / ``h2d``),
 the jitted function's name as the device trace prints it, and ONE SMALL
 output array to watch (the aligner's ``score`` vector, never a table:
-the watcher must not hold anything the direction-matrix budget counts).
+the watcher must not hold anything the direction-matrix budget counts)
+and the static geometry it dispatched with (``compilewatch.geometry``:
+the key that joins the submission to the row of the program it ran, in
+the report's ``compiles`` section).
 The background warm-up threads submit the dummy programs they execute
 as kind ``warm``: the device is busy with them like with any program
 (same queue as ``exec``), but they feed nothing, so the idle before one
@@ -53,7 +56,7 @@ import threading
 import time
 from typing import Dict, List
 
-from . import metrics, trace
+from . import compilewatch, metrics, trace
 from .. import contracts
 
 MAX_ENTRIES = 1 << 16       # ledger bound (a long-lived server); oldest go
@@ -65,6 +68,9 @@ WARM = "warm"               # a warm-up thread's program: busy, not a feeder
 UNATTRIBUTED = "unattributed"
 IDLE_PREFIX = "idle."
 
+# the submit sites' one formatter of a dispatch's static geometry
+geometry = compilewatch.geometry
+
 _cond = threading.Condition()
 _entries: List["_Entry"] = []
 _evicted = 0
@@ -75,13 +81,15 @@ class _Entry:
     """One submission. ``complete_ns`` is written once, by the device's
     watcher, under ``_cond``."""
 
-    __slots__ = ("device", "kind", "name", "buf", "scope", "submit_ns",
-                 "complete_ns")
+    __slots__ = ("device", "kind", "name", "geometry", "buf", "scope",
+                 "submit_ns", "complete_ns")
 
-    def __init__(self, device, kind, name, buf, scope, submit_ns):
+    def __init__(self, device, kind, name, geometry, buf, scope,
+                 submit_ns):
         self.device = device
         self.kind = kind
         self.name = name
+        self.geometry = geometry
         self.buf = buf
         self.scope = scope
         self.submit_ns = submit_ns
@@ -128,19 +136,23 @@ def _queue(kind: str) -> str:
     return "h2d" if kind == "h2d" else "exec"
 
 
-def submit(kind: str, name: str, watch) -> None:
+def submit(kind: str, name: str, watch, geometry: str = "") -> None:
     """Tell the ledger that the calling thread has just enqueued device
     program ``name`` (``kind`` ``"exec"``, or ``"warm"`` from a warm-up
     thread) or started a transfer (``"h2d"``); ``watch`` is one small
-    array whose readiness marks the end. Off — no report and no trace
-    asked for — this returns at the first branch and no watcher thread
-    exists."""
+    array whose readiness marks the end, ``geometry`` the program's
+    static geometry (``compilewatch.geometry``; a transfer has none).
+    Off — no report and no trace asked for — this returns at the first
+    branch and no watcher thread exists."""
     if not trace._active:
         return
     global _evicted
     device = _device_key(watch)
-    entry = _Entry(device, kind, name, trace.current_buf(),
+    entry = _Entry(device, kind, name, geometry, trace.current_buf(),
                    metrics.get_scope() or "", time.perf_counter_ns())
+    if kind != "h2d":
+        # the row this thread compiled for the call, if it did
+        compilewatch.claim(name, geometry)
     with _cond:
         _entries.append(entry)
         if len(_entries) > MAX_ENTRIES:
@@ -162,6 +174,19 @@ def reset() -> None:
     with _cond:
         _entries.clear()
         _evicted = 0
+
+
+def dispatch_counts(scope: str = "") -> Dict[tuple, int]:
+    """``{(program, geometry): exec submissions}`` of this run (of
+    ``scope``'s job): how often each executable really ran. ``warm``
+    submissions ran a dummy and count nothing."""
+    out: Dict[tuple, int] = {}
+    with _cond:
+        for e in _entries:
+            if e.kind == "exec" and (not scope or e.scope == scope):
+                key = (e.name, e.geometry)
+                out[key] = out.get(key, 0) + 1
+    return out
 
 
 def watcher_threads() -> List[str]:

@@ -101,6 +101,18 @@ from .. import contracts
 # longest idle intervals ("gaps") and the run's clock pair ("clock":
 # the same instant as perf_counter_ns and time_ns).  All zeros when
 # nothing was recorded.  A stored v11 report still validates as v11.
+# v13 (PR 39): the "compiles" section became a ledger of programs —
+# "programs": one row per compiled program (JAX's own name for it, the
+# interval on the spans' clock, trace / lower / backend / retrieve
+# seconds, cache hit or miss, the frame, thread and span that asked,
+# and "dispatches": the exec submissions of the occupancy ledger that
+# ran the executable, null for a program the ledger never submits),
+# "dropped" (rows the bounded ring lost) and the totals the set-up
+# metrics read: "wall_s" (the rows' intervals as wall), "unused" /
+# "unused_s" (ledger programs no dispatch ran), "eager_programs",
+# "miss_s", "unrowed_s" (stage seconds that reached no backend).
+# "by_function" and "events" left (contracts.REMOVED_KEYS); a stored
+# v11 / v12 report keeps them and validates as what it is.
 # the schema's key sets (per section, per version) live in
 # racon_tpu/contracts.py — ONE registry shared with the schema-coherence
 # lint rule, so a schema bump is a contracts.py edit the gate enforces
@@ -151,9 +163,13 @@ assert frozenset(_TOP) == _SCHEMA_KEYS["top"], \
 _QUEUE_KEYS = tuple(sorted(_SCHEMA_KEYS["queue"]))
 _PACK_KEYS = tuple(sorted(_SCHEMA_KEYS["pack"]))
 _RECOVERY_KEYS = tuple(sorted(_SCHEMA_KEYS["recovery"]))
-# "by_function" (dict) and "events" (list) validate structurally below
-_COMPILES_NUM_KEYS = tuple(sorted(
-    _SCHEMA_KEYS["compiles"] - {"by_function", "events"}))
+# "programs" (a list of rows) validates structurally below
+_COMPILES_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["compiles"] - {"programs"}))
+_PROGRAM_STR_KEYS = ("program", "fn", "signature", "geometry", "thread",
+                     "phase")
+_PROGRAM_NUM_KEYS = ("t0_ns", "t1_ns", "trace_s", "lower_s", "backend_s",
+                     "retrieve_s")
+_PROGRAM_CACHE = ("hit", "miss", "none")
 _DATAFLOW_KEYS = tuple(sorted(_SCHEMA_KEYS["dataflow"]))
 _FLEET_KEYS = tuple(sorted(_SCHEMA_KEYS["fleet"]))
 _DEVICE_TIME_NUM_KEYS = ("window_s", "busy_s", "idle_s", "head_idle_s",
@@ -161,7 +177,6 @@ _DEVICE_TIME_NUM_KEYS = ("window_s", "busy_s", "idle_s", "head_idle_s",
 # "mode" is the one string key of the overlap section
 _OVERLAP_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["overlap"] - {"mode"}))
 _OVERLAP_MODES = contracts.OVERLAP_MODES
-_COMPILE_EVENT_STR_KEYS = ("fn", "signature", "phase")
 
 # per-shard row schema: key -> (accepted types, required)
 _SHARD_ROW = {
@@ -246,11 +261,14 @@ def build_report(kind: str, *, argv: Optional[list] = None,
         # supervision counters — server-level, so every kind embeds
         # the hosting process's totals (zeros outside serve mode)
         "recovery": metrics.recovery_summary(),
-        # XLA compile attribution (round 18, schema v7): per-function
-        # counts/seconds and the attributed (function, signature,
-        # phase) events from the process-wide jax.monitoring listener;
-        # "post_warm" counts compiles after the serve warm-path seal
-        "compiles": compilewatch.summary(scope),
+        # XLA compile attribution (round 18, schema v7; a ledger of
+        # programs since v13): one row per compiled program from the
+        # process-wide jax.monitoring listener, joined to the
+        # occupancy ledger's exec submissions; "post_warm" counts
+        # compiles after the serve warm-path seal.  Built BEFORE the
+        # metrics snapshot: it pins the compile.retrieve timer
+        "compiles": compilewatch.summary(
+            scope, device_time.dispatch_counts(scope)),
         # device-resident align→consensus accounting (round 19, schema
         # v8): resident on/off, bytes fetched vs host round-trips
         # avoided, host-fallback pair count and per-window insertion-
@@ -344,6 +362,47 @@ def _check_device_time(errors: List[str], dt: dict) -> None:
                       f"end_ns, {{span: seconds}}] rows")
 
 
+def _is_num(v) -> bool:
+    return isinstance(v, _NUM) and not isinstance(v, bool)
+
+
+def _check_compiles(errors: List[str], comp: dict, version: int) -> None:
+    """The section as its version had it: a stored v11 / v12 report
+    holds the retired roll-ups in place of the rows."""
+    keys = contracts.schema_keys(version)["compiles"]
+    for key in sorted(keys):
+        if key not in comp:
+            errors.append(f"compiles[{key!r}] missing")
+        elif key in _COMPILES_NUM_KEYS and not _is_num(comp[key]):
+            errors.append(f"compiles[{key!r}] missing or non-numeric")
+    for key in sorted(set(comp) - keys):
+        removed = contracts.REMOVED_KEYS.get(key)
+        errors.append(f"compiles[{key!r}] retired in schema "
+                      f"v{removed[1]}" if removed
+                      else f"compiles unknown key {key!r}")
+    if "programs" not in keys or "programs" not in comp:
+        return
+    if not isinstance(comp["programs"], list):
+        errors.append("compiles['programs'] is not a list of rows")
+        return
+    for i, row in enumerate(comp["programs"]):
+        ok = isinstance(row, dict) \
+            and all(isinstance(row.get(k), str)
+                    for k in _PROGRAM_STR_KEYS) \
+            and all(_is_num(row.get(k)) for k in _PROGRAM_NUM_KEYS) \
+            and row.get("cache") in _PROGRAM_CACHE \
+            and "dispatches" in row \
+            and (row["dispatches"] is None
+                 or (_is_num(row["dispatches"])
+                     and row["dispatches"] >= 0))
+        if not ok:
+            errors.append(
+                f"compiles.programs[{i}] is not a program row "
+                f"({'/'.join(_PROGRAM_STR_KEYS)}, "
+                f"{'/'.join(_PROGRAM_NUM_KEYS)}, cache "
+                f"{'|'.join(_PROGRAM_CACHE)}, dispatches)")
+
+
 def validate_report(rep) -> List[str]:
     """Schema-check a (parsed) report; returns violations, [] = valid."""
     errors: List[str] = []
@@ -404,32 +463,7 @@ def validate_report(rep) -> List[str]:
         if not isinstance(rep["overlap"].get(key), _NUM) \
                 or isinstance(rep["overlap"].get(key), bool):
             errors.append(f"overlap[{key!r}] missing or non-numeric")
-    comp = rep["compiles"]
-    for key in _COMPILES_NUM_KEYS:
-        if not isinstance(comp.get(key), _NUM) \
-                or isinstance(comp.get(key), bool):
-            errors.append(f"compiles[{key!r}] missing or non-numeric")
-    if not isinstance(comp.get("by_function"), dict):
-        errors.append("compiles['by_function'] missing or not an object")
-    else:
-        for fn, row in comp["by_function"].items():
-            if not isinstance(row, dict):
-                errors.append(f"compiles.by_function[{fn!r}] is not an "
-                              f"object row")
-            else:
-                _check_numeric_dict(errors, row,
-                                    f"compiles.by_function[{fn!r}]")
-    if not isinstance(comp.get("events"), list):
-        errors.append("compiles['events'] missing or not a list")
-    else:
-        for i, ev in enumerate(comp["events"]):
-            if not isinstance(ev, dict) or not all(
-                    isinstance(ev.get(k), str)
-                    for k in _COMPILE_EVENT_STR_KEYS) \
-                    or not isinstance(ev.get("duration_s"), _NUM):
-                errors.append(f"compiles.events[{i}] is not an "
-                              f"attributed record (fn/signature/phase/"
-                              f"duration_s)")
+    _check_compiles(errors, rep["compiles"], version)
     if "device_time" in top:
         _check_device_time(errors, rep["device_time"])
     for kind in ("counters", "gauges", "timers"):
@@ -502,8 +536,11 @@ def _main(argv) -> int:
     if argv and argv[0] == "gaps":
         from . import gaps
         return gaps.main(argv[1:])
+    if argv and argv[0] == "compiles":
+        return compilewatch.main(argv[1:])
     print("usage: python -m racon_tpu.obs --check FILE\n"
-          "       python -m racon_tpu.obs gaps RUN_REPORT DEVICE_TRACE",
+          "       python -m racon_tpu.obs gaps RUN_REPORT DEVICE_TRACE\n"
+          "       python -m racon_tpu.obs compiles RUN_REPORT",
           file=sys.stderr)
     return 2
 

@@ -212,6 +212,14 @@ def current_span():
     return b.open[-1].name if b is not None and b.open else None
 
 
+def current_span_t0():
+    """When the CURRENT THREAD's innermost open span began
+    (``perf_counter_ns``; None with no span open): what the thread did
+    before it belongs to an earlier step."""
+    b = getattr(_tls, "buf", None)
+    return b.open[-1]._t0 if b is not None and b.open else None
+
+
 def current_buf():
     """The CURRENT THREAD's ring (registered on first use) — the
     occupancy ledger keeps it with every submission, so an idle

@@ -80,8 +80,8 @@ if not _flags.get_bool("RACON_TPU_NO_COMPILE_CACHE"):
     configure_compile_cache()
 
 # Process-wide compile attribution (round 18): every XLA compile lands
-# in the obs registry (the scoped ``compile.jax_s`` timer + per-function
-# ``compile.<fn>`` counters) and the compile-watch event ring,
+# in the obs registry (the scoped ``compile.jax_s`` timer) and as one row
+# per program in the compile watch's ring, under JAX's name for it and
 # attributed to (function, shape signature, phase, scope).  Armed here
 # because importing ops precedes every kernel compile; idempotent, and
 # a no-op without jax.

@@ -128,6 +128,22 @@ def _hits_pad(n: int) -> int:
 
 # ---------------------------------------------------------------- kernel
 
+@functools.lru_cache(maxsize=None)
+def _chain_geometry(S: int, B: int, k: int = 0, E: int = 0) -> str:
+    """The occupancy ledger's join key of a ``[B, S]`` chain arena, from
+    the streams and the warm-up alike: the chain kernel's with its
+    ``k``, the arena gather's with the hit arena ``E`` it reads."""
+    return device_time.geometry(B=B, S=S, k=k, E=E)
+
+
+@functools.lru_cache(maxsize=None)
+def _join_geometry(R2: int, T2: int, steps: int = 0, E: int = 0,
+                   Q2: int = 0, k: int = 0) -> str:
+    """The same for the seed join's two programs: the padded table
+    sizes, then the ramp's ``steps`` or the expansion's arena."""
+    return device_time.geometry(steps=steps, R2=R2, T2=T2, E=E, Q2=Q2, k=k)
+
+
 @functools.partial(jax.jit, static_argnames=("S", "k"))
 def _chain_kernel(ts, qs, ns, *, S: int, k: int):
     """Gap-scored colinear chaining over a ``[B, S]`` packed seed arena.
@@ -395,7 +411,8 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
         # graftlint: disable=jit-shape-hazard (R2/T2 are the pow2 _table_pad buckets; steps is JOIN_BUCKET_STEPS for every table of mixed hashes)
         u_d, cnt, offs, total_d, capped_d = _join_ramp_kernel(
             rh_d, uh_d, ucount_d, dstart_d, np.int32(max_occ), steps=steps)
-        device_time.submit("exec", "_join_ramp_kernel", total_d)
+        device_time.submit("exec", "_join_ramp_kernel", total_d,
+                           _join_geometry(R2, T2, steps=steps))
         # the expansion's operands cross while the ramp runs
         sides_d = [jnp.asarray(a) for a in (
             _pad_to(rid_h, R2, 0, np.int32),
@@ -425,7 +442,8 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
             *sides_d, u_d, cnt, offs, np.int32(total),
             _pad_to(read_self_t, Q2, -1, np.int32),
             _pad_to(qlens, Q2, 0, np.int32), E=E, k=k)
-        device_time.submit("exec", "_join_expand_kernel", out_d[5])
+        device_time.submit("exec", "_join_expand_kernel", out_d[5],
+                           _join_geometry(R2, T2, E=E, Q2=Q2, k=k))
     with obs.span("overlap.join.fetch"):
         # whole arenas, cut on the host: a device slice [:n] is a
         # program per hit count, new with every input
@@ -589,12 +607,15 @@ class _ChainStream:
                 # graftlint: disable=jit-shape-hazard (S is the pow4 _seed_bucket rung)
                 ts, qs = _gather_pairs_kernel(
                     self.tp, self.qc, starts.astype(np.int32), ns, S=S)
-                device_time.submit("exec", "_gather_pairs_kernel", ts)
+                device_time.submit(
+                    "exec", "_gather_pairs_kernel", ts,
+                    _chain_geometry(S, B, E=int(self.tp.shape[0])))
             else:
                 ts, qs = _put_lanes(self.tp, self.qc, starts, counts, S, B)
             # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow4 bucket)
             out = _chain_kernel(ts, qs, ns, S=S, k=self.k)
-            device_time.submit("exec", "_chain_kernel", out)
+            device_time.submit("exec", "_chain_kernel", out,
+                               _chain_geometry(S, B, self.k))
         self.inflight.append({"chunk": chunk, "out": out,
                               "cells": B * S})
         self.inflight_cells += B * S
@@ -680,7 +701,8 @@ def chain_pairs(hits: Dict[str, np.ndarray], *, k: int, min_seeds: int
                                     S, B)
                 # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow4 bucket)
                 out = _chain_kernel(ts, qs, ns, S=S, k=k)
-                device_time.submit("exec", "_chain_kernel", out)
+                device_time.submit("exec", "_chain_kernel", out,
+                                   _chain_geometry(S, B, k))
             with obs.span("overlap.chain.fetch", pairs=len(part)):
                 out_np = fetch_global([out])[0]
             rows_out[part] = out_np[:len(part)].astype(np.int64)
@@ -1045,7 +1067,8 @@ def warmup_async(est_seeds: int, est_pairs: int, k: int = 15):
         # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow2 bucket)
         out = _chain_kernel(z, z, np.zeros(B, np.int32), S=S, k=kk)
         # the dummy occupies the device like any program: kind "warm"
-        device_time.submit("warm", "_chain_kernel", out)
+        device_time.submit("warm", "_chain_kernel", out,
+                           _chain_geometry(S, B, kk))
         jax.block_until_ready(out)
 
     def _run():

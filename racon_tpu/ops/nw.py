@@ -423,6 +423,22 @@ def _sweep_bound(max_nm: int, max_len: int) -> int:
     return -(-steps // 512) * 512
 
 
+@functools.lru_cache(maxsize=None)
+def _chunk_geometry(max_len: int, band: int, steps: int, B: int, w: int,
+                    swar: bool):
+    """The occupancy ledger's join keys of one chunk geometry's three
+    programs — row build, align chain, breaking points — each the
+    static arguments and batch its executable is compiled for.
+    Formatted once per geometry; the stream's submit site and the
+    warm-up thread both take them from here, so a warm-up's compile and
+    the dispatch that runs its executable carry the same key."""
+    geometry = device_time.geometry
+    return (geometry(max_len=max_len, band=band, B=B),
+            geometry(max_len=max_len, band=band, steps=steps, B=B,
+                     swar=swar),
+            geometry(steps=steps, B=B, w=w, NW=max_len // max(w, 1) + 2))
+
+
 @functools.partial(jax.jit, static_argnames=("w", "NW"))
 def _breaking_points_kernel(ops_packed, n, m, first_rel, nb, *, w: int,
                             NW: int):
@@ -1315,10 +1331,6 @@ class TpuAligner(PallasDispatchMixin):
                 build = {"2bit": _build_rows_packed2,
                          "nibble": _build_rows_packed,
                          "raw": _build_rows}[kind]
-                qrp, tp = build(q_d, t_d, nd, md, max_len=max_len,
-                                band=band)
-                device_time.submit("exec", build.__name__, tp)
-                args = (qrp, tp, nd, md)
                 B = n.shape[0]
                 use_pallas = self._use_pallas((max_len, band, steps, B))
                 if use_pallas:
@@ -1331,6 +1343,13 @@ class TpuAligner(PallasDispatchMixin):
                     # there; nor may a band under its geometry guard
                     sw = (sw and kind != "raw" and mosaic_swar_fits(band)
                           and pallas_swar_ok())
+                g_build, g_chain, g_bp = _chunk_geometry(
+                    max_len, band, steps, B, bp_meta[0] if bp_meta else 0,
+                    bool(sw))
+                qrp, tp = build(q_d, t_d, nd, md, max_len=max_len,
+                                band=band)
+                device_time.submit("exec", build.__name__, tp, g_build)
+                args = (qrp, tp, nd, md)
                 # no try/except around the dispatch: a Mosaic kernel
                 # that does not compile or run for this shape fails the
                 # run (the jit error names the function and shapes)
@@ -1341,12 +1360,12 @@ class TpuAligner(PallasDispatchMixin):
                 device_time.submit(
                     "exec", "sharded_align" if self.mesh is not None
                     else "_pallas_align_chain" if use_pallas
-                    else "align_chain", out[1])
+                    else "align_chain", out[1], g_chain)
                 if bp_dev is not None:
                     out = self._attach_bp(out, nd, md, bp_dev, bp_meta,
                                           max_len)
                     device_time.submit("exec", "_breaking_points_kernel",
-                                       out[1])
+                                       out[1], g_bp)
         # counted on the path actually taken: the Pallas-level
         # decision can differ from the XLA-level one
         self.stats["swar_chunks"] += int(sw)
@@ -1704,28 +1723,30 @@ class TpuAligner(PallasDispatchMixin):
             else:
                 z = jnp.zeros((B * max_len,), jnp.uint8)
                 build, blocks = _build_rows, (z, z)
-            qrp, tp = build(*blocks, n, m, max_len=max_len, band=band)
-            # the warm-up's dummy programs occupy the device like any
-            # other: the occupancy ledger counts them, as kind "warm"
-            device_time.submit("warm", build.__name__, tp)
             use_pallas = self._use_pallas((max_len, band, steps, B))
             if use_pallas and sw:
                 from .pallas_nw import pallas_swar_ok
                 from .swar import mosaic_swar_fits
                 sw = mosaic_swar_fits(band) and pallas_swar_ok()
+            g_build, g_chain, g_bp = _chunk_geometry(
+                max_len, band, steps, B, w, bool(sw))
+            qrp, tp = build(*blocks, n, m, max_len=max_len, band=band)
+            # the warm-up's dummy programs occupy the device like any
+            # other: the occupancy ledger counts them, as kind "warm"
+            device_time.submit("warm", build.__name__, tp, g_build)
             out = align_chain(qrp, tp, n, m, max_len=max_len, band=band,
                               steps=steps, use_pallas=use_pallas,
                               use_swar=sw)
             device_time.submit(
                 "warm", "_pallas_align_chain" if use_pallas
-                else "align_chain", out[1])
+                else "align_chain", out[1], g_chain)
             if w:
                 NW = max_len // max(w, 1) + 2
                 bp = _breaking_points_kernel(
                     out[0], n, m, jnp.zeros((B,), jnp.int32),
                     jnp.ones((B,), jnp.int32), w=w, NW=NW)
                 device_time.submit("warm", "_breaking_points_kernel",
-                                   bp[1])
+                                   bp[1], g_bp)
                 # resident derive root (round 19): warmed with the SAME
                 # chunk geometry and the shared pow2 pool rule, so a
                 # resident run's per-chunk layer-row derivation

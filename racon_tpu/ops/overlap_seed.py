@@ -141,6 +141,13 @@ def _minimizer_kernel(codes, lens, nwin, *, k: int, w: int, L: int):
     return h, strand, sel, jnp.sum(sel.astype(jnp.int32), axis=1)
 
 
+@functools.lru_cache(maxsize=None)
+def _seed_geometry(B: int, L: int, k: int, w: int) -> str:
+    """The occupancy ledger's join key of a ``[B, L]`` minimizer batch
+    (both of its programs), from the stream and the warm-up alike."""
+    return device_time.geometry(B=B, w=w, L=L, k=k)
+
+
 @jax.jit
 def _compact_kernel(h, strand, sel):
     """Device-side table compaction (the resident path): selected
@@ -266,11 +273,12 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
             # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the one row length)
             h, strand, sel, nsel = _minimizer_kernel(codes_d, lens, nwin,
                                                      k=k, w=w, L=L)
-            device_time.submit("exec", "_minimizer_kernel", nsel)
+            geom = _seed_geometry(B, L, k, w)
+            device_time.submit("exec", "_minimizer_kernel", nsel, geom)
             if resident:
                 h, row, pcol, strand, total = _compact_kernel(
                     h, strand, sel)
-                device_time.submit("exec", "_compact_kernel", total)
+                device_time.submit("exec", "_compact_kernel", total, geom)
         if resident:
             with obs.span("overlap.seed.fetch", rows=len(part)):
                 n_host = fetch_global([total])[0]
@@ -374,7 +382,8 @@ def warmup_async(est_len: int, est_seqs: int,
         # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the pow2 bucket)
         out = _minimizer_kernel(codes, ones, ones, k=kk, w=ww, L=L)
         # the dummy occupies the device like any program: kind "warm"
-        device_time.submit("warm", "_minimizer_kernel", out[3])
+        device_time.submit("warm", "_minimizer_kernel", out[3],
+                           _seed_geometry(B, L, kk, ww))
         jax.block_until_ready(out[3])
 
     def _run():

@@ -893,6 +893,25 @@ def refine_loop(n, qpw, win_of, real, bg, ed,
     return lax.while_loop(cond, body, (jnp.int32(0),) + state)[1:]
 
 
+@functools.lru_cache(maxsize=None)
+def _loop_geometry(Lq: int, Lb: int, band: int, steps: int, Lq2: int,
+                   B: int, nWp: int, rounds: int, swar: bool) -> str:
+    """The occupancy ledger's join key of one group geometry's
+    refinement loop: the static arguments and batch its executable is
+    compiled for. Formatted once per geometry; the stream's dispatch and
+    the warm-up thread both take it from here, so a warm-up's compile
+    and the dispatch that runs its executable carry the same key."""
+    return device_time.geometry(band=band, steps=steps, B=B, nWp=nWp,
+                                Lq=Lq, Lb=Lb, Lq2=Lq2, rounds=rounds,
+                                swar=swar)
+
+
+@functools.lru_cache(maxsize=None)
+def _gather_geometry(B: int, Lq: int, pool: int) -> str:
+    """The same for the resident lane gather."""
+    return device_time.geometry(B=B, Lq=Lq, pool=pool)
+
+
 @functools.partial(jax.jit, static_argnames=("Lq",))
 def _gather_qpw_rows(pool, src0, lens, *, Lq: int):
     """Device-side twin of :meth:`LayerStore.gather_qpw` (round 19):
@@ -1810,8 +1829,12 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 jnp.zeros((_pow2_pool(Lq * B),), jnp.uint16),
                 jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                 Lq=Lq)
-            device_time.submit("warm", "_refine_loop_packed", out[9])
-            device_time.submit("warm", "_gather_qpw_rows", gat)
+            device_time.submit(
+                "warm", "_refine_loop_packed", out[9],
+                _loop_geometry(Lq, Lb, band, steps, Lq2, B, nWp, rounds,
+                               bool(sw)))
+            device_time.submit("warm", "_gather_qpw_rows", gat,
+                               _gather_geometry(B, Lq, _pow2_pool(Lq * B)))
             jax.block_until_ready(out[10])
             jax.block_until_ready(gat)
 
@@ -2112,7 +2135,9 @@ class TpuPoaConsensus(PallasDispatchMixin):
             pool_d, src0_full, lens_full = dev_spec
             qpw_dev = _gather_qpw_rows(pool_d, jnp.asarray(src0_full),
                                        jnp.asarray(lens_full), Lq=Lq)
-            device_time.submit("exec", "_gather_qpw_rows", qpw_dev)
+            device_time.submit(
+                "exec", "_gather_qpw_rows", qpw_dev,
+                _gather_geometry(B, Lq, int(pool_d.shape[0])))
             saved = 2 * B * Lq
             self.stats["lane_upload_saved_bytes"] += saved
             metrics.inc("dataflow.bytes_avoided", saved)
@@ -2168,7 +2193,11 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # the small telemetry rows, never the lane blocks
         device_time.submit(
             "exec", "_refine_loop_packed" if launch["nd"] == 1
-            else "sharded_refine_loop", out[9])
+            else "sharded_refine_loop", out[9],
+            _loop_geometry(Lq, Lb, launch.get("band", self.band), steps,
+                           Lq2, launch["B"], launch["nWp"],
+                           launch.get("rounds", self.rounds),
+                           bool(use_swar)))
         launch["state"] = list(out[:10])
         if launch["nd"] == 1:
             launch["fetch2"] = out[10:12]

@@ -3,13 +3,21 @@
 The static half lives in ``tools/analysis`` (jit-shape-hazard /
 dtype-drift / jit-in-loop / warmup-coverage / host-transfer-in-jit,
 self-tested via ``--selftest``); this file proves the RUNTIME half:
-the process-wide ``jax.monitoring`` listener attributes every XLA
-compile to (function, shape signature, phase, scope), the per-job
-``compile_s`` semantics of the absorbed serve listener are preserved,
-the run report's required schema-v7 ``compiles`` section validates,
+the process-wide ``jax.monitoring`` listener writes one row per
+compiled program — JAX's name for it, the stages' interval and seconds,
+cache hit or miss, and (function, shape signature, phase, scope) of who
+asked — the per-job ``compile_s`` semantics of the absorbed serve
+listener are preserved, the run report's ``compiles`` section (a ledger
+of programs since schema v13) validates and sums to the stage timers,
 and the sanitize gate judges only the offending scope.  (The full
 sanitized-serve warm-path acceptance test rides at the end of
 ``tests/test_serve.py`` — see the note at the bottom of this file.)"""
+
+import json
+import os
+import pathlib
+import subprocess
+import sys
 
 import pytest
 
@@ -26,12 +34,24 @@ def _fresh_watch():
     metrics.clear("compile.")
 
 
-def _fake_compile(max_len, band, duration=0.5):
+@pytest.fixture
+def fake_clock(monkeypatch):
+    """``clock[0]`` is the compile watch's ``perf_counter_ns``."""
+    import types
+
+    clock = [0]
+    monkeypatch.setattr(compilewatch, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: clock[0]))
+    return clock
+
+
+def _fake_compile(max_len, band, duration=0.5, fun_name=""):
     """Drive the listener directly: attribution walks the stack and —
     with no racon_tpu frame above — lands on THIS frame, whose integer
     locals (max_len/band) form the shape signature."""
     compilewatch._on_duration(
-        "/jax/core/compile/backend_compile_duration", duration)
+        "/jax/core/compile/backend_compile_duration", duration,
+        fun_name=fun_name)
 
 
 # ------------------------------------------------------------ attribution
@@ -75,7 +95,13 @@ def test_attribution_names_function_and_shape_on_forced_retrace(
                  f"{compilewatch.events()}")
     assert any("max_len=320" in e["signature"]
                and "band=40" in e["signature"] for e in evs), evs
-    assert metrics.counter("compile.nw.align_chain") >= 1
+    # the rows name the two programs of the XLA chain as JAX and the
+    # device trace do, not the frame that drove them; a fresh cache
+    # directory was asked and had neither
+    assert {e["program"] for e in evs} == {"jit__nw_wavefront_kernel",
+                                           "jit__traceback_kernel"}
+    assert all(e["cache"] == "miss" and e["retrieve_s"] == 0
+               and e["backend_s"] > 0 for e in evs), evs
     assert metrics.timer_s("compile.jax_s") > 0
 
 
@@ -117,9 +143,12 @@ def test_scoped_compile_s_preserved_and_serve_listener_absorbed():
     assert rep["dispatch_fetch"]["compile_s"] == pytest.approx(1.50)
     comp = rep["compiles"]
     assert comp["count"] == 1 and comp["post_warm"] == 0
-    assert list(comp["by_function"]) == \
-        ["test_compile_surface._fake_compile"]
-    assert comp["events"][0]["signature"] == "max_len=256,band=64"
+    (row,) = comp["programs"]
+    assert row["fn"] == "test_compile_surface._fake_compile"
+    assert row["signature"] == "max_len=256,band=64"
+    # the bare trace stage reached no backend and preceded none
+    assert row["backend_s"] == pytest.approx(1.25)
+    assert comp["unrowed_s"] == pytest.approx(0.25)
 
     from racon_tpu.serve import service
     assert not hasattr(service, "arm_compile_monitor")
@@ -202,14 +231,16 @@ def test_run_boundary_resets_attribution():
     obs.begin()
     assert compilewatch.summary() == {
         "total_s": 0.0, "count": 0, "post_warm": 0, "sealed": 0,
-        "by_function": {}, "events": []}
+        "programs": [], "dropped": 0, "wall_s": 0.0, "unused": 0,
+        "unused_s": 0.0, "eager_programs": 0, "miss_s": 0.0,
+        "unrowed_s": 0.0}
 
 
 def test_scoped_count_exact_past_event_ring_eviction(monkeypatch):
-    """The event ring is bounded; a job whose early records were
-    evicted still reports its exact compile count (the scoped counter,
-    not the ring)."""
-    monkeypatch.setattr(compilewatch, "MAX_EVENTS", 4)
+    """The row ring is bounded; a job whose early rows were evicted
+    still reports its exact compile count (the scoped counter, not the
+    ring) and says how many rows it lost."""
+    monkeypatch.setattr(compilewatch, "MAX_ROWS", 4)
     metrics.set_scope("job.ring.")
     try:
         for _ in range(10):
@@ -218,7 +249,7 @@ def test_scoped_count_exact_past_event_ring_eviction(monkeypatch):
         metrics.set_scope(None)
     s = compilewatch.summary("job.ring.")
     assert s["count"] == 10
-    assert len(s["events"]) <= 4
+    assert len(s["programs"]) == 4 and s["dropped"] == 6
     metrics.clear("job.ring.")
 
 
@@ -262,6 +293,352 @@ def test_sanitize_gate_raises_only_when_armed(monkeypatch):
         sanitize.check_post_warm_compiles("job.t9.")
     assert "nearest warmed" in str(ei.value)
     assert "max_len=4096" in str(ei.value)
+
+
+# ------------------------------------------------- the ledger of programs
+
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def test_a_jit_compiled_on_a_named_thread_is_one_row_with_jaxs_name():
+    """One ``jit`` call that reaches the backend is one row: JAX's own
+    name for the program, the compiling thread, the innermost open span,
+    stamps inside that span, and the stages (the nested traces of the
+    operators folded in) summing to what the stage timers got."""
+    jax = pytest.importorskip("jax")
+    import threading
+    import time
+
+    import numpy as np
+
+    from racon_tpu import obs
+
+    assert compilewatch.arm()
+
+    def _row_probe_fn(x):
+        return x * 3 + 1
+
+    stamps = {}
+
+    def work():
+        with obs.span("align.launch"):
+            stamps["t0"] = time.perf_counter_ns()
+            jax.jit(_row_probe_fn)(np.arange(37, dtype=np.int32))
+            stamps["t1"] = time.perf_counter_ns()
+
+    trace.new_run()
+    trace.activate()
+    try:
+        t = threading.Thread(target=work, name="t-compiler")
+        t.start()
+        t.join()
+        comp = compilewatch.summary()
+        timers = sum(metrics.timer_s(f"compile.{st}")
+                     for st in ("trace", "lower", "backend"))
+    finally:
+        trace.deactivate()
+    (row,) = comp["programs"]
+    assert row["program"] == "jit__row_probe_fn"
+    assert row["thread"] == "t-compiler"
+    assert row["phase"] == "align.launch"
+    assert row["fn"].endswith(".work")
+    assert stamps["t0"] <= row["t0_ns"] < row["t1_ns"] <= stamps["t1"]
+    assert min(row["trace_s"], row["lower_s"], row["backend_s"]) > 0
+    # nobody submitted it to the occupancy ledger: an eager helper
+    assert row["dispatches"] is None and row["geometry"] == ""
+    assert comp["count"] == 1 and comp["dropped"] == 0
+    assert comp["eager_programs"] == 1 and comp["unused"] == 0
+    assert compilewatch.stage_s(row) + comp["unrowed_s"] == \
+        pytest.approx(timers, rel=0.01)
+    # one thread: the row's interval is the section's wall
+    assert comp["wall_s"] == pytest.approx(
+        (row["t1_ns"] - row["t0_ns"]) * 1e-9, abs=1e-5)
+    assert comp["wall_s"] >= compilewatch.stage_s(row) - 1e-3
+
+
+def test_a_cache_hit_reads_retrieval_apart_and_a_miss_reads_none(
+        tmp_path, monkeypatch):
+    """Two functions of one body are one persistent-cache key: the
+    first compile misses (``retrieve_s`` 0), the second is handed the
+    executable back — ``cache`` ``hit``, the retrieval's seconds in the
+    row and in the ``compile.retrieve`` timer, still inside
+    ``compile.backend``."""
+    jax = pytest.importorskip("jax")
+    import numpy as np
+
+    from racon_tpu import ops
+
+    def make():
+        def _cache_probe_fn(x):
+            return (x * 5 - 2) % 11
+        return jax.jit(_cache_probe_fn)
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    ops.configure_compile_cache(str(tmp_path / "xla_cache"),
+                                min_compile_time_s=0.0)
+    trace.new_run()
+    trace.activate()
+    try:
+        assert compilewatch.arm()
+        x = np.arange(41, dtype=np.int32)
+        make()(x)
+        make()(x)
+        comp = compilewatch.summary()
+        retrieved = metrics.timer_s("compile.retrieve")
+        backend = metrics.timer_s("compile.backend")
+    finally:
+        trace.deactivate()
+        ops.configure_compile_cache()
+    miss, hit = comp["programs"]
+    assert miss["program"] == hit["program"] == "jit__cache_probe_fn"
+    assert miss["cache"] == "miss" and miss["retrieve_s"] == 0
+    assert hit["cache"] == "hit" and hit["retrieve_s"] > 0
+    assert hit["retrieve_s"] <= hit["backend_s"] + 1e-6
+    assert retrieved == pytest.approx(hit["retrieve_s"], abs=1e-5)
+    # compile.backend keeps its meaning: compile or load, both rows'
+    assert backend == pytest.approx(miss["backend_s"] + hit["backend_s"],
+                                    rel=0.01)
+    assert comp["miss_s"] == pytest.approx(compilewatch.stage_s(miss),
+                                           abs=1e-5)
+    assert metrics.counter("compile.cache_requests") == 2
+    assert metrics.counter("compile.cache_hits") == 1
+
+
+def test_wall_s_of_two_overlapping_rows_is_their_union(fake_clock):
+    """Two threads compile at once: ``wall_s`` is the union of the rows'
+    intervals on a fake clock, not their sum."""
+    import threading
+
+    clock = fake_clock
+
+    def compile_at(now_s, duration):
+        clock[0] = int(now_s * 1e9)
+        _fake_compile(128, 16, duration=duration, fun_name="jit(_k)")
+
+    compile_at(10.0, 4.0)                       # main: 6 .. 10
+    t = threading.Thread(target=compile_at, args=(12.0, 4.0),
+                         name="t-other")        # other: 8 .. 12
+    t.start()
+    t.join()
+    compile_at(20.0, 1.0)                       # main again: 19 .. 20
+    comp = compilewatch.summary()
+    assert [(r["t0_ns"], r["t1_ns"], r["thread"])
+            for r in comp["programs"]] == [
+        (6_000_000_000, 10_000_000_000, "MainThread"),
+        (8_000_000_000, 12_000_000_000, "t-other"),
+        (19_000_000_000, 20_000_000_000, "MainThread")]
+    assert sum(r["backend_s"] for r in comp["programs"]) == \
+        pytest.approx(9.0)
+    assert comp["wall_s"] == pytest.approx(7.0)
+    assert compilewatch.union_s([]) == 0
+
+
+def test_stages_fold_into_the_row_of_the_call_that_reached_the_backend(
+        fake_clock):
+    """An inner jit traced inside the outer trace, the lowering with the
+    operators it traces, and the backend are one row (each stage its
+    self time); a bare trace before it that reached no backend
+    (``eval_shape``) is in no row."""
+    clock = fake_clock
+    ev = "/jax/core/compile/"
+
+    trace_ev, lower_ev = (ev + "jaxpr_trace_duration",
+                          ev + "jaxpr_to_mlir_module_duration")
+
+    def begin(event):
+        compilewatch._on_scalar(event, 0.0)
+
+    def end(end_s, event, duration, fun_name):
+        clock[0] = int(end_s * 1e9)
+        compilewatch._on_duration(event, duration, fun_name=fun_name)
+
+    begin(trace_ev)
+    end(1.000, trace_ev, 0.020, "other")                    # bare
+    begin(trace_ev)                                         # outer
+    begin(trace_ev)
+    end(2.015, trace_ev, 0.010, "inner")
+    end(2.050, trace_ev, 0.050, "outer")
+    begin(lower_ev)
+    # a Mosaic lowering traces thousands of operators before it ends:
+    # they are its frame's two numbers, and the function's own trace is
+    # still the stage before it
+    for k in range(5000):
+        begin(trace_ev)
+        end(2.052 + k * 1e-6, trace_ev, 5e-7, "less")
+    end(2.060, lower_ev, 0.010, "jit(outer)")
+    compilewatch._on_event(compilewatch.CACHE_REQUEST)
+    begin(BACKEND_EVENT)
+    end(2.200, BACKEND_EVENT, 0.100, "jit(outer)")
+    comp = compilewatch.summary()
+    (row,) = comp["programs"]
+    assert row["program"] == "jit_outer" and row["cache"] == "miss"
+    assert row["t0_ns"] == pytest.approx(2_000_000_000, abs=10)
+    assert row["t1_ns"] == 2_200_000_000
+    flood = 5000 * 5e-7
+    assert row["trace_s"] == pytest.approx(0.050 + flood, abs=1e-6)
+    assert row["lower_s"] == pytest.approx(0.010 - flood, abs=1e-6)
+    assert row["backend_s"] == pytest.approx(0.100)
+    assert comp["unrowed_s"] == pytest.approx(0.020)
+    assert comp["wall_s"] == pytest.approx(0.200)
+    # a backend event the cache was asked nothing about
+    end(3.0, BACKEND_EVENT, 0.001, "jit(plain)")
+    assert compilewatch.events()[-1]["cache"] == "none"
+
+
+def test_v13_validates_rows_and_refuses_the_retired_keys():
+    _fake_compile(128, 16, fun_name="jit(_k)")
+    rep = report.build_report("cli")
+    assert rep["schema_version"] == 13
+    assert report.validate_report(rep) == []
+    comp = rep["compiles"]
+    assert "by_function" not in comp and "events" not in comp
+    assert "compile.retrieve" in rep["metrics"]["timers"]
+    bad = dict(rep, compiles=dict(comp, by_function={}))
+    assert any("by_function" in e and "retired" in e
+               for e in report.validate_report(bad))
+    bad = dict(rep, compiles={k: v for k, v in comp.items()
+                              if k != "programs"})
+    assert any("programs" in e for e in report.validate_report(bad))
+    row = dict(comp["programs"][0], cache="maybe")
+    bad = dict(rep, compiles=dict(comp, programs=[row]))
+    assert any("programs[0]" in e for e in report.validate_report(bad))
+    row = {k: v for k, v in comp["programs"][0].items()
+           if k != "dispatches"}
+    bad = dict(rep, compiles=dict(comp, programs=[row]))
+    assert any("programs[0]" in e for e in report.validate_report(bad))
+
+
+# ---------------------------- a process's first job: the warm-up's report
+
+REPO = pathlib.Path(__file__).resolve().parent.parent
+NEW_METRICS = ("warmup_job_s", "warmup_compile_idle_s", "compile_wall_s",
+               "cache_retrieve_s", "compile_unused_s",
+               "compile_eager_programs")
+
+
+@pytest.fixture(scope="module")
+def first_job(tmp_path_factory):
+    """A tiny CLI job as a fresh process's first, both device engines on
+    their XLA twins, with ``--run-report``: what the benchmark's warm-up
+    job is on the chip — every program the job runs is compiled or
+    loaded in it. ``(report, path)``."""
+    sys.path.insert(0, str(REPO / "tests"))
+    from test_columnar_init import write_synthetic_assembly
+
+    td = tmp_path_factory.mktemp("first_job")
+    rp, pp, lp = write_synthetic_assembly(td, seed=43, n_contigs=1,
+                                          contig=2000)
+    rep = td / "run_report.json"
+    # one device: the engines' one-device streams, as on the chip (the
+    # suite's eight virtual devices would make them a mesh)
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
+    env.pop("RACON_TPU_RUN_REPORT", None)
+    with open(td / "polished.fasta", "wb") as out:
+        proc = subprocess.run(
+            [sys.executable, "-m", "racon_tpu", "-t", "2", "-c", "1",
+             "--tpualigner-batches", "1", "--run-report", str(rep),
+             str(rp), str(pp), str(lp)],
+            cwd=str(REPO), env=env, stdout=out, stderr=subprocess.PIPE,
+            timeout=1200)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(rep.read_bytes()), rep
+
+
+def test_first_job_rows_account_for_the_count_and_the_stage_timers(
+        first_job):
+    rep, _ = first_job
+    assert report.validate_report(rep) == []
+    comp = rep["compiles"]
+    assert comp["count"] > 0 and comp["post_warm"] == 0
+    assert len(comp["programs"]) + comp["dropped"] == comp["count"]
+    timers = rep["metrics"]["timers"]
+    staged = sum(map(compilewatch.stage_s, comp["programs"]))
+    assert staged + comp["unrowed_s"] == pytest.approx(
+        sum(timers[f"compile.{st}"]
+            for st in ("trace", "lower", "backend")), rel=0.01)
+    assert 0 < comp["wall_s"] <= rep["wall_s"]
+    assert [r["t0_ns"] for r in comp["programs"]] == sorted(
+        r["t0_ns"] for r in comp["programs"])
+    assert timers["compile.retrieve"] == pytest.approx(
+        sum(r["retrieve_s"] for r in comp["programs"]), abs=1e-4)
+
+
+def test_first_job_every_ledger_program_has_a_row_that_counts_its_dispatches(
+        first_job):
+    """The join: every program the occupancy ledger names was compiled
+    or loaded in this job, under a row whose geometry is a submission's;
+    the rows of one (program, geometry) count that pair's dispatches,
+    and together they count every dispatch."""
+    rep, _ = first_job
+    rows = rep["compiles"]["programs"]
+    by_program = rep["device_time"]["by_program"]
+    ran = {name: row["count"] for name, row in by_program.items()
+           if not name.endswith(".put")}
+    assert ran
+    for name, count in ran.items():
+        programs = {"jit_" + p
+                    for p in compilewatch.CHAINS.get(name, (name,))}
+        for program in programs:
+            mine = [r for r in rows if r["program"] == program
+                    and r["dispatches"] is not None]
+            assert mine, f"{name}: no row of {program}"
+            assert all(r["geometry"] and r["dispatches"] >= 1
+                       for r in mine), mine
+            # one compile per geometry: the rows' counts are disjoint
+            assert len({r["geometry"] for r in mine}) == len(mine)
+            assert sum(r["dispatches"] for r in mine) == count
+    assert rep["compiles"]["unused"] == 0
+    assert rep["compiles"]["eager_programs"] == sum(
+        r["dispatches"] is None for r in rows)
+
+
+def test_first_job_feeds_the_six_set_up_metrics(first_job):
+    """The metric files this PR adds load through the benchmark's own
+    spec and read numbers from a warm-up report."""
+    rep, _ = first_job
+    sys.path.insert(0, str(REPO / "benchmark"))
+    try:
+        from harness import readers, spec
+    finally:
+        sys.path.remove(str(REPO / "benchmark"))
+    cell = spec.load_cell("bact2m-paf30x")
+    files = {e["name"]: (e, m) for e, m in cell.per_layer}
+    assert set(NEW_METRICS) <= set(files)
+    ctx = {"warmup": rep, "traced": None, "window": [], "modules": None,
+           "run": {}}
+    values = {}
+    for name in NEW_METRICS:
+        entry, mfile = files[name]
+        assert entry["layer"] == "compile" and entry["moves"] == "setup_s"
+        assert "workloads" not in entry
+        values[name] = readers.read_metric(mfile, ctx)
+        assert isinstance(values[name], float), name
+    assert values["warmup_job_s"] == rep["wall_s"]
+    assert values["compile_wall_s"] == rep["compiles"]["wall_s"]
+    assert 0 < values["warmup_compile_idle_s"] <= values["warmup_job_s"]
+    assert values["compile_eager_programs"] == \
+        rep["compiles"]["eager_programs"]
+    # a report of before this PR: the readers find nothing, raise nothing
+    old = json.loads((REPO / "tests" / "data" /
+                      "run_report_v11.json").read_bytes())
+    for name in ("compile_wall_s", "cache_retrieve_s", "compile_unused_s",
+                 "compile_eager_programs"):
+        assert readers.read_metric(files[name][1],
+                                   dict(ctx, warmup=old)) is None
+
+
+def test_compiles_command_prints_the_table(first_job, capsys):
+    rep, path = first_job
+    assert report._main(["compiles", str(path)]) == 0
+    out = capsys.readouterr().out.splitlines()
+    rows = rep["compiles"]["programs"]
+    assert len([ln for ln in out if " / jit_" in ln]) == len(rows)
+    assert any(f"{rep['compiles']['count']} programs" in ln for ln in out)
+    assert any("as wall of the job's" in ln for ln in out)
+    old = REPO / "tests" / "data" / "run_report_v11.json"
+    assert report._main(["compiles", str(old)]) == 2
+    assert report._main(["compiles"]) == 2
 
 
 # The sanitized serve warm-path acceptance test

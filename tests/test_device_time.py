@@ -336,11 +336,17 @@ def test_compile_stages_are_backdated_spans(clean_trace):
     from racon_tpu.obs import compilewatch
 
     metrics.clear("compile.")
+    compilewatch.reset()
     trace.new_run()
     trace.activate()
     ev = "/jax/core/compile/"
+    # JAX announces a stage as it begins (a scalar event) and reports
+    # its length as it ends: the outer trace encloses the inner one
+    compilewatch._on_scalar(ev + "jaxpr_trace_duration", 0.0)      # outer
+    compilewatch._on_scalar(ev + "jaxpr_trace_duration", 0.0)
     compilewatch._on_duration(ev + "jaxpr_trace_duration", 0.010)
     compilewatch._on_duration(ev + "jaxpr_trace_duration", 0.050)  # outer
+    compilewatch._on_scalar(ev + "jaxpr_to_mlir_module_duration", 0.0)
     compilewatch._on_duration(ev + "jaxpr_to_mlir_module_duration", 0.002)
     compilewatch._on_event(
         "/jax/compilation_cache/compile_requests_use_cache")
@@ -359,11 +365,79 @@ def test_compile_stages_are_backdated_spans(clean_trace):
     metrics.clear("compile.")
 
 
+def test_a_row_counts_the_exec_submissions_of_its_program_and_geometry(
+        clean_trace):
+    """The join of the ``compiles`` rows to the ledger. A warm-up thread
+    compiles a program and submits its dummy run as ``warm``: the row
+    takes the submission's geometry and reads ``dispatches`` 0 — in
+    ``unused_s`` — until an ``exec`` submission of the same program and
+    geometry (the executable the jit cache hands the stream) makes it 1.
+    Another geometry's dispatch counts nothing here; a program nobody
+    submits is an eager helper, ``null``; a probe's compile of the same
+    kernel in an earlier span is not the submitted call's."""
+    from racon_tpu import obs
+    from racon_tpu.obs import compilewatch
+
+    backend = "/jax/core/compile/backend_compile_duration"
+    compilewatch.reset()
+    trace.new_run()
+    trace.activate()
+    geom = device_time.geometry(steps=1280, max_len=768, B=128, swar=True)
+    assert geom == "max_len=768,steps=1280,B=128,swar=1"
+    other = device_time.geometry(steps=1152, max_len=768, B=128, swar=True)
+
+    def done():
+        w = _FakeArray()
+        w.ready.set()
+        return w
+
+    def warm_up():
+        compilewatch._on_duration(backend, 0.050, fun_name="jit(_loop)")
+        compilewatch._on_duration(backend, 0.004, fun_name="jit(zeros)")
+        device_time.submit("warm", "_loop", done(), geom)
+
+    t = threading.Thread(target=warm_up, name="racon-tpu-warmup")
+    t.start()
+    t.join()
+
+    def rows():
+        comp = compilewatch.summary(ran=device_time.dispatch_counts())
+        return comp, {r["program"]: r for r in comp["programs"]}
+
+    comp, by = rows()
+    assert by["jit__loop"]["dispatches"] == 0
+    assert by["jit__loop"]["geometry"] == geom
+    assert by["jit__loop"]["thread"] == "racon-tpu-warmup"
+    assert by["jit_zeros"]["dispatches"] is None
+    assert comp["unused"] == 1 and comp["eager_programs"] == 1
+    assert comp["unused_s"] == pytest.approx(0.050, abs=1e-3)
+    # the stream: a probe compiles the kernel in one span, the real call
+    # hits the jit cache in the next and is submitted there
+    with obs.span("align.pack"):
+        compilewatch._on_duration(backend, 0.002, fun_name="jit(_loop)")
+    with obs.span("poa.dispatch"):
+        device_time.submit("exec", "_loop", done(), other)
+    comp, by = rows()
+    assert [r["dispatches"] for r in comp["programs"]
+            if r["program"] == "jit__loop"] == [0, None]
+    with obs.span("poa.dispatch"):
+        device_time.submit("exec", "_loop", done(), geom)
+    comp, by = rows()
+    assert [r["dispatches"] for r in comp["programs"]
+            if r["program"] == "jit__loop"] == [1, None]
+    assert comp["unused"] == 0 and comp["unused_s"] == 0
+    assert comp["eager_programs"] == 2
+    assert device_time.dispatch_counts() == {("_loop", other): 1,
+                                             ("_loop", geom): 1}
+    assert report.validate_report(report.build_report("cli")) == []
+    compilewatch.reset()
+
+
 # ------------------------------------------------------ schema v12 / v11
 
 def test_v12_validates_and_requires_device_time():
     rep = report.build_report("cli", wall_s=0.5)
-    assert rep["schema_version"] == 12
+    assert rep["schema_version"] == 13      # the section is v12's
     assert report.validate_report(rep) == []
     broken = {k: v for k, v in rep.items() if k != "device_time"}
     assert any("device_time" in e for e in report.validate_report(broken))
@@ -568,6 +642,20 @@ def test_cli_report_carries_the_prepare_spans_and_counters(cli_series, tag):
     assert pool == cli_series["on1"]["report"]["metrics"]["counters"][
         "build.pool_bytes"]
     assert m["counters"]["build.pool_bytes_ahead"] in (0, pool)
+
+
+@pytest.mark.parametrize("tag", ["on2", "traced"])
+def test_a_later_job_in_the_process_compiles_nothing_and_has_no_rows(
+        cli_series, tag):
+    """What the benchmark's window jobs are: every program is in the jit
+    cache, so the section is empty — and says so in every total."""
+    comp = cli_series[tag]["report"]["compiles"]
+    assert comp["count"] == 0 and comp["programs"] == []
+    assert comp["dropped"] == 0 and comp["post_warm"] == 0
+    assert comp["wall_s"] == 0 and comp["unrowed_s"] == 0
+    assert comp["unused"] == 0 and comp["eager_programs"] == 0
+    assert cli_series[tag]["report"]["metrics"]["timers"][
+        "compile.retrieve"] == 0
 
 
 def test_second_job_in_one_process_reports_its_own_aggregates(cli_series):
