@@ -108,7 +108,15 @@ METRICS: FrozenSet[str] = frozenset((
     # first-party overlapper
     "overlap.cache_hits", "overlap.cache_misses",
     "overlap.candidate_pairs", "overlap.chain_lanes_occupied",
-    "overlap.chain_lanes_total", "overlap.chains_dropped",
+    "overlap.chain_lanes_total",
+    # the hand-off to the align feed: pairs handed to the chain stream
+    # (candidate_pairs less the ones under min_seeds), the length of
+    # every array its intake sorts to decide the launches (linear: a
+    # visit a pair), and (gauge) the pairs launched when the first rows
+    # left for the aligner
+    "overlap.chain_pairs", "overlap.intake_visits",
+    "overlap.first_emit_pairs",
+    "overlap.chains_dropped",
     "overlap.chains_kept", "overlap.chunks",
     "overlap.freq_capped_buckets", "overlap.join_bailouts",
     "overlap.lanes_occupied", "overlap.lanes_total",
@@ -196,6 +204,10 @@ SPANS: FrozenSet[str] = frozenset((
     "exec.shard",
     "fleet.place", "gateway.admit",
     "overlap.chain", "overlap.chain.dispatch", "overlap.chain.fetch",
+    # the streamed hand-off's host work inside `align` (timer-only):
+    # classing + chunk planning, the completed groups' rows, and the
+    # consumer's Overlap objects with their filter
+    "overlap.chain.plan", "overlap.emit", "overlap.rows",
     "overlap.filter", "overlap.join.dispatch", "overlap.join.fetch",
     "overlap.match", "overlap.seed", "overlap.seed.dispatch",
     "overlap.seed.fetch",
@@ -211,8 +223,9 @@ SPANS: FrozenSet[str] = frozenset((
 # idle: the occupancy ledger (obs/device_time.py) reads through them, so
 # the parent's ``idle.<span>`` timer, and every metric that sums it, is
 # what it was before the leaf existed
-TIMER_ONLY_SPANS: FrozenSet[str] = frozenset(("poa.lanes",
-                                               "compile.retrieve"))
+TIMER_ONLY_SPANS: FrozenSet[str] = frozenset((
+    "poa.lanes", "compile.retrieve",
+    "overlap.chain.plan", "overlap.emit", "overlap.rows"))
 
 # ------------------------------------------------------------ fault sites
 

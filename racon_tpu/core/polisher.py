@@ -137,6 +137,11 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
                     stall_escalation=stall_escalation)
 
 
+# overlaps the streamed hand-off collects before it hands the align
+# session a batch (cut after a whole query's rows)
+STREAM_FEED_OVERLAPS = 512
+
+
 def _est_chain_seeds(est_len: int) -> int:
     """Seeds the densest candidate pair of a read set whose longest
     read has ``est_len`` bases will hold, for the chain warm-up: a
@@ -295,10 +300,11 @@ class Polisher:
         stream_auto = (auto_mode and not self.prefiltered_overlaps
                        and flags.get_bool("RACON_TPU_OVERLAP_RAGGED"))
         if stream_auto:
-            # streaming overlap->align handoff: filtered overlap rows
-            # come off the chain stream per query group and feed the
-            # align session incrementally — generation, filtering, and
-            # alignment dispatch interleave instead of phase-barriering
+            # streaming overlap->align hand-off: filtered overlap rows
+            # come off the chain stream per fetched chunk and feed the
+            # align session incrementally — the last chain chunks,
+            # filtering, and alignment dispatch interleave instead of
+            # phase-barriering
             overlaps = self._generate_overlaps_stream(
                 raw_index, name_to_id, id_to_id,
                 has_name, has_data, has_reverse, t_parse)
@@ -485,15 +491,23 @@ class Polisher:
                                   has_name, has_data, has_reverse,
                                   t_parse: float) -> List[Overlap]:
         """``--overlaps auto`` under ``RACON_TPU_OVERLAP_RAGGED``: the
-        streaming overlap→align handoff. Chained overlap rows arrive per
-        query group (:func:`racon_tpu.ops.chain.iter_overlap_groups`),
-        run through exactly the :meth:`_filter_overlaps` consecutive-run
-        sweep as the runs complete, and feed the align session in
-        batches — so chaining for query group N+1 overlaps alignment
-        dispatch for group N. Kept overlaps accumulate in feed order,
-        which IS the barrier path's order (the canonical row sort's
-        primary key is the query ordinal), so the polished output stays
-        byte-identical to the phase-barriered path."""
+        streamed overlap→align hand-off. The order of events: seeding
+        and the join are a barrier; the chain stream then plans its
+        chunks once and launches them, and each fetched chunk's
+        completed query groups arrive here as one block of canonical
+        rows (:func:`racon_tpu.ops.chain.iter_overlap_groups`).
+        ``batches()`` walks a block row by row (span ``overlap.rows``:
+        the ``Overlap`` objects, exactly the :meth:`_filter_overlaps`
+        consecutive-run sweep as each query's run completes, the early
+        reverse complements) and hands the align session a batch
+        whenever :data:`STREAM_FEED_OVERLAPS` kept overlaps are
+        collected at the end of a query's rows. The chain chunks still
+        in flight run under the aligner's first packs; a job is a
+        handful of chunks, so most rows arrive with the last fetches
+        (gauge ``overlap.first_emit_pairs``). Kept overlaps accumulate
+        in feed order, which IS the barrier path's order (the canonical
+        row sort's primary key is the query ordinal), so the polished
+        output stays byte-identical to the phase-barriered path."""
         from ..ops import chain as chain_ops
         from ..ops import overlap_seed
         metrics.set_gauge("overlap.mode_auto", 1)
@@ -541,35 +555,54 @@ class Polisher:
                 state["est_pairs"] += o.length // self.window_length + 1
             return kept
 
+        buf: List[Overlap] = []
+        run: List[Overlap] = []
+
+        def take(cols: List[list], i: int) -> int:
+            # rows i.. of one block into ``run`` / ``buf``, up to the
+            # end of the first query after which ``buf`` holds a batch
+            # (or the block's end); returns the next row
+            q_ord = cols[0]
+            n = len(q_ord)
+            while i < n:
+                q, t, strand, qb, qe, tb, te = (c[i] for c in cols)
+                i += 1
+                o = Overlap.from_paf(
+                    self.sequences[read_pos[q]].name, len(read_seqs[q]),
+                    qb, qe, "-" if strand else "+",
+                    self.sequences[t].name, len(target_seqs[t]), tb, te)
+                o.transmute(self.sequences, name_to_id, id_to_id)
+                if o.is_valid:
+                    if run and o.q_id != run[-1].q_id:
+                        buf.extend(flush_run(run))
+                        run.clear()
+                    run.append(o)
+                if (len(buf) >= STREAM_FEED_OVERLAPS
+                        and (i == n or q_ord[i] != q)):
+                    break
+            return i
+
         def batches():
-            buf: List[Overlap] = []
-            run: List[Overlap] = []
+            nonlocal buf
             with obs.span("overlap.filter"):
                 pass  # span parity with the barrier path (work is inline)
             # graftlint: disable=jit-shape-hazard (k is a run-constant flag value clipped to 4..16 — one compile per run)
             for rows in chain_ops.iter_overlap_groups(
                     read_seqs, target_seqs, read_self_t, k=k):
-                for i in range(rows["q_ord"].size):
-                    q = int(rows["q_ord"][i])
-                    t = int(rows["t_idx"][i])
-                    o = Overlap.from_paf(
-                        self.sequences[read_pos[q]].name,
-                        len(read_seqs[q]),
-                        int(rows["q_begin"][i]), int(rows["q_end"][i]),
-                        "-" if int(rows["strand"][i]) else "+",
-                        self.sequences[t].name, len(target_seqs[t]),
-                        int(rows["t_begin"][i]), int(rows["t_end"][i]))
-                    o.transmute(self.sequences, name_to_id, id_to_id)
-                    if not o.is_valid:
-                        continue
-                    if run and o.q_id != run[-1].q_id:
-                        buf.extend(flush_run(run))
-                        run.clear()
-                    run.append(o)
-                if len(buf) >= 512:
-                    yield buf
-                    buf = []
-            buf.extend(flush_run(run))
+                cols = [rows[key].tolist() for key in (
+                    "q_ord", "t_idx", "strand", "q_begin", "q_end",
+                    "t_begin", "t_end")]
+                i = 0
+                while i < len(cols[0]):
+                    # closed before the yield: the consumer's seconds
+                    # are not this span's
+                    with obs.span("overlap.rows"):
+                        i = take(cols, i)
+                    if len(buf) >= STREAM_FEED_OVERLAPS:
+                        yield buf
+                        buf = []
+            with obs.span("overlap.rows"):
+                buf.extend(flush_run(run))
             # every overlap is known now but alignment is still
             # draining — the consensus compile hides under it exactly
             # like the barrier path's placement before align
@@ -700,13 +733,18 @@ class Polisher:
         self.logger.log("[racon_tpu::Polisher::initialize] aligned overlaps")
 
     def _align_feed(self, feed, overlaps, need, log, msg) -> None:
-        """The streaming half of the overlap→align handoff: drain
-        filtered overlap batches off the chain stream and feed the
-        round-17 align session as they arrive. The session packs and
-        dispatches asynchronously, so the chain stream's device DP and
-        host filtering for query group N+1 run while group N's windows
-        align; ``overlap_feed_s`` records the producer wall that hid
-        under the phase.
+        """The consuming half of the overlap→align hand-off: take
+        filtered overlap batches off ``feed`` (the generator of
+        :meth:`_generate_overlaps_stream`, which runs HERE, on this
+        thread, inside span ``align`` between two ``sess.feed`` calls:
+        its host work is ``align``'s self time but for the chain
+        launches and fetches, and the timer-only leaves
+        ``overlap.chain.plan`` / ``.emit`` / ``.rows`` say how much) and
+        feed the round-17 align session as they arrive. The session
+        packs and dispatches asynchronously, so the aligner's first
+        chunks run while the generator fetches the last chain chunks
+        and builds the later batches; ``overlap_feed_s`` records the
+        producer wall inside the phase.
 
         ``bp_stream`` can return None even on a streaming-capable
         backend (mesh runs) — then there

@@ -243,12 +243,13 @@ REGISTRY: Dict[str, Flag] = _declare([
          "to the host join; set 0 to force the numpy match_seeds "
          "oracle for A/B measurement)."),
     Flag("RACON_TPU_OVERLAP_RAGGED", "1", "bool",
-         "Ragged overlap occupancy: chain batches greedy-fill a fixed "
-         "lane arena by per-pair seed-count cost with double-buffered "
-         "dispatch/fetch (_ChainStream), and chained overlap rows "
-         "stream per query group into the align session instead of "
-         "phase-barriering (byte-identical either way; set 0 to force "
-         "the bucketed barrier path for A/B measurement)."),
+         "Ragged overlap occupancy: candidate pairs fill the fixed "
+         "lane arena of their seed-count class, chunks planned once, "
+         "with double-buffered dispatch/fetch (_ChainStream), and "
+         "chained overlap rows stream into the align session as whole "
+         "query groups per fetched chunk instead of phase-barriering "
+         "(byte-identical either way; set 0 to force the bucketed "
+         "barrier path for A/B measurement)."),
     # --------------------------------------------------------------- tests
     Flag("RACON_TPU_SLOW", "0", "bool",
          "Enable the slow (tier-2) test set."),

@@ -24,23 +24,27 @@ Consumes the flat minimizer tables from :mod:`.overlap_seed` and emits
   so any ascending sort produces the oracle's exact lexsort order. The
   oracles themselves (``match_seeds``, ``chain_np``) are the plain
   reference's, :mod:`racon_tpu.models.overlap`.
-- **chaining** is the device DP: pairs ragged-pack by seed-count class
-  (powers of 4) into the one ``[B, S]`` arena of their class through
-  :class:`_ChainStream` —
-  greedy chunk fill by each pair's own seed-count cost, double-buffered
-  dispatch/fetch behind an in-flight budget, per-pair results invariant
-  to feed batching (the ``_AlignStream`` discipline, warmed via
-  :func:`_warmup_shapes`) — and a ``lax.scan`` over seed positions
-  scores gap-bounded colinear chains against a bounded lookback window,
-  then backtracks on device so only a ``[B, 6]`` summary per launch
-  crosses the link — resident-friendly by construction.
-- **streaming** (:func:`iter_overlap_groups`): chained overlap rows
-  emit per query group as chunks resolve, so the polisher's filter and
-  the round-17 align stream consume group N while group N+1 is still
-  chaining. The canonical full-run row order is the concatenation of
-  the per-group orders (the global lexsort's primary key IS the query
-  ordinal), which is what keeps the streamed and phase-barriered paths
-  byte-identical.
+- **chaining** is the device DP: every candidate pair is known when
+  the join returns, so the pairs are classed by seed count (powers of
+  4) and cut into chunks once (:func:`_plan_chunks`), each chunk the one
+  ``[B, S]`` arena of its class, and :class:`_ChainStream` walks the
+  plan — double-buffered dispatch/fetch behind an in-flight budget,
+  per-pair results invariant to chunk mates (the ``_AlignStream``
+  discipline, warmed via :func:`_warmup_shapes`) — and a ``lax.scan``
+  over seed positions scores gap-bounded colinear chains against a
+  bounded lookback window, then backtracks on device so only a
+  ``[B, 6]`` summary per launch crosses the link — resident-friendly by
+  construction.
+- **streaming** (:func:`iter_overlap_groups`): after each fetched chunk
+  the query groups it completed leave, in order, as one block of
+  canonical rows; the polisher's filter and the round-17 align stream
+  consume a block while the later chunks are still in flight. The
+  canonical full-run row order is the concatenation of the blocks (the
+  global lexsort's primary key IS the query ordinal), which is what
+  keeps the streamed and phase-barriered paths byte-identical. The
+  host work of the hand-off is linear in the pairs and vectorised per
+  chunk: one sort by class, array fills per launch, one lexsort per
+  block.
 
 Scoring is all-integer (seed span minus a gap penalty in 1/16-base
 units), so the kernel and the numpy oracle :func:`chain_np` agree
@@ -52,7 +56,7 @@ colinearity means ascending in both axes) and flip back on emission.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -103,6 +107,15 @@ def _seed_bucket(n: int) -> int:
     compiled program, and a bacterial read set spans three of these
     where it spanned six powers of 2."""
     return _class_of(n, 16, 4)
+
+
+def _seed_buckets(counts: np.ndarray) -> np.ndarray:
+    """:func:`_seed_bucket` of every element (``counts`` non-empty)."""
+    rungs = [_seed_bucket(1)]
+    while rungs[-1] < counts.max():
+        rungs.append(_seed_bucket(rungs[-1] + 1))
+    rungs = np.asarray(rungs, np.int64)
+    return rungs[np.searchsorted(rungs, counts, "left")]
 
 
 def _pair_batch(S: int) -> int:
@@ -525,83 +538,92 @@ def _gather_pairs_kernel(tp_dev, qc_dev, starts, counts, *, S: int):
     return ts, qs
 
 
+def _plan_chunks(counts: np.ndarray) -> List[Tuple[int, np.ndarray]]:
+    """Cut candidate pairs (``counts[i]`` seeds each) into chain chunks,
+    once, before the first launch: ``(S, idx)`` per chunk, ``idx`` the
+    ascending positions of at most :func:`_pair_batch` pairs of seed
+    class ``S``. One stable sort by class over all pairs (counted in
+    ``overlap.intake_visits``: a visit a pair); a class is cut in
+    position order, so every chunk is full but its class's last.
+    Chunks are listed by their first pair — the order
+    :meth:`_ChainStream.run` launches and fetches them in, which lets
+    the query groups below the next chunk's first pair leave as soon
+    as a fetch lands. Which pairs share a chunk changes no row (the DP
+    is per lane), and every chunk runs its class's one ``[B, S]`` arena
+    (:func:`_pair_batch`), so the plan decides no program."""
+    n = int(counts.size)
+    metrics.inc("overlap.intake_visits", n)
+    if n == 0:
+        return []
+    classes = _seed_buckets(counts)
+    order = np.argsort(classes, kind="stable")
+    cuts = np.flatnonzero(np.diff(classes[order])) + 1
+    chunks = []
+    for members in np.split(order, cuts):
+        S = int(classes[members[0]])
+        cap = _pair_batch(S)
+        chunks.extend((S, members[lo:lo + cap])
+                      for lo in range(0, members.size, cap))
+    chunks.sort(key=lambda chunk: (int(chunk[1][0]), chunk[0]))
+    return chunks
+
+
 class _ChainStream:
-    """Ragged streaming chain session — the overlapper analog of
+    """Ragged chain session — the overlapper analog of
     ``nw._AlignStream`` / ``poa._ConsensusStream``.
 
-    Candidate pairs arrive through :meth:`add` (cost = their own seed
-    count) and class into seed-count buckets (powers of 4); each bucket
-    greedy-fills the one ``[B, S]`` arena of its class
-    (:data:`CHAIN_ARENA_CELLS` cells) and dispatches a chunk the moment
-    it fills, ASYNCHRONOUSLY — host packing of later pairs overlaps
-    device DP of earlier chunks, and fetches happen only when the
-    in-flight budget (:data:`CHAIN_INFLIGHT` chunks / 2 arenas of
-    cells) forces one or at :meth:`finish`. The DP is per-lane
-    independent and each pair always lands in the same bucket, so
-    per-pair rows are invariant to feed batching — the property the
-    streamed/barriered byte-identity contract rests on.
+    Every candidate pair (``counts[i]`` seeds at flat-hit offset
+    ``starts[i]``) is known before the first launch, so the chunks are
+    planned once (:func:`_plan_chunks`) and :meth:`run` walks the plan:
+    launch a chunk ASYNCHRONOUSLY into the one ``[B, S]`` arena of its
+    class (:data:`CHAIN_ARENA_CELLS` cells), and fetch the oldest only
+    when the in-flight budget (:data:`CHAIN_INFLIGHT` chunks / 2 arenas
+    of cells) forces one, or at the end — the host fills chunk N+1 while
+    the device chains chunk N. A fetched chunk leaves as one ``(idx,
+    [n, 6])`` block. The DP is per-lane independent and a pair's class
+    is its own seed count's, so a pair's row does not depend on its
+    chunk mates — the property the streamed/barriered byte-identity
+    contract rests on.
 
     ``tp``/``qc`` may be host arrays (vectorized masked gather) or the
     resident join's device copies (:func:`_gather_pairs_kernel` fills
-    the arena on the device). ``on_row(pid, row)`` fires
-    as each pair's ``[6]`` summary row lands, in deterministic
-    (chunk-completion) order — the group streamer's completion
-    signal."""
+    the arena on the device); ``starts``/``counts`` are host arrays in
+    both."""
 
-    def __init__(self, *, k: int, tp, qc, device_src: bool = False,
-                 on_row: Optional[Callable] = None):
+    def __init__(self, *, k: int, tp, qc, starts: np.ndarray,
+                 counts: np.ndarray, device_src: bool = False):
         self.k = k
         self.tp = tp
         self.qc = qc
+        self.starts = starts
+        self.counts = counts
         self.device_src = device_src
-        self.on_row = on_row
-        self.rows: Dict[int, np.ndarray] = {}
-        self.pending: Dict[int, List[Tuple[int, int, int]]] = {}
-        self.inflight: List[dict] = []
+        self.inflight: List[Tuple[np.ndarray, object, int]] = []
         self.inflight_cells = 0
-        self._done = False
+        # pairs handed to the device so far
+        self.launched = 0
 
-    # ------------------------------------------------------------- intake
+    def run(self, chunks: List[Tuple[int, np.ndarray]]
+            ) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
+        """Launch ``chunks`` in order and yield each one's ``(idx,
+        rows)`` — ``rows[j]`` the ``[6]`` summary of pair ``idx[j]`` —
+        as it is fetched, oldest first."""
+        for S, idx in chunks:
+            self._launch(S, idx)
+            while (len(self.inflight) > CHAIN_INFLIGHT
+                   or self.inflight_cells > 2 * CHAIN_ARENA_CELLS):
+                yield self._fetch_oldest()
+        while self.inflight:
+            yield self._fetch_oldest()
 
-    def add(self, pid: int, start: int, count: int) -> None:
-        """Queue one candidate pair (``count`` seeds at flat-hit offset
-        ``start``). Buffered only — call :meth:`pump` after a batch."""
-        assert not self._done, "chain stream already finished"
-        self.pending.setdefault(_seed_bucket(count), []).append(
-            (count, pid, start))
-
-    def pump(self) -> None:
-        """Dispatch every chunk that fills (non-blocking unless the
-        in-flight budget forces a pipelined fetch)."""
-        self._drain(final=False)
-
-    # ----------------------------------------------------------- dispatch
-
-    def _drain(self, final: bool) -> None:
-        for S in sorted(self.pending):
-            entries = self.pending.pop(S)
-            # biggest seed lists first: tail chunks stay dense and the
-            # (S, B) geometry per chunk is the bucket's full arena cap,
-            # so the warm ladder covers every full chunk
-            entries.sort(key=lambda e: (-e[0], e[1]))
-            cap = _pair_batch(S)
-            while entries:
-                if not final and len(entries) < cap:
-                    break
-                chunk = entries[:cap]
-                del entries[:cap]
-                self._launch(chunk, S)
-            if entries:
-                self.pending[S] = entries
-
-    def _launch(self, chunk: List[Tuple[int, int, int]], S: int) -> None:
+    def _launch(self, S: int, idx: np.ndarray) -> None:
         B = _pair_batch(S)
+        n = int(idx.size)
         starts = np.zeros(B, np.int64)
         counts = np.zeros(B, np.int64)
-        for lane, (c, _, s0) in enumerate(chunk):
-            starts[lane] = s0
-            counts[lane] = c
-        with obs.span("overlap.chain.dispatch", pairs=len(chunk)):
+        starts[:n] = self.starts[idx]
+        counts[:n] = self.counts[idx]
+        with obs.span("overlap.chain.dispatch", pairs=n):
             ns = counts.astype(np.int32)
             if self.device_src:
                 # graftlint: disable=jit-shape-hazard (S is the pow4 _seed_bucket rung)
@@ -616,41 +638,23 @@ class _ChainStream:
             out = _chain_kernel(ts, qs, ns, S=S, k=self.k)
             device_time.submit("exec", "_chain_kernel", out,
                                _chain_geometry(S, B, self.k))
-        self.inflight.append({"chunk": chunk, "out": out,
-                              "cells": B * S})
+        self.inflight.append((idx, out, B * S))
         self.inflight_cells += B * S
+        self.launched += n
+        occupied = int(counts.sum())
         metrics.inc("overlap.lanes_total", B * S)
-        metrics.inc("overlap.lanes_occupied", int(counts.sum()))
+        metrics.inc("overlap.lanes_occupied", occupied)
         metrics.inc("overlap.chunks", 1)
         # mirrored legacy names (run-report compat with the barrier path)
         metrics.inc("overlap.chain_lanes_total", B * S)
-        metrics.inc("overlap.chain_lanes_occupied", int(counts.sum()))
-        while (len(self.inflight) > CHAIN_INFLIGHT
-               or self.inflight_cells > 2 * CHAIN_ARENA_CELLS):
-            self._fetch_oldest()
+        metrics.inc("overlap.chain_lanes_occupied", occupied)
 
-    def _fetch_oldest(self) -> None:
-        la = self.inflight.pop(0)
-        self.inflight_cells -= la["cells"]
-        with obs.span("overlap.chain.fetch", pairs=len(la["chunk"])):
-            out_np = fetch_global([la["out"]])[0]
-        for lane, (_, pid, _) in enumerate(la["chunk"]):
-            row = out_np[lane].astype(np.int64)
-            self.rows[pid] = row
-            if self.on_row is not None:
-                self.on_row(pid, row)
-
-    # -------------------------------------------------------------- drain
-
-    def finish(self) -> Dict[int, np.ndarray]:
-        """Dispatch the partial chunks, drain the pipeline, and return
-        the per-pair ``[6]`` rows keyed by pair id."""
-        assert not self._done, "chain stream already finished"
-        self._done = True
-        self._drain(final=True)
-        while self.inflight:
-            self._fetch_oldest()
-        return self.rows
+    def _fetch_oldest(self) -> Tuple[np.ndarray, np.ndarray]:
+        idx, out, cells = self.inflight.pop(0)
+        self.inflight_cells -= cells
+        with obs.span("overlap.chain.fetch", pairs=int(idx.size)):
+            out_np = fetch_global([out])[0]
+        return idx, out_np[:idx.size].astype(np.int64)
 
 
 def chain_pairs(hits: Dict[str, np.ndarray], *, k: int, min_seeds: int
@@ -779,17 +783,18 @@ def _seed_and_join(read_seqs, target_seqs, read_self_t, qlens, *,
 
 
 def _group_rows(q, t, rel, rows6, qlens, k) -> Dict[str, np.ndarray]:
-    """Emit one query group's kept chains as canonical overlap rows:
-    flip reverse-strand chain coords back to forward query space and
-    sort by ``(t, rel, t_begin, q_begin)`` — exactly the global
-    canonical lexsort restricted to one value of its primary key, which
-    is what makes streamed emission byte-identical to the barrier."""
+    """Emit a run of whole query groups' kept chains as canonical
+    overlap rows: flip reverse-strand chain coords back to forward
+    query space and sort by ``(q, t, rel, t_begin, q_begin)`` — the
+    global canonical lexsort :func:`find_overlaps` defines, restricted
+    to a range of its primary key, which is what makes streamed
+    emission byte-identical to the barrier."""
     ql = qlens[q]
     q_begin = np.where(rel == 1, ql - (rows6[:, 3] + k), rows6[:, 2])
     q_end = np.where(rel == 1, ql - rows6[:, 2], rows6[:, 3] + k)
     t_begin = rows6[:, 4]
     t_end = rows6[:, 5] + k
-    order = np.lexsort((q_begin, t_begin, rel, t))
+    order = np.lexsort((q_begin, t_begin, rel, t, q))
     return {"q_ord": q[order], "t_idx": t[order], "strand": rel[order],
             "q_begin": q_begin[order], "q_end": q_end[order],
             "t_begin": t_begin[order], "t_end": t_end[order],
@@ -805,15 +810,24 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
                         device_join: Optional[bool] = None,
                         cache: bool = True
                         ) -> Iterator[Dict[str, np.ndarray]]:
-    """Streaming overlapper driver: yield canonical overlap rows per
-    query group (ascending query ordinal) as chain chunks resolve.
+    """Streaming overlapper driver: yield canonical overlap rows, whole
+    query groups in ascending query ordinal, a block per fetched chain
+    chunk.
 
-    The chain stream keeps :data:`CHAIN_INFLIGHT` chunks in flight, so
-    while the consumer aligns group N's overlaps the device is already
-    chaining groups N+1.. — the phase barrier the round-20 overlapper
-    kept between chaining and alignment streams away. Concatenating
-    every yield reproduces :func:`find_overlaps` byte-for-byte (the
-    global sort's primary key is the query ordinal)."""
+    The order of events: seed and join (a barrier: every candidate pair
+    is known after it); class the eligible pairs and plan their chunks
+    once (span ``overlap.chain.plan``, counter
+    ``overlap.intake_visits`` beside ``overlap.chain_pairs``); then the
+    chain stream launches chunk after chunk, and whenever its in-flight
+    budget forces a fetch — or at the end — the fetched block lowers
+    its groups' open-pair counts and every group now complete, in
+    order, leaves in one block (span ``overlap.emit``). The consumer
+    runs between two fetches, so what it feeds the aligner overlaps the
+    chunks still in flight; with a handful of chunks a job, most rows
+    leave in the last blocks (gauge ``overlap.first_emit_pairs``: pairs
+    launched when the first block left). Concatenating every yield
+    reproduces :func:`find_overlaps` byte-for-byte (the global sort's
+    primary key is the query ordinal)."""
     k, w, max_occ, min_seeds, resident, device_join, _ = _resolve_params(
         k, w, max_occ, min_seeds, resident, device_join, None)
     qlens = np.fromiter((len(s) for s in read_seqs), np.int64,
@@ -823,73 +837,68 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
         k=k, w=w, max_occ=max_occ, resident=resident,
         device_join=device_join, cache=cache,
         resident_hits=resident and device_join)
-    starts, ends, counts = _pair_runs(hits)
+    starts, _, counts = _pair_runs(hits)
     metrics.inc("overlap.candidate_pairs", int(starts.size))
     if starts.size == 0:
         return
-    q_of = hits["q"][starts]
-    t_of = hits["t"][starts]
-    rel_of = hits["rel"][starts]
-    eligible = counts >= min_seeds
+    with obs.span("overlap.chain.plan"):
+        q_of = hits["q"][starts]
+        t_of = hits["t"][starts]
+        rel_of = hits["rel"][starts]
+        # the pairs the stream chains; the rest drop before the DP
+        eligible = counts >= min_seeds
+        pids = np.flatnonzero(eligible)
+        metrics.inc("overlap.chain_pairs", int(pids.size))
+        # query-group boundaries over the pair axis (pairs are
+        # lexsorted, so groups are consecutive runs of q)
+        gchange = np.ones(q_of.size, bool)
+        gchange[1:] = q_of[1:] != q_of[:-1]
+        gstart = np.flatnonzero(gchange)
+        gend = np.append(gstart[1:], q_of.size)
+        ngroups = gstart.size
+        group_of = np.cumsum(gchange) - 1
+        # unresolved eligible pairs per group — the emission gate
+        rem = np.bincount(group_of[pids], minlength=ngroups)
+        device_src = "tp_dev" in hits
+        stream = _ChainStream(
+            k=k, tp=hits["tp_dev"] if device_src else hits["tp"],
+            qc=hits["qc_dev"] if device_src else hits["qc"],
+            starts=starts[pids], counts=counts[pids],
+            device_src=device_src)
+        chunks = _plan_chunks(stream.counts)
+    rows6 = np.zeros((starts.size, 6), np.int64)
     kept_total = 0
-    dropped_total = int((~eligible).sum())
-
-    # query-group boundaries over the pair axis (pairs are lexsorted,
-    # so groups are consecutive runs of q)
-    gchange = np.ones(q_of.size, bool)
-    gchange[1:] = q_of[1:] != q_of[:-1]
-    gstart = np.flatnonzero(gchange)
-    gend = np.append(gstart[1:], q_of.size)
-    ngroups = gstart.size
-    group_of = np.searchsorted(gstart, np.arange(q_of.size), "right") - 1
-    # unresolved eligible pairs per group — the emission gate
-    rem = np.zeros(ngroups, np.int64)
-    np.add.at(rem, group_of[eligible], 1)
-
-    def on_row(pid, _row):
-        rem[group_of[pid]] -= 1
-
-    device_src = "tp_dev" in hits
-    stream = _ChainStream(
-        k=k, tp=hits["tp_dev"] if device_src else hits["tp"],
-        qc=hits["qc_dev"] if device_src else hits["qc"],
-        device_src=device_src, on_row=on_row)
-
-    def emit(g: int) -> Optional[Dict[str, np.ndarray]]:
-        nonlocal kept_total, dropped_total
-        pids = np.arange(gstart[g], gend[g])[eligible[gstart[g]:gend[g]]]
-        if pids.size == 0:
-            return None
-        rows6 = np.stack([stream.rows.pop(int(p)) for p in pids])
-        good = rows6[:, 1] >= min_seeds
-        kept_total += int(good.sum())
-        dropped_total += int((~good).sum())
-        if not good.any():
-            return None
-        sel = pids[good]
-        return _group_rows(q_of[sel], t_of[sel], rel_of[sel],
-                           rows6[good], qlens, k)
-
-    emit_at = 0
-    for g in range(ngroups):
-        for p in range(int(gstart[g]), int(gend[g])):
-            if eligible[p]:
-                stream.add(p, int(starts[p]), int(counts[p]))
-        stream.pump()
-        while emit_at <= g and rem[emit_at] == 0:
-            rows = emit(emit_at)
-            emit_at += 1
-            if rows is not None:
-                yield rows
-    stream.finish()
-    while emit_at < ngroups:
-        rows = emit(emit_at)
-        emit_at += 1
-        if rows is not None:
-            yield rows
+    emit_at = 0  # the first group that has not left
+    first_rows = True
+    for idx, fetched in stream.run(chunks):
+        with obs.span("overlap.emit", pairs=int(idx.size)):
+            done = pids[idx]
+            rows6[done] = fetched
+            np.subtract.at(rem, group_of[done], 1)
+            open_groups = np.flatnonzero(rem[emit_at:])
+            upto = (emit_at + int(open_groups[0]) if open_groups.size
+                    else ngroups)
+            block = None
+            if upto > emit_at:
+                # the complete groups' chained pairs, and of them the
+                # chains that hold min_seeds
+                lo, hi = int(gstart[emit_at]), int(gend[upto - 1])
+                sel = lo + np.flatnonzero(eligible[lo:hi])
+                sel = sel[rows6[sel, 1] >= min_seeds]
+                emit_at = upto
+                kept_total += int(sel.size)
+                if sel.size:
+                    block = _group_rows(q_of[sel], t_of[sel], rel_of[sel],
+                                        rows6[sel], qlens, k)
+        if block is not None:
+            if first_rows:
+                metrics.set_gauge("overlap.first_emit_pairs",
+                                  stream.launched)
+                first_rows = False
+            yield block
     metrics.inc("overlap.stream_groups", ngroups)
     metrics.inc("overlap.chains_kept", kept_total)
-    metrics.inc("overlap.chains_dropped", dropped_total)
+    metrics.inc("overlap.chains_dropped", int(starts.size) - kept_total)
 
 
 def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],

@@ -93,6 +93,27 @@ def test_a_timer_only_leaf_leaves_its_idle_with_its_parent():
                               "unattributed": 0.05}
 
 
+@pytest.mark.parametrize("leaf", ["overlap.chain.plan", "overlap.emit",
+                                  "overlap.rows"])
+def test_the_hand_offs_leaves_leave_their_idle_with_align(leaf):
+    """The streamed overlap -> align hand-off runs inside ``align`` on
+    the feeding thread; its three leaves are timers only, so the idle
+    under them stays ``idle.align`` (``idle_align_feed_s``' list is
+    whole), while a chain fetch beside them keeps its own."""
+    from racon_tpu import contracts
+    assert leaf in contracts.TIMER_ONLY_SPANS <= contracts.SPANS
+    rows = [("0", "exec", "_chain_kernel", "main", 0, 100 * MS),
+            ("0", "h2d", "align.put", "main", 900 * MS, 1000 * MS)]
+    spans = {"main": [("align", 50 * MS, 1000 * MS),
+                      ("overlap.chain.fetch", 100 * MS, 200 * MS),
+                      (leaf, 200 * MS, 700 * MS),
+                      ("align.pack", 800 * MS, 900 * MS)]}
+    out = device_time.account(rows, spans, 0, 1000 * MS, "main")
+    assert out["idle_s"] == pytest.approx(0.8)
+    assert out["idle_by"] == {"align": 0.6, "overlap.chain.fetch": 0.1,
+                              "align.pack": 0.1, "unattributed": 0.0}
+
+
 def test_two_devices_rows_and_means():
     """``--chips N``: one row set per device ordinal; the top level is
     the mean over devices, so busy + idle is still the window."""

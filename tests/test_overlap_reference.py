@@ -203,6 +203,14 @@ def test_the_environment_no_longer_decides_the_mode(monkeypatch):
 
 # ------------------------------------------- whole jobs on ONE device
 
+FEED_BATCH = 8
+
+
+def _overlap_key(o):
+    return (o.q_id, o.t_id, bool(o.strand), o.q_begin, o.q_end,
+            o.t_begin, o.t_end)
+
+
 class _Stdout:
     """The CLI writes its FASTA to ``sys.stdout.buffer``."""
 
@@ -227,7 +235,7 @@ def jobs(tmp_path_factory):
     to one device: ``--overlaps auto`` (with a report), the positional
     ``auto``, ``--overlaps auto`` again (the warm job, with a report),
     and the host path on the generator's exact PAF."""
-    from racon_tpu.core import backends
+    from racon_tpu.core import backends, polisher
     td = tmp_path_factory.mktemp("auto_jobs")
     sim = _simulate()
     paths = sim.write_inputs({**TRAFFIC, "contig_sizes": [12000]},
@@ -237,7 +245,26 @@ def jobs(tmp_path_factory):
     # one device: the single-device streams both cells run. Steered
     # here, in the test, not through an option of the program
     auto_mesh, backends._auto_mesh = backends._auto_mesh, lambda mesh: mesh
-    out = {"truth": truth}
+    # the streamed hand-off's batches as the align session is fed them,
+    # cut every FEED_BATCH kept overlaps (the program's 512 would make
+    # this job's 50 overlaps one batch)
+    align_feed = polisher.Polisher._align_feed
+    feed_batch = polisher.STREAM_FEED_OVERLAPS
+    fed = []
+
+    def spy(self, feed, *rest):
+        fed.append([])
+
+        def tee():
+            for batch in feed:
+                fed[-1].append([_overlap_key(o) for o in batch])
+                yield batch
+
+        return align_feed(self, tee(), *rest)
+
+    polisher.Polisher._align_feed = spy
+    polisher.STREAM_FEED_OVERLAPS = FEED_BATCH
+    out = {"truth": truth, "paths": paths, "fed": fed}
     try:
         trace.deactivate()
         for tag, argv in (
@@ -258,6 +285,8 @@ def jobs(tmp_path_factory):
                         "report": json.loads(rep.read_bytes())}
     finally:
         backends._auto_mesh = auto_mesh
+        polisher.Polisher._align_feed = align_feed
+        polisher.STREAM_FEED_OVERLAPS = feed_batch
         trace.deactivate()
     return out
 
@@ -278,6 +307,66 @@ def test_option_and_positional_auto_are_the_same_job(jobs):
         assert "parse.overlaps" not in rep["metrics"]["timers"]
         assert rep["metrics"]["counters"]["align.chunks"] > 0
     assert jobs["host"]["report"]["overlap"]["mode"] == "paf"
+
+
+def test_align_session_is_fed_the_barrier_paths_batches(jobs):
+    """What reaches ``sess.feed`` in the streamed job: the barrier
+    path's rows (``find_overlaps(ragged=False)``) through
+    ``_filter_overlaps``, in that order, cut where the hand-off cuts —
+    after a whole query's rows, once ``FEED_BATCH`` kept overlaps of
+    the queries before it are collected. Same pairs, same order, same
+    boundaries, in every auto job."""
+    import types
+
+    from racon_tpu.core.overlap import Overlap
+    from racon_tpu.core.polisher import Polisher, PolisherType
+    reads = open(jobs["paths"]["reads"], "rb").read().split(b"\n")[1::4]
+    draft = b"".join(
+        open(jobs["paths"]["draft"], "rb").read().split(b"\n")[1:])
+    rows = chain.find_overlaps(reads, [draft],
+                               np.full(len(reads), -1, np.int64),
+                               ragged=False)
+    settings = types.SimpleNamespace(error_threshold=0.3,
+                                     type=PolisherType.C)
+    want, buf, run = [], [], []
+    for i in range(rows["q_ord"].size):
+        q = int(rows["q_ord"][i])
+        o = Overlap.from_paf(
+            b"", len(reads[q]), int(rows["q_begin"][i]),
+            int(rows["q_end"][i]), "-" if rows["strand"][i] else "+",
+            b"", len(draft), int(rows["t_begin"][i]),
+            int(rows["t_end"][i]))
+        o.q_id, o.t_id = 1 + q, 0      # the draft is sequence 0
+        if run and run[-1].q_id != o.q_id:
+            buf.extend(Polisher._filter_overlaps(settings, run))
+            run = []
+        run.append(o)
+        last_of_query = (i + 1 == rows["q_ord"].size
+                         or rows["q_ord"][i + 1] != q)
+        if last_of_query and len(buf) >= FEED_BATCH:
+            want.append([_overlap_key(o) for o in buf])
+            buf = []
+    buf.extend(Polisher._filter_overlaps(settings, run))
+    want.append([_overlap_key(o) for o in buf])
+    assert len(want) >= 4 and all(len(b) >= FEED_BATCH for b in want[:-1])
+    # three auto jobs streamed; the host job on the PAF has no feed
+    assert len(jobs["fed"]) == 3
+    for fed in jobs["fed"]:
+        assert fed == want
+    counters = jobs["again"]["report"]["metrics"]["counters"]
+    assert counters["overlap.intake_visits"] \
+        == counters["overlap.chain_pairs"] > 0
+    assert counters["overlap.chain_pairs"] \
+        <= counters["overlap.candidate_pairs"]
+    timers = jobs["again"]["report"]["metrics"]["timers"]
+    for leaf in ("overlap.chain.plan", "overlap.emit", "overlap.rows"):
+        assert timers[leaf] > 0, leaf
+        assert "idle." + leaf not in timers
+    assert timers["overlap.chain.plan"] + timers["overlap.emit"] \
+        + timers["overlap.rows"] <= timers["align"]
+    gauges = jobs["again"]["report"]["metrics"]["gauges"]
+    assert 0 < gauges["overlap.first_emit_pairs"] \
+        <= counters["overlap.chain_pairs"]
 
 
 def test_second_auto_job_compiles_nothing(jobs):

@@ -301,14 +301,45 @@ def test_device_join_empty_side_bails_to_oracle():
 
 # ------------------------------------------- stage 2.5: ragged streaming
 
-def test_chain_stream_feed_batching_invariance():
-    """Per-pair chain rows are invariant to how the stream is fed: one
-    giant batch, pair-at-a-time pumping, and ragged 3-pair batches all
-    yield identical rows for every pair id — the property the
-    streamed/barriered byte-identity contract rests on."""
+def _stream_rows(hits, starts, counts, parts):
+    """``{pair: [6] row}`` from one planned chain stream per part of the
+    pairs (``parts``: position arrays into ``starts`` / ``counts``)."""
+    rows = {}
+    for part in parts:
+        st = chain._ChainStream(k=15, tp=hits["tp"], qc=hits["qc"],
+                                starts=starts[part], counts=counts[part])
+        for idx, block in st.run(chain._plan_chunks(st.counts)):
+            assert block.shape == (idx.size, 6)
+            assert block.dtype == np.int64
+            for pair, row in zip(part[idx].tolist(), block.tolist()):
+                assert pair not in rows
+                rows[pair] = row
+        assert st.launched == part.size and not st.inflight
+    return rows
+
+
+# arena cells, and how the pairs are dealt to streams. 2^19 is the
+# program's: every class is one tail chunk. 64 cells: class 16 crosses
+# its cap of 4 pairs (full chunks and a tail), classes 64 and up are a
+# pair a chunk, each past the in-flight budget on its own
+@pytest.mark.parametrize("cells,deal", [
+    (1 << 19, 1), (1 << 19, 3), (64, 1), (64, 2), (256, 1)],
+    ids=["one-chunk-a-class", "dealt-to-three-streams",
+         "class-16-crosses-its-cap", "crossing-and-dealt-to-two",
+         "class-64-crosses-its-cap"])
+def test_chain_rows_do_not_depend_on_chunk_mates(monkeypatch, cells, deal):
+    """A pair's chain row is invariant to which pairs share its chunk:
+    whole classes in one chunk, classes cut at their arena cap into
+    full chunks and a tail, and the pairs dealt to separate streams all
+    give the rows of the phase-barriered ``chain_pairs`` for every
+    pair — the property the streamed/barriered byte-identity contract
+    rests on."""
     rng = np.random.default_rng(34)
     target = rand_seq(rng, 6000)
     reads = [target[i * 400:i * 400 + 1500] for i in range(8)]
+    # short reads: about ten seeds (class 16) and a few tens (class 64)
+    reads += [target[i * 300:i * 300 + 40 + 2 * i] for i in range(8)]
+    reads += [target[i * 450:i * 450 + 120 + 20 * i] for i in range(6)]
     reads += [revcomp(target[2000:3500]), rand_seq(rng, 900)]
     rt = overlap_seed.build_seed_table(reads)
     tt = overlap_seed.build_seed_table([target])
@@ -316,21 +347,73 @@ def test_chain_stream_feed_batching_invariance():
     qlens = np.fromiter((len(r) for r in reads), np.int64, len(reads))
     hits, _ = reference.match_seeds(rt, tt, self_t, qlens, k=15, max_occ=64)
     starts, _, counts = chain._pair_runs(hits)
-    jobs = [(p, int(starts[p]), int(counts[p]))
-            for p in range(starts.size)]
-    assert len(jobs) >= 9
-    outs = []
-    for split in (len(jobs), 1, 3):
-        st = chain._ChainStream(k=15, tp=hits["tp"], qc=hits["qc"])
-        for i, (pid, s0, c) in enumerate(jobs):
-            st.add(pid, s0, c)
-            if (i + 1) % split == 0:
-                st.pump()
-        outs.append(st.finish())
-    for other in outs[1:]:
-        assert set(other) == set(outs[0])
-        for pid in outs[0]:
-            assert other[pid].tolist() == outs[0][pid].tolist()
+    classes = chain._seed_buckets(counts)
+    assert [chain._seed_bucket(int(c)) for c in counts] == classes.tolist()
+    assert len(set(classes.tolist())) >= 3 and starts.size >= 15
+    assert (classes == 16).sum() > 4
+    # the oracle: whole-bucket chunks at the program's arena
+    want, kept, _ = chain.chain_pairs(hits, k=15, min_seeds=1)
+    assert kept == starts.size
+    monkeypatch.setattr(chain, "CHAIN_ARENA_CELLS", cells)
+    pairs = np.arange(starts.size)
+    got = _stream_rows(hits, starts, counts,
+                       [pairs[i::deal] for i in range(deal)])
+    assert sorted(got) == pairs.tolist()
+    for pair in pairs:
+        assert got[pair] == [int(want[key][pair]) for key in (
+            "score", "n_seeds", "q_lo", "q_hi", "t_lo", "t_hi")], pair
+
+
+def test_chain_intake_is_linear(monkeypatch):
+    """6,000 pairs in three seed classes through the plan and the
+    stream ``iter_overlap_groups`` uses, launches recorded instead of
+    run: the intake visits each pair once (it re-sorted every pending
+    pair after every query group: 1,900 visits a pair in
+    ``bact2m-auto30x``), every chunk is full but one tail a class,
+    chunks leave in the order of their first pair, and no more than
+    the in-flight budget is ever unfetched."""
+    rng = np.random.default_rng(40)
+    n = 6000
+    counts = rng.choice([5, 16, 40, 64, 100, 256], n).astype(np.int64)
+    starts = np.cumsum(counts) - counts
+    monkeypatch.setattr(chain, "CHAIN_ARENA_CELLS", 1 << 14)
+    launched, peak = [], []
+
+    def record(self, S, idx):
+        B = chain._pair_batch(S)
+        launched.append((S, idx))
+        out = np.zeros((B, 6), np.int32)
+        out[:idx.size, 1] = self.counts[idx]
+        self.inflight.append((idx, out, B * S))
+        self.inflight_cells += B * S
+        self.launched += idx.size
+        peak.append(len(self.inflight))
+
+    monkeypatch.setattr(chain._ChainStream, "_launch", record)
+    before = metrics.counter("overlap.intake_visits")
+    st = chain._ChainStream(k=15, tp=None, qc=None, starts=starts,
+                            counts=counts)
+    fetched = list(st.run(chain._plan_chunks(counts)))
+    visits = metrics.counter("overlap.intake_visits") - before
+    assert 0 < visits <= 2 * n
+
+    assert [len(i) for _, i in launched] == [len(i) for i, _ in fetched]
+    seen = np.concatenate([idx for idx, _ in fetched])
+    assert sorted(seen.tolist()) == list(range(n))
+    for idx, rows in fetched:
+        assert rows[:, 1].tolist() == counts[idx].tolist()
+    firsts = [int(idx[0]) for _, idx in launched]
+    assert firsts == sorted(firsts)
+    assert max(peak) == chain.CHAIN_INFLIGHT + 1
+    for S in (16, 64, 256):
+        sizes = [idx.size for s, idx in launched if s == S]
+        members = np.concatenate([idx for s, idx in launched if s == S])
+        assert (np.diff(members) > 0).all()
+        assert set(chain._seed_buckets(counts[members]).tolist()) == {S}
+        cap = chain._pair_batch(S)
+        tails = [size for size in sizes if size != cap]
+        assert len(sizes) >= 2 and len(tails) <= 1, (S, sizes)
+        assert all(size < cap for size in tails)
 
 
 def test_ragged_stream_matches_barrier_rows():
@@ -351,6 +434,11 @@ def test_ragged_stream_matches_barrier_rows():
             legs[(ragged, dj)] = chain.find_overlaps(
                 reads, [target], self_t, k=15, w=5,
                 ragged=ragged, device_join=dj)
+    # the resident join's device copies feed the stream's arenas
+    # (_gather_pairs_kernel): the plan is over host arrays in both
+    legs["resident"] = chain.find_overlaps(
+        reads, [target], self_t, k=15, w=5, ragged=True,
+        device_join=True, resident=True)
     base = legs[(True, True)]
     assert base["q_ord"].size > 0
     for key_leg, rows in legs.items():
@@ -367,6 +455,42 @@ def test_ragged_stream_matches_barrier_rows():
     assert chain.paf_bytes({key: v[:0] for key, v in base.items()},
                            names, lens, [b"t0"],
                            np.array([len(target)], np.int64), k=15) == []
+
+
+def test_groups_leave_in_order_per_fetched_chunk(monkeypatch):
+    """An early query group holds a low-seed (class-16) pair whose
+    chunk is a tail: it launches first (chunks go by their first pair),
+    so the groups leave in blocks of whole groups, ascending, a block
+    per fetch — and the blocks concatenate to the barrier path's rows,
+    row for row."""
+    rng = np.random.default_rng(37)
+    target = rand_seq(rng, 12000)
+    reads = [target[100:150]]                      # group 0: ten seeds
+    reads += [target[i * 700:i * 700 + 1400] for i in range(14)]
+    reads += [revcomp(target[3000:4400]), target[9000:9050],
+              rand_seq(rng, 800)]
+    self_t = np.full(len(reads), -1, np.int64)
+    # a class-1024 chunk is 4 pairs, class 16 one tail of its own
+    monkeypatch.setattr(chain, "CHAIN_ARENA_CELLS", 1 << 12)
+    before = {name: metrics.counter(name) for name in (
+        "overlap.chain_pairs", "overlap.intake_visits")}
+    metrics.set_gauge("overlap.first_emit_pairs", 0)
+    parts = list(chain.iter_overlap_groups(
+        reads, [target], self_t, k=15, w=5, device_join=False))
+    pairs, visits = (metrics.counter(name) - was
+                     for name, was in before.items())
+    assert pairs >= 17 and visits == pairs
+    assert 0 < metrics.gauge("overlap.first_emit_pairs") < pairs
+    assert len(parts) >= 3
+    assert parts[0]["q_ord"][0] == 0
+    for a, b in zip(parts, parts[1:]):
+        assert a["q_ord"][-1] < b["q_ord"][0]
+    want = chain.find_overlaps(reads, [target], self_t, k=15, w=5,
+                               ragged=False, device_join=False)
+    assert want["q_ord"].size >= 17
+    for key in chain._ROW_KEYS:
+        assert np.array_equal(np.concatenate([p[key] for p in parts]),
+                              want[key]), key
 
 
 def test_warmed_repeat_run_zero_new_compiles():
