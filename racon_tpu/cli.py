@@ -17,7 +17,9 @@ import time
 
 from . import __version__, flags, obs
 from .core.polisher import PolisherType, create_polisher
+from .core.readset import ReadSet
 from .io import parsers
+from .obs import metrics
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -50,6 +52,18 @@ def build_parser() -> argparse.ArgumentParser:
                         "overlapper, exactly as the positional literal "
                         "'auto' does, and never opens the file named "
                         "there")
+    p.add_argument("--rounds", type=int, default=1, metavar="N",
+                   help="polish N times in this one process and print "
+                        "the last round's FASTA: round k+1 takes round "
+                        "k's contigs as its targets (in memory, named as "
+                        "a parser would name them from the FASTA) and "
+                        "the same reads, parsed and seeded once — byte "
+                        "for byte the output of N runs chained through "
+                        "files, -u or its absence applied in every "
+                        "round. N > 1 needs --overlaps auto (overlaps "
+                        "from a file describe one draft) and the "
+                        "one-shot path: it refuses -f, --chips and the "
+                        "shard runner's options, --submit and --serve")
     p.add_argument("-u", "--include-unpolished", action="store_true",
                    help="output unpolished target sequences")
     p.add_argument("-f", "--fragment-correction", action="store_true",
@@ -319,6 +333,79 @@ def _announce_device(args) -> None:
           f"pallas={'on' if pallas_ok() else 'off'}", file=sys.stderr)
 
 
+def _refuse_rounds(parser, args) -> None:
+    """``--rounds N > 1`` is a loop over the one-shot path with the
+    overlaps computed per round; every option that leaves that path is
+    refused by name (none silently ignored)."""
+    if args.rounds < 1:
+        parser.error(f"--rounds must be >= 1 (got {args.rounds})")
+    if args.rounds == 1:
+        return
+    sharded = [opt for opt, on in (
+        ("--chips", args.chips), ("--shards", args.shards),
+        ("--max-ram", args.max_ram), ("--resume", args.resume),
+        ("--shard-dir", args.shard_dir), ("--workers", args.workers > 1),
+        ("--exec-secondary", args.exec_secondary),
+        ("RACON_TPU_CHIPS", flags.get_int("RACON_TPU_CHIPS") > 0)) if on]
+    for bad, why in (
+            (args.serve and "--serve", "a resident server runs jobs, not "
+             "rounds: submit one-round jobs"),
+            (args.gateway and "--gateway", "a gateway runs no job itself"),
+            (args.submit and "--submit", "the service takes one-round "
+             "jobs"),
+            (args.fragment_correction and "-f", "fragment correction "
+             "has no draft to hand to a next round"),
+            (", ".join(sharded), "the shard runner polishes one round"),
+            (flags.get_bool("RACON_TPU_RESIDENT") and "RACON_TPU_RESIDENT",
+             "the resident dataflow keeps a round's reads on the device"),
+            (args.overlaps
+             and parsers.overlaps_mode(args.overlaps) != "auto"
+             and "overlaps from a file", "they describe one draft: a "
+             "later round needs --overlaps auto")):
+        if bad:
+            parser.error(f"--rounds {args.rounds} cannot be combined with "
+                         f"{bad} ({why})")
+
+
+def _sum_phases(phases: dict, timings: dict) -> None:
+    """A job's ``phases`` are its rounds' timings summed, as its
+    counters and span timers are."""
+    for key, value in timings.items():
+        phases[key] = phases.get(key, 0.0) + value
+
+
+def _compiles_so_far() -> int:
+    return metrics.counter((metrics.get_scope() or "")
+                           + "compile.backend_total")
+
+
+def _round_begin() -> tuple:
+    return time.perf_counter_ns(), _compiles_so_far()
+
+
+def _round_end(k: int, mark: tuple, polisher) -> None:
+    """Round ``k`` is over: its row of the report's ``rounds`` section
+    (gauges ``rounds.<column>.<k>``) and its span. A follow-up round
+    begins where its targets and reads were indexed; what lies between
+    the round before's last stitch and that instant is ``round.handoff``
+    (both spans back-dated, so neither is ever the open span a compile
+    or a submission is charged to)."""
+    t0_ns, compiles0 = mark
+    t1_ns = time.perf_counter_ns()
+    begun_ns = t0_ns
+    if polisher.handoff_end_ns is not None:
+        begun_ns = polisher.handoff_end_ns
+        obs.trace.record("round.handoff", t0_ns, begun_ns)
+        metrics.set_gauge(f"rounds.handoff_s.{k}", (begun_ns - t0_ns) * 1e-9)
+        metrics.inc("rounds.followups")
+    obs.trace.record("round", begun_ns, t1_ns)
+    metrics.inc("rounds.completed")
+    metrics.set_gauge(f"rounds.wall_s.{k}", (t1_ns - begun_ns) * 1e-9)
+    metrics.set_gauge(f"rounds.compiles.{k}",
+                      _compiles_so_far() - compiles0)
+    metrics.set_gauge(f"rounds.overlaps_kept.{k}", polisher.overlaps_kept)
+
+
 def _run_sharded(args, argv, trace_path, report_path, t_start, t0) -> int:
     """Route through the streaming shard runner (racon_tpu.exec)."""
     import subprocess
@@ -402,6 +489,7 @@ def main(argv=None) -> int:
         # one way to decide the mode: from here on every path (one-shot,
         # shard runner, planner, --submit) sees the positional sentinel
         args.overlaps = parsers.AUTO_OVERLAPS
+    _refuse_rounds(parser, args)
 
     trace_path, report_path = _obs_paths(args)
     obs.begin(trace_path, report_path)
@@ -510,52 +598,72 @@ def main(argv=None) -> int:
         return _run_sharded(args, list(argv), trace_path, report_path,
                             t_start, t0)
 
-    try:
-        polisher = create_polisher(
-            args.sequences, args.overlaps, args.target_sequences,
-            PolisherType.F if args.fragment_correction else PolisherType.C,
-            window_length=args.window_length,
-            quality_threshold=args.quality_threshold,
-            error_threshold=args.error_threshold,
-            trim=not args.no_trimming,
-            match=args.match, mismatch=args.mismatch, gap=args.gap,
-            num_threads=args.threads,
-            aligner_backend="tpu" if args.tpualigner_batches > 0 else "auto",
-            consensus_backend="tpu" if args.tpupoa_batches > 0 else "auto",
-            aligner_batches=max(1, args.tpualigner_batches),
-            consensus_batches=max(1, args.tpupoa_batches),
-            banded=args.tpu_banded_alignment,
-        )
-    except (ValueError, ImportError) as e:
-        print(f"[racon::createPolisher] error: {e}", file=sys.stderr)
-        _finish_obs(trace_path, report_path, "cli", list(argv), t_start,
-                    t0)
-        return 1
+    import contextlib
+    if args.profile:
+        import jax
+        trace = jax.profiler.trace(args.profile)
+    else:
+        trace = contextlib.nullcontext()
+    # the rounds of the job, one polisher each. One round is the
+    # one-shot path itself; N > 1 share the reads (parsed and seeded
+    # once: core/readset.py) and the engines, and round k's contigs
+    # are round k+1's targets
+    reads = ReadSet(args.sequences) if args.rounds > 1 else None
+    phases: dict = {}
+    polisher = polished = None
+    with trace:
+        for k in range(1, args.rounds + 1):
+            mark = _round_begin()
+            try:
+                polisher = create_polisher(
+                    args.sequences, args.overlaps, args.target_sequences,
+                    PolisherType.F if args.fragment_correction
+                    else PolisherType.C,
+                    window_length=args.window_length,
+                    quality_threshold=args.quality_threshold,
+                    error_threshold=args.error_threshold,
+                    trim=not args.no_trimming,
+                    match=args.match, mismatch=args.mismatch, gap=args.gap,
+                    num_threads=args.threads,
+                    aligner_backend="tpu" if args.tpualigner_batches > 0
+                    else "auto",
+                    consensus_backend="tpu" if args.tpupoa_batches > 0
+                    else "auto",
+                    aligner_batches=max(1, args.tpualigner_batches),
+                    consensus_batches=max(1, args.tpupoa_batches),
+                    banded=args.tpu_banded_alignment,
+                    **({} if reads is None else dict(
+                        reads=reads, targets=polished,
+                        final=k == args.rounds,
+                        aligner=polisher and polisher.aligner,
+                        consensus=polisher and polisher.consensus)))
+            except (ValueError, ImportError) as e:
+                print(f"[racon::createPolisher] error: {e}",
+                      file=sys.stderr)
+                _finish_obs(trace_path, report_path, "cli", list(argv),
+                            t_start, t0, phases=phases)
+                return 1
 
-    try:
-        import contextlib
-        if args.profile:
-            import jax
-            trace = jax.profiler.trace(args.profile)
-        else:
-            trace = contextlib.nullcontext()
-        with trace:
-            # fused surface: window build and consensus pipelined through
-            # a bounded queue (sequential fallback at -t 1) — output is
-            # byte-identical to initialize() + polish()
-            polished = polisher.run(not args.include_unpolished)
-    except (ValueError, RuntimeError, OSError) as e:
-        print(f"[racon::] error: {e}", file=sys.stderr)
-        _finish_obs(trace_path, report_path, "cli", list(argv), t_start,
-                    t0, phases=dict(polisher.timings))
-        return 1
+            try:
+                # fused surface: window build and consensus pipelined
+                # through a bounded queue (sequential fallback at -t 1)
+                # — output is byte-identical to initialize() + polish()
+                polished = polisher.run(not args.include_unpolished)
+            except (ValueError, RuntimeError, OSError) as e:
+                print(f"[racon::] error: {e}", file=sys.stderr)
+                _sum_phases(phases, polisher.timings)
+                _finish_obs(trace_path, report_path, "cli", list(argv),
+                            t_start, t0, phases=phases)
+                return 1
+            _sum_phases(phases, polisher.timings)
+            _round_end(k, mark, polisher)
 
     out = sys.stdout.buffer
     for seq in polished:
         out.write(b">" + seq.name + b"\n" + seq.data + b"\n")
     out.flush()
     _finish_obs(trace_path, report_path, "cli", list(argv), t_start, t0,
-                phases=dict(polisher.timings))
+                phases=phases)
     return 0
 
 

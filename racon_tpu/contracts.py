@@ -129,6 +129,11 @@ METRICS: FrozenSet[str] = frozenset((
     "polisher.window_type",
     # bounded init->polish queue
     "queue.consumer_wait_s", "queue.depth", "queue.producer_wait_s",
+    # the rounds of one job (cli.main's loop): rounds run, those of them
+    # after the first, read-side seed tables built / taken from the
+    # job's read set (ops/chain.py _read_table), read records parsed
+    "rounds.completed", "rounds.followups", "rounds.read_tables_built",
+    "rounds.read_tables_reused", "rounds.reads_parsed",
     # runtime sanitizer
     "sanitize.lock_order_cycles", "sanitize.contract_never_emitted",
     "sanitize.contract_defaulted_keys",
@@ -154,6 +159,9 @@ DYNAMIC_METRIC_PREFIXES: Tuple[str, ...] = (
     "idle.",             # idle.<span>: device-idle seconds by host span
     "retrace.",          # retrace.<phase> per-phase deltas
     "retrace_total.",    # retrace_total.<phase> run accumulators
+    # rounds.<column>.<k>: round k's row of the report's rounds section
+    "rounds.compiles.", "rounds.handoff_s.", "rounds.overlaps_kept.",
+    "rounds.wall_s.",
     "swallowed.",        # swallowed.<context>|<exc-type>
 )
 
@@ -178,6 +186,7 @@ RUN_PREFIXES: Tuple[str, ...] = (
     "retrace_total.", "swallowed.", "trace.", "parse.", "overlap.",
     "transmute", "bp.", "build.", "stitch", "exec.", "faults.",
     "lease.", "device.", "compile.", "dataflow.", "idle.", "polisher.",
+    "round.", "rounds.",
 )
 
 # ------------------------------------------------------------- span names
@@ -216,6 +225,11 @@ SPANS: FrozenSet[str] = frozenset((
     # leaves of poa.pack / poa.fetch
     "poa.put", "poa.lanes", "poa.wait", "poa.get", "poa.decode",
     "queue.get", "queue.put",
+    # one round of a job (from its targets and reads indexed to its
+    # last stitch; a one-shot job is one round) and, between two
+    # rounds, the hand-off: the contigs become targets, the name and
+    # ordinal tables are keyed anew. Both back-dated (trace.record)
+    "round", "round.handoff",
     "stitch", "transmute",
 ))
 
@@ -225,7 +239,11 @@ SPANS: FrozenSet[str] = frozenset((
 # what it was before the leaf existed
 TIMER_ONLY_SPANS: FrozenSet[str] = frozenset((
     "poa.lanes", "compile.retrieve",
-    "overlap.chain.plan", "overlap.emit", "overlap.rows"))
+    "overlap.chain.plan", "overlap.emit", "overlap.rows",
+    # `round` lies over every span of its round: read through, the
+    # idle under it goes where it went before rounds had a span, and
+    # the hand-off's stays `unattributed` (idle_other_s lists it)
+    "round", "round.handoff"))
 
 # ------------------------------------------------------------ fault sites
 
@@ -247,7 +265,7 @@ FAULT_CLASSES: Tuple[str, ...] = ("transient-io", "device-oom", "stall",
 
 # -------------------------------------------------------- report schema
 
-SCHEMA_VERSION = 13
+SCHEMA_VERSION = 14
 
 # the oldest version validate_report still accepts, as itself: a stored
 # v11 report is held to the v11 key sets
@@ -275,6 +293,7 @@ TOP_KEYS: Dict[str, int] = {
     "overlap": 9,
     "fleet": 11,
     "device_time": 12,
+    "rounds": 14,
 }
 
 SECTION_KEYS: Dict[str, Dict[str, int]] = {
@@ -329,6 +348,12 @@ SECTION_KEYS: Dict[str, Dict[str, int]] = {
         "idle_by": 12, "timeline": 12, "dropped": 12, "gaps": 12,
         "clock": 12, "devices": 12,
     },
+    "rounds": {
+        "count": 14, "first_wall_s": 14, "last_wall_s": 14,
+        "first_compiles": 14, "last_compiles": 14,
+        "first_overlaps_kept": 14, "last_overlaps_kept": 14,
+        "handoff_s": 14, "rows": 14,
+    },
 }
 
 # schema keys REMOVED at a version (key -> (section, removed_in)): a
@@ -373,6 +398,7 @@ SECTION_EMITTERS: Dict[str, Tuple[str, str]] = {
     "overlap": ("racon_tpu/obs/metrics.py", "overlap_summary"),
     "fleet": ("racon_tpu/obs/metrics.py", "fleet_summary"),
     "device_time": ("racon_tpu/obs/device_time.py", "account"),
+    "rounds": ("racon_tpu/obs/metrics.py", "rounds_summary"),
 }
 
 # report key -> the metric whose emission backs it ("section.key" ->
@@ -441,6 +467,7 @@ REPORT_BACKING: Dict[str, str] = {
     "fleet.hosts_dead": "fleet.hosts_dead",
     "fleet.cost_cache_hits": "fleet.cost_cache_hits",
     "fleet.cost_cache_misses": "fleet.cost_cache_misses",
+    "rounds.count": "rounds.completed",
 }
 
 # -------------------------------------------------------- state machines

@@ -54,6 +54,7 @@ from ..utils.logger import Logger
 from .backends import make_aligner, make_consensus
 from .layers import LayerStore, PreparedPool
 from .overlap import Overlap, decode_breaking_points_batch
+from .readset import ReadSet
 from .sequence import Sequence
 from .window import Window, WindowType
 
@@ -88,7 +89,10 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
                     banded: bool = False, *, aligner=None, consensus=None,
                     window_type=None, prefiltered_overlaps: bool = False,
                     evict_reads: bool = False,
-                    stall_escalation: bool = False) -> "Polisher":
+                    stall_escalation: bool = False,
+                    reads: Optional[ReadSet] = None,
+                    targets: Optional[List[Sequence]] = None,
+                    final: bool = True) -> "Polisher":
     """Factory with the reference's validation rules
     (``polisher.cpp:62-133``). ``aligner_batches``/``consensus_batches``
     are the accelerator batch counts (reference ``-c N`` /
@@ -110,7 +114,15 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
     the sanitizer queue watchdog's second-timeout escalation (a
     persistent stall fails the run with a ``stall``-class
     :class:`racon_tpu.faults.StallError` for the runner's degradation
-    ladder — standalone runs keep the passive dump-only watchdog)."""
+    ladder — standalone runs keep the passive dump-only watchdog).
+
+    ``reads`` / ``targets`` / ``final`` make the polisher one round of
+    a ``--rounds N`` job (``cli.main`` owns the loop): ``reads`` is
+    the job's :class:`~racon_tpu.core.readset.ReadSet` in place of a
+    parse of ``sequences_path``, ``targets`` the round before's
+    polished contigs in place of a parse of ``target_path``, and
+    ``final`` off marks a round with another one behind it, which must
+    leave every read whole and waits for no warm-up at ``stitch``."""
     if not isinstance(type_, PolisherType):
         raise ValueError("invalid polisher type")
     if window_length <= 0:
@@ -134,7 +146,8 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
                     window_type=window_type,
                     prefiltered_overlaps=prefiltered_overlaps,
                     evict_reads=evict_reads,
-                    stall_escalation=stall_escalation)
+                    stall_escalation=stall_escalation,
+                    reads=reads, targets=targets, final=final)
 
 
 # overlaps the streamed hand-off collects before it hands the align
@@ -160,7 +173,8 @@ class Polisher:
                  aligner_batches=1, consensus_batches=1, banded=False,
                  aligner=None, consensus=None, window_type=None,
                  prefiltered_overlaps=False, evict_reads=False,
-                 stall_escalation=False):
+                 stall_escalation=False, reads=None, targets=None,
+                 final=True):
         self.sequences_path = sequences_path
         self.overlaps_path = overlaps_path
         self.target_path = target_path
@@ -182,6 +196,15 @@ class Polisher:
         self.prefiltered_overlaps = prefiltered_overlaps
         self.evict_reads = evict_reads
         self.stall_escalation = stall_escalation
+        # one round of a --rounds job (see create_polisher)
+        self._reads: Optional[ReadSet] = reads
+        self._targets: Optional[List[Sequence]] = targets
+        self._final = final
+        # when a follow-up round had its targets and its reads indexed
+        # (perf_counter_ns; the loop's ``round.handoff`` ends there)
+        self.handoff_end_ns: Optional[int] = None
+        # overlaps left after the filter (the report's rounds rows)
+        self.overlaps_kept = 0
         self.logger = Logger()
 
         self.sequences: List[Sequence] = []
@@ -233,10 +256,19 @@ class Polisher:
         log.log()
         t_parse = time.perf_counter()
 
-        with obs.span("parse.targets"):
-            tparse = parsers.sequence_parser_for(self.target_path)
-            self.sequences = [Sequence(r.name, r.data, r.quality)
-                              for r in tparse(self.target_path)]
+        follow_up = self._targets is not None
+        if not follow_up:
+            with obs.span("parse.targets"):
+                tparse = parsers.sequence_parser_for(self.target_path)
+                self.sequences = [Sequence(r.name, r.data, r.quality)
+                                  for r in tparse(self.target_path)]
+        else:
+            # the round before's contigs, as a parse of its FASTA would
+            # hand them over: a header's name ends at the first blank,
+            # so the LN / RC / XC tags go
+            self.sequences = [Sequence(s.name.split(None, 1)[0], s.data)
+                              for s in self._targets]
+            self._targets = None
         self.targets_size = len(self.sequences)
         if self.targets_size == 0:
             raise ValueError("empty target sequences set")
@@ -254,34 +286,20 @@ class Polisher:
         log.log("[racon_tpu::Polisher::initialize] loaded target sequences")
         log.log()
 
-        with obs.span("parse.reads"):
-            sparse = parsers.sequence_parser_for(self.sequences_path)
-            raw_index = 0
-            total_len = 0
-            for rec in sparse(self.sequences_path):
-                seq = Sequence(rec.name, rec.data, rec.quality)
-                total_len += len(seq.data)
-                tkey = seq.name + b"t"
-                tid = name_to_id.get(tkey)
-                if tid is not None:
-                    existing = self.sequences[tid]
-                    if (len(seq.data) != len(existing.data) or
-                            len(seq.quality or b"")
-                            != len(existing.quality or b"")):
-                        raise ValueError(
-                            f"duplicate sequence {seq.name!r} with "
-                            f"unequal data")
-                    name_to_id[seq.name + b"q"] = tid
-                    id_to_id[raw_index << 1 | 0] = tid
-                else:
-                    self.sequences.append(seq)
-                    pos = len(self.sequences) - 1
-                    name_to_id[seq.name + b"q"] = pos
-                    id_to_id[raw_index << 1 | 0] = pos
-                    has_name.append(False)
-                    has_data.append(False)
-                    has_reverse.append(False)
-                raw_index += 1
+        if self._reads is None:
+            with obs.span("parse.reads"):
+                sparse = parsers.sequence_parser_for(self.sequences_path)
+                raw_index, total_len = self._index_reads(
+                    (Sequence(rec.name, rec.data, rec.quality)
+                     for rec in sparse(self.sequences_path)),
+                    name_to_id, id_to_id, has_name, has_data, has_reverse)
+            metrics.inc("rounds.reads_parsed", raw_index)
+        else:
+            raw_index, total_len = self._index_reads(
+                self._reads.sequences(), name_to_id, id_to_id, has_name,
+                has_data, has_reverse)
+        if follow_up:
+            self.handoff_end_ns = time.perf_counter_ns()
 
         if raw_index == 0:
             raise ValueError("empty sequences set")
@@ -380,7 +398,54 @@ class Polisher:
         # meaningful only for run(): layer-assembly wall hidden under the
         # consensus engine (the split surface overlaps nothing)
         self.timings.setdefault("pipeline_overlap_saved_s", 0.0)
+        self.overlaps_kept = len(overlaps)
         return overlaps
+
+    def _index_reads(self, reads, name_to_id: Dict[bytes, int],
+                     id_to_id: Dict[int, int], has_name, has_data,
+                     has_reverse) -> tuple:
+        """Append ``reads`` (``Sequence`` objects in file order) to
+        ``self.sequences`` behind the targets and key them by name and
+        by ordinal (a read named like a target IS that target:
+        ``polisher.cpp:227-263``). Returns ``(reads, their bases)``.
+        A round with another one behind it marks every read as needed
+        whole, so :meth:`_transmute_all` frees nothing of it."""
+        keep = not self._final
+        raw_index = 0
+        total_len = 0
+        for seq in reads:
+            total_len += len(seq.data)
+            tid = name_to_id.get(seq.name + b"t")
+            if tid is not None:
+                existing = self.sequences[tid]
+                if (len(seq.data) != len(existing.data) or
+                        len(seq.quality or b"")
+                        != len(existing.quality or b"")):
+                    raise ValueError(
+                        f"duplicate sequence {seq.name!r} with "
+                        f"unequal data")
+                name_to_id[seq.name + b"q"] = tid
+                id_to_id[raw_index << 1 | 0] = tid
+            else:
+                self.sequences.append(seq)
+                pos = len(self.sequences) - 1
+                name_to_id[seq.name + b"q"] = pos
+                id_to_id[raw_index << 1 | 0] = pos
+                has_name.append(keep)
+                has_data.append(keep)
+                has_reverse.append(False)
+            raw_index += 1
+        return raw_index, total_len
+
+    def _held_read_tables(self, read_self_t) -> Optional[dict]:
+        """Where the job's read-side seed table is kept, for the
+        overlapper to build into and take from — only while the reads
+        it is handed ARE the read set's (a read named like a target is
+        mapped as that target's bytes, which change with every
+        round)."""
+        if self._reads is None or (read_self_t >= 0).any():
+            return None
+        return self._reads.seed_tables
 
     def _generate_overlaps(self, raw_index: int,
                            name_to_id: Dict[bytes, int],
@@ -407,8 +472,9 @@ class Polisher:
         overlap_seed.warmup_async(est_len, len(read_seqs))
         chain_ops.warmup_async(_est_chain_seeds(est_len), raw_index, k=k)
         # graftlint: disable=jit-shape-hazard (k is a run-constant flag value clipped to 4..16 — one compile per run)
-        rows = chain_ops.find_overlaps(read_seqs, target_seqs,
-                                       read_self_t, k=k)
+        rows = chain_ops.find_overlaps(
+            read_seqs, target_seqs, read_self_t, k=k,
+            read_tables=self._held_read_tables(read_self_t))
         overlaps: List[Overlap] = []
         for i in range(rows["q_ord"].size):
             q = int(rows["q_ord"][i])
@@ -588,7 +654,8 @@ class Polisher:
                 pass  # span parity with the barrier path (work is inline)
             # graftlint: disable=jit-shape-hazard (k is a run-constant flag value clipped to 4..16 — one compile per run)
             for rows in chain_ops.iter_overlap_groups(
-                    read_seqs, target_seqs, read_self_t, k=k):
+                    read_seqs, target_seqs, read_self_t, k=k,
+                    read_tables=self._held_read_tables(read_self_t)):
                 cols = [rows[key].tolist() for key in (
                     "q_ord", "t_idx", "strand", "q_begin", "q_end",
                     "t_begin", "t_end")]
@@ -1629,8 +1696,11 @@ class Polisher:
                 num_polished = 0
                 polished_data = []
 
+        # the job that started a warm-up ends only when it has: the
+        # wait is the last round's (a round with another one behind it
+        # hands its engines on, warm-up and all)
         drain = getattr(self.consensus, "drain_warmup", None)
-        if drain is not None:
+        if drain is not None and self._final:
             drain()
         log.log("[racon_tpu::Polisher::polish] generated consensus")
         log.total("[racon_tpu::Polisher::] total =")

@@ -3,7 +3,9 @@
 RUN_REPORT DEVICE_TRACE`` — the report's occupancy ledger laid on a
 device trace (:mod:`racon_tpu.obs.gaps`); ``python -m racon_tpu.obs
 compiles RUN_REPORT`` — the report's compiled programs, one line each,
-and the set-up totals (:mod:`racon_tpu.obs.compilewatch`)."""
+and the set-up totals (:mod:`racon_tpu.obs.compilewatch`); ``python -m
+racon_tpu.obs rounds RUN_REPORT`` — the rounds of a ``--rounds N`` job,
+one line each (the report's ``rounds`` section)."""
 
 import sys
 

@@ -382,6 +382,40 @@ def overlap_summary(scope: str = "") -> Dict[str, Number]:
         }
 
 
+def rounds_summary(scope: str = "") -> dict:
+    """The run report's ``rounds`` section (schema v14): the rounds of
+    the job as ``cli.main``'s loop counted them — a one-shot job is one
+    round; all zeros where no loop ran (a shard, a service job). The
+    first and the last round's wall (from targets and reads indexed to
+    the last stitch), backend compiles and kept overlaps as plain keys
+    a metric's reader can reach, ``handoff_s`` summed over the
+    hand-offs between rounds, and every round's row under ``rows``."""
+    with _lock:
+        count = int(_counters.get(scope + "rounds.completed", 0))
+
+        def column(name: str, k: int):
+            return _gauges.get(f"{scope}rounds.{name}.{k}", 0)
+
+        rows = [{"round": k, "wall_s": round(column("wall_s", k), 6),
+                 "handoff_s": round(column("handoff_s", k), 6),
+                 "compiles": int(column("compiles", k)),
+                 "overlaps_kept": int(column("overlaps_kept", k))}
+                for k in range(1, count + 1)]
+    first = rows[0] if rows else {}
+    last = rows[-1] if rows else {}
+    return {
+        "count": count,
+        "first_wall_s": first.get("wall_s", 0.0),
+        "last_wall_s": last.get("wall_s", 0.0),
+        "first_compiles": first.get("compiles", 0),
+        "last_compiles": last.get("compiles", 0),
+        "first_overlaps_kept": first.get("overlaps_kept", 0),
+        "last_overlaps_kept": last.get("overlaps_kept", 0),
+        "handoff_s": round(sum(r["handoff_s"] for r in rows), 6),
+        "rows": rows,
+    }
+
+
 def recovery_summary() -> Dict[str, Number]:
     """The crash-safe-serving counters the run report's ``recovery``
     section (schema v5) embeds: journal replay/append/compaction

@@ -113,6 +113,14 @@ from .. import contracts
 # "miss_s", "unrowed_s" (stage seconds that reached no backend).
 # "by_function" and "events" left (contracts.REMOVED_KEYS); a stored
 # v11 / v12 report keeps them and validates as what it is.
+# v14 (PR 41): the "rounds" section became required — the rounds of a
+# ``--rounds N`` job as cli.main's loop counted them ("count"; 1 for a
+# one-shot job, 0 where no loop ran: a shard, a service job), the first
+# and the last round's wall, backend compiles and kept overlaps as
+# plain keys, "handoff_s" over the hand-offs between rounds, and one
+# row per round under "rows".  The job's counters and span timers sum
+# over its rounds, as before; spans ``round`` / ``round.handoff`` and
+# counters ``rounds.*`` say which round cost what.
 # the schema's key sets (per section, per version) live in
 # racon_tpu/contracts.py — ONE registry shared with the schema-coherence
 # lint rule, so a schema bump is a contracts.py edit the gate enforces
@@ -148,6 +156,7 @@ _TOP = {
     "overlap": (dict, True),            # first-party overlapper (v9/v10)
     "fleet": (dict, True),              # fleet gateway counters (v11)
     "device_time": (dict, True),        # device-occupancy ledger (v12)
+    "rounds": (dict, True),             # the job's rounds (v14)
     "devices": (dict, True),            # per-chip rows ({} single-chip)
     "peak_rss_bytes": (int, True),
     "metrics": (dict, True),            # full registry snapshot
@@ -172,6 +181,10 @@ _PROGRAM_NUM_KEYS = ("t0_ns", "t1_ns", "trace_s", "lower_s", "backend_s",
 _PROGRAM_CACHE = ("hit", "miss", "none")
 _DATAFLOW_KEYS = tuple(sorted(_SCHEMA_KEYS["dataflow"]))
 _FLEET_KEYS = tuple(sorted(_SCHEMA_KEYS["fleet"]))
+# "rows" (a list of rows) validates structurally below
+_ROUNDS_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["rounds"] - {"rows"}))
+_ROUND_ROW_KEYS = ("round", "wall_s", "handoff_s", "compiles",
+                   "overlaps_kept")
 _DEVICE_TIME_NUM_KEYS = ("window_s", "busy_s", "idle_s", "head_idle_s",
                          "tail_idle_s", "programs", "dropped")
 # "mode" is the one string key of the overlap section
@@ -297,6 +310,9 @@ def build_report(kind: str, *, argv: Optional[list] = None,
         # BEFORE the metrics snapshot below: it writes the idle.<span>
         # timers the snapshot carries
         "device_time": device_time.summary(scope, float(wall_s)),
+        # the job's rounds (schema v14): count, the first and the last
+        # round's wall / compiles / kept overlaps, a row per round
+        "rounds": metrics.rounds_summary(scope),
         "peak_rss_bytes": metrics.peak_rss_bytes(),
         "metrics": metrics.snapshot(scope or None),
     }
@@ -364,6 +380,23 @@ def _check_device_time(errors: List[str], dt: dict) -> None:
 
 def _is_num(v) -> bool:
     return isinstance(v, _NUM) and not isinstance(v, bool)
+
+
+def _check_rounds(errors: List[str], rounds: dict) -> None:
+    for key in _ROUNDS_NUM_KEYS:
+        if not _is_num(rounds.get(key)):
+            errors.append(f"rounds[{key!r}] missing or non-numeric")
+    for key in sorted(set(rounds) - _SCHEMA_KEYS["rounds"]):
+        errors.append(f"rounds unknown key {key!r}")
+    rows = rounds.get("rows")
+    if not isinstance(rows, list) or not all(
+            isinstance(r, dict) and set(r) == set(_ROUND_ROW_KEYS)
+            and all(_is_num(v) for v in r.values()) for r in rows):
+        errors.append(f"rounds['rows'] is not a list of "
+                      f"{{{', '.join(_ROUND_ROW_KEYS)}}} rows")
+    elif _is_num(rounds.get("count")) and len(rows) != rounds["count"]:
+        errors.append(f"rounds['rows'] holds {len(rows)} rows, "
+                      f"rounds['count'] is {rounds['count']}")
 
 
 def _check_compiles(errors: List[str], comp: dict, version: int) -> None:
@@ -466,6 +499,8 @@ def validate_report(rep) -> List[str]:
     _check_compiles(errors, rep["compiles"], version)
     if "device_time" in top:
         _check_device_time(errors, rep["device_time"])
+    if "rounds" in top:
+        _check_rounds(errors, rep["rounds"])
     for kind in ("counters", "gauges", "timers"):
         store = rep["metrics"].get(kind)
         if not isinstance(store, dict):
@@ -516,6 +551,16 @@ def write_report(path: str, rep: dict) -> None:
     atomic_write_bytes(path, json.dumps(rep, indent=1).encode())
 
 
+def rounds_table(rounds: dict) -> str:
+    """The ``rounds`` section for people: one line per round."""
+    lines = [f"{'round':>5} {'handoff_s':>10} {'wall_s':>9} "
+             f"{'compiles':>8} {'overlaps_kept':>13}"]
+    lines += [f"{r['round']:>5} {r['handoff_s']:>10.3f} {r['wall_s']:>9.3f} "
+              f"{r['compiles']:>8} {r['overlaps_kept']:>13}"
+              for r in rounds.get("rows", [])]
+    return "\n".join(lines)
+
+
 def _main(argv) -> int:
     if len(argv) == 2 and argv[0] == "--check":
         try:
@@ -538,9 +583,14 @@ def _main(argv) -> int:
         return gaps.main(argv[1:])
     if argv and argv[0] == "compiles":
         return compilewatch.main(argv[1:])
+    if len(argv) == 2 and argv[0] == "rounds":
+        with open(argv[1], "rb") as f:
+            print(rounds_table(json.loads(f.read()).get("rounds") or {}))
+        return 0
     print("usage: python -m racon_tpu.obs --check FILE\n"
           "       python -m racon_tpu.obs gaps RUN_REPORT DEVICE_TRACE\n"
-          "       python -m racon_tpu.obs compiles RUN_REPORT",
+          "       python -m racon_tpu.obs compiles RUN_REPORT\n"
+          "       python -m racon_tpu.obs rounds RUN_REPORT",
           file=sys.stderr)
     return 2
 

@@ -759,17 +759,39 @@ def _resolve_params(k, w, max_occ, min_seeds, resident, device_join,
     return k, w, max_occ, min_seeds, resident, device_join, ragged
 
 
+def _read_table(read_seqs, held, *, k, w, resident):
+    """The read-side seed table: built here, or taken from ``held`` —
+    a dict its caller keeps across calls on the SAME reads (the rounds
+    of one ``--rounds N`` job: the draft changes between them, the
+    reads do not), keyed by what the table depends on beside the
+    bytes. A table taken is counted as a cached target table is."""
+    key = (k, w, resident)
+    if held is not None and key in held:
+        rt = held[key]
+        metrics.inc("rounds.read_tables_reused")
+        metrics.inc("overlap.minimizers", int(rt[0].size))
+        metrics.inc("dataflow.bytes_avoided", int(rt[0].size) * 10)
+        return rt
+    rt = overlap_seed.build_seed_table(read_seqs, k=k, w=w,
+                                       resident=resident)
+    metrics.inc("rounds.read_tables_built")
+    if held is not None:
+        held[key] = rt
+    return rt
+
+
 def _seed_and_join(read_seqs, target_seqs, read_self_t, qlens, *,
                    k, w, max_occ, resident, device_join, cache,
-                   resident_hits):
-    """Seed both pools (target table through the fingerprint cache)
-    and run the join front end. ``resident_hits`` keeps the matched
-    seed coordinates on device (only meaningful on the device-join
-    path feeding the chain stream)."""
+                   resident_hits, read_tables=None):
+    """Seed both pools (target table through the fingerprint cache,
+    read table through ``read_tables`` where the caller holds one:
+    :func:`_read_table`) and run the join front end. ``resident_hits``
+    keeps the matched seed coordinates on device (only meaningful on
+    the device-join path feeding the chain stream)."""
     with obs.span("overlap.seed", reads=len(read_seqs),
                   targets=len(target_seqs)):
-        rt = overlap_seed.build_seed_table(read_seqs, k=k, w=w,
-                                           resident=resident)
+        rt = _read_table(read_seqs, read_tables, k=k, w=w,
+                         resident=resident)
         tt = overlap_seed.build_seed_table(target_seqs, k=k, w=w,
                                            resident=resident,
                                            cache=cache)
@@ -808,7 +830,8 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
                         min_seeds: Optional[int] = None,
                         resident: Optional[bool] = None,
                         device_join: Optional[bool] = None,
-                        cache: bool = True
+                        cache: bool = True,
+                        read_tables: Optional[dict] = None
                         ) -> Iterator[Dict[str, np.ndarray]]:
     """Streaming overlapper driver: yield canonical overlap rows, whole
     query groups in ascending query ordinal, a block per fetched chain
@@ -827,7 +850,10 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
     leave in the last blocks (gauge ``overlap.first_emit_pairs``: pairs
     launched when the first block left). Concatenating every yield
     reproduces :func:`find_overlaps` byte-for-byte (the global sort's
-    primary key is the query ordinal)."""
+    primary key is the query ordinal). ``read_tables``: a dict the
+    caller keeps over several calls on the same ``read_seqs``; the
+    read-side seed table is built into it once and taken from it
+    after (:func:`_read_table`)."""
     k, w, max_occ, min_seeds, resident, device_join, _ = _resolve_params(
         k, w, max_occ, min_seeds, resident, device_join, None)
     qlens = np.fromiter((len(s) for s in read_seqs), np.int64,
@@ -836,7 +862,7 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
         read_seqs, target_seqs, read_self_t, qlens,
         k=k, w=w, max_occ=max_occ, resident=resident,
         device_join=device_join, cache=cache,
-        resident_hits=resident and device_join)
+        resident_hits=resident and device_join, read_tables=read_tables)
     starts, _, counts = _pair_runs(hits)
     metrics.inc("overlap.candidate_pairs", int(starts.size))
     if starts.size == 0:
@@ -909,7 +935,8 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
                   resident: Optional[bool] = None,
                   device_join: Optional[bool] = None,
                   ragged: Optional[bool] = None,
-                  cache: bool = True
+                  cache: bool = True,
+                  read_tables: Optional[dict] = None
                   ) -> Dict[str, np.ndarray]:
     """The full first-party overlapper: seed both pools, match, chain,
     and emit forward-strand ``Overlap``-shaped rows.
@@ -934,7 +961,8 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
         parts = list(iter_overlap_groups(
             read_seqs, target_seqs, read_self_t, k=k, w=w,
             max_occ=max_occ, min_seeds=min_seeds, resident=resident,
-            device_join=device_join, cache=cache))
+            device_join=device_join, cache=cache,
+            read_tables=read_tables))
         if not parts:
             return _empty_rows()
         return {key: np.concatenate([p[key] for p in parts])
@@ -945,7 +973,8 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
     hits = _seed_and_join(
         read_seqs, target_seqs, read_self_t, qlens,
         k=k, w=w, max_occ=max_occ, resident=resident,
-        device_join=device_join, cache=cache, resident_hits=False)
+        device_join=device_join, cache=cache, resident_hits=False,
+        read_tables=read_tables)
     with obs.span("overlap.chain"):
         chains, kept, dropped = chain_pairs(hits, k=k,
                                             min_seeds=min_seeds)

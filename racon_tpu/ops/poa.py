@@ -139,6 +139,14 @@ STAGE_A_ROUNDS = 2
 # Stage-B repack pays a host pack + upload; it wins only when it shrinks
 # the batch a lot. Above this survivor fraction, continue in place.
 STAGE_B_MAX_SURVIVOR_FRAC = 0.5
+# ... and it never shrinks a group by more than this on either axis
+# (pairs, windows; from the largest stage-A group): under an eighth the
+# remaining rounds cost a few percent of the group's, while the
+# survivors of a well-polished draft are a small random count that
+# crosses a power of two from one input to the next — and every distinct
+# ``(B, nWp)`` is a Mosaic program: 12-20 s to compile and, compiled
+# in-line at the job's heap peak, 1.3 GB of host memory.
+STAGE_B_MAX_SHRINK = 8
 # Vote channels: A C G T N DEL (stride 8 for cheap addressing).
 CH = 8
 A, C, G, T, N_CODE, DEL = 0, 1, 2, 3, 4, 5
@@ -1871,11 +1879,11 @@ class TpuPoaConsensus(PallasDispatchMixin):
 
     # -------------------------------------------------------------- device
 
-    def _launch_group(self, live, Lq, Lb, overrides=None):
+    def _launch_group(self, live, Lq, Lb, overrides=None, floor=(1, 1)):
         """Span-wrapped :meth:`_launch_group_impl` — the host-pack half
         of the consensus dispatch pipeline."""
         with self._pinned(), obs.span("poa.pack", windows=len(live)):
-            return self._launch_group_impl(live, Lq, Lb, overrides)
+            return self._launch_group_impl(live, Lq, Lb, overrides, floor)
 
     def _rounds(self, launch, Lq, Lb, steps, Lq2=0) -> None:
         """Span-wrapped :meth:`_rounds_impl` — the async kernel dispatch
@@ -2058,11 +2066,13 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 0).astype(np.uint16)
         return qpw, dev_spec
 
-    def _launch_group_impl(self, live, Lq, Lb, overrides=None):
+    def _launch_group_impl(self, live, Lq, Lb, overrides=None,
+                           floor=(1, 1)):
         """Pack one window group (per-mesh-shard when a mesh is set — pairs
         of a window never cross shards, so votes stay shard-local) into the
         device-resident refinement state. ``overrides`` carries fetched
-        stage-A state for a stage-B repack (see :meth:`_pack_shard`)."""
+        stage-A state for a stage-B repack (see :meth:`_pack_shard`), and
+        ``floor`` that repack's smallest ``(B, nWp)``."""
         from ..parallel import mesh_size, partition_balanced
         # graftlint: disable=warmup-coverage (mesh size is fixed at engine construction; warm-up runs on the same engine so its shapes see the same nd)
         nd = mesh_size(self.mesh)
@@ -2076,8 +2086,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
         max_wins = max(len(sh) for sh in shards)
         # pow2 batch/window-count padding through the same helper the
         # warm-up derivation uses (warmup-coverage keeps them shared)
-        B = self._pow2_at_least(max_pairs)
-        nWp = self._pow2_at_least(max_wins + 1)
+        B = max(self._pow2_at_least(max_pairs), floor[0])
+        nWp = max(self._pow2_at_least(max_wins + 1), floor[1])
 
         # device-lane ingest gate: one shard, no mesh, no per-chip pin
         # (a pinned engine would gather across devices from the
@@ -2248,16 +2258,20 @@ class TpuPoaConsensus(PallasDispatchMixin):
                           Lq2, band) -> None:
         """Remaining rounds for the stage-A stragglers, re-packed small.
 
-        ``survivors`` is ``[(result_index, work, fetched_state), ...]``
+        ``survivors`` is ``[(result_index, work, fetched_state,
+        (B, nWp) of its stage-A group), ...]``
         collected by :meth:`_finish_group` across ALL stage-A groups, so
         the handful of unconverged windows of a big run coalesce into one
         (or few) groups — B and n_windows shrink by the convergence
-        factor (~30x on real data) while rounds 4+ compute the identical
+        factor, at most ``STAGE_B_MAX_SHRINK`` times from the largest
+        stage-A group's, while rounds 4+ compute the identical
         per-window fixed points (windows are independent; the vote
         accumulation is exact integer arithmetic at any batch size)."""
         rb = self.rounds - STAGE_A_ROUNDS
-        live = [(i, w) for i, w, _ in survivors]
-        overrides = {i: st for i, _, st in survivors}
+        live = [(i, w) for i, w, _, _ in survivors]
+        overrides = {i: st for i, _, st, _ in survivors}
+        floor = tuple(max(1, max(shape[a] for *_, shape in survivors)
+                          // STAGE_B_MAX_SHRINK) for a in (0, 1))
         self.stats["stage_b_windows"] += len(live)
         total_pairs = sum(w.n_layers for _, w in live)
         n_groups = max(1, -(-total_pairs // self.group_pairs_cap))
@@ -2270,7 +2284,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
             groups = [[live[i] for i in b] for b in bins if b]
         inflight = []
         for g in groups:
-            la = self._launch_group(g, Lq, Lb, overrides=overrides)
+            la = self._launch_group(g, Lq, Lb, overrides=overrides,
+                                    floor=floor)
             la["geom"] = (Lq, Lb, steps, Lq2)
             la["band"] = band
             la["rounds"] = rb
@@ -2413,7 +2428,8 @@ class TpuPoaConsensus(PallasDispatchMixin):
                     collect.append((i, w, (
                         bcodes[row].copy(), int(blen[row]),
                         covs[row].copy(), bool(ever[row]),
-                        bg_h[p0:p0 + kw].copy(), ed_h[p0:p0 + kw].copy())))
+                        bg_h[p0:p0 + kw].copy(), ed_h[p0:p0 + kw].copy()),
+                        (B, nWp)))
                     continue
                 if not ever[row]:
                     results[i] = None  # no successful round -> CPU fallback

@@ -136,7 +136,11 @@ def test_probe_shapes_compile(chip):
 # from the lengths in its PAF); each at the engine's OWN pair cap for
 # that geometry, i.e. the largest chunk it would ever dispatch
 ALIGN_CHUNKS = [(16384, 4096, 16384), (16384, 3072, 14336),
-                (8192, 2048, 8192)]
+                (8192, 2048, 8192),
+                # round 2 of a --rounds job (bact1m-auto30x-r2: the same
+                # reads on a polished draft class differently along the
+                # ladder; PR 41's warm-up job compiled these two anew)
+                (16384, 3072, 16384), (8192, 768, 4096)]
 
 
 @pytest.mark.parametrize("max_len,band,steps", ALIGN_CHUNKS)
@@ -319,6 +323,52 @@ def test_overlapper_join_programs_fit_the_chip(chip):
         chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
         chip((), i32), chip((Q2,), i32), chip((Q2,), i32), E=E, k=15))
     assert total < HBM_BYTES // 2
+
+
+def test_second_round_join_program_fits_the_chip(chip):
+    """What round 2 of ``bact1m-auto30x-r2`` adds beside the aligner's
+    rungs (PR 41: the warm-up job's round 2 compiled 21 programs of its
+    own): against a polished draft five times the 15-mers of a read
+    survive, so the join's hits pad to 2^21 where round 1's pad to 2^20
+    (1.0 Mbp at 30x: 10 M read minimizers in 2^24, the draft's 0.33 M
+    in 2^19) and the pairs reach the 1,024-seed chain class
+    (``test_overlapper_chain_program_compiles``)."""
+    from racon_tpu.ops import chain
+    R2, T2 = chain._table_pad(10_000_000), chain._table_pad(334_000)
+    E, Q2 = chain._hits_pad(1_500_000), chain._table_pad(4285)
+    assert (R2, T2, E, Q2) == (1 << 24, 1 << 19, 1 << 21, 8192)
+    i32 = jnp.int32
+    _, _, total = _compile(chain._join_expand_kernel.lower(
+        chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
+        chip((T2,), i32), chip((T2,), i32), chip((T2,), i32),
+        chip((T2,), i32),
+        chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
+        chip((), i32), chip((Q2,), i32), chip((Q2,), i32), E=E, k=15))
+    assert total < HBM_BYTES // 2
+
+
+@pytest.mark.parametrize("pairs, windows", [(13_000, 470), (2_000, 65)])
+def test_repack_programs_of_the_rounds_fit_the_chip(chip, pairs, windows):
+    """The consensus engine's second stage in ``bact1m-auto30x-r2``, whose
+    stage-A groups are 32,768 rows over 2,048 windows at the largest.
+    Round 1 leaves about 470 windows of 13,000 pairs: 16,384 over 512.
+    Round 2, on a polished draft, leaves 60-70 of about 2,000, under the
+    repack's floor of an eighth (``poa.STAGE_B_MAX_SHRINK``): 4,096 over
+    256 (their own powers of two, 2,048 over 64 or 128, moved with the
+    seed, and a run that compiled one read 1.3 GB more host memory)."""
+    eng = _consensus_engine()
+    B = max(eng._pow2_at_least(pairs), 32768 // poa.STAGE_B_MAX_SHRINK)
+    nWp = max(eng._pow2_at_least(windows + 1),
+              2048 // poa.STAGE_B_MAX_SHRINK)
+    assert (B, nWp) == ((16384, 512) if pairs > 4096 else (4096, 256))
+    Lq, Lb, band, steps, Lq2, rounds = 1024, 768, 512, 1152, 640, 4
+    compiled, _, total = _compile(poa._refine_loop_packed.lower(
+        *_refine_args(chip, Lq, Lb, B, nWp), rounds=rounds,
+        n_windows=nWp, max_len=Lq, band=band, Lb=Lb, K=poa.K_INS,
+        steps=steps, use_pallas=True, use_swar=True, Lq2=Lq2,
+        scores=eng.scores, matmul_votes=eng.use_matmul_votes))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert total < HBM_BYTES // 8, f"{total / GIB:.2f} GiB"
 
 
 @pytest.mark.parametrize("S", [16, 64, 256, 1024])

@@ -458,7 +458,7 @@ def test_a_row_counts_the_exec_submissions_of_its_program_and_geometry(
 
 def test_v12_validates_and_requires_device_time():
     rep = report.build_report("cli", wall_s=0.5)
-    assert rep["schema_version"] == 13      # the section is v12's
+    assert rep["schema_version"] == 14      # the section is v12's
     assert report.validate_report(rep) == []
     broken = {k: v for k, v in rep.items() if k != "device_time"}
     assert any("device_time" in e for e in report.validate_report(broken))

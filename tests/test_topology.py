@@ -455,3 +455,81 @@ def test_warmup_shapes_cover_tail_bucket():
     assert b0 == TpuPoaConsensus._pow2_at_least(cap)
     assert r0 == STAGE_A_ROUNDS and r1 == eng.rounds
     assert b1 < b0
+
+
+# ------------------------------------------------- stage-B repack geometry
+
+def _mutated(rng, seq, sub=.06, ins=.03, dele=.03):
+    bases, out = b"ACGT", bytearray()
+    for c in seq:
+        r = rng.random()
+        if r < dele:
+            continue
+        if r < dele + ins:
+            out.append(bases[int(rng.integers(0, 4))])
+        out.append(bases[int(rng.integers(0, 4))]
+                   if rng.random() < sub else c)
+    return bytes(out)
+
+
+def _two_stage_run(monkeypatch, n_noisy, shrink=None):
+    """40 windows in stage-A groups of 16 / 16 / 8: ``n_noisy`` of them
+    (noisy backbone, noisy layers) are still refining after stage A,
+    the rest (layers equal to the backbone) converge at once. Returns
+    the ``(repack?, B, nWp)`` of every launch, the consensus bytes and
+    the number of windows stage A left."""
+    from racon_tpu.core.window import Window, WindowType
+    from racon_tpu.ops import poa as poa_mod
+    from racon_tpu.ops.poa import TpuPoaConsensus
+
+    monkeypatch.setattr(poa_mod, "MAX_GROUP_WINDOWS", 16)
+    if shrink is not None:
+        monkeypatch.setattr(poa_mod, "STAGE_B_MAX_SHRINK", shrink)
+    rng = np.random.default_rng(5)
+    windows = []
+    for k in range(40):
+        truth = bytes(b"ACGT"[i] for i in rng.integers(0, 4, 120))
+        bb = _mutated(rng, truth) if k < n_noisy else truth
+        win = Window(0, k, WindowType.TGS, bb, b"5" * len(bb))
+        for _ in range(6):
+            layer = _mutated(rng, truth) if k < n_noisy else truth
+            win.add_layer(layer, b"9" * len(layer), 0, len(bb) - 1)
+        windows.append(win)
+    eng = TpuPoaConsensus(3, -5, -4, band=64, rounds=6)
+    launches, impl = [], eng._launch_group_impl
+
+    def spy(live, Lq, Lb, overrides=None, floor=(1, 1)):
+        la = impl(live, Lq, Lb, overrides, floor)
+        launches.append((overrides is not None, la["B"], la["nWp"]))
+        return la
+
+    monkeypatch.setattr(eng, "_launch_group_impl", spy)
+    assert all(eng.run(windows, trim=False))
+    return (launches, [w.consensus for w in windows],
+            eng.stats["stage_b_windows"])
+
+
+@pytest.mark.parametrize("n_noisy, left, repack", [
+    (1, 1, (16, 4)), (3, 2, (16, 4)), (5, 3, (32, 4)), (6, 4, (32, 8))])
+def test_stage_b_repack_shrinks_at_most_eightfold(monkeypatch, n_noisy,
+                                                  left, repack):
+    """The repack of stage A's stragglers takes no geometry under an
+    eighth of the largest stage-A group's ``(B, nWp)`` = (128, 32): one
+    survivor or two share ONE program (a polished draft leaves a small
+    random count, and a Mosaic program a power of two on each axis was
+    what a second polishing round compiled per seed: PR 41); more
+    survivors take their own powers of two as before."""
+    launches, _, survivors = _two_stage_run(monkeypatch, n_noisy)
+    assert survivors == left
+    assert launches[:3] == [(False, 128, 32), (False, 128, 32),
+                            (False, 64, 16)]
+    assert launches[3:] == [(True,) + repack]
+
+
+def test_stage_b_repack_floor_moves_no_byte(monkeypatch):
+    """Padding only: the same windows repacked at their own power of
+    two (the floor switched off) give the same consensus bytes."""
+    floored, a, _ = _two_stage_run(monkeypatch, 1)
+    tight, b, _ = _two_stage_run(monkeypatch, 1, shrink=1 << 30)
+    assert floored[3] == (True, 16, 4) and tight[3] == (True, 8, 2)
+    assert a == b
