@@ -119,6 +119,10 @@ METRICS: FrozenSet[str] = frozenset((
     "overlap.chains_dropped",
     "overlap.chains_kept", "overlap.chunks",
     "overlap.freq_capped_buckets", "overlap.join_bailouts",
+    # the seed join's host prefilter (ops/chain.py _present_reads): read
+    # entries offered to the device joins of the job, and those of them
+    # padded and uploaded (equal where the prefilter stood aside)
+    "overlap.join_read_entries", "overlap.join_read_kept",
     "overlap.lanes_occupied", "overlap.lanes_total",
     "overlap.minimizers", "overlap.mode_auto",
     # reads offered to the overlapper / reads with a row after the filter
@@ -218,6 +222,8 @@ SPANS: FrozenSet[str] = frozenset((
     # consumer's Overlap objects with their filter
     "overlap.chain.plan", "overlap.emit", "overlap.rows",
     "overlap.filter", "overlap.join.dispatch", "overlap.join.fetch",
+    # the join's host prefilter inside overlap.match (timer-only)
+    "overlap.join.prefilter",
     "overlap.match", "overlap.seed", "overlap.seed.dispatch",
     "overlap.seed.fetch",
     "parse.overlaps", "parse.reads", "parse.targets",
@@ -240,6 +246,7 @@ SPANS: FrozenSet[str] = frozenset((
 TIMER_ONLY_SPANS: FrozenSet[str] = frozenset((
     "poa.lanes", "compile.retrieve",
     "overlap.chain.plan", "overlap.emit", "overlap.rows",
+    "overlap.join.prefilter",
     # `round` lies over every span of its round: read through, the
     # idle under it goes where it went before rounds had a span, and
     # the hand-off's stays `unattributed` (idle_other_s lists it)

@@ -437,7 +437,10 @@ class ShardRunner:
                     f"{metrics.counter('overlap.cache_hits')}h/"
                     f"{metrics.counter('overlap.cache_misses')}m, "
                     f"{metrics.counter('overlap.join_bailouts')} "
-                    f"join bailout(s)")
+                    f"join bailout(s), join took "
+                    f"{metrics.counter('overlap.join_read_kept')} of "
+                    f"{metrics.counter('overlap.join_read_entries')} "
+                    f"read minimizers")
         else:
             _eprint(f"indexing {os.path.basename(self.overlaps)} / "
                     f"{os.path.basename(self.sequences)} "

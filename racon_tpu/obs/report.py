@@ -551,13 +551,20 @@ def write_report(path: str, rep: dict) -> None:
     atomic_write_bytes(path, json.dumps(rep, indent=1).encode())
 
 
-def rounds_table(rounds: dict) -> str:
-    """The ``rounds`` section for people: one line per round."""
+def rounds_table(rounds: dict, counters: Optional[dict] = None) -> str:
+    """The ``rounds`` section for people: one line per round and, given
+    the report's counters, what the job's seed joins took of the read
+    table (the host prefilter of ``ops/chain.py``)."""
     lines = [f"{'round':>5} {'handoff_s':>10} {'wall_s':>9} "
              f"{'compiles':>8} {'overlaps_kept':>13}"]
     lines += [f"{r['round']:>5} {r['handoff_s']:>10.3f} {r['wall_s']:>9.3f} "
               f"{r['compiles']:>8} {r['overlaps_kept']:>13}"
               for r in rounds.get("rows", [])]
+    offered = (counters or {}).get("overlap.join_read_entries")
+    if offered:
+        kept = counters.get("overlap.join_read_kept", 0)
+        lines.append(f"join: {kept} of {offered} read minimizers crossed "
+                     f"to the device ({100 * kept / offered:.1f} %)")
     return "\n".join(lines)
 
 
@@ -585,7 +592,9 @@ def _main(argv) -> int:
         return compilewatch.main(argv[1:])
     if len(argv) == 2 and argv[0] == "rounds":
         with open(argv[1], "rb") as f:
-            print(rounds_table(json.loads(f.read()).get("rounds") or {}))
+            rep = json.loads(f.read())
+        print(rounds_table(rep.get("rounds") or {},
+                           (rep.get("metrics") or {}).get("counters")))
         return 0
     print("usage: python -m racon_tpu.obs --check FILE\n"
           "       python -m racon_tpu.obs gaps RUN_REPORT DEVICE_TRACE\n"

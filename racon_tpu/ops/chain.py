@@ -6,8 +6,11 @@ Consumes the flat minimizer tables from :mod:`.overlap_seed` and emits
 
 - **matching** runs on device by default (``RACON_TPU_OVERLAP_DEVICE_JOIN``):
   the target table sorts by hash on the host (it is the small side),
-  every read minimizer — in table order, never sorted — is looked up
-  among its distinct hashes on the device through a directory over the
+  the read minimizers that share their hash's low bits with none of
+  its distinct hashes drop on the host (:func:`_present_reads`: they
+  are misses whatever the search does, nine in ten of a read set
+  against its draft), every one left — in table order, never sorted — is looked
+  up among those hashes on the device through a directory over the
   hash's top bits, each target bucket's read occurrences are counted
   by a scatter-add so super-hot repeat
   buckets over the occurrence cap drop whole (counted in
@@ -79,12 +82,21 @@ from . import overlap_seed
 CHAIN_ARENA_CELLS = 1 << 19
 # device-join arena bounds: padded table entries / expanded hits past
 # these bail to the host oracle (counted, never silent) so one
-# pathological input can't demand an unbounded device arena. 2^26 table
-# cells hold a 30x bacterial read set (2 Mbp: 20 M read minimizers pad
-# to 2^25, beside 2^20 of the draft's) in about 1.2 GB of operands, ramp
-# and look-up temporaries
+# pathological input can't demand an unbounded device arena. The read
+# side counts after the host's prefilter: of a 30x bacterial read set's
+# 20 M minimizers (2 Mbp) the 1.6 M that can match pad to 2^21, beside
+# 2^20 of the draft's. What fills 2^26 cells is a table joined with
+# itself (`-f`: the prefilter stands aside), 2^25 + 2^25 in about 1.2 GB
+# of operands, ramp and look-up temporaries
 JOIN_TABLE_CELLS = 1 << 26
 JOIN_MAX_HITS = 1 << 26
+# the presence table of the prefilter: slots per distinct target hash
+# (a read hash that matches nothing passes one time in this many; of a
+# read set's minimizers 6 % match its draft, so 32 keeps 8 % where 16
+# keeps 10 %, which at 2 Mbp x 30 is the step from 2^21 to 2^22) and the
+# most hash bits it takes (2^26 one-byte slots: 64 MB)
+PRESENCE_SLOTS_PER_HASH = 32
+PRESENCE_MAX_BITS = 26
 # in-flight chain chunks before a fetch is forced (double buffering:
 # the device works chunk N while the host packs N+1 and fetches N-1)
 CHAIN_INFLIGHT = 2
@@ -255,17 +267,20 @@ def _join_ramp_kernel(rh, uh, ucount, dstart, max_occ, *, steps: int):
     occurrences, drop super-hot buckets whole, and emit the read→target
     join ramp (``u``/``cnt``/inclusive ``offs``).
 
-    ``rh`` is the padded read table's hashes in table order — the read
+    ``rh`` is the padded read table's hashes in table order (what the
+    host's prefilter left of it: :func:`_present_reads`) — the read
     side is never sorted. The target side comes sorted from the host
     (:func:`_sorted_target`): ``uh`` its distinct hashes, ``ucount``
     each one's entries, and ``dstart`` a directory over the hash's top
     bits (``uh[dstart[p]:dstart[p + 1]]`` share prefix ``p``), so a
     look-up is two directory reads and ``steps`` rounds of a search
-    inside one short bucket (``2**steps`` exceeds the longest). On the chip a gather
-    over the 2^25 padded read minimizers costs 0.29 s, so the two
-    21-round binary searches this replaced were 12 of a job's 40 s
-    (PR 34). Pad slots carry ``_HASH_MAX``, which no real entry can
-    (the seed builder filters it). No sort and no long scan but one
+    inside one short bucket (``2**steps`` exceeds the longest). On the
+    chip every gather over ``rh``'s slots costs by the slots, whatever
+    the table gathered from (0.29 s at 2^25: the two 21-round binary
+    searches this replaced were 12 of a job's 40 s, PR 34), which is
+    why the slots are the reads that can match and not the read set
+    (2^21 of 2^25, PR 42). Pad slots carry ``_HASH_MAX``, which no real
+    entry can (the seed builder filters it). No sort and no long scan but one
     prefix sum: the sorts all this replaced took the chip's compiler
     minutes. Returns the ramp, the total hit count and the count of
     target buckets dropped — only the two scalars need fetching before
@@ -368,6 +383,38 @@ def _sorted_target(table, n_pad: int):
     return entries, distinct, dstart, int(per_bucket.max())
 
 
+def _presence_bits(n_distinct: int) -> int:
+    """Hash bits of the prefilter's presence table: the fewest that
+    give every distinct target hash :data:`PRESENCE_SLOTS_PER_HASH`
+    slots, between a byte and :data:`PRESENCE_MAX_BITS`."""
+    want = (PRESENCE_SLOTS_PER_HASH * max(1, n_distinct) - 1).bit_length()
+    return max(8, min(PRESENCE_MAX_BITS, want))
+
+
+def _present_reads(rh: np.ndarray, uh: np.ndarray) -> Optional[np.ndarray]:
+    """The host prefilter of the seed join: ascending indices of the
+    read entries ``rh`` whose low hash bits some distinct target hash
+    of ``uh`` shares, or ``None`` where they pad to the table's own
+    class (a table joined with itself, a handful of minimizers), when
+    nothing is worth compacting.
+
+    Exact: a read hash whose low bits no target hash has equals none of
+    them, so the ramp would find it unmatched — it adds to no bucket's
+    count, to no cap and to no hit; an entry kept by bits it only
+    shares is resolved by the kernel's search as before. The LOW bits:
+    a minimizer is the least of its window's mixed hashes
+    (``overlap_seed._mix32``), so the top bits crowd towards zero (the
+    median hash of a read set is 0.18 of the range, and a table over
+    them passes twice the misses: PR 42, on the chip) while the low
+    bits stay uniform — of the entries that match nothing about one in
+    :data:`PRESENCE_SLOTS_PER_HASH` passes."""
+    low = np.uint32((1 << _presence_bits(uh.size)) - 1)
+    present = np.zeros(int(low) + 1, bool)
+    present[uh & low] = True
+    kept = np.flatnonzero(present[rh & low])
+    return None if _table_pad(kept.size) == _table_pad(rh.size) else kept
+
+
 def join_seeds(read_table, target_table, read_self_t: np.ndarray,
                qlens: np.ndarray, *, k: int, max_occ: int,
                device_join: bool = True, resident: bool = False
@@ -381,10 +428,18 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
     ``tp_dev``/``qc_dev`` int32 arrays the chain stream gathers from
     directly.
 
+    Of the read table only the entries that can match cross to the
+    device (:func:`_present_reads`; counters ``overlap.join_read_entries``
+    offered and ``overlap.join_read_kept`` padded and uploaded, equal
+    where the prefilter stood aside), so the two programs' ``R2`` is the
+    class of the kept entries. The hits carry values, never table
+    indices, and the host orders them: the same hits either way.
+
     The bail-out ladder (empty tables, padded tables over
     :data:`JOIN_TABLE_CELLS`, hit counts over :data:`JOIN_MAX_HITS`,
-    int32 ramp overflow risk) falls back to the oracle and counts into
-    ``overlap.join_bailouts`` — never approximation, never silent."""
+    int32 ramp overflow risk — both table rungs by the kept entries)
+    falls back to the oracle and counts into ``overlap.join_bailouts``
+    — never approximation, never silent."""
     rh, th = read_table[0], target_table[0]
 
     def _oracle(bail: bool):
@@ -400,20 +455,35 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
         # rung 1: an empty side joins to nothing — the oracle's trivial
         # path costs less than one kernel launch
         return _oracle(bail=True)
-    # graftlint: disable=warmup-coverage (the join runs ONCE per run immediately after seeding produces the very sizes these pow2 buckets quantize — there is no earlier moment to warm them from)
-    R2, T2 = _table_pad(rh.size), _table_pad(th.size)
+    T2 = _table_pad(th.size)
+    if T2 >= JOIN_TABLE_CELLS:
+        # rung 2, before the target is sorted: no read side fits beside it
+        return _oracle(bail=True)
+    entries_h, (uh_h, ustart_h, ucount_h), dstart_h, longest = \
+        _sorted_target(target_table, T2)
+    offered = int(rh.size)
+    _, rid_h, rpos_h, rstr_h = read_table
+    with obs.span("overlap.join.prefilter", reads=offered):
+        # the directory's last entry counts the distinct hashes: the
+        # rest of ``uh_h`` is padding
+        kept = _present_reads(rh, uh_h[:int(dstart_h[-1])])
+        if kept is not None:
+            rh, rid_h, rpos_h, rstr_h = (
+                a[kept] for a in (rh, rid_h, rpos_h, rstr_h))
+    # graftlint: disable=warmup-coverage (the join runs ONCE per round immediately after seeding produces the very tables whose kept entries this pow2 bucket quantizes — there is no earlier moment to warm it from)
+    R2 = _table_pad(rh.size)
     # a kept read entry joins fewer than max_occ target entries (its
-    # bucket would have dropped whole) and a pad slot joins none, so
-    # the int32 ramp holds while real entries x max_occ stays under 2^31
+    # bucket would have dropped whole), a dropped one and a pad slot
+    # join none, so the int32 ramp holds while kept entries x max_occ
+    # stays under 2^31
     if R2 + T2 > JOIN_TABLE_CELLS \
             or int(rh.size) * max(1, max_occ) >= (1 << 31):
         # rung 2: table arena overflow / int32 ramp overflow risk
         return _oracle(bail=True)
+    metrics.inc("overlap.join_read_entries", offered)
+    metrics.inc("overlap.join_read_kept", int(rh.size))
 
     hmax = np.uint32(overlap_seed._HASH_MAX)
-    _, rid_h, rpos_h, rstr_h = read_table
-    entries_h, (uh_h, ustart_h, ucount_h), dstart_h, longest = \
-        _sorted_target(target_table, T2)
     steps = max(JOIN_BUCKET_STEPS, longest.bit_length())
     with obs.span("overlap.join.dispatch", reads=int(rh.size),
                   targets=int(th.size)):

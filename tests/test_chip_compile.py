@@ -298,24 +298,17 @@ def test_overlapper_minimizer_program_compiles(chip):
     assert total < HBM_BYTES // 8
 
 
-def test_overlapper_join_programs_fit_the_chip(chip):
-    """The device join at the tables of a 30x 2 Mbp read set (20 M read
-    minimizers pad to 2^25, the draft's 0.67 M to 2^20 under a directory
-    of 2^21 buckets; 1.2 M hits pad to 2^21): the look-up, the bucket
-    counts and the ramp in one program, then the expansion. Neither
-    holds a sort (PR 34: the sorts they replaced took this compiler 70,
-    44 and 198 s)."""
+def _compile_join(chip, R2, T2, E, Q2):
+    """Both programs of the device join at padded tables ``R2`` (the
+    read side's kept entries) and ``T2``, ``E`` hit slots and ``Q2``
+    reads; returns the ramp's text."""
     from racon_tpu.ops import chain
-    R2, T2 = chain._table_pad(20_000_000), chain._table_pad(670_000)
-    assert R2 + T2 <= chain.JOIN_TABLE_CELLS
     u32, i32 = jnp.uint32, jnp.int32
     ramp, _, total = _compile(chain._join_ramp_kernel.lower(
         chip((R2,), u32), chip((T2,), u32), chip((T2,), i32),
         chip((2 * T2 + 1,), i32), chip((), i32),
         steps=chain.JOIN_BUCKET_STEPS))
     assert total < HBM_BYTES // 2
-    assert "sort" not in ramp.as_text().lower()
-    E, Q2 = chain._hits_pad(1_210_178), chain._table_pad(8571)
     _, _, total = _compile(chain._join_expand_kernel.lower(
         chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
         chip((T2,), i32), chip((T2,), i32), chip((T2,), i32),
@@ -323,28 +316,57 @@ def test_overlapper_join_programs_fit_the_chip(chip):
         chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
         chip((), i32), chip((Q2,), i32), chip((Q2,), i32), E=E, k=15))
     assert total < HBM_BYTES // 2
+    return ramp.as_text()
 
 
-def test_second_round_join_program_fits_the_chip(chip):
-    """What round 2 of ``bact1m-auto30x-r2`` adds beside the aligner's
-    rungs (PR 41: the warm-up job's round 2 compiled 21 programs of its
-    own): against a polished draft five times the 15-mers of a read
-    survive, so the join's hits pad to 2^21 where round 1's pad to 2^20
-    (1.0 Mbp at 30x: 10 M read minimizers in 2^24, the draft's 0.33 M
-    in 2^19) and the pairs reach the 1,024-seed chain class
-    (``test_overlapper_chain_program_compiles``)."""
+# read entries that cross to the device, hits: of a 30x 2 Mbp read set's
+# 19.3 M minimizers the host's prefilter keeps 8 % (2^21; 14 % and 2^22
+# in PR 42's first chip run, whose presence table read the hashes' top
+# bits), or all of them where it stands aside
+JOIN_READ_SIDES = [(1_600_000, 1_210_178), (2_630_034, 1_210_178),
+                   (19_303_946, 1_210_178)]
+
+
+@pytest.mark.parametrize("kept, hits", JOIN_READ_SIDES)
+def test_overlapper_join_programs_fit_the_chip(chip, kept, hits):
+    """The device join at the tables of a 30x 2 Mbp read set: the
+    draft's 0.67 M minimizers pad to 2^20 under a directory of 2^21
+    buckets, 1.2 M hits to 2^21, and of the read minimizers the ones
+    that can match pad to 2^21 or 2^22 (PR 42) — or the whole table to
+    2^25, the class a prefilter that stands aside uploads. The
+    look-up, the bucket counts and the ramp in one program, then the
+    expansion. Neither holds a sort (PR 34: the sorts they replaced
+    took this compiler 70, 44 and 198 s)."""
     from racon_tpu.ops import chain
-    R2, T2 = chain._table_pad(10_000_000), chain._table_pad(334_000)
-    E, Q2 = chain._hits_pad(1_500_000), chain._table_pad(4285)
-    assert (R2, T2, E, Q2) == (1 << 24, 1 << 19, 1 << 21, 8192)
-    i32 = jnp.int32
-    _, _, total = _compile(chain._join_expand_kernel.lower(
-        chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
-        chip((T2,), i32), chip((T2,), i32), chip((T2,), i32),
-        chip((T2,), i32),
-        chip((R2,), i32), chip((R2,), i32), chip((R2,), i32),
-        chip((), i32), chip((Q2,), i32), chip((Q2,), i32), E=E, k=15))
-    assert total < HBM_BYTES // 2
+    R2, T2 = chain._table_pad(kept), chain._table_pad(670_000)
+    assert R2 in (1 << 21, 1 << 22, 1 << 25) and T2 == 1 << 20
+    assert R2 + T2 <= chain.JOIN_TABLE_CELLS
+    ramp = _compile_join(chip, R2, T2, chain._hits_pad(hits),
+                         chain._table_pad(8571))
+    assert "sort" not in ramp.lower()
+
+
+@pytest.mark.parametrize("kept, hits", [(790_000, 600_000),
+                                        (2_000_000, 1_500_000),
+                                        (2_200_000, 1_500_000),
+                                        (9_650_000, 1_500_000)])
+def test_second_round_join_program_fits_the_chip(chip, kept, hits):
+    """The joins of ``bact1m-auto30x-r2`` (1.0 Mbp at 30x: 9.65 M read
+    minimizers, the draft's 0.33 M in 2^19): round 1 keeps one read
+    entry in twelve (2^20) for 0.6 M hits in 2^20; against a polished
+    draft a fifth of a read's minimizers match, so round 2 keeps 2.0 to
+    2.2 M — on either side of 2^21 — its hits pad to 2^21 and its pairs
+    reach the 1,024-seed chain class
+    (``test_overlapper_chain_program_compiles``; PR 41: the warm-up
+    job's round 2 compiled 21 programs of its own). Last the table as
+    it crossed before the prefilter: 2^24."""
+    from racon_tpu.ops import chain
+    R2, T2 = chain._table_pad(kept), chain._table_pad(334_000)
+    E, Q2 = chain._hits_pad(hits), chain._table_pad(4285)
+    assert (T2, Q2) == (1 << 19, 8192)
+    assert (R2, E) in ((1 << 20, 1 << 20), (1 << 21, 1 << 21),
+                       (1 << 22, 1 << 21), (1 << 24, 1 << 21))
+    _compile_join(chip, R2, T2, E, Q2)
 
 
 @pytest.mark.parametrize("pairs, windows", [(13_000, 470), (2_000, 65)])

@@ -229,37 +229,150 @@ def rand_table(rng, n_seqs, n_entries, hash_space):
     return h, sid, pos, strand
 
 
-def test_device_join_matches_oracle():
-    """The device seed join (sort kernel + ragged expand kernel)
-    reproduces the numpy ``match_seeds`` oracle exactly — randomized
-    dense tables (collision-heavy hash space), both strands, self-hit
-    suppression, and hot-bucket capping included — with zero bail-outs
-    to the oracle."""
+def pooled_table(rng, n_seqs, n_entries, pool, weights=None):
+    """:func:`rand_table` with its hashes drawn from ``pool`` — mixed
+    32-bit values, as the seed builder's are, so the low bits the
+    join's prefilter reads are spread."""
+    _, sid, pos, strand = rand_table(rng, n_seqs, n_entries, 2)
+    return rng.choice(pool, sid.size, p=weights), sid, pos, strand
+
+
+def _mixed_hashes(rng, n):
+    return rng.choice(1 << 32, n, replace=False).astype(np.uint32)
+
+
+def _dense_case(rng):
+    """Tiny hash space: dense cross-table collisions (what the
+    prefilter does with hashes this crowded is not asserted)."""
+    n_reads = int(rng.integers(2, 10))
+    n_targets = int(rng.integers(1, 6))
+    hash_space = int(rng.integers(20, 300))
+    rt = rand_table(rng, n_reads, int(rng.integers(50, 600)), hash_space)
+    tt = rand_table(rng, n_targets, int(rng.integers(50, 600)), hash_space)
+    return rt, tt, int(rng.integers(2, 40)), None
+
+
+def _planted_tables(rng, distinct, alone, share, n_reads, read_entries,
+                    n_targets, target_entries):
+    """A draft table over ``distinct`` mixed hashes and a read table of
+    which ``share`` draws from them, the rest from ``alone`` others."""
+    shared, others = _mixed_hashes(rng, distinct), _mixed_hashes(rng, alone)
+    tt = pooled_table(rng, n_targets, target_entries, shared)
+    w = np.r_[np.full(distinct, share / distinct),
+              np.full(alone, (1 - share) / alone)]
+    rt = pooled_table(rng, n_reads, read_entries, np.r_[shared, others], w)
+    return rt, tt
+
+
+def _engaged_case(rng):
+    """A read table much larger than the draft's, a few per cent of its
+    entries planted from the draft's hashes: the prefilter compacts."""
+    rt, tt = _planted_tables(rng, 240, 20_000, 0.04, 40, 30_000, 3, 700)
+    return rt, tt, int(rng.integers(8, 40)), True
+
+
+def _collisions_case(rng):
+    """So many distinct draft hashes that thousands of read entries pass
+    the presence table on low bits they only share (one slot in 32 is
+    taken) and the kernel's search has to turn them down."""
+    rt, tt = _planted_tables(rng, 2_000, 50_000, 0.02, 60, 80_000, 4, 6_000)
+    matching = int(np.isin(rt[0], tt[0]).sum())
+    assert chain._present_reads(rt[0], np.unique(tt[0])).size \
+        > matching + 1000
+    return rt, tt, 64, True
+
+
+def _hot_by_reads_case(rng):
+    """Buckets of two or three draft entries that the reads' own
+    occurrences carry over ``max_occ`` (the ramp's ``tr``): they drop
+    whole, and only kept entries can have made them hot."""
+    rt, tt = _planted_tables(rng, 30, 8_000, 0.08, 30, 6_000, 2, 80)
+    return rt, tt, 16, True
+
+
+def _all_vs_all_case(rng):
+    """``-f``: the target table IS the read table, every entry's bits
+    are present, nothing is compacted."""
+    rt = pooled_table(rng, 12, 3_000, _mixed_hashes(rng, 1_500))
+    return rt, rt, 8, False
+
+
+_JOIN_COUNTERS = ("overlap.join_bailouts", "overlap.join_read_entries",
+                  "overlap.join_read_kept")
+
+
+def _join_counted(rt, tt, self_t, qlens, max_occ):
+    """``join_seeds`` on the device path and what it added to
+    :data:`_JOIN_COUNTERS`: ``(hits, capped, bailed, offered, kept)``."""
+    before = [metrics.counter(name) for name in _JOIN_COUNTERS]
+    hits, capped = chain.join_seeds(rt, tt, self_t, qlens, k=15,
+                                    max_occ=max_occ, device_join=True)
+    return (hits, capped, *(metrics.counter(name) - was
+                            for name, was in zip(_JOIN_COUNTERS, before)))
+
+
+def _assert_same_hits(got, want, note=None):
+    for key in ("q", "t", "rel", "tp", "qc"):
+        assert np.array_equal(np.asarray(got[key], np.int64),
+                              want[key]), (note, key)
+
+
+@pytest.mark.parametrize("case, trials", [
+    (_dense_case, 8), (_engaged_case, 3), (_collisions_case, 1),
+    (_hot_by_reads_case, 3), (_all_vs_all_case, 2)],
+    ids=lambda v: v.__name__.strip("_") if callable(v) else None)
+def test_device_join_matches_oracle(case, trials):
+    """The device seed join (host prefilter, look-up + ramp kernel,
+    ragged expand kernel) reproduces the numpy ``match_seeds`` oracle
+    exactly — both strands, self-hit suppression and hot-bucket capping
+    included, the prefilter engaged and stood aside — with zero
+    bail-outs to the oracle."""
     rng = np.random.default_rng(31)
-    before = metrics.counter("overlap.join_bailouts")
-    for trial in range(8):
-        n_reads = int(rng.integers(2, 10))
-        n_targets = int(rng.integers(1, 6))
-        hash_space = int(rng.integers(20, 300))
-        max_occ = int(rng.integers(2, 40))
-        rt = rand_table(rng, n_reads, int(rng.integers(50, 600)),
-                        hash_space)
-        tt = rand_table(rng, n_targets, int(rng.integers(50, 600)),
-                        hash_space)
+    for trial in range(trials):
+        rt, tt, max_occ, engaged = case(rng)
+        n_reads, n_targets = int(rt[1].max()) + 1, int(tt[1].max()) + 1
         self_t = np.where(rng.random(n_reads) < 0.3,
                           rng.integers(0, n_targets, n_reads),
                           -1).astype(np.int64)
         qlens = rng.integers(4100, 6000, n_reads).astype(np.int64)
         want, capped_w = reference.match_seeds(rt, tt, self_t, qlens,
                                            k=15, max_occ=max_occ)
-        got, capped_g = chain.join_seeds(rt, tt, self_t, qlens, k=15,
-                                         max_occ=max_occ,
-                                         device_join=True)
+        got, capped_g, bailed, offered, kept = _join_counted(
+            rt, tt, self_t, qlens, max_occ)
         assert capped_g == capped_w, trial
-        for key in ("q", "t", "rel", "tp", "qc"):
-            assert np.array_equal(np.asarray(got[key], np.int64),
-                                  want[key]), (trial, key)
-    assert metrics.counter("overlap.join_bailouts") == before
+        _assert_same_hits(got, want, trial)
+        assert bailed == 0 and offered == rt[0].size
+        if engaged is not None:
+            assert (kept * 4 < offered) if engaged else (kept == offered)
+        if case is _hot_by_reads_case:
+            assert capped_g > 0 and want["q"].size > 0
+
+
+def test_join_prefilter_counts_and_reads_the_kept_entries(monkeypatch):
+    """``overlap.join_read_kept`` never passes ``.join_read_entries``,
+    and the bail-out ladder's table rung reads the kept entries: a read
+    table whose own padding would not fit :data:`JOIN_TABLE_CELLS`
+    beside the draft's stays on the device when what can match does."""
+    rng = np.random.default_rng(34)
+    rt, tt, max_occ, _ = _engaged_case(rng)
+    self_t = np.full(int(rt[1].max()) + 1, -1, np.int64)
+    qlens = np.full(self_t.size, 5000, np.int64)
+    want, capped_w = reference.match_seeds(rt, tt, self_t, qlens, k=15,
+                                           max_occ=max_occ)
+    assert chain._table_pad(rt[0].size) == 1 << 15
+    monkeypatch.setattr(chain, "JOIN_TABLE_CELLS", 1 << 14)
+    got, capped_g, bailed, offered, kept = _join_counted(
+        rt, tt, self_t, qlens, max_occ)
+    assert bailed == 0 and 0 < kept <= offered == rt[0].size
+    assert chain._table_pad(kept) + chain._table_pad(tt[0].size) <= 1 << 14
+    assert capped_g == capped_w and want["q"].size > 0
+    _assert_same_hits(got, want)
+    # a draft table that alone fills the arena still bails, counted,
+    # and such a join offers the device nothing
+    monkeypatch.setattr(chain, "JOIN_TABLE_CELLS", 1 << 10)
+    _, _, bailed, offered, kept = _join_counted(rt, tt, self_t, qlens,
+                                                max_occ)
+    assert (bailed, offered, kept) == (1, 0, 0)
 
 
 def test_device_join_resident_layout():

@@ -208,6 +208,14 @@ def test_report_has_the_rounds_section(jobs, n):
         sum(r["wall_s"] for r in rounds["rows"]), abs=1e-3)
     assert timers["round"] + timers["round.handoff"] <= rep["wall_s"]
     assert len(report.rounds_table(rounds).splitlines()) == n + 1
+    # every round's join was offered the one held read table and took
+    # the entries that can match its own draft: the table's last line
+    counters = rep["metrics"]["counters"]
+    offered = counters["overlap.join_read_entries"]
+    assert offered % n == 0
+    assert 0 < counters["overlap.join_read_kept"] < offered
+    assert report.rounds_table(rounds, counters).splitlines()[n + 1] \
+        .startswith(f"join: {counters['overlap.join_read_kept']} of ")
 
 
 def test_one_round_is_the_options_absence(jobs):
