@@ -447,9 +447,9 @@ def _run_sharded(args, argv, trace_path, report_path, t_start, t0) -> int:
                     stdout=subprocess.DEVNULL))
         if secondary:
             with open(os.devnull, "wb") as sink:
-                runner.run(sink)
+                runner.run(sink, begun=True)
         else:
-            runner.run(sys.stdout.buffer)
+            runner.run(sys.stdout.buffer, begun=True)
     except (ValueError, RuntimeError, OSError) as e:
         print(f"[racon::] error: {e}", file=sys.stderr)
         for proc in children:

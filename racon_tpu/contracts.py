@@ -94,6 +94,18 @@ METRICS: FrozenSet[str] = frozenset((
     "dataflow.resident", "dataflow.resident_bailouts",
     # exec ladder
     "exec.backoff_s",
+    # the shard runner's job (the report's shard_run section): shards
+    # done in this process, those of them on the slot's device engines
+    # at the first attempt, shards that needed a second attempt (done
+    # or quarantined), bytes of the parts written and of the shard
+    # inputs cut out of the job's files, and (gauges) the first and
+    # the last done shard's wall and backend compiles and, written by
+    # obs/device_time.py, the device-idle seconds between one shard's
+    # last device interval and the next one's first
+    "exec.shards_done", "exec.shards_primary", "exec.shards_retried",
+    "exec.part_bytes", "exec.extract_bytes", "exec.boundary_idle_s",
+    "exec.first_shard_compiles", "exec.first_shard_wall_s",
+    "exec.last_shard_compiles", "exec.last_shard_wall_s",
     # fault taxonomy + injection
     "faults.backpressure_halvings", "faults.injected.exec.polish",
     "faults.part_corrupt", "faults.stall_escalations",
@@ -213,8 +225,12 @@ SPANS: FrozenSet[str] = frozenset((
     "compile.backend", "compile.lower", "compile.retrieve",
     "compile.trace",
     "consensus", "consensus.feed", "consensus.finish", "consensus.run",
-    "exec.extract", "exec.index", "exec.merge", "exec.plan",
-    "exec.shard",
+    # exec.commit: a shard's part written (tmp, fsync, rename) and
+    # its terminal state saved (state file + manifest, fsync each);
+    # exec.drain: a slot with no shard left waits for the warm-ups its
+    # shards kicked on its engines
+    "exec.commit", "exec.drain", "exec.extract", "exec.index",
+    "exec.merge", "exec.plan", "exec.shard",
     "fleet.place", "gateway.admit",
     "overlap.chain", "overlap.chain.dispatch", "overlap.chain.fetch",
     # the streamed hand-off's host work inside `align` (timer-only):
@@ -252,6 +268,16 @@ TIMER_ONLY_SPANS: FrozenSet[str] = frozenset((
     # the hand-off's stays `unattributed` (idle_other_s lists it)
     "round", "round.handoff"))
 
+# the shard runner's spans, which mark a slot's thread. A shard's
+# pipeline feeds the device from threads born inside ``exec.shard``, so
+# the idle such a thread is charged while it holds no span of its own
+# (before its first parse: the stitch, the commit, the extract, the
+# index) is cut by the spans of the slot thread that fed the same
+# device: ``idle.exec.*`` (obs/device_time.py)
+DRIVER_SPANS: FrozenSet[str] = frozenset((
+    "exec.commit", "exec.drain", "exec.extract", "exec.index",
+    "exec.merge", "exec.plan", "exec.shard"))
+
 # ------------------------------------------------------------ fault sites
 
 # the named RACON_TPU_FAULTS injection points (racon_tpu.faults.check
@@ -272,7 +298,7 @@ FAULT_CLASSES: Tuple[str, ...] = ("transient-io", "device-oom", "stall",
 
 # -------------------------------------------------------- report schema
 
-SCHEMA_VERSION = 14
+SCHEMA_VERSION = 15
 
 # the oldest version validate_report still accepts, as itself: a stored
 # v11 report is held to the v11 key sets
@@ -301,6 +327,7 @@ TOP_KEYS: Dict[str, int] = {
     "fleet": 11,
     "device_time": 12,
     "rounds": 14,
+    "shard_run": 15,
 }
 
 SECTION_KEYS: Dict[str, Dict[str, int]] = {
@@ -354,12 +381,19 @@ SECTION_KEYS: Dict[str, Dict[str, int]] = {
         "tail_idle_s": 12, "programs": 12, "by_program": 12,
         "idle_by": 12, "timeline": 12, "dropped": 12, "gaps": 12,
         "clock": 12, "devices": 12,
+        "boundary_idle_s": 15,
     },
     "rounds": {
         "count": 14, "first_wall_s": 14, "last_wall_s": 14,
         "first_compiles": 14, "last_compiles": 14,
         "first_overlaps_kept": 14, "last_overlaps_kept": 14,
         "handoff_s": 14, "rows": 14,
+    },
+    "shard_run": {
+        "count": 15, "primary": 15, "retried": 15,
+        "first_wall_s": 15, "last_wall_s": 15,
+        "first_compiles": 15, "last_compiles": 15,
+        "boundary_idle_s": 15, "part_bytes": 15, "extract_bytes": 15,
     },
 }
 
@@ -406,6 +440,7 @@ SECTION_EMITTERS: Dict[str, Tuple[str, str]] = {
     "fleet": ("racon_tpu/obs/metrics.py", "fleet_summary"),
     "device_time": ("racon_tpu/obs/device_time.py", "account"),
     "rounds": ("racon_tpu/obs/metrics.py", "rounds_summary"),
+    "shard_run": ("racon_tpu/obs/metrics.py", "shard_run_summary"),
 }
 
 # report key -> the metric whose emission backs it ("section.key" ->
@@ -475,6 +510,16 @@ REPORT_BACKING: Dict[str, str] = {
     "fleet.cost_cache_hits": "fleet.cost_cache_hits",
     "fleet.cost_cache_misses": "fleet.cost_cache_misses",
     "rounds.count": "rounds.completed",
+    "shard_run.count": "exec.shards_done",
+    "shard_run.primary": "exec.shards_primary",
+    "shard_run.retried": "exec.shards_retried",
+    "shard_run.part_bytes": "exec.part_bytes",
+    "shard_run.extract_bytes": "exec.extract_bytes",
+    "shard_run.boundary_idle_s": "exec.boundary_idle_s",
+    "shard_run.first_wall_s": "exec.first_shard_wall_s",
+    "shard_run.last_wall_s": "exec.last_shard_wall_s",
+    "shard_run.first_compiles": "exec.first_shard_compiles",
+    "shard_run.last_compiles": "exec.last_shard_compiles",
 }
 
 # -------------------------------------------------------- state machines

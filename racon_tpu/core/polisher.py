@@ -121,8 +121,12 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
     the job's :class:`~racon_tpu.core.readset.ReadSet` in place of a
     parse of ``sequences_path``, ``targets`` the round before's
     polished contigs in place of a parse of ``target_path``, and
-    ``final`` off marks a round with another one behind it, which must
-    leave every read whole and waits for no warm-up at ``stitch``."""
+    ``final`` off marks a polisher with another one behind it on the
+    same engines — a round before the last, or a shard of the shard
+    runner — which waits for no warm-up at ``stitch`` (the engines are
+    handed on, warm-up and all; the last round drains, or the runner's
+    slot once it has no shard left) and, where it was given the job's
+    ``reads``, leaves every read whole for the next round."""
     if not isinstance(type_, PolisherType):
         raise ValueError("invalid polisher type")
     if window_length <= 0:
@@ -149,6 +153,10 @@ def create_polisher(sequences_path: str, overlaps_path: str, target_path: str,
                     stall_escalation=stall_escalation,
                     reads=reads, targets=targets, final=final)
 
+
+# estimated pairs below which a job kicks no consensus warm-up: the whole
+# polish costs less than the compile the warm-up would race to hide
+WARMUP_MIN_PAIRS = 16384
 
 # overlaps the streamed hand-off collects before it hands the align
 # session a batch (cut after a whole query's rows)
@@ -409,8 +417,9 @@ class Polisher:
         by ordinal (a read named like a target IS that target:
         ``polisher.cpp:227-263``). Returns ``(reads, their bases)``.
         A round with another one behind it marks every read as needed
-        whole, so :meth:`_transmute_all` frees nothing of it."""
-        keep = not self._final
+        whole, so :meth:`_transmute_all` frees nothing of it (a shard
+        with another one behind it shares engines, not reads)."""
+        keep = self._reads is not None and not self._final
         raw_index = 0
         total_len = 0
         for seq in reads:
@@ -512,9 +521,7 @@ class Polisher:
                             for i in range(self.targets_size))
         est_windows = targets_bases // self.window_length + \
             self.targets_size
-        # threshold: below ~16k pairs the whole polish costs less
-        # than the compile the warm-up would race to hide
-        if est_pairs >= 16384:
+        if est_pairs >= WARMUP_MIN_PAIRS:
             warm(self.window_length, est_pairs, est_windows,
                  est_layer_len=min(longest_overlap,
                                    self.window_length + 64),
@@ -1697,8 +1704,9 @@ class Polisher:
                 polished_data = []
 
         # the job that started a warm-up ends only when it has: the
-        # wait is the last round's (a round with another one behind it
-        # hands its engines on, warm-up and all)
+        # wait is the last round's (a polisher with another one behind
+        # it hands its engines on, warm-up and all; the shard runner's
+        # slot waits once, when it has no shard left)
         drain = getattr(self.consensus, "drain_warmup", None)
         if drain is not None and self._final:
             drain()

@@ -184,6 +184,18 @@ class _ChipWorker:
                                banded=r.banded, device=self.device))
         return self.engines
 
+    def drain_warmups(self) -> None:
+        """Wait for the warm-ups the slot's shards kicked on its device
+        engines. A shard's polisher hands its engines on, warm-up and
+        all (``create_polisher``'s ``final`` off), so the slot waits
+        once, when it has no shard left — whichever shard was its last,
+        and however that one ended (``drain_warmup``'s docstring names
+        what a warm-up left running costs the process's next job)."""
+        for engines in (self.engines, self.mesh_engines):
+            drain = getattr(engines and engines[1], "drain_warmup", None)
+            if drain is not None:
+                drain()
+
     def reduce_capacity(self, mesh: bool = False) -> bool:
         """Memory backpressure for a device-oom fault, scoped to THIS
         worker's engines — per device, not per process: chip 3 OOMing
@@ -393,11 +405,14 @@ class ShardRunner:
 
     # ----------------------------------------------------------------- run
 
-    def run(self, out) -> Dict:
+    def run(self, out, begun: bool = False) -> Dict:
         """Execute (or resume / join) the full sharded run, writing the
         merged polished FASTA to the binary stream ``out`` (primary
         workers only). Returns the summary dict (also kept as
-        :attr:`summary`)."""
+        :attr:`summary`). ``begun``: the caller has marked the run
+        boundary itself (``cli.main``'s ``obs.begin``), and what the
+        job counted since — the kernel probes' compiles, anything
+        swallowed — belongs to this job's report."""
         t0 = time.perf_counter()
         t_start = time.time()
         # run boundary: drop per-run metrics so a second in-process run
@@ -407,7 +422,8 @@ class ShardRunner:
         # exec run persists a run report next to the manifest and its
         # dispatch-vs-fetch split must hold real seconds, not
         # schema-valid zeros
-        metrics.clear_run()
+        if not begun:
+            metrics.clear_run()
         obs.trace.activate()
         if parsers.is_auto_overlaps(self.overlaps):
             # first-party overlapper: materialize a deterministic PAF
@@ -802,6 +818,8 @@ class ShardRunner:
         try:
             self._drain_loop_inner(worker, manifest, beat, poll_s,
                                    multi)
+            with obs.span("exec.drain"):
+                worker.drain_warmups()
         finally:
             if multi:
                 obs.trace.set_timer_prefix(None)
@@ -1038,6 +1056,7 @@ class ShardRunner:
                    worker: Optional["_ChipWorker"] = None,
                    use_mesh: bool = False) -> None:
         worker = worker if worker is not None else self._chip_slots()[0]
+        compiles0 = metrics.counter("compile.backend_total")
         sleep_s = flags.get_float("RACON_TPU_EXEC_SLEEP_S")
         if sleep_s > 0 and si > 0:
             time.sleep(sleep_s)  # test hook: widen the kill window
@@ -1079,11 +1098,15 @@ class ShardRunner:
                     with obs.span("exec.extract", shard=si):
                         paths = self._extract_shard(si, shard)
                     extract_s += time.perf_counter() - t_ext
+                    metrics.inc("exec.extract_bytes", sum(
+                        os.path.getsize(paths[k])
+                        for k in ("targets", "reads", "overlaps")))
                 faults.check("exec.polish", shard=si, attempt=attempt_no)
                 records, timings = self._polish_shard(
                     paths, cpu=tier_cpu, worker=worker,
                     use_mesh=use_mesh)
-                part_stat = self._write_part(part, records)
+                with obs.span("exec.commit", shard=si):
+                    part_stat = self._write_part(part, records)
                 break
             except Exception as e:
                 cls = faults.classify(e)
@@ -1129,6 +1152,7 @@ class ShardRunner:
                             reason=self._reason(attempts),
                             attempts=attempts, worker=worker.worker,
                             wall_s=round(time.perf_counter() - t0, 2))
+                    metrics.inc("exec.shards_retried")
                     self._save_owned(entry, manifest, claim)
                     self._drop_shard_inputs(paths)
                     return
@@ -1173,7 +1197,27 @@ class ShardRunner:
             metrics.inc(f"device.{dev_key}.shards")
             metrics.inc(f"device.{dev_key}.mbp", shard_mbp)
             metrics.add_time(f"device.{dev_key}.polish_s", wall)
-        self._save_owned(entry, manifest, claim)
+        with obs.span("exec.commit", shard=si):
+            self._save_owned(entry, manifest, claim)
+        # what the report's ``shard_run`` section reads: shards done in
+        # this process, those of them on the slot's device engines at
+        # the first attempt (a shard that fell down the ladder still
+        # ends DONE with the right bytes: here is where it shows), and
+        # the first and the last done shard's wall and backend compiles
+        wall_s = time.perf_counter() - t0
+        compiles = metrics.counter("compile.backend_total") - compiles0
+        with self._mf_lock:
+            metrics.inc("exec.shards_done")
+            if attempts:
+                metrics.inc("exec.shards_retried")
+            elif not tier_cpu:
+                metrics.inc("exec.shards_primary")
+            metrics.inc("exec.part_bytes", part_stat[0])
+            if metrics.counter("exec.shards_done") == 1:
+                metrics.set_gauge("exec.first_shard_wall_s", wall_s)
+                metrics.set_gauge("exec.first_shard_compiles", compiles)
+            metrics.set_gauge("exec.last_shard_wall_s", wall_s)
+            metrics.set_gauge("exec.last_shard_compiles", compiles)
         self._drop_shard_inputs(paths)
 
     @staticmethod
@@ -1224,6 +1268,10 @@ class ShardRunner:
             return self._unpolished_records(paths), {}
         worker = worker if worker is not None else self._chip_slots()[0]
         aligner, consensus = worker.get_engines(cpu, mesh=use_mesh)
+        # a shard is a round with another one behind it: it hands the
+        # slot's engines on, warm-up and all (create_polisher's
+        # ``final``), and the slot waits once, when it leaves the drain
+        # loop (_ChipWorker.drain_warmups)
         p = create_polisher(
             paths["reads"], paths["overlaps"], paths["targets"],
             self.type, window_length=self.window_length,
@@ -1233,7 +1281,7 @@ class ShardRunner:
             num_threads=self.num_threads, aligner=aligner,
             consensus=consensus, window_type=self.index.window_type,
             prefiltered_overlaps=True, evict_reads=True,
-            stall_escalation=True)
+            stall_escalation=True, final=False)
         polished = p.run(not self.include_unpolished)
         return [(s.name, s.data) for s in polished], dict(p.timings)
 

@@ -38,7 +38,12 @@ submission ended it (head: the thread of the first submission; tail:
 the thread that writes the report; after a ``warm`` row: the thread of
 the next real submission) and cut by that thread's innermost
 spans open in it, from the span rings (:mod:`.trace`); time in no span
-goes to ``unattributed``. A leaf in ``contracts.TIMER_ONLY_SPANS`` is
+goes to ``unattributed`` — unless the shard runner's slot thread (the
+one that holds ``contracts.DRIVER_SPANS``) fed the same device: a
+shard's pipeline feeds from threads born inside ``exec.shard``, so what
+such a thread holds in no span of its own is cut by the slot thread's
+spans (``idle.exec.*``: the commit, the extract, the index, the merge,
+``exec.shard``'s own time; and the stitch before them). A leaf in ``contracts.TIMER_ONLY_SPANS`` is
 read through: its stretch stays its parent's. The result is the run
 report's ``device_time`` section and the timers ``idle.<span>`` /
 ``idle.unattributed``, which by construction sum to the idle seconds.
@@ -107,7 +112,10 @@ class _Watcher(threading.Thread):
 
     def run(self) -> None:
         while True:
-            entry, watch = self.inbox.get()
+            item = self.inbox.get()
+            if item is None:        # stop_watchers
+                return
+            entry, watch = item
             try:
                 # graftlint: disable=host-sync-in-hot-loop (this thread exists to block: it is the ledger's completion stamp, off every dispatch path)
                 watch.block_until_ready()
@@ -176,6 +184,19 @@ def reset() -> None:
         _evicted = 0
 
 
+def stop_watchers() -> None:
+    """Stop and join the watcher threads, once they have stamped what
+    they were given. A process keeps its watchers; a test that counts
+    them hands the next one a process with none."""
+    with _cond:
+        watchers = list(_watchers.values())
+        _watchers.clear()
+    for watcher in watchers:
+        watcher.inbox.put(None)
+    for watcher in watchers:
+        watcher.join(FLUSH_TIMEOUT_S)
+
+
 def dispatch_counts(scope: str = "") -> Dict[tuple, int]:
     """``{(program, geometry): exec submissions}`` of this run (of
     ``scope``'s job): how often each executable really ran. ``warm``
@@ -235,22 +256,50 @@ def _flatten(events: list, start_ns: int, end_ns: int) -> tuple:
             [n for _, _, n in segs])
 
 
-def _charge(flat: tuple, a: int, b: int) -> Dict[str, int]:
+def _charge(flat: tuple, a: int, b: int, under: tuple = None
+            ) -> Dict[str, int]:
     """Nanoseconds of ``[a, b]`` by innermost span of one thread's
-    flattened spans; what no span covers is ``unattributed``."""
+    flattened spans; what no span covers is cut by ``under`` (another
+    flattened span list) where one is given, and is ``unattributed``
+    where that covers nothing either."""
     starts, ends, names = flat
     out: Dict[str, int] = {}
-    covered = 0
+
+    def bare(x: int, y: int) -> None:
+        cut = {UNATTRIBUTED: y - x} if under is None \
+            else _charge(under, x, y)
+        for k, v in cut.items():
+            out[k] = out.get(k, 0) + v
+
+    pos = a
     i = max(0, bisect.bisect_right(ends, a))
     while i < len(starts) and starts[i] < b:
-        ns = min(ends[i], b) - max(starts[i], a)
-        if ns > 0:
-            out[names[i]] = out.get(names[i], 0) + ns
-            covered += ns
+        lo, hi = max(starts[i], a), min(ends[i], b)
+        if hi > lo:
+            if lo > pos:
+                bare(pos, lo)
+            out[names[i]] = out.get(names[i], 0) + hi - lo
+            pos = hi
         i += 1
-    if b - a > covered:
-        out[UNATTRIBUTED] = b - a - covered
+    if b > pos:
+        bare(pos, b)
     return out
+
+
+def _boundary_idle(occupied: list, idle: list, shards: list) -> int:
+    """Idle nanoseconds of one device at its shard boundaries: for two
+    ``exec.shard`` spans ``(t0, t1)`` that follow each other, from the
+    end of the last occupied interval begun inside the first (its ``t1``
+    where it gave the device nothing) to the begin of the first occupied
+    interval inside the second (its ``t0`` where it gave nothing)."""
+    total = 0
+    for (t0, t1), (u0, u1) in zip(shards, shards[1:]):
+        lo = max((end for begin, end, _ in occupied if t0 <= begin < t1),
+                 default=t1)
+        hi = min((begin for begin, _, _ in occupied if u0 <= begin < u1),
+                 default=u0)
+        total += sum(max(0, min(b, hi) - max(a, lo)) for a, b, _ in idle)
+    return total
 
 
 def _device_rows(rows: list, start_ns: int, end_ns: int) -> tuple:
@@ -323,6 +372,14 @@ def account(rows: list, spans: dict, start_ns: int, end_ns: int,
                 start_ns, end_ns)
         return flats[thread]
 
+    slots: dict = {}
+
+    def slot_thread(thread) -> bool:
+        if thread not in slots:
+            slots[thread] = any(ev[0] in contracts.DRIVER_SPANS
+                                for ev in spans.get(thread, ()))
+        return slots[thread]
+
     by_device: Dict[str, list] = {}
     for device, *row in rows:
         by_device.setdefault(str(device), []).append(tuple(row))
@@ -330,6 +387,17 @@ def account(rows: list, spans: dict, start_ns: int, end_ns: int,
     for device, drows in sorted(by_device.items() or [("0", [])]):
         occupied, by_program = _device_rows(drows, start_ns, end_ns)
         busy, idle = _idle_intervals(occupied, start_ns, end_ns)
+        # the shard runner's slot thread, where it fed this device (it
+        # dispatches its shards' consensus groups): what a shard's own
+        # feeding thread holds in no span is cut by that thread's spans
+        drive = [ev for thread in {r[2] for r in drows if r[0] != WARM}
+                 | {report_thread} if slot_thread(thread)
+                 for ev in spans.get(thread, ())
+                 if ev[0] not in contracts.TIMER_ONLY_SPANS]
+        driver = _flatten(drive, start_ns, end_ns) if drive else None
+        shard_spans = sorted(
+            (max(t0, start_ns), end_ns if t1 is None else min(t1, end_ns))
+            for name, t0, t1 in drive if name == "exec.shard")
         idle_by: Dict[str, int] = {}
         gaps = []
         head = tail = 0
@@ -341,7 +409,7 @@ def account(rows: list, spans: dict, start_ns: int, end_ns: int,
                 # submission's thread is who kept the device waiting
                 i = next((j for j in feeders if j > i), None)
             thread = report_thread if i is None else drows[i][2]
-            cut = _charge(flat(thread), a, b)
+            cut = _charge(flat(thread), a, b, driver)
             for k, v in cut.items():
                 idle_by[k] = idle_by.get(k, 0) + v
             gaps.append((b - a, a, b, cut))
@@ -354,11 +422,14 @@ def account(rows: list, spans: dict, start_ns: int, end_ns: int,
         for thread in {drows[j][2] for j in feeders} | {report_thread}:
             for name in set(flat(thread)[2]):
                 idle_by.setdefault(name, 0)
+        for name in set(driver[2]) if driver else ():
+            idle_by.setdefault(name, 0)
         idle_by.setdefault(UNATTRIBUTED, 0)
         gaps.sort(key=lambda g: (-g[0], g[1]))
         per_device[device] = {
             "busy": busy, "idle": sum(b - a for a, b, _ in idle),
             "head": head, "tail": tail, "programs": len(drows),
+            "boundary": _boundary_idle(occupied, idle, shard_spans),
             "by_program": by_program, "idle_by": idle_by,
             "gaps": [[a, b, {k: _s(v) for k, v in sorted(cut.items())}]
                      for _, a, b, cut in gaps[:GAP_ROWS]]}
@@ -389,6 +460,9 @@ def account(rows: list, spans: dict, start_ns: int, end_ns: int,
         "window_s": _s(end_ns - start_ns),
         "busy_s": mean("busy"), "idle_s": mean("idle"),
         "head_idle_s": mean("head"), "tail_idle_s": mean("tail"),
+        # idle between one shard's last device interval and the next
+        # one's first, summed over the shard boundaries (0: no shards)
+        "boundary_idle_s": mean("boundary"),
         "programs": len(rows),
         "by_program": programs(by_program),
         "idle_by": {k: _s(v) for k, v in sorted(idle_by.items())},
@@ -462,4 +536,6 @@ def summary(scope: str = "", window_s: float = 0.0,
     out["dropped"] = max(0, len(entries) - TIMELINE_ROWS) + evicted
     out["clock"] = trace.clock()
     metrics.replace_timers(IDLE_PREFIX, out["idle_by"], scope)
+    # the report's shard_run section reads it beside its counters
+    metrics.set_gauge("exec.boundary_idle_s", out["boundary_idle_s"])
     return out

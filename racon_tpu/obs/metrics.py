@@ -416,6 +416,40 @@ def rounds_summary(scope: str = "") -> dict:
     }
 
 
+def shard_run_summary(scope: str = "") -> Dict[str, Number]:
+    """The run report's ``shard_run`` section (schema v15): the job of
+    the shard runner as it counted it in this process — shards done,
+    those of them on the slot's device engines at the first attempt,
+    those that needed another; the first and the last done shard's wall
+    (running state saved to terminal state saved) and backend compiles; the bytes of the
+    parts and of the extracted inputs; and ``boundary_idle_s``, the
+    device-idle seconds between one shard's last device interval and
+    the next one's first, summed over the boundaries (from the
+    occupancy ledger: ``device_time.summary`` writes the gauge). Plain
+    keys, so a metric's reader can reach them; the per-shard rows stay
+    under the report's ``shards``. All zeros where no shard runner
+    ran."""
+    with _lock:
+        def counted(name: str) -> int:
+            return int(_counters.get(f"{scope}exec.{name}", 0))
+
+        def gauged(name: str):
+            return _gauges.get(f"{scope}exec.{name}", 0)
+
+        return {
+            "count": counted("shards_done"),
+            "primary": counted("shards_primary"),
+            "retried": counted("shards_retried"),
+            "first_wall_s": round(gauged("first_shard_wall_s"), 6),
+            "last_wall_s": round(gauged("last_shard_wall_s"), 6),
+            "first_compiles": int(gauged("first_shard_compiles")),
+            "last_compiles": int(gauged("last_shard_compiles")),
+            "boundary_idle_s": round(gauged("boundary_idle_s"), 6),
+            "part_bytes": counted("part_bytes"),
+            "extract_bytes": counted("extract_bytes"),
+        }
+
+
 def recovery_summary() -> Dict[str, Number]:
     """The crash-safe-serving counters the run report's ``recovery``
     section (schema v5) embeds: journal replay/append/compaction

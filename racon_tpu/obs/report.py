@@ -121,6 +121,18 @@ from .. import contracts
 # row per round under "rows".  The job's counters and span timers sum
 # over its rounds, as before; spans ``round`` / ``round.handoff`` and
 # counters ``rounds.*`` say which round cost what.
+# v15 (PR 43): the "shard_run" section became required — the shard
+# runner's job as it counted it ("count" shards done in this process,
+# "primary" of them on the slot's device engines at the first attempt,
+# "retried"; the first and the last done shard's wall and backend
+# compiles; "part_bytes" / "extract_bytes"; "boundary_idle_s": device
+# idle between one shard's last device interval and the next one's
+# first, from the occupancy ledger), all zeros where no shard runner
+# ran; the per-shard rows stay under "shards".  Spans ``exec.commit``
+# (part write + state saves) and ``exec.drain`` (a slot's one wait for
+# its shards' warm-ups) joined the exec spans, and device idle a
+# shard's feeding thread holds in no span of its own is cut by the
+# slot thread's exec.* spans (``idle.exec.*``).
 # the schema's key sets (per section, per version) live in
 # racon_tpu/contracts.py — ONE registry shared with the schema-coherence
 # lint rule, so a schema bump is a contracts.py edit the gate enforces
@@ -157,6 +169,7 @@ _TOP = {
     "fleet": (dict, True),              # fleet gateway counters (v11)
     "device_time": (dict, True),        # device-occupancy ledger (v12)
     "rounds": (dict, True),             # the job's rounds (v14)
+    "shard_run": (dict, True),          # the shard runner's job (v15)
     "devices": (dict, True),            # per-chip rows ({} single-chip)
     "peak_rss_bytes": (int, True),
     "metrics": (dict, True),            # full registry snapshot
@@ -185,8 +198,10 @@ _FLEET_KEYS = tuple(sorted(_SCHEMA_KEYS["fleet"]))
 _ROUNDS_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["rounds"] - {"rows"}))
 _ROUND_ROW_KEYS = ("round", "wall_s", "handoff_s", "compiles",
                    "overlaps_kept")
+_SHARD_RUN_KEYS = tuple(sorted(_SCHEMA_KEYS["shard_run"]))
 _DEVICE_TIME_NUM_KEYS = ("window_s", "busy_s", "idle_s", "head_idle_s",
-                         "tail_idle_s", "programs", "dropped")
+                         "tail_idle_s", "boundary_idle_s", "programs",
+                         "dropped")
 # "mode" is the one string key of the overlap section
 _OVERLAP_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["overlap"] - {"mode"}))
 _OVERLAP_MODES = contracts.OVERLAP_MODES
@@ -313,6 +328,11 @@ def build_report(kind: str, *, argv: Optional[list] = None,
         # the job's rounds (schema v14): count, the first and the last
         # round's wall / compiles / kept overlaps, a row per round
         "rounds": metrics.rounds_summary(scope),
+        # the shard runner's job (schema v15): shards done / on the
+        # device engines at the first attempt / retried, the first and
+        # the last shard's wall and compiles, the idle at the shard
+        # boundaries (device_time.summary, above, wrote its gauge)
+        "shard_run": metrics.shard_run_summary(scope),
         "peak_rss_bytes": metrics.peak_rss_bytes(),
         "metrics": metrics.snapshot(scope or None),
     }
@@ -342,15 +362,17 @@ def _check_numeric_dict(errors: List[str], d: dict, where: str) -> None:
             errors.append(f"{where}[{k!r}] is not a numeric value: {v!r}")
 
 
-def _check_device_time(errors: List[str], dt: dict) -> None:
+def _check_device_time(errors: List[str], dt: dict,
+                       version: int) -> None:
     where = "device_time"
-    for key in sorted(_SCHEMA_KEYS[where] - set(dt)):
+    keys = contracts.schema_keys(version)[where]
+    for key in sorted(keys - set(dt)):
         errors.append(f"{where}[{key!r}] missing")
-    for key in sorted(set(dt) - _SCHEMA_KEYS[where]):
+    for key in sorted(set(dt) - keys):
         errors.append(f"{where} unknown key {key!r}")
     if errors:
         return
-    for key in _DEVICE_TIME_NUM_KEYS:
+    for key in keys.intersection(_DEVICE_TIME_NUM_KEYS):
         if not isinstance(dt[key], _NUM) or isinstance(dt[key], bool):
             errors.append(f"{where}[{key!r}] non-numeric")
     for key in ("idle_by", "clock"):
@@ -498,9 +520,15 @@ def validate_report(rep) -> List[str]:
             errors.append(f"overlap[{key!r}] missing or non-numeric")
     _check_compiles(errors, rep["compiles"], version)
     if "device_time" in top:
-        _check_device_time(errors, rep["device_time"])
+        _check_device_time(errors, rep["device_time"], version)
     if "rounds" in top:
         _check_rounds(errors, rep["rounds"])
+    if "shard_run" in top:
+        for key in _SHARD_RUN_KEYS:
+            if not _is_num(rep["shard_run"].get(key)):
+                errors.append(f"shard_run[{key!r}] missing or non-numeric")
+        for key in sorted(set(rep["shard_run"]) - set(_SHARD_RUN_KEYS)):
+            errors.append(f"shard_run unknown key {key!r}")
     for kind in ("counters", "gauges", "timers"):
         store = rep["metrics"].get(kind)
         if not isinstance(store, dict):
@@ -568,6 +596,35 @@ def rounds_table(rounds: dict, counters: Optional[dict] = None) -> str:
     return "\n".join(lines)
 
 
+def shards_table(rep: dict) -> str:
+    """The shard runner's job for people: the ``shard_run`` section,
+    one line per shard row, and where the host held the device while it
+    drove them (the ``idle.exec.*`` timers)."""
+    run = rep.get("shard_run") or {}
+    timers = (rep.get("metrics") or {}).get("timers") or {}
+    lines = [f"shards done {run.get('count', 0)} (on the device engines "
+             f"at the first attempt {run.get('primary', 0)}, retried "
+             f"{run.get('retried', 0)}); parts {run.get('part_bytes', 0)} "
+             f"B, extracted inputs {run.get('extract_bytes', 0)} B",
+             f"first shard {run.get('first_wall_s', 0.0):.3f} s "
+             f"({run.get('first_compiles', 0)} compiles), last "
+             f"{run.get('last_wall_s', 0.0):.3f} s "
+             f"({run.get('last_compiles', 0)} compiles); device idle at "
+             f"the shard boundaries {run.get('boundary_idle_s', 0.0):.3f} s",
+             f"{'shard':>5} {'status':>11} {'engine':>9} {'mbp':>7} "
+             f"{'wall_s':>8} {'extract_s':>9} {'attempts':>8}"]
+    lines += [f"{r['id']:>5} {r['status']:>11} {r.get('engine', '-'):>9} "
+              f"{r.get('mbp', 0.0):>7.3f} {r.get('wall_s', 0.0):>8.2f} "
+              f"{r.get('extract_s', 0.0):>9.2f} "
+              f"{len(r.get('attempts') or []):>8}"
+              for r in rep.get("shards", [])]
+    spans = sorted(contracts.DRIVER_SPANS)
+    lines.append("host seconds / device idle under them: " + ", ".join(
+        f"{name} {timers.get(name, 0.0):.3f} / "
+        f"{timers.get('idle.' + name, 0.0):.3f}" for name in spans))
+    return "\n".join(lines)
+
+
 def _main(argv) -> int:
     if len(argv) == 2 and argv[0] == "--check":
         try:
@@ -596,10 +653,15 @@ def _main(argv) -> int:
         print(rounds_table(rep.get("rounds") or {},
                            (rep.get("metrics") or {}).get("counters")))
         return 0
+    if len(argv) == 2 and argv[0] == "shards":
+        with open(argv[1], "rb") as f:
+            print(shards_table(json.loads(f.read())))
+        return 0
     print("usage: python -m racon_tpu.obs --check FILE\n"
           "       python -m racon_tpu.obs gaps RUN_REPORT DEVICE_TRACE\n"
           "       python -m racon_tpu.obs compiles RUN_REPORT\n"
-          "       python -m racon_tpu.obs rounds RUN_REPORT",
+          "       python -m racon_tpu.obs rounds RUN_REPORT\n"
+          "       python -m racon_tpu.obs shards RUN_REPORT",
           file=sys.stderr)
     return 2
 

@@ -1846,7 +1846,14 @@ class TpuPoaConsensus(PallasDispatchMixin):
             jax.block_until_ready(out[10])
             jax.block_until_ready(gat)
 
+        # behind the warm-up before it, where a polisher that waits for
+        # none (``final`` off) left one running: the last thread's end
+        # is then every thread's, and drain_warmup waits for them all
+        before = self._warmup
+
         def _compile():
+            if before is not None:
+                before.join()
             try:
                 with self._pinned():
                     for shape in shapes:

@@ -246,6 +246,30 @@ def test_consensus_group_program_fits_the_chip(chip):
         f"{total / GIB:.2f} GiB"
 
 
+def test_shard_consensus_group_program_fits_the_chip(chip):
+    """What a shard of ``frag2m-shards4-paf30x`` adds: 0.5 Mbp at 30x is
+    about 31,150 pairs over 1,000 windows, under the arena's 32,768, so
+    a shard's windows close as ONE group at ``finish`` and run the full
+    round budget in one stage — where the one-shot job of the same
+    2 Mbp runs four stage-A groups of two rounds and a repack. The
+    first shard's warm-up derives the same shape; the groups run it at
+    the sweep their layers need (1,152 steps, not the estimate's
+    1,280)."""
+    eng = _consensus_engine()
+    Lq, Lb, band, steps, Lq2, B, nWp, rounds = eng._warmup_shapes(
+        500, 2_142 * 15, 1_002, 564, 2)[0]
+    assert (Lq, Lb, band, steps, Lq2, B, nWp, rounds) == (
+        1024, 768, 512, 1280, 640, poa.MAX_GROUP_PAIRS, 1024, eng.rounds)
+    compiled, _, total = _compile(poa._refine_loop_packed.lower(
+        *_refine_args(chip, Lq, Lb, B, nWp), rounds=rounds,
+        n_windows=nWp, max_len=Lq, band=band, Lb=Lb, K=poa.K_INS,
+        steps=1152, use_pallas=True, use_swar=True, Lq2=Lq2,
+        scores=eng.scores, matmul_votes=eng.use_matmul_votes))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert total + poa.MAX_INFLIGHT_BYTES < HBM_BYTES, \
+        f"{total / GIB:.2f} GiB"
+
+
 def test_short_read_consensus_group_program_fits_the_chip(chip):
     """The group the short-read cell forms: 32,768 rows of at most 150
     bases in ``Lq`` 1,024, 200 to a window, so 256 window rows where
