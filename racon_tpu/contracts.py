@@ -79,6 +79,12 @@ METRICS: FrozenSet[str] = frozenset((
     "consensus.groups", "consensus.ins_overflow",
     "consensus.ins_overflow_windows", "consensus.lanes_occupied",
     "consensus.lanes_total", "consensus.pallas_groups",
+    # the two-stage schedule: first-stage groups dispatched and those
+    # of them sent for STAGE_A_ROUNDS with their survivors collected;
+    # real windows of the stage-A groups fetched, and those of them
+    # neither converged nor frozen (repacked, or continued in place)
+    "consensus.first_stage_groups", "consensus.stage_a_groups",
+    "consensus.stage_a_windows", "consensus.stage_a_survivors",
     # the depth cap: windows and layers offered to the packer (before
     # the cap; dropped_layers counts the layers past it) and windows
     # that lost at least one

@@ -43,13 +43,20 @@ one-block-per-group graph POA, consensus is computed as a
    (n = m = 0 pairs, which the Pallas kernels' per-block dynamic bounds
    skip nearly for free), the loop exits early once every window is
    converged or frozen, and after ``STAGE_A_ROUNDS`` a mostly-converged
-   group re-packs its few stragglers ~25x smaller for the remaining
-   rounds (clean high-coverage windows reach their fixed point in ~2
-   rounds; noisy real windows often never reproduce byte-exactly, so a
-   mostly-live group instead continues in place on its device-resident
-   state). Recorded goldens are unchanged by all three mechanisms:
-   converged/frozen windows reject updates, so skipped rounds are
-   provably no-ops.
+   group re-packs its few stragglers up to ``STAGE_B_MAX_SHRINK`` times
+   smaller on each axis for the remaining rounds (clean high-coverage
+   windows reach their fixed point in ~2 rounds; noisy real windows
+   often never reproduce byte-exactly, so a mostly-live group instead
+   continues in place on its device-resident state). Every group of
+   ``TWO_STAGE_MIN_PAIRS`` rows or more takes that schedule, alone in
+   its bucket or not (:meth:`TpuPoaConsensus.first_stage_rounds`): the
+   early exit fires only once EVERY window is done and the vote's
+   routing runs dense over all pair rows whatever has converged, so on
+   the chip a lone full-size group that ran its whole budget in one
+   dispatch swept all its windows six times where two sweeps and a
+   small repack do. Recorded goldens are unchanged by all three
+   mechanisms: converged/frozen windows reject updates, so skipped
+   rounds are provably no-ops.
 
 Like the reference's GPU path, this engine is allowed to differ slightly
 from the CPU spoa-semantics engine (upstream records separate CUDA goldens:
@@ -147,6 +154,17 @@ STAGE_B_MAX_SURVIVOR_FRAC = 0.5
 # ``(B, nWp)`` is a Mosaic program: 12-20 s to compile and, compiled
 # in-line at the job's heap peak, 1.3 GB of host memory.
 STAGE_B_MAX_SHRINK = 8
+# Padded pair rows from which a group takes the two-stage schedule: the
+# largest group the repack's floor would not shrink at all were it the
+# full arena's. On the chip (PERF.md §6, PR 44) a lone group's six
+# rounds in one dispatch took 0.098 / 0.182 / 0.423 s of device time at
+# 2,048 / 4,096 / 8,192 rows and 2.2 s at 32,768; split, 0.084 / 0.157 /
+# 0.335 and 1.5, for a round trip (fetch, repack, upload, dispatch) of
+# 0.007 s at the small sizes and 0.05 s at the arena's: 6 / 19 / 81 ms
+# and 0.7 s a group won. Under this the split wins less than a
+# hundredth of a second and still brings one more Mosaic program to
+# compile (12-20 s cold) for every small job's own ``(B, nWp)``.
+TWO_STAGE_MIN_PAIRS = MAX_GROUP_PAIRS // STAGE_B_MAX_SHRINK
 # Vote channels: A C G T N DEL (stride 8 for cheap addressing).
 CH = 8
 A, C, G, T, N_CODE, DEL = 0, 1, 2, 3, 4, 5
@@ -1050,12 +1068,13 @@ class _ConsensusStream:
     windows are independent and the vote accumulation is exact integer
     arithmetic at any grouping.
 
-    Two-stage refinement carries over per bucket: groups dispatched
-    while more work is expected run ``STAGE_A_ROUNDS`` and collect their
-    unconverged windows; :meth:`finish` coalesces each bucket's
-    stragglers into small stage-B groups (a bucket whose only group is
-    its last runs the full budget directly, like the padded path's
-    single-group rule)."""
+    Two-stage refinement carries over per bucket: a group of
+    ``TWO_STAGE_MIN_PAIRS`` rows or more runs ``STAGE_A_ROUNDS`` and
+    collects its unconverged windows, whether or not another group
+    shares its bucket (:meth:`TpuPoaConsensus.first_stage_rounds`, the
+    padded path's rule too); :meth:`finish` coalesces each bucket's
+    stragglers into small stage-B groups. A smaller group runs the
+    full budget in its one dispatch."""
 
     def __init__(self, eng: "TpuPoaConsensus", trim: bool,
                  band_hint: int = 0, progress=None):
@@ -1071,7 +1090,7 @@ class _ConsensusStream:
         self._Lq_pad = 0                   # padded-path reject caps,
         self._Lb_pad = 0                   # set when the band freezes
         self.pending: dict = {}            # bucket L -> [(slot, work)]
-        self.bucket_state: dict = {}       # bucket L -> {groups,steps,Lq2}
+        self.bucket_state: dict = {}       # bucket L -> {steps,Lq2}
         self.survivors: dict = {}          # bucket L -> stage-B collect
         self.inflight: List[dict] = []
         self.inflight_bytes = 0
@@ -1181,12 +1200,11 @@ class _ConsensusStream:
                         break
                     pairs += w.n_layers
                     group.append(items.pop(0))
-                more = bool(items) or not final
-                self._dispatch(L, group, more_expected=more)
+                self._dispatch(L, group)
             if not items:
                 del self.pending[L]
 
-    def _dispatch(self, L: int, group: List, more_expected: bool) -> None:
+    def _dispatch(self, L: int, group: List) -> None:
         eng = self.eng
         band = self.band
         Lq = L + band
@@ -1196,24 +1214,18 @@ class _ConsensusStream:
             for _, w in group)
         max_n = max(w.max_layer_len for _, w in group)
         steps, Lq2 = eng._sweep_geometry(Lq, max_nm, max_n)
-        bk = self.bucket_state.setdefault(
-            L, {"groups": 0, "steps": 0, "Lq2": 0})
+        bk = self.bucket_state.setdefault(L, {"steps": 0, "Lq2": 0})
         bk["steps"] = max(bk["steps"], steps)
         bk["Lq2"] = max(bk["Lq2"], Lq2)
-        two_stage = (eng.rounds > STAGE_A_ROUNDS
-                     and (more_expected or bk["groups"] > 0))
         la = eng._launch_group(group, Lq, Lb)
         la["geom"] = (Lq, Lb, steps, Lq2)
         la["band"] = band
-        la["rounds"] = (min(eng.rounds, STAGE_A_ROUNDS) if two_stage
-                        else eng.rounds)
         la["bucket"] = L
-        la["collect"] = two_stage
+        eng._first_stage(la)
         # resident bytes of this launch (packed pair inputs + per-window
         # state + coalesced fetch arrays) — the in-flight budget's unit
         la["bytes"] = (2 * Lq + 24) * la["B"] + 16 * Lb * la["nWp"]
         eng._rounds(la, Lq, Lb, steps, Lq2)
-        bk["groups"] += 1
         self.inflight.append(la)
         self.inflight_bytes += la["bytes"]
         while (len(self.inflight) > max(eng.num_batches, 1)
@@ -1434,6 +1446,27 @@ class TpuPoaConsensus(PallasDispatchMixin):
         return max(2048, min(self.arena_lanes_cap // (L + band),
                              4 * self.group_pairs_cap))
 
+    def first_stage_rounds(self, B: int) -> int:
+        """THE two-stage rule: the rounds a first-stage group of ``B``
+        padded pair rows runs. ``STAGE_A_ROUNDS`` where the rounds it
+        skips pay for the round trip of a second stage (fetch, repack,
+        upload, dispatch) — by the group's own size, whatever else its
+        bucket holds — and the whole budget otherwise. A group that
+        runs fewer than ``self.rounds`` collects its survivors. Shared
+        by the ragged stream, the padded path and
+        :meth:`_warmup_shapes`."""
+        if self.rounds > STAGE_A_ROUNDS and B >= TWO_STAGE_MIN_PAIRS:
+            return STAGE_A_ROUNDS
+        return self.rounds
+
+    def _first_stage(self, launch) -> None:
+        """Give a packed first-stage group its schedule (``rounds``,
+        ``collect``) and count it."""
+        launch["rounds"] = self.first_stage_rounds(launch["B"])
+        launch["collect"] = launch["rounds"] < self.rounds
+        metrics.inc("consensus.first_stage_groups")
+        metrics.inc("consensus.stage_a_groups", int(launch["collect"]))
+
     @staticmethod
     def bucket_L_for(L_req: int) -> Optional[int]:
         """THE power-of-two lane-width rule: the smallest pow2 bucket
@@ -1591,14 +1624,15 @@ class TpuPoaConsensus(PallasDispatchMixin):
             # at full group size; windows still unconverged after it are
             # re-packed (with their refined backbones and remapped spans)
             # into far smaller stage-B groups for the remaining rounds.
-            # Single-group runs skip the split: a lone group's stage-B
-            # launch cannot coalesce anything, so the split only adds a
-            # host round trip there — the monolithic dispatch with the
-            # in-loop early exit is strictly better.
-            two_stage = self.rounds > STAGE_A_ROUNDS and len(groups) > 1
-            survivors = [] if two_stage else None
-            ra = min(self.rounds, STAGE_A_ROUNDS) if two_stage \
-                else self.rounds
+            # Which groups split is first_stage_rounds' to say, by their
+            # size alone: on the chip a lone group of 32,768 rows that
+            # ran its six rounds in one dispatch cost 0.58 s more than
+            # the split (ledger, PR 43: four such groups a job, 9.26 s
+            # of consensus programs against the one-shot job's 6.95; the
+            # in-loop early exit waits for every window, and the vote's
+            # routing does not shrink with convergence), so being alone
+            # in a run is no reason.
+            survivors: List = []
             # per-launch resident bytes: packed pair inputs (the qpw
             # uint16 lanes are 2*Lq bytes/pair — codes and weights
             # travel in ONE array; +24 covers n/bg/ed/win_of/real) PLUS
@@ -1612,11 +1646,17 @@ class TpuPoaConsensus(PallasDispatchMixin):
                            + 16 * Lb * nWp_max)
             inflight_cap = max(self.num_batches,
                                MAX_INFLIGHT_BYTES // max(group_bytes, 1))
+
+            def finish(la):
+                self._finish_group(
+                    la, trim, results,
+                    collect=survivors if la["collect"] else None)
+
             for g in groups:
                 la = self._launch_group(g, Lq, Lb)
                 la["geom"] = (Lq, Lb, steps, Lq2)
                 la["band"] = band
-                la["rounds"] = ra
+                self._first_stage(la)
                 self._rounds(la, Lq, Lb, steps, Lq2)
                 done_units += 1
                 if progress is not None:
@@ -1627,10 +1667,9 @@ class TpuPoaConsensus(PallasDispatchMixin):
                     progress(done_units, total_units)
                 inflight.append(la)
                 if len(inflight) > inflight_cap:
-                    self._finish_group(inflight.pop(0), trim, results,
-                                       collect=survivors)
+                    finish(inflight.pop(0))
             for la in inflight:
-                self._finish_group(la, trim, results, collect=survivors)
+                finish(la)
             if survivors:
                 self._run_stage_b(survivors, trim, results,
                                   Lq, Lb, steps, Lq2, band)
@@ -1708,24 +1747,21 @@ class TpuPoaConsensus(PallasDispatchMixin):
         depth = max(1.0, est_pairs / max(1, est_windows))
         shapes = []
 
-        def add(L_b, pairs, wins, rounds):
+        def add(L_b, pairs, wins):
             lq = L_b + band
             lb = min(L_b + GROW, lq)
             ell = min(est_layer_len or window_length + 64, lq)
             max_nm = ell + min(ell + 64, lb)
             steps, Lq2 = self._sweep_geometry(lq, max_nm, ell)
-            shapes.append((lq, lb, band, steps, Lq2,
-                           self._pow2_at_least(pairs),
-                           self._pow2_at_least(wins + 1), rounds))
+            B = self._pow2_at_least(pairs)
+            shapes.append((lq, lb, band, steps, Lq2, B,
+                           self._pow2_at_least(wins + 1),
+                           self.first_stage_rounds(B)))
 
         if not self.use_ragged:
             cap = self.group_pairs_cap
             n_groups = max(self.num_batches, -(-est_pairs // cap))
-            rounds = (min(self.rounds, STAGE_A_ROUNDS)
-                      if self.rounds > STAGE_A_ROUNDS and n_groups > 1
-                      else self.rounds)
-            add(L, -(-est_pairs // n_groups),
-                -(-est_windows // n_groups), rounds)
+            add(L, -(-est_pairs // n_groups), -(-est_windows // n_groups))
             return shapes
 
         # ragged stream geometry: windows bucket by their own
@@ -1735,7 +1771,10 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # undershoots that shape whenever the estimate is not an exact
         # multiple of the cap, wasting the warm compile precisely on
         # big runs. A run smaller than one arena dispatches a single
-        # group of everything at the full round budget.
+        # group of everything — at the rounds its own size gives it, as
+        # every group (first_stage_rounds): a shard of 31,150 pairs runs
+        # stage A's two, and a warm-up at the full budget compiled a
+        # program no group ran.
         max_dev_L = (1 << 18) // (K_INS * CH) - GROW
         # the dominant bucket width through THE shared pow2 rule (the
         # L_req is capped at the device ceiling, so this never rejects)
@@ -1744,24 +1783,19 @@ class TpuPoaConsensus(PallasDispatchMixin):
         if est_pairs > cap:
             wins = min(est_windows, max(1, int(cap / depth)),
                        MAX_GROUP_WINDOWS)
-            # full groups dispatch with more work expected -> stage A
-            rounds = (min(self.rounds, STAGE_A_ROUNDS)
-                      if self.rounds > STAGE_A_ROUNDS else self.rounds)
-            add(Ld, cap, wins, rounds)
+            add(Ld, cap, wins)
         else:
-            add(Ld, est_pairs, min(est_windows, MAX_GROUP_WINDOWS),
-                self.rounds)
+            add(Ld, est_pairs, min(est_windows, MAX_GROUP_WINDOWS))
         # contig-tail windows (<= one per contig, shorter than the
         # window length) coalesce in the half-width bucket and flush as
-        # one lone full-budget group at finish
+        # one group at finish: a few rows, so the full budget at once
         if est_contigs > 0 and Ld > 256 and est_pairs > cap:
             # capped like any greedy-filled group: a fragmented assembly
             # (10^5 contigs) must not warm a multi-GB batch the stream
             # would never dispatch
             t_pairs = min(max(1, int(est_contigs * depth)),
                           self.cap_pairs_for(Ld // 2, band))
-            add(Ld // 2, t_pairs, min(est_contigs, MAX_GROUP_WINDOWS),
-                self.rounds)
+            add(Ld // 2, t_pairs, min(est_contigs, MAX_GROUP_WINDOWS))
         return shapes
 
     def warmup_async(self, window_length: int, est_pairs: int,
@@ -2384,16 +2418,15 @@ class TpuPoaConsensus(PallasDispatchMixin):
 
     def _mostly_unconverged(self, launch, host) -> bool:
         """Stage A's decision point: repack the stragglers only when
-        few survive."""
+        few survive. Counts what stage A left, the one place its
+        ``conv`` / ``frozen`` are walked for it."""
         shards, nWp = launch["shards"], launch["nWp"]
-        conv_h, frozen_h = host["conv"], host["frozen"]
+        done = host["conv"].astype(bool) | host["frozen"].astype(bool)
         n_real = sum(len(sh) for sh in shards)
-        n_surv = 0
-        for s, sh in enumerate(shards):
-            for wi in range(len(sh)):
-                row = s * nWp + wi
-                if not conv_h[row] and not frozen_h[row]:
-                    n_surv += 1
+        n_surv = sum(len(sh) - int(done[s * nWp:s * nWp + len(sh)].sum())
+                     for s, sh in enumerate(shards))
+        metrics.inc("consensus.stage_a_windows", n_real)
+        metrics.inc("consensus.stage_a_survivors", n_surv)
         return n_surv > STAGE_B_MAX_SURVIVOR_FRAC * n_real
 
     def _decode_group(self, launch, host, trim: bool, results,

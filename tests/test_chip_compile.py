@@ -249,17 +249,23 @@ def test_consensus_group_program_fits_the_chip(chip):
 def test_shard_consensus_group_program_fits_the_chip(chip):
     """What a shard of ``frag2m-shards4-paf30x`` adds: 0.5 Mbp at 30x is
     about 31,150 pairs over 1,000 windows, under the arena's 32,768, so
-    a shard's windows close as ONE group at ``finish`` and run the full
-    round budget in one stage — where the one-shot job of the same
-    2 Mbp runs four stage-A groups of two rounds and a repack. The
-    first shard's warm-up derives the same shape; the groups run it at
-    the sweep their layers need (1,152 steps, not the estimate's
-    1,280)."""
+    a shard's windows close as ONE group at ``finish`` — which takes
+    the two-stage schedule by its size like any other (PR 44; until
+    then it ran the full round budget in one stage: 24 full-size
+    group-rounds a job where the one-shot job of the same 2 Mbp runs
+    12). Its stage A is 32,768 rows over 1,024 window rows where the
+    one-shot job's groups pad to 2,048; the repack of the 220-255
+    windows it leaves is the repack test's (6,500, 240) below. The
+    first shard's warm-up derives the stage-A shape from the same
+    rule; the groups run it at the sweep their layers need (1,152
+    steps, not the estimate's 1,280)."""
     eng = _consensus_engine()
     Lq, Lb, band, steps, Lq2, B, nWp, rounds = eng._warmup_shapes(
         500, 2_142 * 15, 1_002, 564, 2)[0]
     assert (Lq, Lb, band, steps, Lq2, B, nWp, rounds) == (
-        1024, 768, 512, 1280, 640, poa.MAX_GROUP_PAIRS, 1024, eng.rounds)
+        1024, 768, 512, 1280, 640, poa.MAX_GROUP_PAIRS, 1024,
+        poa.STAGE_A_ROUNDS)
+    assert rounds == eng.first_stage_rounds(B) < eng.rounds
     compiled, _, total = _compile(poa._refine_loop_packed.lower(
         *_refine_args(chip, Lq, Lb, B, nWp), rounds=rounds,
         n_windows=nWp, max_len=Lq, band=band, Lb=Lb, K=poa.K_INS,
@@ -393,20 +399,28 @@ def test_second_round_join_program_fits_the_chip(chip, kept, hits):
     _compile_join(chip, R2, T2, E, Q2)
 
 
-@pytest.mark.parametrize("pairs, windows", [(13_000, 470), (2_000, 65)])
-def test_repack_programs_of_the_rounds_fit_the_chip(chip, pairs, windows):
+@pytest.mark.parametrize("pairs, windows, stage_a, shape", [
+    (13_000, 470, (32768, 2048), (16384, 512)),
+    (2_000, 65, (32768, 2048), (4096, 256)),
+    (6_500, 240, (32768, 1024), (8192, 256))])
+def test_repack_programs_of_the_rounds_fit_the_chip(chip, pairs, windows,
+                                                    stage_a, shape):
     """The consensus engine's second stage in ``bact1m-auto30x-r2``, whose
     stage-A groups are 32,768 rows over 2,048 windows at the largest.
     Round 1 leaves about 470 windows of 13,000 pairs: 16,384 over 512.
     Round 2, on a polished draft, leaves 60-70 of about 2,000, under the
     repack's floor of an eighth (``poa.STAGE_B_MAX_SHRINK``): 4,096 over
     256 (their own powers of two, 2,048 over 64 or 128, moved with the
-    seed, and a run that compiled one read 1.3 GB more host memory)."""
+    seed, and a run that compiled one read 1.3 GB more host memory).
+    Last a shard of ``frag2m-shards4-paf30x`` (PR 44): its lone group of
+    32,768 rows over 1,024 leaves 220-255 windows of 6,000-7,000 pairs,
+    8,192 over 256."""
     eng = _consensus_engine()
-    B = max(eng._pow2_at_least(pairs), 32768 // poa.STAGE_B_MAX_SHRINK)
+    B = max(eng._pow2_at_least(pairs),
+            stage_a[0] // poa.STAGE_B_MAX_SHRINK)
     nWp = max(eng._pow2_at_least(windows + 1),
-              2048 // poa.STAGE_B_MAX_SHRINK)
-    assert (B, nWp) == ((16384, 512) if pairs > 4096 else (4096, 256))
+              stage_a[1] // poa.STAGE_B_MAX_SHRINK)
+    assert (B, nWp) == shape
     Lq, Lb, band, steps, Lq2, rounds = 1024, 768, 512, 1152, 640, 4
     compiled, _, total = _compile(poa._refine_loop_packed.lower(
         *_refine_args(chip, Lq, Lb, B, nWp), rounds=rounds,

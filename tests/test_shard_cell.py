@@ -55,7 +55,9 @@ def jobs(tmp_path_factory):
     """Every job of this module, run once: per flag set the one-shot
     reference and ``--shards 4`` (with a report and a kept shard
     directory), and ``--shards 4`` with a compute fault injected into
-    the second shard's first attempt. ``calls`` counts, per job, the
+    the second shard's first attempt, and ``--shards 4`` with the
+    two-stage threshold under a shard's 256 pair rows (``two_stage``:
+    the cell's shards pass it as they are). ``calls`` counts, per job, the
     consensus warm-ups asked for (``kicked``), the threads they started
     (``started``: the engine starts none for shapes it has warmed) and
     the waits for them (the threshold under which a job kicks none is
@@ -116,6 +118,11 @@ def jobs(tmp_path_factory):
             out["faulted"] = sharded("faulted", [])
         finally:
             mp.undo()
+        mp.setattr(poa, "TWO_STAGE_MIN_PAIRS", 64)
+        try:
+            out["two_stage"] = sharded("two_stage", [])
+        finally:
+            mp.undo()
     finally:
         backends._auto_mesh = auto_mesh
         polisher.WARMUP_MIN_PAIRS = min_pairs
@@ -148,6 +155,27 @@ def test_shards_print_the_one_shot_runs_bytes(jobs, tag):
     names = [line.split()[0] for line in fasta.split(b"\n")
              if line.startswith(b">")]
     assert names == [b">contig_%d" % i for i in range(7)]
+
+
+def test_two_stage_shards_print_the_one_shot_runs_bytes(jobs):
+    """Every shard's lone consensus group sent for stage A's rounds and
+    its survivors repacked (or continued in place), as the cell's
+    shards of 31,150 pairs are since PR 44: the one-shot run's bytes,
+    which ran every group's whole budget in one dispatch."""
+    fasta = jobs["two_stage"]["fasta"]
+    assert fasta == jobs["one"]["default"]
+    c = jobs["two_stage"]["report"]["metrics"]["counters"]
+    # (a shard's contig-tail windows form a group of their own in the
+    # half-width bucket, a few rows: one stage at any threshold)
+    assert c["consensus.stage_a_groups"] == 4
+    assert c["consensus.first_stage_groups"] >= 4
+    assert (0 < c["consensus.stage_a_survivors"]
+            < c["consensus.stage_a_windows"])
+    # the default jobs' groups are under the threshold: one stage each
+    c = jobs["sharded"]["default"]["report"]["metrics"]["counters"]
+    assert c["consensus.first_stage_groups"] >= 4
+    assert c["consensus.stage_a_groups"] == 0
+    assert "consensus.stage_a_windows" not in c
 
 
 @pytest.mark.parametrize("si", range(4))

@@ -441,7 +441,8 @@ def test_warmup_precompiles_ragged_stream_shape():
 def test_warmup_shapes_cover_tail_bucket():
     """Full-scale estimates produce the dominant bucket's greedy-close
     shape (pow2 of the arena cap, stage-A rounds) plus the half-width
-    contig-tail bucket at the full round budget."""
+    contig-tail bucket at the full round budget (a few hundred rows:
+    under ``TWO_STAGE_MIN_PAIRS``)."""
     from racon_tpu.ops.poa import STAGE_A_ROUNDS, TpuPoaConsensus
 
     eng = TpuPoaConsensus(3, -5, -4)  # band 512, rounds 6, ragged
@@ -472,22 +473,16 @@ def _mutated(rng, seq, sub=.06, ins=.03, dele=.03):
     return bytes(out)
 
 
-def _two_stage_run(monkeypatch, n_noisy, shrink=None):
-    """40 windows in stage-A groups of 16 / 16 / 8: ``n_noisy`` of them
-    (noisy backbone, noisy layers) are still refining after stage A,
-    the rest (layers equal to the backbone) converge at once. Returns
-    the ``(repack?, B, nWp)`` of every launch, the consensus bytes and
-    the number of windows stage A left."""
+def _noisy_windows(seed, n, n_noisy):
+    """``n`` windows of 6 layers over 120 random bases: the first
+    ``n_noisy`` with a noisy backbone and noisy layers (still refining
+    after stage A), the rest with layers equal to the backbone (they
+    converge at once)."""
     from racon_tpu.core.window import Window, WindowType
-    from racon_tpu.ops import poa as poa_mod
-    from racon_tpu.ops.poa import TpuPoaConsensus
 
-    monkeypatch.setattr(poa_mod, "MAX_GROUP_WINDOWS", 16)
-    if shrink is not None:
-        monkeypatch.setattr(poa_mod, "STAGE_B_MAX_SHRINK", shrink)
-    rng = np.random.default_rng(5)
+    rng = np.random.default_rng(seed)
     windows = []
-    for k in range(40):
+    for k in range(n):
         truth = bytes(b"ACGT"[i] for i in rng.integers(0, 4, 120))
         bb = _mutated(rng, truth) if k < n_noisy else truth
         win = Window(0, k, WindowType.TGS, bb, b"5" * len(bb))
@@ -495,6 +490,22 @@ def _two_stage_run(monkeypatch, n_noisy, shrink=None):
             layer = _mutated(rng, truth) if k < n_noisy else truth
             win.add_layer(layer, b"9" * len(layer), 0, len(bb) - 1)
         windows.append(win)
+    return windows
+
+
+def _two_stage_run(monkeypatch, n_noisy, shrink=None):
+    """40 windows (``_noisy_windows``) in stage-A groups of 16 / 16 / 8,
+    ``n_noisy`` of them still refining after stage A. Returns
+    the ``(repack?, B, nWp)`` of every launch, the consensus bytes and
+    the number of windows stage A left."""
+    from racon_tpu.ops import poa as poa_mod
+    from racon_tpu.ops.poa import TpuPoaConsensus
+
+    monkeypatch.setattr(poa_mod, "MAX_GROUP_WINDOWS", 16)
+    monkeypatch.setattr(poa_mod, "TWO_STAGE_MIN_PAIRS", 64)
+    if shrink is not None:
+        monkeypatch.setattr(poa_mod, "STAGE_B_MAX_SHRINK", shrink)
+    windows = _noisy_windows(5, 40, n_noisy)
     eng = TpuPoaConsensus(3, -5, -4, band=64, rounds=6)
     launches, impl = [], eng._launch_group_impl
 
@@ -533,3 +544,75 @@ def test_stage_b_repack_floor_moves_no_byte(monkeypatch):
     tight, b, _ = _two_stage_run(monkeypatch, 1, shrink=1 << 30)
     assert floored[3] == (True, 16, 4) and tight[3] == (True, 8, 2)
     assert a == b
+
+
+# ----------------------------------------------- a lone group's schedule
+
+def _lone_group_run(monkeypatch, n_noisy, min_pairs, ragged=True):
+    """24 windows of 6 layers — ONE group of ``(B, nWp)`` = (256, 32),
+    alone in its bucket and in the run — with the two-stage threshold
+    at ``min_pairs``, through the ragged stream or the padded path.
+    Returns every launch as ``(repack?, B, nWp, rounds)``, the
+    consensus bytes and the run's counters."""
+    from racon_tpu.obs import metrics
+    from racon_tpu.ops import poa as poa_mod
+    from racon_tpu.ops.poa import TpuPoaConsensus
+
+    monkeypatch.setattr(poa_mod, "TWO_STAGE_MIN_PAIRS", min_pairs)
+    windows = _noisy_windows(9, 24, n_noisy)
+    eng = TpuPoaConsensus(3, -5, -4, band=64, rounds=6,
+                          use_ragged=ragged)
+    launches, impl, rounds_impl = [], eng._launch_group_impl, \
+        eng._rounds_impl
+
+    def spy_launch(live, Lq, Lb, overrides=None, floor=(1, 1)):
+        la = impl(live, Lq, Lb, overrides, floor)
+        la["repack"] = overrides is not None
+        return la
+
+    def spy_rounds(la, *a, **kw):
+        launches.append((la["repack"], la["B"], la["nWp"], la["rounds"]))
+        return rounds_impl(la, *a, **kw)
+
+    monkeypatch.setattr(eng, "_launch_group_impl", spy_launch)
+    monkeypatch.setattr(eng, "_rounds_impl", spy_rounds)
+    names = ["consensus." + n for n in (
+        "first_stage_groups", "stage_a_groups", "stage_a_windows",
+        "stage_a_survivors")]
+    before = [metrics.counter(n) for n in names]
+    assert all(eng.run(windows, trim=False))
+    counted = tuple(metrics.counter(n) - b for n, b in zip(names, before))
+    return launches, [w.consensus for w in windows], counted
+
+
+@pytest.mark.parametrize("n_noisy, min_pairs, ragged, launches, counted", [
+    # at the threshold: stage A, then ONE repack of the three windows it
+    # left, at the floor of an eighth of the group's own (256, 32)
+    (3, 256, True, [(False, 256, 32, 2), (True, 32, 4, 4)],
+     (1, 1, 24, 3)),
+    # ... and the padded path reads the same rule
+    (3, 256, False, [(False, 256, 32, 2), (True, 32, 4, 4)],
+     (1, 1, 24, 3)),
+    # under it: the whole budget in the one dispatch, nothing fetched
+    # in between, nothing counted as stage A
+    (3, 512, True, [(False, 256, 32, 6)], (1, 0, 0, 0)),
+    # at it with most windows still refining: no repack launch, the
+    # other four rounds on the state the device already holds
+    (20, 256, True, [(False, 256, 32, 2), (False, 256, 32, 4)],
+     (1, 1, 24, 15)),
+])
+def test_lone_group_takes_the_two_stage_schedule(monkeypatch, n_noisy,
+                                                 min_pairs, ragged,
+                                                 launches, counted):
+    """A group's schedule follows its padded pair rows, not whether
+    another group shares its bucket (PR 44: on the chip a shard's lone
+    group of 32,768 rows swept 1,000 windows six times): two stages
+    from ``TWO_STAGE_MIN_PAIRS`` rows on, one under it — and the bytes
+    are the single-stage run's either way."""
+    got, bytes_, n = _lone_group_run(monkeypatch, n_noisy, min_pairs,
+                                     ragged)
+    assert got == launches
+    assert n == counted
+    single, ref, _ = _lone_group_run(monkeypatch, n_noisy, 1 << 30)
+    assert [la[3] for la in single] == [6]
+    assert bytes_ == ref
