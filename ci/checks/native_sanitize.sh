@@ -5,8 +5,9 @@
 # subprocess — with the ASan runtime preloaded, since CPython itself is
 # not ASan-built — exercises the two threaded/streaming paths with the
 # ugliest memory behaviour: the bp.cpp thread-pool breaking-points
-# decoder, the chunked-inflate gzip sequence parser and the lane-block
-# row copier (lanes.cpp: a memcpy per row). Any heap
+# decoder, the chunked-inflate gzip sequence parser, the lane-block
+# row copier (lanes.cpp: a memcpy per row) and the seed-table compaction
+# beside it. Any heap
 # overflow / UB the sanitizers see aborts the process (UBSan runs with
 # -fno-sanitize-recover), failing this check. Skips cleanly when the
 # toolchain has no ASan runtime.
@@ -80,6 +81,24 @@ assert out[3, :10].tolist() == list(range(990, 1000))
 assert not out[3, 10:].any() and not out[1].any()
 assert out[0].tolist() == list(range(64)) and out[2, -1] == 999
 print("lane-block row copier under ASan/UBSan: ok", file=sys.stderr)
+
+# 4) lanes.cpp: the seed-table compaction (eight mask bytes a load): a
+#    row width that is no multiple of eight, the last slot of the last
+#    row selected, a seam repeat, and a slice that ends at the table's
+#    last entry
+P = 13
+sel = np.zeros((3, P), bool)
+sel[0, [0, 12]] = sel[1, [2, 9]] = sel[2, P - 1] = True
+h = np.arange(3 * P, dtype=np.uint32).reshape(3, P)
+table = (np.zeros(6, np.uint32), np.zeros(6, np.int32),
+         np.zeros(6, np.int32), np.zeros(6, bool))
+n = native.compact_seed_rows(
+    h, sel, sel.copy(), np.array([7, 7, 8]), np.array([0, 10, 0]),
+    sel.sum(1), table, 1, 5)
+# row 1's slot 2 is position 12 of sequence 7 again: dropped
+assert n == 4 and table[2][1:5].tolist() == [0, 12, 19, 12]
+assert table[0][1:5].tolist() == [0, 12, 22, 38] and table[1][4] == 8
+print("seed-table compaction under ASan/UBSan: ok", file=sys.stderr)
 PY
 
 echo "native sanitize: OK"

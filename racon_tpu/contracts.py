@@ -145,6 +145,11 @@ METRICS: FrozenSet[str] = frozenset((
     "overlap.minimizers", "overlap.mode_auto",
     # reads offered to the overlapper / reads with a row after the filter
     "overlap.queries", "overlap.queries_kept",
+    # the seeding stream (ops/overlap_seed.py _SeedStream): arenas
+    # launched, and those of them packed before the arena in front had
+    # been fetched (the pack-ahead engaged: all but the first of a
+    # multi-arena build)
+    "overlap.seed_arenas", "overlap.seed_arenas_ahead",
     "overlap.seed_lanes_occupied", "overlap.seed_lanes_total",
     "overlap.stream_feed", "overlap.stream_groups", "overlap.streamed",
     # the read set's window type (WindowType.value: 0 NGS, 1 TGS)
@@ -248,6 +253,10 @@ SPANS: FrozenSet[str] = frozenset((
     "overlap.join.prefilter",
     "overlap.match", "overlap.seed", "overlap.seed.dispatch",
     "overlap.seed.fetch",
+    # the seeding stream's leaves (timer-only): an arena's host pack on
+    # the feeding thread; its planes' way back and their compaction
+    # into the table, on a worker of a multi-arena build
+    "overlap.seed.pack", "overlap.seed.get", "overlap.seed.compact",
     "parse.overlaps", "parse.reads", "parse.targets",
     "poa.dispatch", "poa.fetch", "poa.pack", "poa.stage_b",
     # leaves of poa.pack / poa.fetch
@@ -269,6 +278,7 @@ TIMER_ONLY_SPANS: FrozenSet[str] = frozenset((
     "poa.lanes", "compile.retrieve",
     "overlap.chain.plan", "overlap.emit", "overlap.rows",
     "overlap.join.prefilter",
+    "overlap.seed.pack", "overlap.seed.get", "overlap.seed.compact",
     # `round` lies over every span of its round: read through, the
     # idle under it goes where it went before rounds had a span, and
     # the hand-off's stays `unattributed` (idle_other_s lists it)

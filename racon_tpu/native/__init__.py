@@ -234,6 +234,14 @@ def load():
     lib.rt_copy_byte_rows.argtypes = [
         ctypes.c_int64, ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
         ctypes.c_int64, ctypes.POINTER(ctypes.c_uint8)]
+    lib.rt_compact_seed_rows.restype = ctypes.c_int64
+    lib.rt_compact_seed_rows.argtypes = [
+        ctypes.c_int64, ctypes.c_int64, ctypes.POINTER(ctypes.c_uint32),
+        ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_uint8),
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint8)]
     _lib = lib
     return _lib
 
@@ -450,6 +458,61 @@ def copy_byte_rows(pool: bytes, length, out) -> None:
     lib.rt_copy_byte_rows(
         count, pool, length.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
         out.shape[1], out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
+
+
+def compact_seed_rows(h, sel, strand, row_id, row_off, row_sel, out,
+                      offset: int, cap: int) -> int:
+    """Write a minimizer arena's selected slots into the seed table:
+    rows ``[0, len(row_id))`` of the C-contiguous ``[B, P]`` planes
+    ``h`` (uint32), ``sel`` and ``strand`` (bool) are walked in
+    row-major order and every selected slot lands as ``(hash,
+    row_id[r], row_off[r] + column, strand)`` in the four arrays of
+    ``out`` (uint32, int32, int32, bool) from ``offset`` on, an entry
+    equal in ``(id, pos)`` to the one before it dropped (a slice seam's
+    repeat). ``row_sel`` is the selected count a row; ``cap`` entries
+    at most are written. Returns how many were."""
+    import numpy as np
+
+    lib = load()
+    if lib is None:
+        raise NativeBuildError("native library unavailable")
+    rows = len(row_id)
+    planes = ((h, np.uint32), (sel, np.bool_), (strand, np.bool_))
+    if any(a.dtype != t or a.ndim != 2 or a.shape != h.shape
+           or not a.flags.c_contiguous for a, t in planes):
+        raise ValueError("compact_seed_rows wants C-contiguous [B, P] "
+                         "uint32 / bool / bool planes of one shape")
+    meta = [np.ascontiguousarray(a, dtype=np.int32)
+            for a in (row_id, row_off, row_sel)]
+    if rows > h.shape[0] or any(len(a) != rows for a in meta):
+        raise ValueError("compact_seed_rows: one id, offset and count a "
+                         "row, at most the planes' rows")
+    outs = ((out[0], np.uint32), (out[1], np.int32), (out[2], np.int32),
+            (out[3], np.bool_))
+    if any(a.dtype != t or a.ndim != 1 or not a.flags.c_contiguous
+           for a, t in outs):
+        raise ValueError("compact_seed_rows wants contiguous uint32 / "
+                         "int32 / int32 / bool output arrays")
+    if offset < 0 or cap < 0 or any(offset + cap > len(a) for a, _ in outs):
+        raise IndexError("seed slice outside the table")
+    if not rows:
+        return 0
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    u32p = ctypes.POINTER(ctypes.c_uint32)
+
+    def at(a, ptr):
+        return ctypes.cast(a.ctypes.data + offset * a.itemsize, ptr)
+
+    n = lib.rt_compact_seed_rows(
+        rows, h.shape[1], h.ctypes.data_as(u32p), sel.ctypes.data_as(u8p),
+        strand.ctypes.data_as(u8p), *(a.ctypes.data_as(i32p) for a in meta),
+        cap, at(out[0], u32p), at(out[1], i32p), at(out[2], i32p),
+        at(out[3], u8p))
+    if n < 0:
+        raise ValueError("compact_seed_rows: more selected slots than the "
+                         "rows' counts sum to")
+    return n
 
 
 def parse_seqfile(path: str, is_fastq: bool):

@@ -22,7 +22,11 @@ chain overlapper (ROADMAP item 5); this module is the seeding half:
   under ``RACON_TPU_RESIDENT=1``, a device compaction kernel that ships
   only the selected entries over the link) flattens the batch into one
   flat ``(hash, seq_id, pos, strand)`` table for the matcher
-  (:mod:`racon_tpu.ops.chain`).
+  (:mod:`racon_tpu.ops.chain`);
+- the arenas of one build are a stream (:class:`_SeedStream`, PR 45):
+  arena k + 1 is packed and launched while arena k's planes cross back
+  and a worker writes its selected entries once, at their place in the
+  final arrays (the kernel's per-row counts size the slice).
 
 Sequences longer than a row (contig targets, long reads) are sliced
 into bounded window-start spans so the arena never scales with sequence
@@ -37,7 +41,8 @@ from __future__ import annotations
 import functools
 import hashlib
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, deque
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -49,7 +54,8 @@ from .. import obs
 from ..models.overlap import BASE_LUT as _BASE_LUT
 from ..models.overlap import HASH_MAX as _HASH_MAX
 from ..obs import device_time, metrics
-from ..parallel import fetch_global
+from ..parallel import fetch_global, is_multihost
+from .nw import _copy_rows
 
 # defaults mirrored by the RACON_TPU_OVERLAP_K/W flags (k=15/w=5: ONT
 # read-vs-draft seeding; ~1/3 of positions carry a minimizer)
@@ -217,6 +223,306 @@ def clear_table_cache() -> None:
         _TABLE_CACHE.clear()
 
 
+# arenas whose planes are on their way into the table at once (one worker
+# each): arena k + 1 is packed and launched while arena k's planes cross
+# back and are compacted behind it, and a launch waits only for the
+# arena two in front of it. Fixed here: nothing a caller knows changes it
+SEED_IN_FLIGHT = 2
+_CODE_TABLE = _BASE_LUT.tobytes()
+
+
+def _pack_arena(part, B: int, L: int):
+    """One arena's host arrays from its chunks: ``codes [B, L]`` (4, no
+    base, wherever no sequence lies), ``lens`` / ``nwin [B]``, and the
+    rows' ``seq_id`` / window-start offset. One remap over the chunks'
+    bytes back to back, then a row copy (``ops/nw.py`` ``_copy_rows``:
+    native where the core is built)."""
+    n = len(part)
+    blobs = [c[2] for c in part]
+    lens = np.zeros(B, np.int32)
+    nwin = np.zeros(B, np.int32)
+    lens[:n] = np.fromiter(map(len, blobs), np.int32, n)
+    nwin[:n] = np.fromiter((c[3] for c in part), np.int32, n)
+    codes = np.full((B, L), 4, np.uint8)
+    _copy_rows(b"".join(blobs).translate(_CODE_TABLE), lens[:n], codes)
+    ids = np.fromiter((c[0] for c in part), np.int32, n)
+    offs = np.fromiter((c[1] for c in part), np.int32, n)
+    return codes, lens, nwin, ids, offs
+
+
+def _write_entries(out, offset: int, h, ids, pos, strand) -> int:
+    """Write one arena's entries (row-major order) into the table's four
+    arrays from ``offset`` on, less every entry whose ``(seq_id, pos)``
+    repeats its left neighbour's: a position picked by windows on both
+    sides of a slice seam emits once per slice and sits beside its
+    twin. Returns the entries written."""
+    if h.size > 1:
+        twin = (ids[1:] == ids[:-1]) & (pos[1:] == pos[:-1])
+        if twin.any():
+            keep = np.concatenate(([True], ~twin))
+            h, ids, pos, strand = h[keep], ids[keep], pos[keep], strand[keep]
+    n = int(h.size)
+    for dst, src in zip(out, (h, ids, pos, strand)):
+        dst[offset:offset + n] = src
+    return n
+
+
+def _compact_arena(out, offset: int, reserved: int, planes, ids, offs,
+                   row_sel) -> int:
+    """An arena's fetched ``(hash, strand, selected)`` planes into its
+    slice ``[offset, offset + reserved)`` of the table ``out``: every
+    selected slot of the rows that hold a chunk, in row-major order,
+    written once (``native/lanes.cpp`` ``rt_compact_seed_rows``; the
+    ``np.nonzero`` walk where the native core is absent). Returns the
+    entries written: ``reserved`` less the arena's seam repeats."""
+    from .. import native
+    h, strand, sel = map(np.ascontiguousarray, planes)
+    if native.available():
+        return native.compact_seed_rows(h, sel, strand, ids, offs, row_sel,
+                                        out, offset, reserved)
+    rows, cols = np.nonzero(sel[:len(ids)])
+    return _write_entries(out, offset, h[rows, cols], ids[rows],
+                          offs[rows] + cols.astype(np.int32),
+                          strand[rows, cols])
+
+
+class _Arena:
+    """One launched arena on its way into the table."""
+
+    __slots__ = ("ids", "offs", "device", "row_sel", "offset", "reserved",
+                 "count", "fetched")
+
+    def __init__(self, ids, offs, device):
+        self.ids = ids
+        self.offs = offs
+        self.device = device        # the kernel's outputs, until fetched
+        self.row_sel = None         # selected slots a row
+        self.offset = 0             # its slice of the table: from here,
+        self.reserved = 0           # this many entries at most,
+        self.count = 0              # this many written
+        self.fetched = False
+
+
+class _SeedStream:
+    """The arena loop of :func:`build_seed_table` as a stream.
+
+    The calling thread packs arena k + 1 (host only) and launches it —
+    every submission from this thread, in arena order, so the occupancy
+    ledger charges the idle to its ``overlap.seed*`` spans — while a
+    worker fetches arena k's planes and compacts them. The kernel's
+    per-row selected counts size an arena's slice of the final
+    ``(hash, seq_id, pos, strand)`` arrays when the arena behind it has
+    been launched, so slices lie in launch order whatever order the
+    workers finish in, and every entry is written once, in place.
+    A seam repeat is dropped where it is written (inside an arena) or
+    when the slices are closed up (its twin in the arena before); the
+    holes this leaves — none for reads under a row's length — are
+    closed by :meth:`finish`.
+
+    One arena (a draft of a few Mbp, the tests' inputs), the resident
+    path and a multi-host run start no thread: pack, launch, fetch,
+    compact on the calling thread, the order of events a build has
+    always had."""
+
+    def __init__(self, n_arenas: int, windows: int, k: int, w: int,
+                 resident: bool):
+        self.k, self.w, self.resident = k, w, resident
+        self.B, self.L = SEED_BATCH, SEED_ROW
+        # a window picks one slot, so a table holds `windows` entries at
+        # most; the minimizers of a random sequence take 2 / (w + 1) of
+        # them. A quarter over that to begin with: low-complexity input
+        # grows the arrays (a copy), nothing else
+        self.limit = windows
+        self.out = self._alloc(min(windows,
+                                   int(windows * 2.5 / (w + 1)) + 1024))
+        self.used = 0
+        self.arenas: List[_Arena] = []            # in launch order
+        self.front: Optional[_Arena] = None       # launched, slice not sized
+        self.writing: deque = deque()             # (arena, future) at a worker
+        self.ordered = True
+        self._last_key = -1
+        self.pool = None
+        # across hosts a fetch is a collective, and collectives leave in
+        # one order from one thread
+        if n_arenas > 1 and not resident and not is_multihost():
+            self.pool = ThreadPoolExecutor(
+                SEED_IN_FLIGHT, thread_name_prefix="racon-seedstream")
+        self._scope = metrics.get_scope()
+
+    @staticmethod
+    def _alloc(cap: int):
+        return (np.empty(cap, np.uint32), np.empty(cap, np.int32),
+                np.empty(cap, np.int32), np.empty(cap, np.bool_))
+
+    # ------------------------------------------------------ calling thread
+
+    def feed(self, part) -> None:
+        """Pack and launch the next arena; hand the one in front of it
+        on."""
+        B, L, k, w = self.B, self.L, self.k, self.w
+        with obs.span("overlap.seed.pack", rows=len(part)):
+            codes, lens, nwin, ids, offs = _pack_arena(part, B, L)
+        # chunks arrive in (seq_id, offset) order and a row's slots in
+        # position order, so the slices are the canonical table as they
+        # lie; anything else is sorted at the end
+        key = (ids.astype(np.int64) << 32) | offs
+        if key[0] <= self._last_key or not bool(np.all(key[1:] > key[:-1])):
+            self.ordered = False
+        self._last_key = int(key[-1])
+        # packed before the arena in front had been fetched: the stream
+        # engaged (never on the calling thread's own fetch)
+        ahead = self.front is not None and not self.front.fetched
+        if len(self.writing) >= SEED_IN_FLIGHT:
+            with obs.span("overlap.seed.fetch"):
+                self._settle(SEED_IN_FLIGHT - 1)
+        with obs.span("overlap.seed.dispatch", rows=len(part)):
+            codes_d = jnp.asarray(codes)
+            device_time.submit("h2d", "overlap.seed.put", codes_d)
+            # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the one row length)
+            h, strand, sel, nsel = _minimizer_kernel(codes_d, lens, nwin,
+                                                     k=k, w=w, L=L)
+            geom = _seed_geometry(B, L, k, w)
+            device_time.submit("exec", "_minimizer_kernel", nsel, geom)
+            device = (h, strand, sel, nsel)
+            if self.resident:
+                h, row, pcol, strand, total = _compact_kernel(
+                    h, strand, sel)
+                device_time.submit("exec", "_compact_kernel", total, geom)
+                device = (h, row, pcol, strand, total)
+        metrics.inc("overlap.seed_arenas")
+        metrics.inc("overlap.seed_arenas_ahead", int(ahead))
+        metrics.inc("overlap.seed_lanes_total", B * L)
+        metrics.inc("overlap.seed_lanes_occupied", int(lens.sum()))
+        arena = _Arena(ids, offs, device)
+        self.arenas.append(arena)
+        if self.pool is None:
+            with obs.span("overlap.seed.fetch", rows=len(part)):
+                if self.resident:
+                    arena.count = self._fetch_resident(arena)
+                else:
+                    self._size(arena)
+                    arena.count = self._drain(arena)
+            return
+        if self.front is not None:
+            self._hand_over(self.front)
+        self.front = arena
+
+    def _reserve(self, n: int) -> int:
+        """``n`` entries of the table from the offset returned."""
+        if self.used + n > len(self.out[0]):
+            self._settle(0)     # no writer holds the arrays that go
+            grown = self._alloc(max(self.used + n, min(
+                self.limit, len(self.out[0]) * 3 // 2)))
+            for dst, src in zip(grown, self.out):
+                dst[:self.used] = src[:self.used]
+            self.out = grown
+        offset = self.used
+        self.used += n
+        return offset
+
+    def _size(self, arena: _Arena) -> None:
+        """``arena``'s slice of the table, from the kernel's selected
+        counts (2 KB; the kernel has run)."""
+        nsel = fetch_global([arena.device[3]])[0]
+        arena.row_sel = nsel[:len(arena.ids)]
+        arena.reserved = int(arena.row_sel.sum())
+        arena.offset = self._reserve(arena.reserved)
+
+    def _hand_over(self, arena: _Arena) -> None:
+        """Size ``arena``'s slice (its kernel ran while the arena behind
+        it was packed) and give its planes to a worker."""
+        with obs.span("overlap.seed.fetch"):
+            self._size(arena)
+        self.writing.append((arena, self.pool.submit(self._drain, arena)))
+
+    def _settle(self, keep: int) -> None:
+        """Wait until at most ``keep`` arenas are at the workers."""
+        while len(self.writing) > keep:
+            arena, future = self.writing.popleft()
+            arena.count = future.result()
+
+    def _fetch_resident(self, arena: _Arena) -> int:
+        """The resident path's fetch: the entries the device compacted,
+        nothing else (counted into the ``dataflow.*`` bytes ledger)."""
+        h, row, pcol, strand, total = arena.device
+        arena.device = None
+        n = int(fetch_global([total])[0])
+        h_np, rows, cols, s_np = fetch_global(
+            [h[:n], row[:n], pcol[:n], strand[:n]])
+        arena.fetched = True
+        fetched = n * 10  # 4 + 4 + 1 + 1 bytes per entry
+        metrics.inc("dataflow.bytes_fetched", fetched)
+        metrics.inc("dataflow.bytes_avoided",
+                    max(0, self.B * (self.L - self.k + 1) * 6 - fetched))
+        keep = h_np != np.uint32(_HASH_MAX)
+        rows, cols = rows[keep], cols[keep]
+        arena.offset = self._reserve(int(rows.size))
+        return _write_entries(self.out, arena.offset, h_np[keep],
+                              arena.ids[rows],
+                              arena.offs[rows] + cols.astype(np.int32),
+                              np.asarray(s_np)[keep])
+
+    def _drain(self, arena: _Arena) -> int:
+        """Fetch ``arena``'s planes and compact them into its slice
+        (a worker, or the calling thread of a one-arena build)."""
+        # the metrics scope is thread-local: re-declare the caller's
+        metrics.set_scope(self._scope)
+        planes = arena.device[:3]
+        arena.device = None
+        with obs.span("overlap.seed.get"):
+            h, strand, sel = fetch_global(list(planes))
+        del planes
+        arena.fetched = True
+        with obs.span("overlap.seed.compact"):
+            return _compact_arena(self.out, arena.offset, arena.reserved,
+                                  (h, strand, sel), arena.ids, arena.offs,
+                                  arena.row_sel)
+
+    # -------------------------------------------------------------- the end
+
+    def finish(self):
+        """The table: the last arena handed on, every slice landed, the
+        holes closed up in arena order."""
+        if self.front is not None:
+            self._hand_over(self.front)
+            self.front = None
+            with obs.span("overlap.seed.fetch"):
+                self._settle(0)
+        ids, pos = self.out[1], self.out[2]
+        end = 0
+        for arena in self.arenas:
+            offset, count = arena.offset, arena.count
+            # a seam between two arenas: the twin ends the slice before
+            if (count and end and ids[offset] == ids[end - 1]
+                    and pos[offset] == pos[end - 1]):
+                offset, count = offset + 1, count - 1
+            if count and offset != end:
+                for a in self.out:
+                    a[end:end + count] = a[offset:offset + count]
+            end += count
+        table = tuple(a[:end] for a in self.out)
+        return table if self.ordered else _canonical(table)
+
+    def close(self) -> None:
+        """Retire the workers (a build that failed leaves none behind)."""
+        if self.pool is not None:
+            self.pool.shutdown(wait=True)
+
+
+def _canonical(table):
+    """``table`` in canonical ``(seq_id, pos)`` order, an entry a key:
+    the stable sort (and the repeats it brings together dropped) for
+    chunks that did not arrive in ``(seq_id, offset)`` order."""
+    h, ids, pos, strand = table
+    key = (ids.astype(np.int64) << 32) | pos
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    uniq = np.ones(key.size, bool)
+    uniq[1:] = key[1:] != key[:-1]
+    order = order[uniq]
+    return h[order], ids[order], pos[order], strand[order]
+
+
 def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
                      w: int = DEFAULT_W, resident: bool = False,
                      cache: bool = False
@@ -224,12 +530,13 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
                                 np.ndarray]:
     """The flat minimizer table of a sequence set: parallel numpy arrays
     ``(hash uint32, seq_id int32, pos int32, strand bool)`` in
-    deterministic (bucket-grouped, sequence-order) row order.
+    deterministic (bucket-grouped, sequence-order) row order, built
+    arena by arena as a stream (:class:`_SeedStream`).
 
     ``resident=True`` compacts on device and fetches only the selected
     entries (counted into the ``dataflow.*`` bytes ledger); the host
-    path fetches the full masks and compacts with numpy. Both produce
-    identical tables (tests assert the parity).
+    path fetches the full masks and compacts them into place. Both
+    produce identical tables (tests assert the parity).
 
     ``cache=True`` (the target side of the overlapper) consults the
     fingerprint-keyed table
@@ -251,91 +558,20 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
             return hit
         metrics.inc("overlap.cache_misses")
     chunks = list(_iter_chunks(seqs, k, w))
-    B, L = SEED_BATCH, SEED_ROW
-
-    hs: List[np.ndarray] = []
-    ids: List[np.ndarray] = []
-    ps: List[np.ndarray] = []
-    ss: List[np.ndarray] = []
-    for begin in range(0, len(chunks), B):
-        part = chunks[begin:begin + B]
-        codes = np.full((B, L), 4, np.uint8)
-        lens = np.zeros(B, np.int32)
-        nwin = np.zeros(B, np.int32)
-        for i, (_, _, blob, n_here) in enumerate(part):
-            arr = _BASE_LUT[np.frombuffer(blob, np.uint8)]
-            codes[i, :arr.size] = arr
-            lens[i] = arr.size
-            nwin[i] = n_here
-        with obs.span("overlap.seed.dispatch", rows=len(part)):
-            codes_d = jnp.asarray(codes)
-            device_time.submit("h2d", "overlap.seed.put", codes_d)
-            # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the one row length)
-            h, strand, sel, nsel = _minimizer_kernel(codes_d, lens, nwin,
-                                                     k=k, w=w, L=L)
-            geom = _seed_geometry(B, L, k, w)
-            device_time.submit("exec", "_minimizer_kernel", nsel, geom)
-            if resident:
-                h, row, pcol, strand, total = _compact_kernel(
-                    h, strand, sel)
-                device_time.submit("exec", "_compact_kernel", total, geom)
-        if resident:
-            with obs.span("overlap.seed.fetch", rows=len(part)):
-                n_host = fetch_global([total])[0]
-                n = int(n_host)
-                h_np, rows, cols, s_np = fetch_global(
-                    [h[:n], row[:n], pcol[:n], strand[:n]])
-            fetched = n * 10  # 4 + 4 + 1 + 1 bytes per entry
-            metrics.inc("dataflow.bytes_fetched", fetched)
-            metrics.inc("dataflow.bytes_avoided",
-                        max(0, B * (L - k + 1) * 6 - fetched))
-        else:
-            with obs.span("overlap.seed.fetch", rows=len(part)):
-                h_full, sel_np, s_full = fetch_global(
-                    [h, sel, strand])
-            rows, cols = np.nonzero(sel_np)
-            h_np = h_full[rows, cols]
-            s_np = s_full[rows, cols]
-        keep = h_np != np.uint32(_HASH_MAX)
-        rows, cols = rows[keep], cols[keep]
-        chunk_ids = np.fromiter((c[0] for c in part), np.int32,
-                                len(part))
-        chunk_off = np.fromiter((c[1] for c in part), np.int32,
-                                len(part))
-        hs.append(h_np[keep])
-        ids.append(chunk_ids[rows])
-        ps.append(chunk_off[rows] + cols.astype(np.int32))
-        ss.append(np.asarray(s_np)[keep])
-        metrics.inc("overlap.seed_lanes_total", B * L)
-        metrics.inc("overlap.seed_lanes_occupied", int(lens.sum()))
-    if not hs:
+    if chunks:
+        B = SEED_BATCH
+        stream = _SeedStream(-(-len(chunks) // B),
+                             sum(c[3] for c in chunks), k, w, resident)
+        try:
+            for begin in range(0, len(chunks), B):
+                stream.feed(chunks[begin:begin + B])
+            table = stream.finish()
+        finally:
+            stream.close()
+        metrics.inc("overlap.minimizers", int(table[0].size))
+    else:
         z = np.zeros(0, np.int32)
         table = (np.zeros(0, np.uint32), z, z, np.zeros(0, bool))
-        if ckey is not None:
-            _table_cache_put(ckey, table)
-        return table
-    h_all = np.concatenate(hs)
-    id_all = np.concatenate(ids)
-    p_all = np.concatenate(ps)
-    s_all = np.concatenate(ss)
-    # canonical (seq_id, pos) order. Rows come in (seq_id, offset)
-    # order and a window's minimizer never lies left of the previous
-    # window's, so the walk above is that order already; the one
-    # repeat — a position picked by windows on both sides of a slice
-    # boundary emits once per slice — sits beside its twin
-    key = (id_all.astype(np.int64) << 32) | p_all
-    if key.size > 1 and not bool(np.all(key[1:] >= key[:-1])):
-        order = np.argsort(key, kind="stable")
-        h_all, id_all, p_all, s_all = (h_all[order], id_all[order],
-                                       p_all[order], s_all[order])
-        key = key[order]
-    uniq = np.ones(h_all.size, bool)
-    uniq[1:] = key[1:] != key[:-1]
-    if not uniq.all():
-        h_all, id_all, p_all, s_all = (h_all[uniq], id_all[uniq],
-                                       p_all[uniq], s_all[uniq])
-    table = (h_all, id_all, p_all, s_all)
-    metrics.inc("overlap.minimizers", int(table[0].size))
     if ckey is not None:
         _table_cache_put(ckey, table)
     return table

@@ -117,6 +117,224 @@ def test_seed_table_skips_short_sequences():
     assert set(table[1].tolist()) == {1}
 
 
+# ------------------------------------------- stage 1 as a stream of arenas
+
+STREAM_LENGTHS = (150, 700, 90, 400, 1000, 60, 333, 511, 96, 820)
+
+
+def _stream_seqs(seed=16):
+    rng = np.random.default_rng(seed)
+    seqs = [bytearray(rand_seq(rng, n)) for n in STREAM_LENGTHS]
+    seqs[3][200] = ord(b"N")       # an ambiguous base in a sliced read
+    return [bytes(s) for s in seqs]
+
+
+def _small_arenas(monkeypatch, batch=4, row=96):
+    monkeypatch.setattr(overlap_seed, "SEED_ROW", row)
+    monkeypatch.setattr(overlap_seed, "SEED_BATCH", batch)
+
+
+def _oracle_rows(seqs, k=15, w=5):
+    return [(i, p, h, bool(s)) for i, seq in enumerate(seqs)
+            for h, p, s in reference.minimizers_np(seq, k, w)]
+
+
+def test_seed_stream_many_arenas_equal_the_oracle_and_one_arena(monkeypatch):
+    """Rows of 96 bases in arenas of 4: ten sequences fill 14 arenas,
+    sliced sequences lie across arena boundaries, and seam repeats
+    arise inside an arena and between two — the streamed table equals
+    ``minimizers_np`` and the one-arena build entry for entry."""
+    seqs = _stream_seqs()
+    _small_arenas(monkeypatch)
+    chunks = list(overlap_seed._iter_chunks(seqs, 15, 5))
+    assert len(chunks) >= 4 * 4
+    # a repeat: the last slot one slice picked is the first of the next
+    picks = [[off + p for _, p, _ in reference.minimizers_np(blob, 15, 5)]
+             for _, off, blob, _ in chunks]
+    seams = [r for r in range(1, len(chunks))
+             if chunks[r][0] == chunks[r - 1][0] and picks[r]
+             and picks[r - 1] and picks[r][0] == picks[r - 1][-1]]
+    assert any(r % 4 == 0 for r in seams), "no repeat between two arenas"
+    assert any(r % 4 != 0 for r in seams), "no repeat inside an arena"
+    assert any(chunks[r][0] == chunks[r - 1][0]
+               for r in range(4, len(chunks), 4))
+    got = table_rows(overlap_seed.build_seed_table(seqs))
+    assert got == _oracle_rows(seqs)
+    monkeypatch.setattr(overlap_seed, "SEED_BATCH", 64)
+    assert len(chunks) <= 64
+    assert table_rows(overlap_seed.build_seed_table(seqs)) == got
+
+
+def test_seed_stream_orders_by_arena_not_by_completion(monkeypatch):
+    """Arena 0's compaction is held until arena 1's has finished (and
+    the interpreter switches threads as often as it can): the slices
+    lie by arena index, so the table is the oracle's all the same."""
+    import sys
+    import threading
+    seqs = _stream_seqs(17)
+    _small_arenas(monkeypatch)
+    compact = overlap_seed._compact_arena
+    second_done = threading.Event()
+    finished = []
+
+    def held(out, offset, *rest):
+        if offset == 0:
+            assert second_done.wait(30), "arena 1 never finished"
+        n = compact(out, offset, *rest)
+        finished.append(offset)
+        if offset:
+            second_done.set()
+        return n
+
+    monkeypatch.setattr(overlap_seed, "_compact_arena", held)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        got = table_rows(overlap_seed.build_seed_table(seqs))
+    finally:
+        sys.setswitchinterval(interval)
+    assert len(finished) >= 4 and finished[0] != 0 and 0 in finished
+    assert got == _oracle_rows(seqs)
+
+
+def test_seed_table_out_of_order_chunks_take_the_sort(monkeypatch):
+    """Chunks that do not arrive in ``(seq_id, offset)`` order reach
+    the sort fallback and leave in canonical order, a seam's repeat
+    dropped wherever its twin landed."""
+    seqs = _stream_seqs(18)
+    _small_arenas(monkeypatch)
+    want = table_rows(overlap_seed.build_seed_table(seqs))
+    in_order = overlap_seed._iter_chunks
+    sorts = []
+    canonical = overlap_seed._canonical
+    monkeypatch.setattr(overlap_seed, "_iter_chunks",
+                        lambda *a: reversed(list(in_order(*a))))
+    monkeypatch.setattr(overlap_seed, "_canonical",
+                        lambda table: sorts.append(1) or canonical(table))
+    assert table_rows(overlap_seed.build_seed_table(seqs)) == want
+    assert sorts == [1]
+    assert want == _oracle_rows(seqs)
+
+
+def test_seed_stream_without_the_native_core(monkeypatch):
+    """With the native core reported absent the numpy row copy and the
+    ``np.nonzero`` compaction build the same table."""
+    from racon_tpu import native
+    seqs = _stream_seqs(19)
+    _small_arenas(monkeypatch)
+    assert native.available()
+    want = table_rows(overlap_seed.build_seed_table(seqs))
+    monkeypatch.setattr(native, "available", lambda: False)
+    monkeypatch.setattr(native, "compact_seed_rows", None)
+    monkeypatch.setattr(native, "copy_byte_rows", None)
+    assert table_rows(overlap_seed.build_seed_table(seqs)) == want
+    assert want == _oracle_rows(seqs)
+
+
+def test_compact_seed_rows_refuses_what_it_cannot_place():
+    """The native compaction checks before it writes: planes of another
+    dtype or shape, a slice that leaves the table, and more selected
+    slots than the rows' counts sum to all raise."""
+    from racon_tpu import native
+    sel = np.zeros((2, 11), bool)
+    sel[0, [1, 10]] = sel[1, 3] = True
+    h = np.arange(22, dtype=np.uint32).reshape(2, 11)
+    ids, offs = np.array([0, 1]), np.array([0, 0])
+
+    def table(n):
+        return (np.zeros(n, np.uint32), np.zeros(n, np.int32),
+                np.zeros(n, np.int32), np.zeros(n, bool))
+
+    out = table(4)
+    assert native.compact_seed_rows(h, sel, sel, ids, offs, [2, 1],
+                                    out, 1, 3) == 3
+    assert out[2].tolist() == [0, 1, 10, 3]
+    assert out[0].tolist() == [0, 1, 10, 14]
+    with pytest.raises(ValueError):
+        native.compact_seed_rows(h.astype(np.int64), sel, sel, ids, offs,
+                                 [2, 1], table(4), 0, 3)
+    with pytest.raises(ValueError):
+        native.compact_seed_rows(h, sel[:, :10], sel, ids, offs, [2, 1],
+                                 table(4), 0, 3)
+    with pytest.raises(IndexError):
+        native.compact_seed_rows(h, sel, sel, ids, offs, [2, 1],
+                                 table(4), 2, 3)
+    with pytest.raises(ValueError):
+        native.compact_seed_rows(h, sel, sel, ids, offs, [2, 1],
+                                 table(4), 0, 2)
+
+
+@pytest.mark.parametrize("batch, resident", [(4, False), (64, False),
+                                             (4, True)])
+def test_seed_stream_counts_its_arenas(monkeypatch, batch, resident):
+    """``overlap.seed_arenas`` is the arenas launched;
+    ``overlap.seed_arenas_ahead`` all but the first of a streamed
+    build, and none where one arena (or the resident path's own fetch)
+    leaves nothing to pack ahead of."""
+    seqs = _stream_seqs(20)
+    _small_arenas(monkeypatch, batch=batch)
+    arenas = -(-len(list(overlap_seed._iter_chunks(seqs, 15, 5))) // batch)
+    assert arenas == (1 if batch == 64 else 14)
+    before = [metrics.counter("overlap.seed_arenas"),
+              metrics.counter("overlap.seed_arenas_ahead")]
+    overlap_seed.build_seed_table(seqs, resident=resident)
+    assert metrics.counter("overlap.seed_arenas") - before[0] == arenas
+    ahead = metrics.counter("overlap.seed_arenas_ahead") - before[1]
+    assert ahead == (arenas - 1 if batch == 4 and not resident else 0)
+
+
+def test_seed_stream_spans_are_declared_and_workers_take_no_idle(
+        assembly, tmp_path, monkeypatch):
+    """A job whose 32 reads fill two seeding arenas, through the CLI
+    with a report and a trace: every ``overlap.seed*`` span it opened
+    is declared; what the stream's workers opened is timer-only, so the
+    ledger charges them nothing and ``idle_overlap_s``' listed timers
+    (with ``idle.overlap.filter``, the ingest's) are still all of the
+    overlapper's idle."""
+    import json
+
+    from racon_tpu import cli, contracts
+    from racon_tpu.obs import trace
+    rp, _, lp = assembly
+    monkeypatch.setattr(overlap_seed, "SEED_BATCH", 16)
+    overlap_seed.clear_table_cache()
+    rep, tr = tmp_path / "report.json", tmp_path / "trace.json"
+    try:
+        rc = cli.main(["-t", "2", "--overlaps", "auto", "--run-report",
+                       str(rep), "--trace", str(tr), str(rp), "auto",
+                       str(lp)])
+    finally:
+        trace.deactivate()
+    assert rc == 0
+    report = json.loads(rep.read_bytes())
+    counters = report["metrics"]["counters"]
+    # two arenas of reads, one of the two contigs
+    assert counters["overlap.seed_arenas"] == 3
+    assert counters["overlap.seed_arenas_ahead"] == 1
+    timers = report["metrics"]["timers"]
+    opened = {n for n in timers if n.startswith("overlap.seed")}
+    assert {"overlap.seed", "overlap.seed.pack", "overlap.seed.dispatch",
+            "overlap.seed.fetch", "overlap.seed.get",
+            "overlap.seed.compact"} == opened <= contracts.SPANS
+    events = json.loads(tr.read_bytes())["traceEvents"]
+    names = {e["tid"]: e["args"]["name"] for e in events
+             if e["name"] == "thread_name"}
+    workers = {e["name"] for e in events if e["ph"] == "X"
+               and names[e["tid"]].startswith("racon-seedstream")}
+    assert workers == {"overlap.seed.get", "overlap.seed.compact"}
+    assert workers <= contracts.TIMER_ONLY_SPANS
+    listed = set(json.loads(pathlib.Path(
+        REPO_ROOT, "benchmark/metrics/idle_overlap_s.json"
+    ).read_bytes())["spans"]) | {"idle.overlap.filter"}
+    idle = {n for n in timers if n.startswith("idle.overlap")}
+    assert idle <= listed
+    dt = report["device_time"]
+    assert sum(dt["idle_by"].values()) == pytest.approx(dt["idle_s"],
+                                                        abs=1e-4)
+    # the three arenas (and the warm-up's dummy, where it ran)
+    assert dt["by_program"]["_minimizer_kernel"]["count"] in (3, 4)
+
+
 # --------------------------------------------------- stage 2: chain DP
 
 def test_chain_kernel_matches_numpy_oracle():
