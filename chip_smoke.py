@@ -229,8 +229,7 @@ def check_report(rep: dict, n_pairs: int, n_windows: int, out: dict,
     # host rejects, counted and bounded
     rejects = {k: c.get(k, 0) for k in (
         "aligner.fallback_band", "aligner.fallback_length",
-        "dataflow.fallback_pairs", "consensus.fallback_windows",
-        "consensus.dropped_layers")}
+        "consensus.fallback_windows", "consensus.dropped_layers")}
     out["host_rejects"] = rejects
     out["pairs"], out["windows"] = n_pairs, n_windows
     host_pairs = (rejects["aligner.fallback_band"]

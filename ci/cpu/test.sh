@@ -47,8 +47,8 @@ python -m pytest tests/test_columnar_init.py tests/test_window.py -q
 # first-party overlapper shard (fail-fast, round 20; the consolidated
 # graftlint gate above covers racon_tpu/ops/overlap_seed.py +
 # chain.py): minimizer/chain kernel-vs-numpy-oracle parity, the slice-
-# boundary dedup, resident-fetch parity, freq-cap accounting, the
-# warm-up cache claim, and the --overlaps auto determinism contract —
+# boundary dedup, freq-cap accounting, the warm-up cache claim, and
+# the --overlaps auto determinism contract —
 # byte-identical across thread counts, --shards 2, and gz/FASTQ/FASTA
 # input variants — plus the planner/rampler no-overlaps-file cases
 python -m pytest tests/test_overlapper.py -q
@@ -126,14 +126,6 @@ python -m pytest tests/test_fleet.py -q
 # bound + torn-tail replay, spool-corruption re-queue, slot-death
 # supervision/quarantine, the drain protocol and the retrying client
 python -m pytest tests/test_serve_recovery.py -q
-# resident-dataflow shard (fail-fast, round 19): device-resident
-# align→consensus byte-parity across strands / dummy-quality FASTA
-# reads / F-mode multi-overlap / chunked pipelined emit — with the
-# engagement assert (dataflow.resident gauge; a silently-disengaged
-# path would pass parity trivially) — the bail-out ladder (fractional
-# quality threshold → host fallback, identical bytes) and the
-# unit-level derive-kernel-vs-host-oracle grid
-python -m pytest tests/test_resident_dataflow.py -q
 # observability shard (fail-fast, round 11): trace schema,
 # RACON_TPU_TRACE byte-identity, disabled-span overhead guard,
 # run-report schema validation for CLI and exec runs
@@ -160,7 +152,6 @@ python -m pytest tests/ -x -q -m "not slow" --ignore=tests/test_ops_swar.py \
   --ignore=tests/test_exec.py --ignore=tests/test_ragged.py \
   --ignore=tests/test_align_stream.py \
   --ignore=tests/test_obs.py --ignore=tests/test_faults.py \
-  --ignore=tests/test_resident_dataflow.py \
   --ignore=tests/test_serve.py --ignore=tests/test_serve_recovery.py \
   --ignore=tests/test_topology.py --ignore=tests/test_parallel.py \
   --ignore=tests/test_compile_surface.py --ignore=tests/test_overlapper.py \

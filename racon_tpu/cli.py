@@ -356,8 +356,6 @@ def _refuse_rounds(parser, args) -> None:
             (args.fragment_correction and "-f", "fragment correction "
              "has no draft to hand to a next round"),
             (", ".join(sharded), "the shard runner polishes one round"),
-            (flags.get_bool("RACON_TPU_RESIDENT") and "RACON_TPU_RESIDENT",
-             "the resident dataflow keeps a round's reads on the device"),
             (args.overlaps
              and parsers.overlaps_mode(args.overlaps) != "auto"
              and "overlaps from a file", "they describe one draft: a "

@@ -94,10 +94,6 @@ METRICS: FrozenSet[str] = frozenset((
     "consensus.lane_rows", "consensus.lane_rows_copied",
     "consensus.swar_guard_int32",
     "consensus.sweep_truncated", "consensus.wavefront_steps",
-    # device-resident align->consensus dataflow
-    "dataflow.bytes_avoided", "dataflow.bytes_fetched",
-    "dataflow.fallback_pairs", "dataflow.lanes_device_groups",
-    "dataflow.resident", "dataflow.resident_bailouts",
     # exec ladder
     "exec.backoff_s",
     # the shard runner's job (the report's shard_run section): shards
@@ -212,7 +208,7 @@ RUN_PREFIXES: Tuple[str, ...] = (
     "align.", "aligner.", "poa.", "consensus.", "queue.", "retrace.",
     "retrace_total.", "swallowed.", "trace.", "parse.", "overlap.",
     "transmute", "bp.", "build.", "stitch", "exec.", "faults.",
-    "lease.", "device.", "compile.", "dataflow.", "idle.", "polisher.",
+    "lease.", "device.", "compile.", "idle.", "polisher.",
     "round.", "rounds.",
 )
 
@@ -314,7 +310,7 @@ FAULT_CLASSES: Tuple[str, ...] = ("transient-io", "device-oom", "stall",
 
 # -------------------------------------------------------- report schema
 
-SCHEMA_VERSION = 15
+SCHEMA_VERSION = 16
 
 # the oldest version validate_report still accepts, as itself: a stored
 # v11 report is held to the v11 key sets
@@ -417,9 +413,13 @@ SECTION_KEYS: Dict[str, Dict[str, int]] = {
 # stored report of an older version still holds them, a newer one must
 # not, and the schema-coherence message says "retired in v<N>" instead
 # of "unknown key". v13: the truncated event list and the roll-up by
-# frame, superseded by compiles.programs
+# frame, superseded by compiles.programs. v16: the whole "dataflow"
+# section (section "top": a key of the report itself), with the
+# flag-gated device-resident path whose bytes it counted; its key set
+# stays in SECTION_KEYS for the stored reports that hold it
 REMOVED_KEYS: Dict[str, Tuple[str, int]] = {
-    "by_function": ("compiles", 13), "events": ("compiles", 13)}
+    "by_function": ("compiles", 13), "events": ("compiles", 13),
+    "dataflow": ("top", 16)}
 
 
 def schema_keys(version: int = SCHEMA_VERSION) -> Dict[str, FrozenSet[str]]:
@@ -427,16 +427,18 @@ def schema_keys(version: int = SCHEMA_VERSION) -> Dict[str, FrozenSet[str]]:
     report's top level).  ``schema_keys(9)`` answers "what did a v9
     report contain" — the registry twin of report.py's version-history
     comment block."""
-    out = {"top": frozenset(k for k, v in TOP_KEYS.items()
-                            if v <= version)}
     def retired(section: str, key: str) -> bool:
         where, since = REMOVED_KEYS.get(key, ("", 0))
         return where == section and since <= version
 
+    out = {"top": frozenset(k for k, v in TOP_KEYS.items()
+                            if v <= version and not retired("top", k))}
     for section, keys in SECTION_KEYS.items():
-        out[section] = frozenset(k for k, v in keys.items()
-                                 if v <= version
-                                 and not retired(section, k))
+        # a section that left the report has no keys from then on
+        if not retired("top", section):
+            out[section] = frozenset(k for k, v in keys.items()
+                                     if v <= version
+                                     and not retired(section, k))
     return out
 
 
@@ -451,7 +453,6 @@ SECTION_EMITTERS: Dict[str, Tuple[str, str]] = {
     "pack": ("racon_tpu/obs/metrics.py", "pack_summary"),
     "recovery": ("racon_tpu/obs/metrics.py", "recovery_summary"),
     "compiles": ("racon_tpu/obs/compilewatch.py", "summary"),
-    "dataflow": ("racon_tpu/obs/metrics.py", "dataflow_summary"),
     "overlap": ("racon_tpu/obs/metrics.py", "overlap_summary"),
     "fleet": ("racon_tpu/obs/metrics.py", "fleet_summary"),
     "device_time": ("racon_tpu/obs/device_time.py", "account"),
@@ -492,13 +493,6 @@ REPORT_BACKING: Dict[str, str] = {
     "recovery.journal_compactions": "serve.journal_compactions",
     "recovery.slot_restarts": "slot.restarts",
     "recovery.slot_quarantined": "slot.quarantined",
-    "dataflow.resident": "dataflow.resident",
-    "dataflow.bytes_fetched": "dataflow.bytes_fetched",
-    "dataflow.bytes_avoided": "dataflow.bytes_avoided",
-    "dataflow.fallback_pairs": "dataflow.fallback_pairs",
-    "dataflow.resident_bailouts": "dataflow.resident_bailouts",
-    "dataflow.lanes_device_groups": "dataflow.lanes_device_groups",
-    "dataflow.ins_overflow_windows": "consensus.ins_overflow_windows",
     "overlap.minimizers": "overlap.minimizers",
     "overlap.candidate_pairs": "overlap.candidate_pairs",
     "overlap.freq_capped_buckets": "overlap.freq_capped_buckets",

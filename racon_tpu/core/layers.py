@@ -48,22 +48,18 @@ class PreparedPool:
     """What :meth:`LayerStore.prepare` makes of reads + overlaps alone:
     ``pool``/``qpool``/``qpw_pool`` as :class:`LayerStore` keeps them,
     per overlap its pool offset ``ov_off`` and whether it has qualities
-    ``hq_ov``, the smallest quality byte of a quality-bearing base
-    ``q_min`` (255 when there is none), and the wrapping prefix sums
-    ``qsum`` over ``qpool`` (``len(qpool) + 1`` entries) — the only
-    field the store does not keep: its user drops it after the
-    mean-PHRED filter."""
+    ``hq_ov``, and the wrapping prefix sums ``qsum`` over ``qpool``
+    (``len(qpool) + 1`` entries) — the only field the store does not
+    keep: its user drops it after the mean-PHRED filter."""
 
-    __slots__ = ("pool", "qpool", "qpw_pool", "ov_off", "hq_ov", "q_min",
-                 "qsum")
+    __slots__ = ("pool", "qpool", "qpw_pool", "ov_off", "hq_ov", "qsum")
 
-    def __init__(self, pool, qpool, qpw_pool, ov_off, hq_ov, q_min, qsum):
+    def __init__(self, pool, qpool, qpw_pool, ov_off, hq_ov, qsum):
         self.pool = pool
         self.qpool = qpool
         self.qpw_pool = qpw_pool
         self.ov_off = ov_off
         self.hq_ov = hq_ov
-        self.q_min = q_min
         self.qsum = qsum
 
 
@@ -76,10 +72,10 @@ class LayerStore:
     phred-33 clipped at 0, or 1 for no-quality reads)."""
 
     __slots__ = ("pool", "qpool", "qpw_pool", "src", "length", "begin",
-                 "end", "win_id", "has_qual", "row_bounds", "dev_qpw")
+                 "end", "win_id", "has_qual", "row_bounds")
 
     def __init__(self, pool, qpool, qpw_pool, src, length, begin, end,
-                 win_id, has_qual, row_bounds, dev_qpw=None):
+                 win_id, has_qual, row_bounds):
         self.pool = pool
         self.qpool = qpool
         self.qpw_pool = qpw_pool
@@ -90,11 +86,6 @@ class LayerStore:
         self.win_id = win_id
         self.has_qual = has_qual
         self.row_bounds = row_bounds
-        # device-resident copy of qpw_pool (round 19): when the resident
-        # dataflow built this store it uploaded the packed pool once, and
-        # the consensus packer gathers lanes on device instead of
-        # re-uploading host-gathered [B, Lq] blocks per group
-        self.dev_qpw = dev_qpw
 
     @property
     def n_rows(self) -> int:
@@ -183,13 +174,7 @@ class LayerStore:
         # 0, so no weight bit is set yet)
         for i in np.flatnonzero(~has_q):
             qpw_pool[part_off[i]:part_off[i] + part_len[i]] |= 1 << 3
-        # per read its smallest quality byte, in one pass (an empty read
-        # has no segment of its own)
-        real = part_len > 0
-        q_min = int(np.minimum.reduceat(qpool, part_off[real])
-                    [has_q[real]].min(initial=255))
-        return PreparedPool(pool, qpool, qpw_pool, ov_off, hq_ov, q_min,
-                            qsum)
+        return PreparedPool(pool, qpool, qpw_pool, ov_off, hq_ov, qsum)
 
     # ------------------------------------------------------ device packing
 

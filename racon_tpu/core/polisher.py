@@ -228,12 +228,6 @@ class Polisher:
         # build_windows_s, pipeline_overlap_saved_s): the run report's
         # ``phases`` section
         self.timings: Dict[str, float] = {}
-        # device-resident align->consensus dataflow (round 19): accepted
-        # breaking points stay on device and layer rows derive there;
-        # _resident_info carries the pool-upload bandwidth measurement
-        # _stitch uses for the lane-upload-saved accounting
-        self._resident = flags.get_bool("RACON_TPU_RESIDENT")
-        self._resident_info: Dict[str, float] = {}
         # _prepare_layers started ahead of the aligner (_start_prepare),
         # until _assemble_layers takes it
         self._prepare_ahead: Optional[_PrepareAhead] = None
@@ -787,11 +781,6 @@ class Polisher:
         # columnar rows inside align.fetch
         with obs.span("bp.decode"):
             todo = [o for o in overlaps if o.breaking_points is None]
-            if todo and self._resident:
-                # the small host-needed CIGAR subset (SAM input, host
-                # aligner fallback) — part of the dataflow's
-                # fallback-to-host count
-                metrics.inc("dataflow.fallback_pairs", len(todo))
             if todo:
                 arrs = decode_breaking_points_batch(
                     [o.cigar or "" for o in todo],
@@ -826,8 +815,7 @@ class Polisher:
         the barrier path, same as a sessionless backend."""
         sess = self.aligner.bp_stream(
             self.window_length, total=len(need),
-            progress=lambda d, t: log.bar_to(msg, d, t),
-            resident=self._resident)
+            progress=lambda d, t: log.bar_to(msg, d, t))
         feed_wall = 0.0
         t0 = time.perf_counter()
         for batch in feed:
@@ -877,8 +865,7 @@ class Polisher:
             # its overlap's filter-time error estimate
             mk = getattr(self.aligner, "bp_stream", None)
             sess = mk(self.window_length, total=len(need),
-                      progress=lambda d, t: log.bar_to(msg, d, t),
-                      resident=self._resident) \
+                      progress=lambda d, t: log.bar_to(msg, d, t)) \
                 if mk is not None else None
             for begin in range(0, len(need), chunk):
                 part = need[begin:begin + chunk]
@@ -946,8 +933,7 @@ class Polisher:
 
     def _layer_refs(self, overlaps: List[Overlap]):
         """Per-overlap oriented (data, quality) references into the read
-        set — forward or reverse-complement per strand. Shared by the
-        host and device-resident layer assembly."""
+        set — forward or reverse-complement per strand."""
         data_refs: List[bytes] = []
         qual_refs: List[Optional[bytes]] = []
         for o in overlaps:
@@ -1024,11 +1010,10 @@ class Polisher:
     def _filter_layer_rows(self, prep: PreparedPool, bp, pair_ov, t_ids):
         """The vectorized filter core of :meth:`_assemble_layers` —
         min-span, mean-PHRED and window arithmetic over one concatenated
-        (P, 4) breaking-point matrix. THE single-source host oracle: the
-        device-resident derive kernel mirrors these exact compares, and
-        the resident path runs this same code for its host-fallback
-        subset (rejected/CIGAR pairs). Returns ``(keep, win_id,
-        layer_begin, layer_end)`` aligned with ``bp``'s rows."""
+        (P, 4) breaking-point matrix, whichever aligner wrote its rows
+        (device tables, host fallback, a file's CIGARs). Returns
+        ``(keep, win_id, layer_begin, layer_end)`` aligned with ``bp``'s
+        rows."""
         window_length = self.window_length
         t_first, q_first = bp[:, 0], bp[:, 1]
         t_endx, q_endx = bp[:, 2], bp[:, 3]
@@ -1057,209 +1042,6 @@ class Polisher:
         keep &= layer_begin != layer_end
         return keep, win_id, layer_begin, layer_end
 
-    def _assemble_layers_resident(self, overlaps: List[Overlap],
-                                  prep: PreparedPool, emit,
-                                  chunk_windows: int, t_build) -> bool:
-        """Device-resident layer assembly (round 19): derive window
-        assignment and per-window layer rows ON DEVICE from the align
-        stream's resident breaking-point tables, fetch ONE sorted
-        [rows, 6] table, and construct the window-major
-        :class:`LayerStore` directly from it — no per-chunk bp fetch, no
-        host filter sweep, no host argsort. Byte-identical to the host
-        path by construction (the derive kernel mirrors
-        :meth:`_filter_layer_rows` exactly; the parity suite asserts
-        it).
-
-        Returns True when it handled the assembly. Returns False —
-        after host-decoding every device handle, so the caller's host
-        body sees plain arrays — when a precondition fails: no resident
-        handles (host/CIGAR-only run), a fractional quality threshold
-        or sub-33 quality bytes (the integer-exactness gate of the
-        device mean-PHRED compare)."""
-        dev = [(i, o.breaking_points) for i, o in enumerate(overlaps)
-               if getattr(o.breaking_points, "is_device_resident", False)]
-        if not dev:
-            return False
-
-        def bail(reason: str) -> bool:
-            metrics.inc("dataflow.resident_bailouts")
-            metrics.set_gauge("dataflow.resident", 0)
-            self.logger.log(
-                f"[racon_tpu::Polisher::initialize] resident dataflow "
-                f"falling back to host assembly ({reason})")
-            for i, h in dev:
-                overlaps[i].breaking_points = h.decode_host()
-            return False
-
-        qthr = self.quality_threshold
-        if not float(qthr).is_integer() or not 0 <= qthr < (1 << 20):
-            return bail("non-integer quality threshold")
-
-        from ..ops import nw as _nw
-        window_length = self.window_length
-        n_ov = len(overlaps)
-        n_win = len(self.windows)
-        t_ids = np.fromiter((o.t_id for o in overlaps), np.int64, n_ov)
-        # graftlint: disable=lock-discipline (one builder thread per polisher; see _initialize_core)
-        self.targets_coverages = np.bincount(
-            t_ids, minlength=self.targets_size).tolist()
-
-        ov_off, hq_ov, qpw_pool = prep.ov_off, prep.hq_ov, prep.qpw_pool
-        if prep.q_min < 33:
-            return bail("quality bytes below phred+33")
-
-        t_derive = time.perf_counter()
-        # one pool upload for the whole run — timed, because the
-        # measured bandwidth prices the lane uploads the consensus
-        # engine no longer makes (lane_upload_saved_s in _stitch)
-        t_up = time.perf_counter()
-        dev_pool = _nw.upload_qpw_pool(qpw_pool)
-        up_s = time.perf_counter() - t_up
-        # graftlint: disable=lock-discipline (init zeroes it before the produce thread starts; this is the only live write and _stitch reads after join)
-        self._resident_info = {"pool_bytes": float(qpw_pool.nbytes),
-                               "upload_s": up_s}
-        # an integer >= a real iff >= its ceiling: s_min reproduces the
-        # host's  span < 0.02 * window_length  float compare exactly
-        s_min = int(np.ceil(0.02 * window_length))
-        q_need = int(qthr)
-
-        # per-chunk derive dispatch: group handles by their chunk and
-        # hand the kernel full-B per-lane metadata (dead lanes zeroed)
-        by_chunk: Dict[int, list] = {}
-        chunks: Dict[int, object] = {}
-        for i, h in dev:
-            by_chunk.setdefault(id(h.chunk), []).append((i, h))
-            chunks[id(h.chunk)] = h.chunk
-        parts = []
-        starts = np.zeros(n_ov, np.int64)
-        cnts = np.zeros(n_ov, np.int64)
-        base = 0
-        for key, items in by_chunk.items():
-            ch = chunks[key]
-            B = ch.B
-            live = np.zeros(B, bool)
-            tb = np.zeros(B, np.int32)
-            qo_read = np.zeros(B, np.int32)
-            qo_pool = np.zeros(B, np.int32)
-            n_reg = np.zeros(B, np.int32)
-            win_base = np.zeros(B, np.int32)
-            ov_idx = np.zeros(B, np.int32)
-            has_q = np.zeros(B, bool)
-            qlen = np.zeros(B, np.int32)
-            for i, h in items:
-                k = h.lane
-                live[k] = True
-                tb[k] = h.t_begin
-                qo_read[k] = h.q_off
-                qo_pool[k] = int(ov_off[i]) + h.q_off
-                n_reg[k] = h.n_reg
-                win_base[k] = int(self._id_to_first_window[t_ids[i]])
-                ov_idx[k] = i
-                has_q[k] = bool(hq_ov[i])
-                qlen[k] = h.qlen
-                # every lane contributes its chunk's full NW-row block;
-                # dropped rows carry the sentinel and sort to the tail
-                starts[i] = base + k * ch.NW
-                cnts[i] = ch.NW
-            # graftlint: disable=jit-shape-hazard (ov_idx is a traced [B] operand — only w/NW/Lq are static, and both come from the chunk's pow2 stream geometry)
-            parts.append(ch.derive(dev_pool, live, tb, qo_read, qo_pool,
-                                   n_reg, win_base, ov_idx, has_q, qlen,
-                                   s_min, q_need))
-            base += B * ch.NW
-
-        # host-fallback subset (rejected pairs, CIGAR decodes): the SAME
-        # oracle filter, restricted by zeroing device overlaps' counts
-        dev_set = set(i for i, _ in dev)
-        counts_host = np.fromiter(
-            (0 if (i in dev_set or o.breaking_points is None)
-             else len(o.breaking_points)
-             for i, o in enumerate(overlaps)), np.int64, n_ov)
-        host_rows = int(counts_host.sum())
-        if host_rows:
-            bp_h = np.concatenate(
-                [overlaps[i].breaking_points
-                 for i in np.flatnonzero(counts_host)]).astype(np.int64)
-            pair_ov_h = np.repeat(np.arange(n_ov), counts_host)
-            keep_h, win_h, lb_h, le_h = self._filter_layer_rows(
-                prep, bp_h, pair_ov_h, t_ids)
-            kidx = np.flatnonzero(keep_h)
-            host_flat = np.stack(
-                [win_h[kidx], pair_ov_h[kidx], bp_h[kidx, 1],
-                 bp_h[kidx, 3], lb_h[kidx], le_h[kidx]],
-                axis=1).astype(np.int32)
-            host_counts = np.bincount(pair_ov_h[kidx], minlength=n_ov)
-        else:
-            host_flat = np.zeros((0, 6), np.int32)
-            host_counts = np.zeros(n_ov, np.int64)
-        prep.qsum = None
-        cum_host = np.zeros(n_ov + 1, np.int64)
-        np.cumsum(host_counts, out=cum_host[1:])
-        host_mask = np.ones(n_ov, bool)
-        host_mask[list(dev_set)] = False
-        starts[host_mask] = base + cum_host[:-1][host_mask]
-        cnts[host_mask] = host_counts[host_mask]
-
-        # gather order = overlap-stream order (device rows keep their
-        # boundary order inside each lane block, host rows theirs), so
-        # the device stable sort reproduces the host stable argsort
-        total = int(cnts.sum())
-        cum0 = np.zeros(n_ov + 1, np.int64)
-        np.cumsum(cnts, out=cum0[1:])
-        src = (np.repeat(starts, cnts)
-               + (np.arange(total, dtype=np.int64)
-                  - np.repeat(cum0[:-1], cnts)))
-
-        table = _nw.finalize_layer_table(parts, host_flat, src)
-        self.timings["window_derive_s"] = round(
-            time.perf_counter() - t_derive, 3)
-        metrics.set_gauge("dataflow.resident", 1)
-        metrics.inc("dataflow.bytes_fetched", int(table.nbytes))
-
-        nkept = int(np.searchsorted(table[:, 0], _nw._ROW_SENTINEL))
-        rows = table[:nkept].astype(np.int64)
-        win_id = rows[:, 0]
-        ov = rows[:, 1]
-        q_first = rows[:, 2]
-        q_endx = rows[:, 3]
-        layer_begin = rows[:, 4]
-        layer_end = rows[:, 5]
-        if nkept:
-            backbone_len = self._window_lengths[win_id]
-            if ((layer_begin > layer_end)
-                    | (layer_end > backbone_len)).any():
-                raise ValueError("layer begin and end positions are invalid")
-
-        store = LayerStore(
-            prep.pool, prep.qpool, qpw_pool, ov_off[ov] + q_first,
-            q_endx - q_first, layer_begin, layer_end, win_id, hq_ov[ov],
-            np.searchsorted(win_id, np.arange(n_win + 1)),
-            dev_qpw=dev_pool)
-
-        windows = self.windows
-        if not chunk_windows:
-            chunk_windows = n_win
-        t_append = time.thread_time()
-        bounds = store.row_bounds
-        for w0 in range(0, n_win, chunk_windows):
-            w1 = min(w0 + chunk_windows, n_win)
-            for wi in range(w0, w1):
-                r0, r1 = int(bounds[wi]), int(bounds[wi + 1])
-                if r1 > r0:
-                    windows[wi].attach_layers(store, r0, r1)
-            if emit is not None:
-                emit(w0, w1)
-        self.timings["layer_append_s"] = round(
-            time.thread_time() - t_append, 3)
-
-        for o in overlaps:
-            o.breaking_points = None
-        if self.evict_reads:
-            for seq in self.sequences[self.targets_size:]:
-                seq.release()
-        self.timings["build_windows_s"] = round(
-            self._backbone_s + (time.perf_counter() - t_build), 3)
-        return True
-
     def _assemble_layers(self, overlaps: List[Overlap], emit=None,
                          chunk_windows: int = 0) -> None:
         """Columnar layer assembly, the half that needs breaking points
@@ -1285,9 +1067,6 @@ class Polisher:
             # graftlint: disable=lock-discipline (one builder thread per polisher; see _initialize_core)
             self.windows = []
             raise
-        if self._resident and self._assemble_layers_resident(
-                overlaps, prep, emit, chunk_windows, t_build):
-            return
         window_length = self.window_length
         n_ov = len(overlaps)
         n_win = len(self.windows)
@@ -1668,19 +1447,6 @@ class Polisher:
     def _stitch(self, polished_flags: List[bool],
                 drop_unpolished_sequences: bool) -> List[Sequence]:
         log = self.logger
-        # resident-dataflow accounting: price the per-group lane uploads
-        # the consensus engine skipped (it gathered from the resident
-        # pool instead) at the measured pool-upload bandwidth — the
-        # "upload time we did not spend" line of
-        # pipeline_init_breakdown
-        saved = getattr(self.consensus, "stats", {}).get(
-            "lane_upload_saved_bytes", 0)
-        if saved:
-            info = self._resident_info
-            up_s = info.get("upload_s", 0.0)
-            bw = (info.get("pool_bytes", 0.0) / up_s) if up_s else 0.0
-            self.timings["lane_upload_saved_s"] = (
-                round(saved / bw, 3) if bw else 0.0)
         dst: List[Sequence] = []
         polished_data: List[bytes] = []
         num_polished = 0

@@ -30,7 +30,7 @@ class ReadSet:
     def __init__(self, path: str):
         self.path = path
         self._sequences: Optional[List[Sequence]] = None
-        # (k, w, resident) -> the overlapper's table of these reads
+        # (k, w) -> the overlapper's table of these reads
         self.seed_tables: Dict[tuple, tuple] = {}
 
     def sequences(self) -> List[Sequence]:

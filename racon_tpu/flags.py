@@ -60,16 +60,6 @@ REGISTRY: Dict[str, Flag] = _declare([
     Flag("RACON_TPU_SWAR", "1", "bool",
          "Packed SWAR kernels (int16x2 score lanes, 2-bit bases); set 0 "
          "to force the int32 path for A/B measurement."),
-    Flag("RACON_TPU_RESIDENT", "0", "bool",
-         "Device-resident align->consensus dataflow: accepted breaking-"
-         "point tables stay on device, window assignment and per-window "
-         "layer rows are derived by jit'd array ops (min-span + "
-         "mean-PHRED filters, window arithmetic, stable argsort), and "
-         "the consensus engine gathers weight<<3|code lanes from the "
-         "device-resident pool instead of re-uploading host-packed "
-         "lanes. Byte-identical to the host path (the parity oracle); "
-         "falls back per-run when a precondition fails (mesh sharding, "
-         "fractional quality threshold, sub-33 quality bytes)."),
     # ------------------------------------------------------- compile cache
     Flag("RACON_TPU_NO_COMPILE_CACHE", "0", "bool",
          "Set to disable the persistent XLA compilation cache (its "

@@ -300,34 +300,6 @@ def device_summary(scope: str = "") -> Dict[str, Dict[str, Number]]:
     return rows
 
 
-def dataflow_summary(scope: str = "") -> Dict[str, Number]:
-    """The device-resident align→consensus accounting the run report's
-    ``dataflow`` section (schema v8) embeds: whether the resident path
-    was live this run (``dataflow.resident`` gauge; 0 when the
-    RACON_TPU_RESIDENT flag is off or the path bailed), bytes actually
-    fetched from device vs bytes whose host round-trip was avoided,
-    overlap pairs that fell back to host decode, bail-out count, the
-    number of consensus groups whose qpw lanes were gathered on device
-    instead of re-uploaded, and per-window insertion-overflow
-    attribution.  ``scope`` reads one job's numbers."""
-    with _lock:
-        return {
-            "resident": _gauges.get(scope + "dataflow.resident", 0),
-            "bytes_fetched": _counters.get(
-                scope + "dataflow.bytes_fetched", 0),
-            "bytes_avoided": _counters.get(
-                scope + "dataflow.bytes_avoided", 0),
-            "fallback_pairs": _counters.get(
-                scope + "dataflow.fallback_pairs", 0),
-            "resident_bailouts": _counters.get(
-                scope + "dataflow.resident_bailouts", 0),
-            "lanes_device_groups": _counters.get(
-                scope + "dataflow.lanes_device_groups", 0),
-            "ins_overflow_windows": _counters.get(
-                scope + "consensus.ins_overflow_windows", 0),
-        }
-
-
 def overlap_summary(scope: str = "") -> Dict[str, Number]:
     """The first-party overlapper accounting the run report's
     ``overlap`` section (schema v10) embeds: the overlap source

@@ -67,8 +67,8 @@ from .. import contracts
 # re-uploads), overlap pairs that fell back to the host decode path
 # (CIGAR-needed subset + band rejects), bail-out count, and per-window
 # insertion-overflow attribution ("ins_overflow_windows").  All zeros
-# when RACON_TPU_RESIDENT is off.  Per-job reports filter to the
-# job's scope.
+# where the path's flag was off.  Per-job reports filter to the job's
+# scope.  The section left at v16, with the path.
 # v9 (round 20): the "overlap" section became required — first-party
 # overlapper accounting (``overlap.*`` metrics): the overlap source
 # ("mode": "auto" for the in-process minimizer+chain overlapper, "paf"
@@ -133,6 +133,12 @@ from .. import contracts
 # its shards' warm-ups) joined the exec spans, and device idle a
 # shard's feeding thread holds in no span of its own is cut by the
 # slot thread's exec.* spans (``idle.exec.*``).
+# v16 (PR 46): the "dataflow" section left with the flag-gated device-
+# resident path it accounted for (contracts.REMOVED_KEYS, section
+# "top"); a v16 report that carries it is refused as retired, a stored
+# v11-v15 report keeps it and validates as what it is.
+# "consensus.ins_overflow_windows" stays a counter under "metrics": the
+# section only mirrored it.
 # the schema's key sets (per section, per version) live in
 # racon_tpu/contracts.py — ONE registry shared with the schema-coherence
 # lint rule, so a schema bump is a contracts.py edit the gate enforces
@@ -164,7 +170,6 @@ _TOP = {
     "faults": (dict, True),             # fault class/site/lease counts
     "recovery": (dict, True),           # crash-safe serving counters
     "compiles": (dict, True),           # XLA compile attribution (v7)
-    "dataflow": (dict, True),           # resident-dataflow bytes (v8)
     "overlap": (dict, True),            # first-party overlapper (v9/v10)
     "fleet": (dict, True),              # fleet gateway counters (v11)
     "device_time": (dict, True),        # device-occupancy ledger (v12)
@@ -182,6 +187,12 @@ _TOP = {
 assert frozenset(_TOP) == _SCHEMA_KEYS["top"], \
     "report._TOP drifted from contracts.TOP_KEYS"
 
+# top-level keys that left the schema (contracts.REMOVED_KEYS, section
+# "top"): a stored report from before holds them, and is held to them
+_TOP_RETIRED = {
+    "dataflow": (dict, True),           # resident-dataflow bytes (v8-v15)
+}
+
 _QUEUE_KEYS = tuple(sorted(_SCHEMA_KEYS["queue"]))
 _PACK_KEYS = tuple(sorted(_SCHEMA_KEYS["pack"]))
 _RECOVERY_KEYS = tuple(sorted(_SCHEMA_KEYS["recovery"]))
@@ -192,7 +203,6 @@ _PROGRAM_STR_KEYS = ("program", "fn", "signature", "geometry", "thread",
 _PROGRAM_NUM_KEYS = ("t0_ns", "t1_ns", "trace_s", "lower_s", "backend_s",
                      "retrieve_s")
 _PROGRAM_CACHE = ("hit", "miss", "none")
-_DATAFLOW_KEYS = tuple(sorted(_SCHEMA_KEYS["dataflow"]))
 _FLEET_KEYS = tuple(sorted(_SCHEMA_KEYS["fleet"]))
 # "rows" (a list of rows) validates structurally below
 _ROUNDS_NUM_KEYS = tuple(sorted(_SCHEMA_KEYS["rounds"] - {"rows"}))
@@ -297,11 +307,6 @@ def build_report(kind: str, *, argv: Optional[list] = None,
         # metrics snapshot: it pins the compile.retrieve timer
         "compiles": compilewatch.summary(
             scope, device_time.dispatch_counts(scope)),
-        # device-resident align→consensus accounting (round 19, schema
-        # v8): resident on/off, bytes fetched vs host round-trips
-        # avoided, host-fallback pair count and per-window insertion-
-        # overflow attribution — all zeros with the flag off
-        "dataflow": metrics.dataflow_summary(scope),
         # first-party overlapper accounting (round 20 v9, extended
         # round 21 v10): overlap source, table/candidate volume,
         # freq-cap and chain keep/drop counts, chain-arena occupancy,
@@ -470,8 +475,9 @@ def validate_report(rep) -> List[str]:
                       f"{MIN_SCHEMA_VERSION}..{SCHEMA_VERSION}")
         version = SCHEMA_VERSION
     # a stored report of an older version is held to ITS key sets
-    top = {k: v for k, v in _TOP.items()
-           if contracts.TOP_KEYS[k] <= version}
+    keys = contracts.schema_keys(version)
+    top = {k: v for k, v in {**_TOP, **_TOP_RETIRED}.items()
+           if k in keys["top"]}
     for key, (types, required) in top.items():
         if key not in rep:
             if required:
@@ -479,8 +485,10 @@ def validate_report(rep) -> List[str]:
             continue
         if not isinstance(rep[key], types) or isinstance(rep[key], bool):
             errors.append(f"{key!r} has type {type(rep[key]).__name__}")
-    for key in set(rep) - set(top):
-        errors.append(f"unknown key {key!r}")
+    for key in sorted(set(rep) - set(top)):
+        removed = contracts.REMOVED_KEYS.get(key, ("", 0))
+        errors.append(f"{key!r} retired in schema v{removed[1]}"
+                      if removed[0] == "top" else f"unknown key {key!r}")
     if errors:
         return errors
     if rep["kind"] not in KINDS:
@@ -503,9 +511,8 @@ def validate_report(rep) -> List[str]:
     for key in _PACK_KEYS:
         if not isinstance(rep["pack"].get(key), _NUM):
             errors.append(f"pack[{key!r}] missing or non-numeric")
-    for key in _DATAFLOW_KEYS:
-        if not isinstance(rep["dataflow"].get(key), _NUM) \
-                or isinstance(rep["dataflow"].get(key), bool):
+    for key in sorted(keys.get("dataflow", ())):
+        if not _is_num(rep["dataflow"].get(key)):
             errors.append(f"dataflow[{key!r}] missing or non-numeric")
     for key in _FLEET_KEYS:
         if not isinstance(rep["fleet"].get(key), _NUM) \

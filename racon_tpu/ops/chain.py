@@ -36,8 +36,7 @@ Consumes the flat minimizer tables from :mod:`.overlap_seed` and emits
   discipline, warmed via :func:`_warmup_shapes`) — and a ``lax.scan``
   over seed positions scores gap-bounded colinear chains against a
   bounded lookback window, then backtracks on device so only a
-  ``[B, 6]`` summary per launch crosses the link — resident-friendly by
-  construction.
+  ``[B, 6]`` summary per launch crosses the link.
 - **streaming** (:func:`iter_overlap_groups`): after each fetched chunk
   the query groups it completed leave, in order, as one block of
   canonical rows; the polisher's filter and the round-17 align stream
@@ -154,11 +153,10 @@ def _hits_pad(n: int) -> int:
 # ---------------------------------------------------------------- kernel
 
 @functools.lru_cache(maxsize=None)
-def _chain_geometry(S: int, B: int, k: int = 0, E: int = 0) -> str:
-    """The occupancy ledger's join key of a ``[B, S]`` chain arena, from
-    the streams and the warm-up alike: the chain kernel's with its
-    ``k``, the arena gather's with the hit arena ``E`` it reads."""
-    return device_time.geometry(B=B, S=S, k=k, E=E)
+def _chain_geometry(S: int, B: int, k: int) -> str:
+    """The occupancy ledger's join key of the chain kernel on a
+    ``[B, S]`` arena, from the streams and the warm-up alike."""
+    return device_time.geometry(B=B, S=S, k=k)
 
 
 @functools.lru_cache(maxsize=None)
@@ -417,16 +415,14 @@ def _present_reads(rh: np.ndarray, uh: np.ndarray) -> Optional[np.ndarray]:
 
 def join_seeds(read_table, target_table, read_self_t: np.ndarray,
                qlens: np.ndarray, *, k: int, max_occ: int,
-               device_join: bool = True, resident: bool = False
-               ) -> Tuple[Dict[str, object], int]:
+               device_join: bool = True
+               ) -> Tuple[Dict[str, np.ndarray], int]:
     """Seed join front end: the device kernels when eligible, the numpy
     :func:`match_seeds` oracle otherwise.
 
-    Returns ``(hits, freq_capped)``. ``hits`` always carries host
+    Returns ``(hits, freq_capped)``. ``hits`` carries host
     ``q``/``t``/``rel``/``tp``/``qc`` int64 arrays in the oracle's
-    order; under ``resident=True`` on the device path also device
-    ``tp_dev``/``qc_dev`` int32 arrays the chain stream gathers from
-    directly.
+    order.
 
     Of the read table only the entries that can match cross to the
     device (:func:`_present_reads`; counters ``overlap.join_read_entries``
@@ -504,7 +500,6 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
         device_time.submit("h2d", "overlap.join.put", sides_d[-1])
     with obs.span("overlap.join.fetch"):
         total, capped = (int(x) for x in fetch_global([total_d, capped_d]))
-    metrics.inc("dataflow.bytes_fetched", 8)
     if total > JOIN_MAX_HITS:
         # rung 3: hit arena overflow (a repeat-soaked join the chain
         # phase could not absorb anyway)
@@ -538,14 +533,7 @@ def join_seeds(read_table, target_table, read_self_t: np.ndarray,
         # this is the oracle's order exactly
         order = np.lexsort(cols[::-1])
         q_h, t_h, rel_h, tp_h, qc_h = (c[order] for c in cols)
-    metrics.inc("dataflow.bytes_fetched", 20 * E + 4)
-    hits: Dict[str, object] = {"q": q_h, "t": t_h, "rel": rel_h,
-                               "tp": tp_h, "qc": qc_h}
-    if resident:
-        # the chain stream gathers its arenas on the device from these
-        hits["tp_dev"] = jnp.asarray(tp_h.astype(np.int32))
-        hits["qc_dev"] = jnp.asarray(qc_h.astype(np.int32))
-    return hits, capped
+    return {"q": q_h, "t": t_h, "rel": rel_h, "tp": tp_h, "qc": qc_h}, capped
 
 
 # -------------------------------------------------------------- chaining
@@ -572,9 +560,8 @@ def _pack_lanes(tp: np.ndarray, qc: np.ndarray, starts: np.ndarray,
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Vectorized host fill of one ``[B, S]`` chain arena from the flat
     hit arrays — one masked gather instead of the former per-lane
-    Python slice loop (the host analog of :func:`_gather_pairs_kernel`;
-    ``starts``/``counts`` are length B, zero-padded past the live
-    lanes)."""
+    Python slice loop (``starts``/``counts`` are length B, zero-padded
+    past the live lanes)."""
     lane_starts = starts[:, None] + np.arange(S, dtype=np.int64)[None, :]
     mask = np.arange(S, dtype=np.int64)[None, :] < counts[:, None]
     np.clip(lane_starts, 0, max(0, tp.size - 1), out=lane_starts)
@@ -592,19 +579,6 @@ def _put_lanes(tp: np.ndarray, qc: np.ndarray, starts: np.ndarray,
     ts, qs = (jnp.asarray(a) for a in _pack_lanes(tp, qc, starts, counts,
                                                   S, B))
     device_time.submit("h2d", "overlap.chain.put", qs)
-    return ts, qs
-
-
-@functools.partial(jax.jit, static_argnames=("S",))
-def _gather_pairs_kernel(tp_dev, qc_dev, starts, counts, *, S: int):
-    """Device fill of one ``[B, S]`` chain arena straight from the
-    resident join output — the matched seed coordinates feed
-    :func:`_chain_kernel` without ever visiting the host."""
-    idx = starts[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    mask = jnp.arange(S, dtype=jnp.int32)[None, :] < counts[:, None]
-    idx = jnp.clip(idx, 0, tp_dev.shape[0] - 1)
-    ts = jnp.where(mask, tp_dev[idx], jnp.int32(0))
-    qs = jnp.where(mask, qc_dev[idx], jnp.int32(0))
     return ts, qs
 
 
@@ -653,21 +627,16 @@ class _ChainStream:
     [n, 6])`` block. The DP is per-lane independent and a pair's class
     is its own seed count's, so a pair's row does not depend on its
     chunk mates — the property the streamed/barriered byte-identity
-    contract rests on.
+    contract rests on. ``tp``/``qc``/``starts``/``counts`` are host
+    arrays."""
 
-    ``tp``/``qc`` may be host arrays (vectorized masked gather) or the
-    resident join's device copies (:func:`_gather_pairs_kernel` fills
-    the arena on the device); ``starts``/``counts`` are host arrays in
-    both."""
-
-    def __init__(self, *, k: int, tp, qc, starts: np.ndarray,
-                 counts: np.ndarray, device_src: bool = False):
+    def __init__(self, *, k: int, tp: np.ndarray, qc: np.ndarray,
+                 starts: np.ndarray, counts: np.ndarray):
         self.k = k
         self.tp = tp
         self.qc = qc
         self.starts = starts
         self.counts = counts
-        self.device_src = device_src
         self.inflight: List[Tuple[np.ndarray, object, int]] = []
         self.inflight_cells = 0
         # pairs handed to the device so far
@@ -695,15 +664,7 @@ class _ChainStream:
         counts[:n] = self.counts[idx]
         with obs.span("overlap.chain.dispatch", pairs=n):
             ns = counts.astype(np.int32)
-            if self.device_src:
-                # graftlint: disable=jit-shape-hazard (S is the pow4 _seed_bucket rung)
-                ts, qs = _gather_pairs_kernel(
-                    self.tp, self.qc, starts.astype(np.int32), ns, S=S)
-                device_time.submit(
-                    "exec", "_gather_pairs_kernel", ts,
-                    _chain_geometry(S, B, E=int(self.tp.shape[0])))
-            else:
-                ts, qs = _put_lanes(self.tp, self.qc, starts, counts, S, B)
+            ts, qs = _put_lanes(self.tp, self.qc, starts, counts, S, B)
             # graftlint: disable=jit-shape-hazard (k is a run-constant flag value — one compile per run; S is the pow4 bucket)
             out = _chain_kernel(ts, qs, ns, S=S, k=self.k)
             device_time.submit("exec", "_chain_kernel", out,
@@ -809,8 +770,7 @@ def _empty_rows() -> Dict[str, np.ndarray]:
     return {key: np.zeros(0, np.int64) for key in _ROW_KEYS}
 
 
-def _resolve_params(k, w, max_occ, min_seeds, resident, device_join,
-                    ragged):
+def _resolve_params(k, w, max_occ, min_seeds, device_join, ragged):
     from .. import flags
     k = flags.get_int("RACON_TPU_OVERLAP_K") if k is None else k
     w = flags.get_int("RACON_TPU_OVERLAP_W") if w is None else w
@@ -818,32 +778,28 @@ def _resolve_params(k, w, max_occ, min_seeds, resident, device_join,
         max_occ = flags.get_int("RACON_TPU_OVERLAP_MAX_OCC")
     if min_seeds is None:
         min_seeds = flags.get_int("RACON_TPU_OVERLAP_MIN_SEEDS")
-    if resident is None:
-        resident = flags.get_bool("RACON_TPU_RESIDENT")
     if device_join is None:
         device_join = flags.get_bool("RACON_TPU_OVERLAP_DEVICE_JOIN")
     if ragged is None:
         ragged = flags.get_bool("RACON_TPU_OVERLAP_RAGGED")
     k = max(4, min(16, k))  # uint32 canonical codes hold 2k bits
     w = max(1, w)
-    return k, w, max_occ, min_seeds, resident, device_join, ragged
+    return k, w, max_occ, min_seeds, device_join, ragged
 
 
-def _read_table(read_seqs, held, *, k, w, resident):
+def _read_table(read_seqs, held, *, k, w):
     """The read-side seed table: built here, or taken from ``held`` —
     a dict its caller keeps across calls on the SAME reads (the rounds
     of one ``--rounds N`` job: the draft changes between them, the
     reads do not), keyed by what the table depends on beside the
     bytes. A table taken is counted as a cached target table is."""
-    key = (k, w, resident)
+    key = (k, w)
     if held is not None and key in held:
         rt = held[key]
         metrics.inc("rounds.read_tables_reused")
         metrics.inc("overlap.minimizers", int(rt[0].size))
-        metrics.inc("dataflow.bytes_avoided", int(rt[0].size) * 10)
         return rt
-    rt = overlap_seed.build_seed_table(read_seqs, k=k, w=w,
-                                       resident=resident)
+    rt = overlap_seed.build_seed_table(read_seqs, k=k, w=w)
     metrics.inc("rounds.read_tables_built")
     if held is not None:
         held[key] = rt
@@ -851,25 +807,19 @@ def _read_table(read_seqs, held, *, k, w, resident):
 
 
 def _seed_and_join(read_seqs, target_seqs, read_self_t, qlens, *,
-                   k, w, max_occ, resident, device_join, cache,
-                   resident_hits, read_tables=None):
+                   k, w, max_occ, device_join, cache, read_tables=None):
     """Seed both pools (target table through the fingerprint cache,
     read table through ``read_tables`` where the caller holds one:
-    :func:`_read_table`) and run the join front end. ``resident_hits``
-    keeps the matched seed coordinates on device (only meaningful on
-    the device-join path feeding the chain stream)."""
+    :func:`_read_table`) and run the join front end."""
     with obs.span("overlap.seed", reads=len(read_seqs),
                   targets=len(target_seqs)):
-        rt = _read_table(read_seqs, read_tables, k=k, w=w,
-                         resident=resident)
+        rt = _read_table(read_seqs, read_tables, k=k, w=w)
         tt = overlap_seed.build_seed_table(target_seqs, k=k, w=w,
-                                           resident=resident,
                                            cache=cache)
     with obs.span("overlap.match"):
         hits, capped = join_seeds(rt, tt, read_self_t, qlens,
                                   k=k, max_occ=max_occ,
-                                  device_join=device_join,
-                                  resident=resident_hits)
+                                  device_join=device_join)
         metrics.inc("overlap.freq_capped_buckets", capped)
     return hits
 
@@ -898,7 +848,6 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
                         k: Optional[int] = None, w: Optional[int] = None,
                         max_occ: Optional[int] = None,
                         min_seeds: Optional[int] = None,
-                        resident: Optional[bool] = None,
                         device_join: Optional[bool] = None,
                         cache: bool = True,
                         read_tables: Optional[dict] = None
@@ -924,15 +873,14 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
     caller keeps over several calls on the same ``read_seqs``; the
     read-side seed table is built into it once and taken from it
     after (:func:`_read_table`)."""
-    k, w, max_occ, min_seeds, resident, device_join, _ = _resolve_params(
-        k, w, max_occ, min_seeds, resident, device_join, None)
+    k, w, max_occ, min_seeds, device_join, _ = _resolve_params(
+        k, w, max_occ, min_seeds, device_join, None)
     qlens = np.fromiter((len(s) for s in read_seqs), np.int64,
                         len(read_seqs))
     hits = _seed_and_join(
         read_seqs, target_seqs, read_self_t, qlens,
-        k=k, w=w, max_occ=max_occ, resident=resident,
-        device_join=device_join, cache=cache,
-        resident_hits=resident and device_join, read_tables=read_tables)
+        k=k, w=w, max_occ=max_occ, device_join=device_join, cache=cache,
+        read_tables=read_tables)
     starts, _, counts = _pair_runs(hits)
     metrics.inc("overlap.candidate_pairs", int(starts.size))
     if starts.size == 0:
@@ -955,12 +903,8 @@ def iter_overlap_groups(read_seqs: List[bytes], target_seqs: List[bytes],
         group_of = np.cumsum(gchange) - 1
         # unresolved eligible pairs per group — the emission gate
         rem = np.bincount(group_of[pids], minlength=ngroups)
-        device_src = "tp_dev" in hits
-        stream = _ChainStream(
-            k=k, tp=hits["tp_dev"] if device_src else hits["tp"],
-            qc=hits["qc_dev"] if device_src else hits["qc"],
-            starts=starts[pids], counts=counts[pids],
-            device_src=device_src)
+        stream = _ChainStream(k=k, tp=hits["tp"], qc=hits["qc"],
+                              starts=starts[pids], counts=counts[pids])
         chunks = _plan_chunks(stream.counts)
     rows6 = np.zeros((starts.size, 6), np.int64)
     kept_total = 0
@@ -1002,7 +946,6 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
                   k: Optional[int] = None, w: Optional[int] = None,
                   max_occ: Optional[int] = None,
                   min_seeds: Optional[int] = None,
-                  resident: Optional[bool] = None,
                   device_join: Optional[bool] = None,
                   ragged: Optional[bool] = None,
                   cache: bool = True,
@@ -1024,13 +967,12 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
     ragged stream's per-group emission; ``ragged=False`` runs the
     phase-barriered ``chain_pairs`` A/B leg. Both orders are the same
     canonical order, so output bytes never depend on the flag."""
-    (k, w, max_occ, min_seeds, resident, device_join,
-     ragged) = _resolve_params(k, w, max_occ, min_seeds, resident,
-                               device_join, ragged)
+    k, w, max_occ, min_seeds, device_join, ragged = _resolve_params(
+        k, w, max_occ, min_seeds, device_join, ragged)
     if ragged:
         parts = list(iter_overlap_groups(
             read_seqs, target_seqs, read_self_t, k=k, w=w,
-            max_occ=max_occ, min_seeds=min_seeds, resident=resident,
+            max_occ=max_occ, min_seeds=min_seeds,
             device_join=device_join, cache=cache,
             read_tables=read_tables))
         if not parts:
@@ -1042,8 +984,7 @@ def find_overlaps(read_seqs: List[bytes], target_seqs: List[bytes],
                         len(read_seqs))
     hits = _seed_and_join(
         read_seqs, target_seqs, read_self_t, qlens,
-        k=k, w=w, max_occ=max_occ, resident=resident,
-        device_join=device_join, cache=cache, resident_hits=False,
+        k=k, w=w, max_occ=max_occ, device_join=device_join, cache=cache,
         read_tables=read_tables)
     with obs.span("overlap.chain"):
         chains, kept, dropped = chain_pairs(hits, k=k,

@@ -499,220 +499,6 @@ def _breaking_points_kernel(ops_packed, n, m, first_rel, nb, *, w: int,
     return bp_first, bp_last
 
 
-def _pow2_pool(n: int) -> int:
-    """THE packed-pool padding rule (round 19): the resident dataflow's
-    uploaded ``weight << 3 | code`` pool is zero-padded to this pow2
-    length so the derive-kernel jit signature stays stable across runs
-    of similar size. Shared by :func:`upload_qpw_pool` and the
-    aligner's warm-up so the warm-cache claim cannot drift."""
-    c = 1024
-    while c < n:
-        c *= 2
-    return c
-
-
-@functools.partial(jax.jit, static_argnames=("w", "NW", "Lq"))
-def _derive_layer_rows(bp_first, bp_last, qpw_pool, live, tb, qo_read,
-                       qo_pool, n_reg, win_base, ov_idx, has_q, qlen,
-                       s_min, q_need, *, w: int, NW: int, Lq: int):
-    """Device-resident layer-row derivation (round 19): the vectorized
-    filter core of ``Polisher._assemble_layers`` re-expressed over ONE
-    align chunk's device-resident breaking-point tables, so the tables
-    are never fetched and no per-row host work remains.
-
-    Inputs are the chunk's packed ``tpos << 14 | qpos`` tables
-    ([B, NW], :func:`_breaking_points_kernel`), the run's uploaded
-    packed pool, and per-lane scalars: ``live`` marks accepted lanes,
-    ``tb``/``qo_read`` the overlap's global target begin / oriented
-    query offset, ``qo_pool`` the lane's pool offset (``ov_off + qo``),
-    ``win_base`` the target's first window id, ``qlen`` the query-span
-    length (<= Lq, the bucket cap — which is what keeps the weight
-    gather [B, Lq] instead of [B, read_len]).
-
-    The three keeps mirror the host oracle EXACTLY (the parity suite
-    locks this): min-span as ``span >= s_min`` with ``s_min =
-    ceil(0.02 * w)`` (an integer >= a real iff >= its ceiling);
-    mean-PHRED as the integer cross-multiplication ``sum(q - 33) >=
-    q_need * span`` — equivalent to the host's f64 quotient compare
-    whenever the threshold is an integer and every quality byte >= 33
-    (the resident gate), because a non-equal quotient differs from the
-    threshold by >= 1/span >= 2^-14, far above f64 rounding error;
-    and the empty-layer drop ``begin != end``.
-
-    Returns a flat [B * NW, 6] int32 table of (win_id, overlap index,
-    q_first, q_end_excl, layer_begin, layer_end) rows; dropped rows
-    carry the ``_ROW_SENTINEL`` win_id and sort to the tail of the
-    finalize output."""
-    BIG = jnp.int32(1 << 30)
-    col = jnp.arange(NW, dtype=jnp.int32)[None, :]
-    fp = bp_first
-    lp = bp_last
-    valid = (col <= n_reg[:, None]) & (fp < BIG) & live[:, None]
-    t_first = tb[:, None] + (fp >> 14)
-    qf = fp & 0x3FFF
-    qe = (lp & 0x3FFF) + 1
-    t_endx = tb[:, None] + (lp >> 14) + 1
-    span = qe - qf
-    keep = valid & (span >= s_min)
-    # per-lane quality prefix sums over the lane's own query span: the
-    # host oracle's budgeted csum slices collapse to one [B, Lq] gather
-    B = bp_first.shape[0]
-    pos = jnp.arange(Lq, dtype=jnp.int32)[None, :]
-    src = qo_pool[:, None] + jnp.minimum(pos,
-                                         jnp.maximum(qlen[:, None] - 1, 0))
-    wrow = jnp.where(pos < qlen[:, None],
-                     (qpw_pool[src] >> 3).astype(jnp.int32), 0)
-    csum = jnp.concatenate(
-        [jnp.zeros((B, 1), jnp.int32), jnp.cumsum(wrow, axis=1)], axis=1)
-    qf_c = jnp.clip(qf, 0, Lq)
-    qe_c = jnp.clip(qe, 0, Lq)
-    sum_w = (jnp.take_along_axis(csum, qe_c, axis=1)
-             - jnp.take_along_axis(csum, qf_c, axis=1))
-    keep = keep & jnp.where(has_q[:, None], sum_w >= q_need * span, True)
-    rank = t_first // w
-    win = win_base[:, None] + rank
-    lb = t_first - rank * w
-    le = t_endx - rank * w - 1
-    keep = keep & (lb != le)
-    out = jnp.stack(
-        [jnp.where(keep, win, jnp.int32(_ROW_SENTINEL)),
-         jnp.broadcast_to(ov_idx[:, None], (B, NW)),
-         qo_read[:, None] + qf, qo_read[:, None] + qe, lb, le], axis=-1)
-    return out.reshape(B * NW, 6)
-
-
-# win_id sentinel for dropped derive rows: sorts after every real window
-_ROW_SENTINEL = (1 << 31) - 1
-
-
-@jax.jit
-def _finalize_layer_table(flat_all, src):
-    """One-shot finalize of the resident layer table: gather the
-    per-chunk derive blocks (+ host-fallback rows) into overlap-stream
-    order and stable-sort by window id — exactly the host oracle's
-    ``np.argsort(win_id, kind="stable")`` over rows in overlap order,
-    with dropped rows (``_ROW_SENTINEL``) sorting to the tail. Traced
-    shapes on purpose: this runs ONCE per run, and the shape-hazard
-    lint exempts traced-shape jits."""
-    g = jnp.take(flat_all, src, axis=0)
-    order = jnp.argsort(g[:, 0], stable=True)
-    return jnp.take(g, order, axis=0)
-
-
-def upload_qpw_pool(qpw_pool: np.ndarray):
-    """Upload the run's packed ``weight << 3 | code`` pool ONCE (padded
-    to the shared pow2 rule), synchronously — the caller times this to
-    estimate link bandwidth for the lane-upload-saved accounting."""
-    cap = _pow2_pool(len(qpw_pool))
-    if cap != len(qpw_pool):
-        qpw_pool = np.pad(qpw_pool, (0, cap - len(qpw_pool)))
-    arr = jnp.asarray(qpw_pool)
-    arr.block_until_ready()
-    return arr
-
-
-def finalize_layer_table(parts, host_flat: np.ndarray,
-                         src: np.ndarray) -> np.ndarray:
-    """Concatenate the per-chunk derive blocks with the host-fallback
-    rows, run :func:`_finalize_layer_table`, and fetch the ONE sorted
-    [T, 6] table — the resident dataflow's single bulk device->host
-    transfer."""
-    from ..parallel import fetch_global
-    segs = list(parts)
-    segs.append(jnp.asarray(
-        np.ascontiguousarray(host_flat, dtype=np.int32).reshape(-1, 6)))
-    flat_all = jnp.concatenate(segs, axis=0) if len(segs) > 1 else segs[0]
-    table = _finalize_layer_table(flat_all,
-                                  jnp.asarray(src.astype(np.int32)))
-    return np.asarray(fetch_global([table])[0])
-
-
-class _DevChunkBp:
-    """Device-resident breaking-point tables of ONE align chunk (round
-    19): the resident ``_finish_chunk_bp`` keeps ``bp_first``/``bp_last``
-    on device and fetches only the 12 bytes/lane accept-gate scalars.
-    Accepted lanes hold :class:`_DevBp` handles into this object; the
-    polisher's resident assemble calls :meth:`derive` per chunk, and
-    :meth:`fetch` is the host-decode escape hatch (one whole-chunk
-    fetch, shared by every handle)."""
-
-    __slots__ = ("bp_first", "bp_last", "w", "NW", "B", "max_len",
-                 "_host")
-
-    def __init__(self, bp_first, bp_last, w: int, max_len: int):
-        self.bp_first = bp_first
-        self.bp_last = bp_last
-        self.w = w
-        self.B = int(bp_first.shape[0])
-        self.NW = int(bp_first.shape[1])
-        self.max_len = max_len
-        self._host = None
-
-    def fetch(self):
-        """Host copies of the tables (cached; one fetch per chunk)."""
-        if self._host is None:
-            from ..parallel import fetch_global
-            fp, lp = fetch_global([self.bp_first, self.bp_last])
-            # graftlint: disable=lock-discipline (idempotent lazy cache — both contexts would store the same fetched tables; worst case is one duplicate fetch)
-            self._host = (np.asarray(fp, dtype=np.int64),
-                          np.asarray(lp, dtype=np.int64))
-        return self._host
-
-    def derive(self, dev_pool, live, tb, qo_read, qo_pool, n_reg,
-               win_base, ov_idx, has_q, qlen, s_min: int, q_need: int):
-        """Dispatch :func:`_derive_layer_rows` for this chunk's lanes
-        (per-lane arrays are host np, full-B, dead lanes zeroed)."""
-        return _derive_layer_rows(
-            self.bp_first, self.bp_last, dev_pool,
-            jnp.asarray(live), jnp.asarray(tb), jnp.asarray(qo_read),
-            jnp.asarray(qo_pool), jnp.asarray(n_reg),
-            jnp.asarray(win_base), jnp.asarray(ov_idx),
-            jnp.asarray(has_q), jnp.asarray(qlen),
-            np.int32(s_min), np.int32(q_need),
-            w=self.w, NW=self.NW, Lq=self.max_len)
-
-
-class _DevBp:
-    """One accepted pair's device-resident breaking points: a (chunk,
-    lane) reference plus the host-side meta the row construction needs.
-    Replaces the (k, 4) ndarray in ``overlap.breaking_points`` when the
-    resident dataflow is on; :meth:`decode_host` reproduces the host
-    path's rows byte-exactly (the universal fallback when a resident
-    precondition fails)."""
-
-    __slots__ = ("chunk", "lane", "t_begin", "q_off", "n_reg", "qlen")
-
-    is_device_resident = True
-
-    def __init__(self, chunk: _DevChunkBp, lane: int, t_begin: int,
-                 q_off: int, n_reg: int, qlen: int):
-        self.chunk = chunk
-        self.lane = lane
-        self.t_begin = t_begin
-        self.q_off = q_off
-        self.n_reg = n_reg
-        self.qlen = qlen
-
-    def __len__(self) -> int:
-        # row-count upper bound (n_reg + 1 boundary intervals) — the
-        # pipelined run()'s queue-depth heuristic only needs the scale
-        return self.n_reg + 1
-
-    def decode_host(self) -> np.ndarray:
-        """The non-resident ``_finish_chunk_bp`` row construction for
-        this lane, from the chunk's (cached) host fetch."""
-        fp_all, lp_all = self.chunk.fetch()
-        fp = fp_all[self.lane]
-        lp = lp_all[self.lane]
-        col = np.arange(fp.shape[0], dtype=np.int64)
-        valid = (col <= self.n_reg) & (fp < (1 << 30))
-        rows = np.stack(
-            [self.t_begin + (fp >> 14), self.q_off + (fp & 0x3FFF),
-             self.t_begin + (lp >> 14) + 1,
-             self.q_off + (lp & 0x3FFF) + 1], axis=-1)
-        return rows[valid].astype(np.int32)
-
-
 def _alphabet(pools) -> np.ndarray:
     """The distinct byte values of ``pools`` (bytes objects), sorted,
     without 0 (the blocks' pad). ACGT are looked for by ``in`` and only
@@ -1081,8 +867,7 @@ class TpuAligner(PallasDispatchMixin):
         every path."""
         return self._drive(pairs, progress, (window_length, metas), errors)
 
-    def bp_stream(self, window_length: int, progress=None, total: int = 0,
-                  resident: bool = False):
+    def bp_stream(self, window_length: int, progress=None, total: int = 0):
         """Open a ragged streaming breaking-points session (round 17):
         ``feed()`` buckets pairs by their own sweep cost and band rung
         and **asynchronously dispatches** greedy-filled chunks as
@@ -1097,8 +882,7 @@ class TpuAligner(PallasDispatchMixin):
         if not self.use_ragged or self.mesh is not None:
             return None
         return _AlignStream(self, window_length=window_length,
-                            progress=progress, total_hint=total,
-                            resident=resident)
+                            progress=progress, total_hint=total)
 
     def _drive(self, pairs, progress, bp_meta, errors=None):
         if self.use_ragged and self.mesh is None:
@@ -1500,22 +1284,20 @@ class TpuAligner(PallasDispatchMixin):
                     f"band={band}, steps={steps})")
         return out
 
-    def _finish_chunk(self, launched, band, cigars, reject, bp_meta=None,
-                      resident=False):
+    def _finish_chunk(self, launched, band, cigars, reject, bp_meta=None):
         """Span-wrapped :meth:`_finish_chunk_impl` — the fetch half of
         the dispatch-vs-fetch split (blocks on the device result)."""
         faults.check("align.fetch")
         with self._pinned(), obs.span("align.fetch",
                                       pairs=len(launched[0]), band=band):
             self._finish_chunk_impl(launched, band, cigars, reject,
-                                    bp_meta, resident)
+                                    bp_meta)
 
     def _finish_chunk_impl(self, launched, band, cigars, reject,
-                           bp_meta=None, resident=False):
+                           bp_meta=None):
         chunk, pairs, n, m, out, _max_len = launched
         from ..parallel import fetch_global
-        # resident bp chunks fetch only the accept gate's scalars
-        wanted = list(out[2:] if bp_meta is not None and resident else out)
+        wanted = list(out)
         # the three leaves of align.fetch: waiting for the device and
         # nothing else, the device->host copy, the host decode
         leaf = dict(pairs=len(chunk), band=band)
@@ -1526,7 +1308,7 @@ class TpuAligner(PallasDispatchMixin):
         with obs.span("align.decode", **leaf):
             if bp_meta is not None:
                 self._finish_chunk_bp(launched, fetched, band, cigars,
-                                      reject, bp_meta, resident)
+                                      reject, bp_meta)
             else:
                 self._finish_chunk_cigar(launched, fetched, band, cigars,
                                          reject)
@@ -1574,25 +1356,15 @@ class TpuAligner(PallasDispatchMixin):
             self._observe_divergence(obs_scores, obs_maxlens)
 
     def _finish_chunk_bp(self, launched, fetched, band, results, reject,
-                         bp_meta, resident=False):
+                         bp_meta):
         """Breaking-points decode: convert the fetched per-boundary tables
         to columnar (k, 4) int32 row arrays for the WHOLE chunk in one
         vectorized pass (same accept/reject gate as the CIGAR path — the
         walk is complete and provably optimal inside the band, else
-        escalate). The per-pair arrays are views into one flat buffer.
-
-        With ``resident`` (round 19) the tables STAY on device: only the
-        12 bytes/lane of accept-gate scalars (score, fi, fj) are
-        fetched, and accepted lanes resolve to :class:`_DevBp` handles
-        into one shared :class:`_DevChunkBp` — the polisher's resident
-        assemble derives layer rows from them without a host decode."""
-        chunk, pairs, n, m, out, max_len = launched
+        escalate). The per-pair arrays are views into one flat buffer."""
+        chunk, pairs, n, m, out, _max_len = launched
         w, metas = bp_meta
-        if resident:
-            score, fi, fj = fetched
-            bp_first = bp_last = None
-        else:
-            bp_first, bp_last, score, fi, fj = fetched
+        bp_first, bp_last, score, fi, fj = fetched
         from .. import sanitize
         if sanitize.enabled():
             sanitize.check_aligner_canaries(
@@ -1616,21 +1388,6 @@ class TpuAligner(PallasDispatchMixin):
         tb, qo = np.array([metas[idx] for idx in chunk],
                           np.int64).reshape(C, 2).T
         n_reg = (tb + m_h - 1) // w - tb // w
-        if resident:
-            devc = _DevChunkBp(out[0], out[1], w, max_len)
-            # dataflow accounting: the gate scalars crossed the link,
-            # the two [B, NW] int32 tables did not
-            metrics.inc("dataflow.bytes_fetched", 12 * C)
-            metrics.inc("dataflow.bytes_avoided", 8 * devc.B * devc.NW)
-            for k, idx in enumerate(chunk):
-                if accept[k]:
-                    results[idx] = _DevBp(devc, k, int(tb[k]), int(qo[k]),
-                                          int(n_reg[k]),
-                                          len(pairs[idx][0]))
-                    self.stats["device"] += 1
-                else:
-                    reject.append(idx)
-            return
         fp = np.asarray(bp_first[:C], dtype=np.int64)
         lp = np.asarray(bp_last[:C], dtype=np.int64)
         col = np.arange(fp.shape[1], dtype=np.int64)
@@ -1747,24 +1504,6 @@ class TpuAligner(PallasDispatchMixin):
                     jnp.ones((B,), jnp.int32), w=w, NW=NW)
                 device_time.submit("warm", "_breaking_points_kernel",
                                    bp[1], g_bp)
-                # resident derive root (round 19): warmed with the SAME
-                # chunk geometry and the shared pow2 pool rule, so a
-                # resident run's per-chunk layer-row derivation
-                # dispatches into a hot cache (the one-shot finalize
-                # sort is traced-shape and compiles on use); skipped
-                # when the flag is off — a host-path run never
-                # dispatches this root
-                from .. import flags
-                if flags.get_bool("RACON_TPU_RESIDENT"):
-                    zi = jnp.zeros((B,), jnp.int32)
-                    zb = jnp.zeros((B,), bool)
-                    _derive_layer_rows(
-                        bp[0], bp[1],
-                        jnp.zeros((_pow2_pool(est_len * est_pairs),),
-                                  jnp.uint16),
-                        zb, zi, zi, zi, zi, zi, zi, zb,
-                        jnp.ones((B,), jnp.int32), np.int32(1),
-                        np.int32(10), w=w, NW=NW, Lq=max_len)
             jax.block_until_ready(out[1])
 
         def _run():
@@ -1838,14 +1577,9 @@ class _AlignStream:
     polisher's O(slice) transient-copy contract."""
 
     def __init__(self, eng: "TpuAligner", window_length=None,
-                 progress=None, total_hint: int = 0,
-                 resident: bool = False):
+                 progress=None, total_hint: int = 0):
         self.eng = eng
         self.w = window_length             # None -> CIGAR mode
-        # resident mode (round 19): accepted chunks keep their bp
-        # tables on device and resolve to _DevBp handles; host-fallback
-        # rejects are the dataflow's fallback-pair count
-        self.resident = bool(resident) and window_length is not None
         self.progress = progress
         self.total_hint = total_hint
         self.results: List = []            # per fed pair, feed order
@@ -2038,7 +1772,7 @@ class _AlignStream:
         self.inflight_pairs -= len(la["chunk"])
         esc: List[int] = []
         eng._finish_chunk(la["launched"], la["cls"][1], self.results,
-                          esc, self._bp_meta(), self.resident)
+                          esc, self._bp_meta())
         esc_set = set(esc)
         for slot in la["chunk"]:
             if slot not in esc_set:
@@ -2078,10 +1812,6 @@ class _AlignStream:
                 self._finish_oldest()
             self._flush(final=True)
         self.done_pairs += len(self.reject)
-        if self.resident and self.reject:
-            # band/length escapees decode on host — the resident
-            # dataflow's (small) fallback set
-            metrics.inc("dataflow.fallback_pairs", len(self.reject))
         eng._resolve_rejects(self.pairs, self.reject, self.results,
                              self._bp_meta())
         for slot in self.reject:

@@ -18,11 +18,9 @@ chain overlapper (ROADMAP item 5); this module is the seeding half:
   it through an invertible 32-bit finalizer so rank ties don't follow
   base composition, and selects each w-window's leftmost minimum with a
   strict-< iterative sweep (deterministic: no argmin tie ambiguity);
-- the windows' picks mark a per-position mask; the host (or,
-  under ``RACON_TPU_RESIDENT=1``, a device compaction kernel that ships
-  only the selected entries over the link) flattens the batch into one
-  flat ``(hash, seq_id, pos, strand)`` table for the matcher
-  (:mod:`racon_tpu.ops.chain`);
+- the windows' picks mark a per-position mask; the host flattens the
+  batch into one flat ``(hash, seq_id, pos, strand)`` table for the
+  matcher (:mod:`racon_tpu.ops.chain`);
 - the arenas of one build are a stream (:class:`_SeedStream`, PR 45):
   arena k + 1 is packed and launched while arena k's planes cross back
   and a worker writes its selected entries once, at their place in the
@@ -152,26 +150,6 @@ def _seed_geometry(B: int, L: int, k: int, w: int) -> str:
     """The occupancy ledger's join key of a ``[B, L]`` minimizer batch
     (both of its programs), from the stream and the warm-up alike."""
     return device_time.geometry(B=B, w=w, L=L, k=k)
-
-
-@jax.jit
-def _compact_kernel(h, strand, sel):
-    """Device-side table compaction (the resident path): selected
-    entries pack to the front in row-major order — identical to the
-    host ``np.nonzero`` walk — so only ``n_selected`` elements ever
-    cross the host link instead of the full ``[B, P]`` arenas."""
-    B, P = h.shape
-    flat = sel.reshape(-1)
-    rank = jnp.cumsum(flat.astype(jnp.int32))
-    total = rank[-1]
-    idx = jnp.where(flat, rank - 1, jnp.int32(B * P))
-    lin = jnp.arange(B * P, dtype=jnp.int32)
-    out_h = jnp.zeros((B * P + 1,), jnp.uint32).at[idx].set(h.reshape(-1))
-    out_row = jnp.zeros((B * P + 1,), jnp.int32).at[idx].set(lin // P)
-    out_pos = jnp.zeros((B * P + 1,), jnp.int32).at[idx].set(lin % P)
-    out_s = jnp.zeros((B * P + 1,), jnp.bool_).at[idx].set(
-        strand.reshape(-1))
-    return out_h, out_row, out_pos, out_s, total
 
 
 # ------------------------------------------------------------ host driver
@@ -319,14 +297,12 @@ class _SeedStream:
     holes this leaves — none for reads under a row's length — are
     closed by :meth:`finish`.
 
-    One arena (a draft of a few Mbp, the tests' inputs), the resident
-    path and a multi-host run start no thread: pack, launch, fetch,
-    compact on the calling thread, the order of events a build has
-    always had."""
+    One arena (a draft of a few Mbp, the tests' inputs) and a
+    multi-host run start no thread: pack, launch, fetch, compact on the
+    calling thread, the order of events a build has always had."""
 
-    def __init__(self, n_arenas: int, windows: int, k: int, w: int,
-                 resident: bool):
-        self.k, self.w, self.resident = k, w, resident
+    def __init__(self, n_arenas: int, windows: int, k: int, w: int):
+        self.k, self.w = k, w
         self.B, self.L = SEED_BATCH, SEED_ROW
         # a window picks one slot, so a table holds `windows` entries at
         # most; the minimizers of a random sequence take 2 / (w + 1) of
@@ -344,7 +320,7 @@ class _SeedStream:
         self.pool = None
         # across hosts a fetch is a collective, and collectives leave in
         # one order from one thread
-        if n_arenas > 1 and not resident and not is_multihost():
+        if n_arenas > 1 and not is_multihost():
             self.pool = ThreadPoolExecutor(
                 SEED_IN_FLIGHT, thread_name_prefix="racon-seedstream")
         self._scope = metrics.get_scope()
@@ -381,27 +357,18 @@ class _SeedStream:
             # graftlint: disable=jit-shape-hazard (k/w are run-constant flag values — one compile per run; L is the one row length)
             h, strand, sel, nsel = _minimizer_kernel(codes_d, lens, nwin,
                                                      k=k, w=w, L=L)
-            geom = _seed_geometry(B, L, k, w)
-            device_time.submit("exec", "_minimizer_kernel", nsel, geom)
-            device = (h, strand, sel, nsel)
-            if self.resident:
-                h, row, pcol, strand, total = _compact_kernel(
-                    h, strand, sel)
-                device_time.submit("exec", "_compact_kernel", total, geom)
-                device = (h, row, pcol, strand, total)
+            device_time.submit("exec", "_minimizer_kernel", nsel,
+                               _seed_geometry(B, L, k, w))
         metrics.inc("overlap.seed_arenas")
         metrics.inc("overlap.seed_arenas_ahead", int(ahead))
         metrics.inc("overlap.seed_lanes_total", B * L)
         metrics.inc("overlap.seed_lanes_occupied", int(lens.sum()))
-        arena = _Arena(ids, offs, device)
+        arena = _Arena(ids, offs, (h, strand, sel, nsel))
         self.arenas.append(arena)
         if self.pool is None:
             with obs.span("overlap.seed.fetch", rows=len(part)):
-                if self.resident:
-                    arena.count = self._fetch_resident(arena)
-                else:
-                    self._size(arena)
-                    arena.count = self._drain(arena)
+                self._size(arena)
+                arena.count = self._drain(arena)
             return
         if self.front is not None:
             self._hand_over(self.front)
@@ -440,27 +407,6 @@ class _SeedStream:
         while len(self.writing) > keep:
             arena, future = self.writing.popleft()
             arena.count = future.result()
-
-    def _fetch_resident(self, arena: _Arena) -> int:
-        """The resident path's fetch: the entries the device compacted,
-        nothing else (counted into the ``dataflow.*`` bytes ledger)."""
-        h, row, pcol, strand, total = arena.device
-        arena.device = None
-        n = int(fetch_global([total])[0])
-        h_np, rows, cols, s_np = fetch_global(
-            [h[:n], row[:n], pcol[:n], strand[:n]])
-        arena.fetched = True
-        fetched = n * 10  # 4 + 4 + 1 + 1 bytes per entry
-        metrics.inc("dataflow.bytes_fetched", fetched)
-        metrics.inc("dataflow.bytes_avoided",
-                    max(0, self.B * (self.L - self.k + 1) * 6 - fetched))
-        keep = h_np != np.uint32(_HASH_MAX)
-        rows, cols = rows[keep], cols[keep]
-        arena.offset = self._reserve(int(rows.size))
-        return _write_entries(self.out, arena.offset, h_np[keep],
-                              arena.ids[rows],
-                              arena.offs[rows] + cols.astype(np.int32),
-                              np.asarray(s_np)[keep])
 
     def _drain(self, arena: _Arena) -> int:
         """Fetch ``arena``'s planes and compact them into its slice
@@ -524,25 +470,18 @@ def _canonical(table):
 
 
 def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
-                     w: int = DEFAULT_W, resident: bool = False,
-                     cache: bool = False
+                     w: int = DEFAULT_W, cache: bool = False
                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                 np.ndarray]:
     """The flat minimizer table of a sequence set: parallel numpy arrays
     ``(hash uint32, seq_id int32, pos int32, strand bool)`` in
     deterministic (bucket-grouped, sequence-order) row order, built
-    arena by arena as a stream (:class:`_SeedStream`).
-
-    ``resident=True`` compacts on device and fetches only the selected
-    entries (counted into the ``dataflow.*`` bytes ledger); the host
-    path fetches the full masks and compacts them into place. Both
-    produce identical tables (tests assert the parity).
+    arena by arena as a stream (:class:`_SeedStream`): the full masks
+    are fetched and compacted into place on the host.
 
     ``cache=True`` (the target side of the overlapper) consults the
-    fingerprint-keyed table
-    cache first: a hit skips packing, kernels, and fetches entirely —
-    counted in ``overlap.cache_hits`` and credited to
-    ``dataflow.bytes_avoided`` at the table's own wire size."""
+    fingerprint-keyed table cache first: a hit skips packing, kernels,
+    and fetches entirely — counted in ``overlap.cache_hits``."""
     ckey = None
     if cache:
         ckey = _fingerprint(seqs, k, w)
@@ -553,15 +492,13 @@ def build_seed_table(seqs: List[bytes], *, k: int = DEFAULT_K,
         if hit is not None:
             metrics.inc("overlap.cache_hits")
             metrics.inc("overlap.minimizers", int(hit[0].size))
-            # the fetch (resident wire size) + kernels this hit skipped
-            metrics.inc("dataflow.bytes_avoided", int(hit[0].size) * 10)
             return hit
         metrics.inc("overlap.cache_misses")
     chunks = list(_iter_chunks(seqs, k, w))
     if chunks:
         B = SEED_BATCH
         stream = _SeedStream(-(-len(chunks) // B),
-                             sum(c[3] for c in chunks), k, w, resident)
+                             sum(c[3] for c in chunks), k, w)
         try:
             for begin in range(0, len(chunks), B):
                 stream.feed(chunks[begin:begin + B])

@@ -932,27 +932,6 @@ def _loop_geometry(Lq: int, Lb: int, band: int, steps: int, Lq2: int,
                                 swar=swar)
 
 
-@functools.lru_cache(maxsize=None)
-def _gather_geometry(B: int, Lq: int, pool: int) -> str:
-    """The same for the resident lane gather."""
-    return device_time.geometry(B=B, Lq=Lq, pool=pool)
-
-
-@functools.partial(jax.jit, static_argnames=("Lq",))
-def _gather_qpw_rows(pool, src0, lens, *, Lq: int):
-    """Device-side twin of :meth:`LayerStore.gather_qpw` (round 19):
-    gather a group's packed ``weight << 3 | code`` lane block [B, Lq]
-    straight from the resident pool the align->consensus dataflow
-    uploaded once — the 2*B*Lq-byte per-group lane upload this replaces
-    is the ``lane_upload_saved_bytes`` accounting. Same clipped-index /
-    zero-pad construction, so the lanes are byte-identical to the host
-    gather."""
-    pos = jnp.arange(Lq, dtype=jnp.int32)[None, :]
-    idx = src0[:, None] + jnp.minimum(pos,
-                                      jnp.maximum(lens[:, None] - 1, 0))
-    return jnp.where(pos < lens[:, None], pool[idx], jnp.uint16(0))
-
-
 @jax.jit
 def _fetch_pack(bcodes, blen, covs, ever, frozen, conv, dropped, bg, ed):
     """Coalesce a group's fetch into TWO device arrays: every transfer
@@ -1413,8 +1392,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
                       "ins_overflow": 0, "passthrough": 0,
                       "stage_b_windows": 0, "wavefront_steps": 0,
                       "lanes_occupied": 0, "lanes_total": 0,
-                      "groups": 0, "group_windows": 0,
-                      "lane_upload_saved_bytes": 0}
+                      "groups": 0, "group_windows": 0}
         # per-window attribution of the ins_overflow counter (keyed by
         # result index): WHICH window's insertion density tripped the
         # uncapped-scatter fallback — kept out of ``stats`` so numeric
@@ -1863,22 +1841,11 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 K=K_INS, steps=steps, use_pallas=use_pallas,
                 use_swar=sw, Lq2=Lq2, scores=self.scores,
                 matmul_votes=self.use_matmul_votes)
-            # resident lane-ingest root, warmed with the SAME pow2 pool
-            # rule the uploader pads to (nw._pow2_pool) — a size
-            # mismatch costs one background compile of a tiny gather
-            from .nw import _pow2_pool
-            gat = _gather_qpw_rows(
-                jnp.zeros((_pow2_pool(Lq * B),), jnp.uint16),
-                jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                Lq=Lq)
             device_time.submit(
                 "warm", "_refine_loop_packed", out[9],
                 _loop_geometry(Lq, Lb, band, steps, Lq2, B, nWp, rounds,
                                bool(sw)))
-            device_time.submit("warm", "_gather_qpw_rows", gat,
-                               _gather_geometry(B, Lq, _pow2_pool(Lq * B)))
             jax.block_until_ready(out[10])
-            jax.block_until_ready(gat)
 
         # behind the warm-up before it, where a polisher that waits for
         # none (``final`` off) left one running: the last thread's end
@@ -1953,8 +1920,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
             self._run_stage_b_impl(survivors, trim, results, Lq, Lb,
                                    steps, Lq2, band)
 
-    def _pack_shard(self, items, Lq, B, nWp, Lb, overrides=None,
-                    allow_dev=False):
+    def _pack_shard(self, items, Lq, B, nWp, Lb, overrides=None):
         """Pack one shard's windows into fixed-shape pair/window arrays.
 
         ``items`` is a list of ``(result_index, _Work)``; pair rows beyond
@@ -1963,14 +1929,6 @@ class TpuPoaConsensus(PallasDispatchMixin):
         window's fetched stage-A state ``(bcodes_row, blen, covs_row,
         ever, bg_per_layer, ed_per_layer)`` so the window resumes from
         its refined backbone and remapped spans instead of restarting.
-
-        With ``allow_dev`` (single-shard, unpinned, meshless launches)
-        and every layer coming from ONE columnar store that carries a
-        device-resident pool (``store.dev_qpw``, uploaded by the
-        resident dataflow), the lane block is NOT host-gathered: the
-        third return value is ``(dev_pool, src0, lens)`` full-B gather
-        metadata for :func:`_gather_qpw_rows` and the host ``qpw`` stays
-        zeros. Otherwise the third return is None.
         """
         n = np.ones(B, np.int32)
         bg = np.zeros(B, np.int32)
@@ -1997,7 +1955,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
         # a leaf of poa.pack: the lane block's construction (a timer
         # only: device idle under it stays poa.pack's, contracts.py)
         with obs.span("poa.lanes", pairs=k):
-            qpw, dev_spec = self._pack_lanes(items, offs, Lq, B, allow_dev)
+            qpw = self._pack_lanes(items, offs, Lq, B)
 
         bcodes = np.zeros((nWp, Lb), np.uint8)
         bweights = np.zeros((nWp, Lb), np.float32)
@@ -2034,17 +1992,15 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 off += kw
 
         return (n, qpw, win_of, real, bg, ed), \
-               (bcodes, bweights, blen, covs, ever), dev_spec
+               (bcodes, bweights, blen, covs, ever)
 
-    def _pack_lanes(self, items, offs, Lq, B, allow_dev):
+    def _pack_lanes(self, items, offs, Lq, B):
         """One shard's ``[B, Lq]`` lane block, written once: ``weight <<
         3 | code`` per base (codes 3 bits, phred weights <= 93 in 7) —
         codes and weights travel as ONE uint16 array, the format both
         vote emitters consume directly. Window ``wi``'s layers land in
-        rows ``offs[wi]:offs[wi + 1]``; rows and lanes beyond stay 0.
-        Returns ``(qpw, dev_spec)`` (see :meth:`_pack_shard`)."""
+        rows ``offs[wi]:offs[wi + 1]``; rows and lanes beyond stay 0."""
         qpw = np.zeros((B, Lq), np.uint16)
-        dev_spec = None
         # columnar windows: one row copy per layer, straight into this
         # block, lands every layer's finished uint16 lanes (codes +
         # phred weights were packed once at store build)
@@ -2063,20 +2019,9 @@ class TpuPoaConsensus(PallasDispatchMixin):
             dest = np.concatenate(
                 [np.arange(offs[wi], offs[wi + 1]) for wi in wis])
             metrics.inc("consensus.lane_rows", len(rows))
-            if (allow_dev and len(by_store) == 1 and not legacy
-                    and store.dev_qpw is not None):
-                # resident dataflow: ship 8-byte gather rows, not
-                # 2*Lq-byte lanes — the device reads the pool it
-                # already holds
-                src0_full = np.zeros(B, np.int32)
-                lens_full = np.zeros(B, np.int32)
-                src0_full[dest] = store.src[rows]
-                lens_full[dest] = store.length[rows]
-                dev_spec = (store.dev_qpw, src0_full, lens_full)
-            else:
-                store.gather_qpw(rows, Lq, out=qpw, dest=dest)
-                if native.available():
-                    metrics.inc("consensus.lane_rows_copied", len(rows))
+            store.gather_qpw(rows, Lq, out=qpw, dest=dest)
+            if native.available():
+                metrics.inc("consensus.lane_rows_copied", len(rows))
 
         # hand-built windows (tests): the round-7 join-and-
         # LUT path over just their layers
@@ -2105,7 +2050,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
                 valid,
                 (weights.astype(np.uint16) << 3) | codes_cat[src],
                 0).astype(np.uint16)
-        return qpw, dev_spec
+        return qpw
 
     def _launch_group_impl(self, live, Lq, Lb, overrides=None,
                            floor=(1, 1)):
@@ -2130,12 +2075,7 @@ class TpuPoaConsensus(PallasDispatchMixin):
         B = max(self._pow2_at_least(max_pairs), floor[0])
         nWp = max(self._pow2_at_least(max_wins + 1), floor[1])
 
-        # device-lane ingest gate: one shard, no mesh, no per-chip pin
-        # (a pinned engine would gather across devices from the
-        # polisher-uploaded pool) — the parity grids cover both sides
-        allow_dev = nd == 1 and self.mesh is None and self.device is None
-        packs = [self._pack_shard(sh, Lq, B, nWp, Lb, overrides,
-                                  allow_dev=allow_dev)
+        packs = [self._pack_shard(sh, Lq, B, nWp, Lb, overrides)
                  for sh in shards]
         # one shard (every run without a mesh): the shard's arrays ARE
         # the group's, handed to the put as they were written
@@ -2167,36 +2107,16 @@ class TpuPoaConsensus(PallasDispatchMixin):
         from ..parallel import to_global
         put = ((lambda a: to_global(self.mesh, a)) if self.mesh is not None
                else jnp.asarray)
-        dev_spec = packs[0][2] if allow_dev else None
-        # the other leaf of poa.pack: the host->device puts (and, on
-        # the resident path, the lane gather's launch)
+        # the other leaf of poa.pack: the host->device puts
         with obs.span("poa.put", windows=len(live)):
-            state, static = self._put_group(put, pair_np, win_np, dev_spec,
-                                            nd, nWp, B, Lq)
+            state, static = self._put_group(put, pair_np, win_np, nd, nWp)
         device_time.submit("h2d", "poa.put", state[-1])
         return {"shards": shards, "static": static, "state": state,
                 "nWp": nWp, "nd": nd, "B": B}
 
-    def _put_group(self, put, pair_np, win_np, dev_spec, nd, nWp, B, Lq):
+    def _put_group(self, put, pair_np, win_np, nd, nWp):
         """The packed group's arrays on the device: ``(state, static)``."""
-        if dev_spec is not None:
-            # resident lane ingest: the pool is already on device, so the
-            # group's [B, Lq] uint16 lane block never crosses the link —
-            # only the 8-byte-per-pair gather rows do
-            pool_d, src0_full, lens_full = dev_spec
-            qpw_dev = _gather_qpw_rows(pool_d, jnp.asarray(src0_full),
-                                       jnp.asarray(lens_full), Lq=Lq)
-            device_time.submit(
-                "exec", "_gather_qpw_rows", qpw_dev,
-                _gather_geometry(B, Lq, int(pool_d.shape[0])))
-            saved = 2 * B * Lq
-            self.stats["lane_upload_saved_bytes"] += saved
-            metrics.inc("dataflow.bytes_avoided", saved)
-            metrics.inc("dataflow.lanes_device_groups")
-            static = (put(pair_np[0]), qpw_dev, put(pair_np[2]),
-                      put(pair_np[3]))
-        else:
-            static = tuple(put(a) for a in pair_np[:4])  # n qpw win_of real
+        static = tuple(put(a) for a in pair_np[:4])  # n qpw win_of real
         bg, ed = (put(pair_np[4]), put(pair_np[5]))
         bcodes, bweights, blen, covs, ever = (put(a) for a in win_np)
         zput = (lambda a: put(np.asarray(a)))

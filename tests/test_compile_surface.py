@@ -488,7 +488,7 @@ def test_stages_fold_into_the_row_of_the_call_that_reached_the_backend(
 def test_v13_validates_rows_and_refuses_the_retired_keys():
     _fake_compile(128, 16, fun_name="jit(_k)")
     rep = report.build_report("cli")
-    assert rep["schema_version"] == 15      # the rows are v13's
+    assert rep["schema_version"] == 16      # the rows are v13's
     assert report.validate_report(rep) == []
     comp = rep["compiles"]
     assert "by_function" not in comp and "events" not in comp

@@ -261,9 +261,8 @@ def test_pack_shard_arrays_equal_the_oracles(case, copier, engine,
                                              monkeypatch):
     items, overrides = shard_case(case)
     args = (items, LQ, 32, 8, 64, overrides)
-    pair, win, dev_spec = engine._pack_shard(*args)
-    pair0, win0, _ = oracle_pack(monkeypatch, engine, *args)
-    assert dev_spec is None
+    pair, win = engine._pack_shard(*args)
+    pair0, win0 = oracle_pack(monkeypatch, engine, *args)
     assert len(pair) == 6 and len(win) == 5
     for got, want in zip((*pair, *win), (*pair0, *win0)):
         assert got.dtype == want.dtype and got.shape == want.shape
@@ -291,8 +290,8 @@ def test_launch_puts_the_shards_arrays_as_they_were_written(
     assert metrics.counter("consensus.lane_rows_copied") - copied0 \
         == columnar
     B, nWp = launch["B"], launch["nWp"]
-    pair0, win0, _ = oracle_pack(monkeypatch, engine, items, LQ, B, nWp,
-                                 64, overrides)
+    pair0, win0 = oracle_pack(monkeypatch, engine, items, LQ, B, nWp,
+                              64, overrides)
     n, qpw, win_of, real = (np.asarray(a) for a in launch["static"])
     bg, ed, bcodes, bweights, blen, covs, ever = (
         np.asarray(a) for a in launch["state"][:7])

@@ -30,6 +30,27 @@ def test_registry_selfcheck_is_clean():
     assert contracts.selfcheck() == []
 
 
+def test_a_retired_section_leaves_the_key_sets_at_its_version():
+    """``REMOVED_KEYS`` takes a key of the report itself (section
+    ``"top"``): from that version on the key is no top-level key and its
+    section has no key set; the versions before keep both, which is what
+    a stored report of theirs is held to."""
+    assert contracts.REMOVED_KEYS["dataflow"] == ("top", 16)
+    before, after = contracts.schema_keys(15), contracts.schema_keys(16)
+    assert "dataflow" in before["top"] and "dataflow" not in after["top"]
+    assert before["dataflow"] == frozenset(
+        contracts.SECTION_KEYS["dataflow"]) and "dataflow" not in after
+    assert after["top"] == before["top"] - {"dataflow"}
+    # a key retired inside a section still leaves only that section
+    assert "events" not in after["compiles"]
+    assert "events" in contracts.schema_keys(12)["compiles"]
+    # no emitter, metric or report key is left for it
+    assert "dataflow" not in contracts.SECTION_EMITTERS
+    assert not [m for m in contracts.METRICS if m.startswith("dataflow.")]
+    assert not [k for k in contracts.REPORT_BACKING
+                if k.startswith("dataflow.")]
+
+
 def test_state_machines_declare_the_lifecycles():
     job, shard, lease = (contracts.JOB_MACHINE, contracts.SHARD_MACHINE,
                          contracts.LEASE_MACHINE)
@@ -112,9 +133,9 @@ def test_contract_audit_diffs_registry_against_seen(monkeypatch, capsys):
 # report keys whose backing metric a small-but-real polish (first-party
 # overlapper, device aligner + consensus, span timers armed) MUST drive.
 # Deliberately excludes feature-gated families a CLI run never touches:
-# recovery.* (serve-only), dataflow residency (RACON_TPU_RESIDENT),
-# compile_s (jax.monitoring availability varies) and the event-
-# conditional overlap counters (join_bailouts, freq caps, cache hits).
+# recovery.* (serve-only), compile_s (jax.monitoring availability
+# varies) and the event-conditional overlap counters (join_bailouts,
+# freq caps, cache hits).
 _EXERCISED_KEYS = frozenset((
     "queue.depth", "queue.producer_wait_s", "queue.consumer_wait_s",
     "queue.stall_s",

@@ -337,6 +337,29 @@ def test_live_ledger_charges_the_feeding_threads_span(clean_trace):
     assert sec["clock"] == trace.clock() and sec["clock"]["unix_ns"] > 0
 
 
+def test_consensus_warmup_submits_only_the_loop(clean_trace):
+    """A consensus warm-up's rows in the ledger: the refinement loop of
+    each geometry it derived, as ``warm``, and no other program — what a
+    warm-up dispatches occupies the device in every job, so a dummy for
+    a program no job runs is device time taken from all of them."""
+    from racon_tpu.ops.poa import TpuPoaConsensus
+
+    trace.new_run()
+    trace.activate()
+    t0 = time.perf_counter()
+    eng = TpuPoaConsensus(3, -5, -4, mesh=None)
+    thread = eng.warmup_async(64, est_pairs=64, est_windows=8)
+    assert thread is not None
+    thread.join(timeout=300)
+    assert not thread.is_alive()
+    sec = device_time.summary(window_s=time.perf_counter() - t0)
+    warm = [row for row in sec["timeline"] if row[1] == "warm"]
+    assert warm and {row[2] for row in warm} == {"_refine_loop_packed"}
+    assert all(row[3] == thread.name for row in warm)
+    # and nothing else of the thread's reached the device's queues
+    assert [row for row in sec["timeline"] if row[3] == thread.name] == warm
+
+
 def test_off_means_off(clean_trace):
     """No report and no trace asked for: the shared no-op span, no
     ledger entry, no watcher thread started by a submission."""
@@ -462,7 +485,7 @@ def test_a_row_counts_the_exec_submissions_of_its_program_and_geometry(
 
 def test_v12_validates_and_requires_device_time():
     rep = report.build_report("cli", wall_s=0.5)
-    assert rep["schema_version"] == 15      # the section is v12's
+    assert rep["schema_version"] == 16      # the section is v12's
     assert report.validate_report(rep) == []
     broken = {k: v for k, v in rep.items() if k != "device_time"}
     assert any("device_time" in e for e in report.validate_report(broken))

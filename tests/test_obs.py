@@ -277,45 +277,47 @@ def test_report_validate_rejects_corruption():
     assert any("phases" in e for e in report.validate_report(bad))
 
 
-def test_report_v8_requires_dataflow_section():
-    """Schema v8: the resident-dataflow accounting section is required,
-    fully populated (all keys numeric, zeros with the flag off), and
-    validated key-by-key."""
-    metrics.clear("dataflow.")
-    rep = report.build_report("cli")
-    assert report.validate_report(rep) == []
-    df = rep["dataflow"]
-    for key in ("resident", "bytes_fetched", "bytes_avoided",
-                "fallback_pairs", "resident_bailouts",
-                "lanes_device_groups", "ins_overflow_windows"):
-        assert df[key] == 0, (key, df)
-    broken = dict(rep)
-    del broken["dataflow"]
-    assert any("dataflow" in e for e in report.validate_report(broken))
-    bad = dict(rep, dataflow=dict(df, bytes_fetched="lots"))
-    assert any("bytes_fetched" in e for e in report.validate_report(bad))
-    bad = dict(rep, dataflow={k: v for k, v in df.items()
-                              if k != "resident"})
-    assert any("resident" in e for e in report.validate_report(bad))
-
-    # a resident run's numbers flow through (scoped, like a job report)
+def test_report_v16_has_no_dataflow_section():
+    """Schema v16: the resident-dataflow accounting section left with
+    its path. A report built today has none and validates; the counter
+    the section mirrored is still a counter under ``metrics``."""
     metrics.set_scope("job.df1.")
     try:
-        metrics.set_gauge("dataflow.resident", 1)
-        metrics.inc("dataflow.bytes_fetched", 4096)
-        metrics.inc("dataflow.bytes_avoided", 1 << 20)
-        metrics.inc("dataflow.fallback_pairs", 3)
         metrics.inc("consensus.ins_overflow_windows", 2)
     finally:
         metrics.set_scope(None)
-    scoped = report.build_report("job", scope="job.df1.")
-    assert report.validate_report(scoped) == []
-    assert scoped["dataflow"]["resident"] == 1
-    assert scoped["dataflow"]["bytes_fetched"] == 4096
-    assert scoped["dataflow"]["bytes_avoided"] == 1 << 20
-    assert scoped["dataflow"]["fallback_pairs"] == 3
-    assert scoped["dataflow"]["ins_overflow_windows"] == 2
+    rep = report.build_report("job", scope="job.df1.")
     metrics.clear("job.df1.")
+    assert rep["schema_version"] >= 16 and "dataflow" not in rep
+    assert report.validate_report(rep) == []
+    assert rep["metrics"]["counters"]["consensus.ins_overflow_windows"] == 2
+
+
+def test_report_v16_refuses_a_dataflow_section_as_retired():
+    """A v16 report that still carries the section is refused by name
+    and version, not as an unknown key; the stored v11 report holds the
+    section, is held to it key by key, and validates as what it is."""
+    stored = json.loads((pathlib.Path(__file__).parent / "data" /
+                         "run_report_v11.json").read_bytes())
+    assert stored["schema_version"] == 11 and "dataflow" in stored
+    assert report.validate_report(stored) == []
+    rep = dict(report.build_report("cli"), dataflow=stored["dataflow"])
+    assert report.validate_report(rep) == [
+        "'dataflow' retired in schema v16"]
+    # held to ITS key sets: without the section, or with one of its
+    # keys gone or non-numeric, the v11 report is refused
+    df = stored["dataflow"]
+    broken = {k: v for k, v in stored.items() if k != "dataflow"}
+    assert any("dataflow" in e for e in report.validate_report(broken))
+    bad = dict(stored, dataflow=dict(df, bytes_fetched="lots"))
+    assert any("bytes_fetched" in e for e in report.validate_report(bad))
+    bad = dict(stored, dataflow={k: v for k, v in df.items()
+                                 if k != "resident"})
+    assert any("resident" in e for e in report.validate_report(bad))
+    # the newest stored version that held it still does
+    last = dict(report.build_report("cli"), schema_version=15,
+                dataflow=df)
+    assert report.validate_report(last) == []
 
 
 def test_report_v10_requires_overlap_section():

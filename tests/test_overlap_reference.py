@@ -82,7 +82,7 @@ def device_rows(seed: int):
     reads, draft, _ = generated(seed)
     self_t = np.full(len(reads), -1, np.int64)
     return chain.find_overlaps(reads, [draft], self_t, k=15, w=5,
-                               max_occ=64, min_seeds=4, resident=False,
+                               max_occ=64, min_seeds=4,
                                device_join=True, ragged=True)
 
 
@@ -102,7 +102,7 @@ def test_device_rows_equal_the_plain_reference(seed):
     # the stream's per-group emission is the same rows again
     parts = list(chain.iter_overlap_groups(
         reads, [draft], np.full(len(reads), -1, np.int64), k=15, w=5,
-        max_occ=64, min_seeds=4, resident=False, device_join=True))
+        max_occ=64, min_seeds=4, device_join=True))
     for key in reference.ROW_KEYS:
         assert np.array_equal(np.concatenate([p[key] for p in parts]),
                               want[key]), key

@@ -99,16 +99,6 @@ def test_minimizer_slice_boundary_dedup(monkeypatch):
     assert got == want
 
 
-def test_seed_table_resident_matches_host():
-    """The device-compaction fetch path returns the identical table to
-    the host nonzero path (order included)."""
-    rng = np.random.default_rng(14)
-    seqs = [rand_seq(rng, int(n)) for n in rng.integers(80, 1500, 6)]
-    host = table_rows(overlap_seed.build_seed_table(seqs))
-    res = table_rows(overlap_seed.build_seed_table(seqs, resident=True))
-    assert res == host
-
-
 def test_seed_table_skips_short_sequences():
     rng = np.random.default_rng(15)
     k, w = 15, 5
@@ -264,23 +254,21 @@ def test_compact_seed_rows_refuses_what_it_cannot_place():
                                  table(4), 0, 2)
 
 
-@pytest.mark.parametrize("batch, resident", [(4, False), (64, False),
-                                             (4, True)])
-def test_seed_stream_counts_its_arenas(monkeypatch, batch, resident):
+@pytest.mark.parametrize("batch", [4, 64])
+def test_seed_stream_counts_its_arenas(monkeypatch, batch):
     """``overlap.seed_arenas`` is the arenas launched;
     ``overlap.seed_arenas_ahead`` all but the first of a streamed
-    build, and none where one arena (or the resident path's own fetch)
-    leaves nothing to pack ahead of."""
+    build, and none where one arena leaves nothing to pack ahead of."""
     seqs = _stream_seqs(20)
     _small_arenas(monkeypatch, batch=batch)
     arenas = -(-len(list(overlap_seed._iter_chunks(seqs, 15, 5))) // batch)
     assert arenas == (1 if batch == 64 else 14)
     before = [metrics.counter("overlap.seed_arenas"),
               metrics.counter("overlap.seed_arenas_ahead")]
-    overlap_seed.build_seed_table(seqs, resident=resident)
+    overlap_seed.build_seed_table(seqs)
     assert metrics.counter("overlap.seed_arenas") - before[0] == arenas
     ahead = metrics.counter("overlap.seed_arenas_ahead") - before[1]
-    assert ahead == (arenas - 1 if batch == 4 and not resident else 0)
+    assert ahead == arenas - 1
 
 
 def test_seed_stream_spans_are_declared_and_workers_take_no_idle(
@@ -593,28 +581,6 @@ def test_join_prefilter_counts_and_reads_the_kept_entries(monkeypatch):
     assert (bailed, offered, kept) == (1, 0, 0)
 
 
-def test_device_join_resident_layout():
-    """Under ``resident=True`` the join also hands the matched seed
-    coordinates over on the device (``tp_dev``/``qc_dev``) for the chain
-    stream's device gather; they must equal the oracle's host
-    ``tp``/``qc`` columns."""
-    rng = np.random.default_rng(32)
-    rt = rand_table(rng, 6, 400, 150)
-    tt = rand_table(rng, 3, 400, 150)
-    self_t = np.full(6, -1, np.int64)
-    qlens = np.full(6, 5000, np.int64)
-    want, _ = reference.match_seeds(rt, tt, self_t, qlens, k=15, max_occ=32)
-    got, _ = chain.join_seeds(rt, tt, self_t, qlens, k=15, max_occ=32,
-                              device_join=True, resident=True)
-    assert "tp_dev" in got and "qc_dev" in got
-    n = got["q"].size
-    assert n == want["q"].size > 0
-    assert np.array_equal(np.asarray(got["tp_dev"])[:n].astype(np.int64),
-                          want["tp"])
-    assert np.array_equal(np.asarray(got["qc_dev"])[:n].astype(np.int64),
-                          want["qc"])
-
-
 def test_device_join_empty_side_bails_to_oracle():
     """An empty table on either side takes the counted bail-out rung —
     the oracle's trivial path, never a kernel launch."""
@@ -765,11 +731,6 @@ def test_ragged_stream_matches_barrier_rows():
             legs[(ragged, dj)] = chain.find_overlaps(
                 reads, [target], self_t, k=15, w=5,
                 ragged=ragged, device_join=dj)
-    # the resident join's device copies feed the stream's arenas
-    # (_gather_pairs_kernel): the plan is over host arrays in both
-    legs["resident"] = chain.find_overlaps(
-        reads, [target], self_t, k=15, w=5, ragged=True,
-        device_join=True, resident=True)
     base = legs[(True, True)]
     assert base["q_ord"].size > 0
     for key_leg, rows in legs.items():
@@ -844,6 +805,36 @@ def test_warmed_repeat_run_zero_new_compiles():
 
 
 # ------------------------------------------------------------- warm-up
+
+def test_held_read_table_is_keyed_by_k_and_w():
+    """The read-side table a caller holds across calls on the same reads
+    (a ``--rounds N`` job's) is keyed by what it depends on beside the
+    bytes, ``(k, w)``: built once, taken after, and a call at another
+    ``k`` builds its own."""
+    rng = np.random.default_rng(38)
+    target = rand_seq(rng, 6000)
+    reads = [target[300:2500], revcomp(target[2000:5200])]
+    self_t = np.full(len(reads), -1, np.int64)
+    held = {}
+    before = [metrics.counter("rounds.read_tables_built"),
+              metrics.counter("rounds.read_tables_reused")]
+    first = chain.find_overlaps(reads, [target], self_t, k=15, w=5,
+                                read_tables=held)
+    assert list(held) == [(15, 5)]
+    again = chain.find_overlaps(reads, [target], self_t, k=15, w=5,
+                                read_tables=held)
+    assert list(held) == [(15, 5)]
+    assert metrics.counter("rounds.read_tables_built") - before[0] == 1
+    assert metrics.counter("rounds.read_tables_reused") - before[1] == 1
+    for col in chain._ROW_KEYS:
+        assert np.array_equal(first[col], again[col]), col
+    assert first["q_ord"].size > 0
+    chain.find_overlaps(reads, [target], self_t, k=13, w=5,
+                        read_tables=held)
+    assert sorted(held) == [(13, 5), (15, 5)]
+    assert held[(15, 5)][0].size != held[(13, 5)][0].size or \
+        not np.array_equal(held[(15, 5)][0], held[(13, 5)][0])
+
 
 def test_warmup_shape_cache():
     """warmup_async compiles each (shape, k, w) geometry once per

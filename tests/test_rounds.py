@@ -267,12 +267,11 @@ def test_refused_without_a_job(capsys, argv, named):
 
 
 @pytest.mark.parametrize("name,value,named", [
-    ("RACON_TPU_RESIDENT", "1", "RACON_TPU_RESIDENT"),
     ("RACON_TPU_CHIPS", "1", "RACON_TPU_CHIPS"),
 ])
 def test_refused_environment(monkeypatch, capsys, name, value, named):
-    """The resident dataflow's copy of the reads and the chip
-    scheduler's environment switch are refused by name, not ignored."""
+    """The chip scheduler's environment switch is refused by name, not
+    ignored."""
     monkeypatch.setenv(name, value)
     with pytest.raises(SystemExit) as exc:
         cli.main(["--rounds", "2", "reads.fastq", "auto", "draft.fasta"])

@@ -1,5 +1,5 @@
 """Seeded schema-coherence violations: ``queue_summary`` emits an
-unknown key and drops a required one; ``dataflow_summary`` drops a
+unknown key and drops a required one; ``recovery_summary`` drops a
 required key."""
 
 
@@ -12,12 +12,14 @@ def queue_summary():
     }
 
 
-def dataflow_summary():
+def recovery_summary():
     return {
-        "resident": True,
-        "bytes_fetched": 0,
-        "bytes_avoided": 0,
-        "fallback_pairs": 0,
-        "ins_overflow_windows": 0,
-        "lanes_device_groups": 0,
+        "recovered_jobs": 0,
+        "requeued_jobs": 0,
+        "served_from_spool": 0,
+        "spool_corrupt": 0,
+        "journal_replayed": 0,
+        "journal_records": 0,
+        "journal_compactions": 0,
+        "slot_restarts": 0,
     }
